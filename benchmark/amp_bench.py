@@ -87,6 +87,8 @@ def _run(units, layers, dp, data, label, steps, skip):
 
 
 def main(argv=None):
+    from mxnet_tpu.base import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--steps", type=int, default=12)
     ap.add_argument("--skip", type=int, default=2)

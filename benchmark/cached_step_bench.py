@@ -79,6 +79,8 @@ def _run(n_layers, units, optimizer, opt_args, steps, cached):
 
 
 def main():
+    from mxnet_tpu.base import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--units", type=int, default=64)

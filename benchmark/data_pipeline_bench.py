@@ -112,6 +112,8 @@ def _run(net, trainer, source, skip):
 
 
 def main(argv=None):
+    from mxnet_tpu.base import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     # default sizing note: on the CPU backend the producer's device_put
     # shares XLA's intra-op thread pool with the step compute, so very

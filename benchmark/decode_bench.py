@@ -171,6 +171,8 @@ def run_spec_identity(model, prompts, max_new, *, slots, pages, page_size,
 
 
 def main():
+    from mxnet_tpu.base import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--vocab", type=int, default=128)
     ap.add_argument("--dim", type=int, default=64)

@@ -111,6 +111,8 @@ def train(emb, it, w, b, lr, steps_cap):
 
 
 def main(argv=None):
+    from mxnet_tpu.base import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--smoke", action="store_true",
                     help="CI-sized run (smaller table, fewer steps)")

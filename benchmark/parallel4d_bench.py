@@ -114,6 +114,8 @@ def _median(vals):
 
 
 def main(argv=None):
+    from mxnet_tpu.base import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--windows", type=int, default=12)
     ap.add_argument("--window-steps", type=int, default=4)

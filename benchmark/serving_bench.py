@@ -192,6 +192,8 @@ def run_open(net, units, max_batch, max_delay_ms, rate_rps, requests):
 
 
 def main():
+    from mxnet_tpu.base import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--units", type=int, default=64)
     ap.add_argument("--layers", type=int, default=3)

@@ -26,8 +26,18 @@ opperf_smoke() {      # operator micro-bench sanity (CPU)
         --ops exp,dot,Convolution,FullyConnected,softmax --runs 3 --warmup 1
 }
 
-bench() {             # the driver benchmark (real TPU when present)
+bench() {             # the benchmark rows (a TPU, or it fails)
     python bench.py
+}
+
+chip_smoke() {        # main path end to end on one TPU (or it fails)
+    python chip_smoke.py "$@"
+}
+
+chip_smoke_rehearsal() {  # the smoke's control flow on the CPU, tiny
+    # a rehearsal always ends ok:false / exit 1, so second-tier tests
+    # assert on its phase lines instead (tests/test_chip_smoke.py)
+    JAX_PLATFORMS=cpu python -m pytest tests/test_chip_smoke.py -q -m slow
 }
 
 sanitize() {          # import + compile sanity, no test run

@@ -22,12 +22,6 @@ os.environ.setdefault("XLA_FLAGS",
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    from jax.extend.backend import clear_backends
-    if jax._src.xla_bridge.backends_are_initialized():
-        clear_backends()
-except Exception:
-    pass
 
 import jax.numpy as jnp
 import numpy as onp

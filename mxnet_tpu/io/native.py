@@ -5,9 +5,12 @@ threaded ImageRecordIter pipeline (src/io/iter_image_recordio_2.cc:887)
 — implemented in C++ (src_native/) and consumed here the way the
 reference's Python consumes libmxnet via ctypes (python/mxnet/base.py).
 
-The library is built lazily (`make -C src_native`) on first use when a
-toolchain is present; callers should catch MXNetError and fall back to
-the pure-Python recordio path.
+The library is git-ignored, so what is loaded must come from the
+tracked sources: the first use in a process runs `make -C src_native`,
+which rebuilds when a source or the Makefile is newer than the binary
+and is a no-op otherwise.  A `make` that cannot run raises MXNetError
+rather than loading whatever binary is lying there; callers without a
+toolchain catch it and take the pure-Python recordio path.
 """
 from __future__ import annotations
 
@@ -35,16 +38,18 @@ def _build():
                        capture_output=True, timeout=120)
     except (subprocess.CalledProcessError, FileNotFoundError,
             subprocess.TimeoutExpired) as e:
-        raise MXNetError(f"building libmxtpu_io failed: {e}") from e
+        err = (getattr(e, "stderr", None) or b"").decode(errors="replace")
+        raise MXNetError(
+            f"building libmxtpu_io failed: {e}\n{err[-2000:]}") from e
 
 
 def get_lib() -> ctypes.CDLL:
-    """Load (building if needed) the native IO library."""
+    """Bring the native IO library up to date with its sources and
+    load it."""
     global _LIB
     if _LIB is not None:
         return _LIB
-    if not os.path.exists(_LIB_PATH):
-        _build()
+    _build()
     lib = ctypes.CDLL(_LIB_PATH)
     # writer
     lib.mxtpu_rec_writer_open.restype = ctypes.c_void_p

@@ -58,8 +58,9 @@ class KernelSpec:
     ``run(config, *arrays, **params)``
         execute the Pallas implementation under ``config``.
     ``fallback(*arrays, **params)``
-        the XLA lowering — the production fallback when the Pallas path
-        can't run, and the numerics oracle parity tests compare against.
+        the XLA lowering — the numerics oracle parity tests and the
+        chip smoke compare against.  No call site switches to it when
+        the Pallas path fails to build.
     ``signature(*arrays, **params) -> (sig, dtype)``
         bucketed shape signature + dtype string for the cache key.
     ``make_args(case) -> (arrays, params)``
@@ -247,8 +248,9 @@ def warm_cache() -> int:
 
 
 def record_fallback(name: str) -> None:
-    """Account one dispatch that took the XLA fallback instead of the
-    registered Pallas path (build/lowering failure, unsupported case)."""
+    """Account one dispatch that ran a registered kernel's XLA lowering
+    in place of its Pallas path.  Only a caller's explicit choice does
+    that; a Pallas path that fails to build raises."""
     _C_FALLBACKS.inc()
     telemetry.counter(f"kernel.{name}.fallbacks").inc()
 

@@ -7,11 +7,13 @@ computes the sum, the row statistics (f32), and the affine output —
 the residual sum never hits HBM.
 
 Second registrant of the kernel registry (``mxnet_tpu.kernels``): the
-tunable config is the row-block size; the XLA fallback below is both
-the production escape hatch (``kernel.fallbacks`` ticks when the
-Pallas path can't build) and the numerics oracle the parity tests pin
-the kernel against.  Backward recomputes through ``jax.vjp`` of the
-fallback — the standard recompute-from-inputs flash-style trade.
+tunable config is the row-block size; the XLA lowering below is the
+numerics oracle the parity tests pin the kernel against, and runs in
+production only when a caller asks for it (``use_pallas=False``, which
+ticks ``kernel.fallbacks``).  A Pallas path that cannot build is an
+error, never a quiet switch to XLA.  Backward recomputes through
+``jax.vjp`` of the oracle — the standard recompute-from-inputs
+flash-style trade.
 """
 from __future__ import annotations
 
@@ -157,21 +159,17 @@ def layer_norm_residual(x, residual, gamma, beta, *, eps=1e-5,
 
     Shapes: ``x``/``residual`` (..., F), ``gamma``/``beta`` (F,).
     The Pallas path resolves its row-block size through the kernel
-    registry; any failure to build falls back to the XLA lowering and
-    ticks ``kernel.fallbacks`` — numerics are identical by the oracle
-    contract either way.
+    registry and raises if it cannot build; ``use_pallas=False`` runs
+    the XLA lowering instead and ticks ``kernel.fallbacks``.
     """
     if x.shape != residual.shape:
         raise ValueError(
             f"x {x.shape} and residual {residual.shape} must match")
     if not use_pallas:
+        _kernels.record_fallback("layer_norm_residual")
         return _lnr_kernel_fallback(x, residual, gamma, beta, eps=eps)
     sig, dt = _lnr_signature(x, residual, gamma, beta)
     args = (x, residual, gamma, beta)
     cfg = _kernels.resolve("layer_norm_residual", sig, dt,
                            tune_args=(args, {"eps": eps}))
-    try:
-        return _lnr_kernel_run(cfg, x, residual, gamma, beta, eps=eps)
-    except Exception:
-        _kernels.record_fallback("layer_norm_residual")
-        return _lnr_kernel_fallback(x, residual, gamma, beta, eps=eps)
+    return _lnr_kernel_run(cfg, x, residual, gamma, beta, eps=eps)

@@ -1,11 +1,13 @@
 """Paged attention: one query token per slot over a paged KV pool.
 
 The decode serving plane (serving/decode/) keeps every slot's KV
-history in a pre-allocated page pool ``(num_pages, page_size, H, D)``
-plus a per-slot page table ``(max_slots, pages_per_slot)`` — sequence
-state lives behind traced integer indices, so one compiled
-``decode_step`` serves any mix of lengths (the fixed-shape-executable
-invariant, docs/ARCHITECTURE.md "Decode serving").
+history in a pre-allocated page pool ``(num_pages, page_size, H*D)``
+(heads folded into the lane axis; a ``(num_pages, page_size, H, D)``
+pool is accepted and viewed the same way) plus a per-slot page table
+``(max_slots, pages_per_slot)`` — sequence state lives behind traced
+integer indices, so one compiled ``decode_step`` serves any mix of
+lengths (the fixed-shape-executable invariant, docs/ARCHITECTURE.md
+"Decode serving").
 
 The Pallas path rides ``PrefetchScalarGridSpec``: the page table and
 per-slot lengths are scalar-prefetched, and the K/V BlockSpec index
@@ -17,9 +19,10 @@ dims; pages wholly past a slot's length are skipped via ``pl.when``.
 Slots with length 0 (inactive) produce exact zeros, matching the
 oracle.
 
-The XLA fallback (:func:`paged_attention_reference`) gathers
+The XLA lowering (:func:`paged_attention_reference`) gathers
 ``pool[tables]`` and runs a masked softmax — the numerics oracle the
-parity tests pin the kernel against across ragged lengths.
+parity tests pin the kernel against across ragged lengths.  No call
+site switches to it.
 """
 from __future__ import annotations
 
@@ -39,7 +42,6 @@ from .registry import register
 __all__ = ["paged_attention", "paged_attention_reference"]
 
 _NEG_INF = -1e30
-_ACC_LANES = 128            # m/l scratch lane broadcast (TPU tiling)
 
 _PAGED_ENV_KEY = "MXNET_TPU_PAGED_BLOCK_K"
 _paged_env_snapshot: tuple = (False,)          # impossible sentinel
@@ -47,8 +49,9 @@ _paged_env_snapshot: tuple = (False,)          # impossible sentinel
 
 def paged_attention_reference(q, k_pool, v_pool, tables, lengths,
                               sm_scale=None):
-    """Gather-based oracle: q (S, H, D), pools (pages, ps, H, D),
-    tables (S, P) int32, lengths (S,) int32 → (S, H, D).  Positions at
+    """Gather-based oracle: q (S, H, D), pools (pages, ps, H*D) or
+    (pages, ps, H, D), tables (S, P) int32, lengths (S,) int32 →
+    (S, H, D).  Positions at
     or past a slot's length are masked; length-0 slots yield zeros."""
     s_, h, d = q.shape
     ps = k_pool.shape[1]
@@ -68,8 +71,16 @@ def paged_attention_reference(q, k_pool, v_pool, tables, lengths,
     return out.astype(q.dtype)
 
 
-def _pa_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
+def _pa_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, seg_ref, o_ref,
                acc_ref, m_ref, l_ref, *, sm_scale, block_k, page_size):
+    """Heads stay folded into the lane axis: every operand is a 2-D
+    ``(rows, H*D)`` or ``(rows, H)`` tile.  ``seg (H, H*D)`` is the 0/1
+    head-membership matrix; a matmul against it is the per-head lane
+    reduction (scores) or lane broadcast (probabilities, running
+    statistics).  The TPU compiler refuses the batched-over-heads form
+    (no free lhs dim for one query row; ``(block_k, H, D)`` tiles need
+    a sublane<->major shape cast that bf16 packing rules out), and this
+    form needs no transpose or reshape for any (H, D, dtype)."""
     s_i = pl.program_id(0)
     p_i = pl.program_id(1)
     b_i = pl.program_id(2)
@@ -85,68 +96,83 @@ def _pa_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     length = len_ref[s_i]
     start = p_i * page_size + b_i * block_k
 
+    def per_head(x, contract_lanes):
+        # contract_lanes: (rows, H*D) -> (rows, H); else (rows, H) ->
+        # (rows, H*D).  HIGHEST keeps the f32 operands exact on the MXU.
+        dims = (((1,), (1 if contract_lanes else 0,)), ((), ()))
+        return lax.dot_general(x, seg_ref[...], dims,
+                               precision=lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
     @pl.when(start < length)
     def _body():
-        q = q_ref[0]                              # (H, D)
-        kt = jnp.swapaxes(k_ref[0], 0, 1)         # (H, block_k, D)
-        vt = jnp.swapaxes(v_ref[0], 0, 1).astype(jnp.float32)
-        s = lax.dot_general(q, kt, (((1,), (2,)), ((0,), (0,))),
-                            preferred_element_type=jnp.float32) * sm_scale
-        kpos = start + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        q = q_ref[0].astype(jnp.float32)          # (1, H*D)
+        k = k_ref[0].astype(jnp.float32)          # (block_k, H*D)
+        v = v_ref[0].astype(jnp.float32)
+        s = per_head(k * q, True) * sm_scale      # (block_k, H)
+        kpos = start + lax.broadcasted_iota(jnp.int32, s.shape, 0)
         mask = kpos < length
         s = jnp.where(mask, s, _NEG_INF)
-        m_prev = m_ref[:, :1]                     # (H, 1)
-        m_cur = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        m_prev = m_ref[...]                       # (1, H)
+        m_cur = jnp.maximum(m_prev, s.max(axis=0, keepdims=True))
         corr = jnp.exp(m_prev - m_cur)
         p = jnp.where(mask, jnp.exp(s - m_cur), 0.0)
-        l_new = l_ref[:, :1] * corr + p.sum(axis=1, keepdims=True)
-        pv = lax.dot_general(p, vt, (((1,), (1,)), ((0,), (0,))),
-                             preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * corr + pv
-        m_ref[...] = jnp.broadcast_to(m_cur, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=0, keepdims=True)
+        m_ref[...] = m_cur
+        pv = (per_head(p, False) * v).sum(axis=0, keepdims=True)
+        acc_ref[...] = acc_ref[...] * per_head(corr, False) + pv
 
     @pl.when((p_i == np_ - 1) & (b_i == nb - 1))
     def _finish():
-        l = l_ref[:, :1]
+        l = l_ref[...]
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / per_head(l, False)).astype(o_ref.dtype)
 
 
 def _paged_attention_pallas(q, k_pool, v_pool, tables, lengths,
                             sm_scale, block_k):
     s_, h, d = q.shape
-    page_size = k_pool.shape[1]
+    num_pages, page_size = k_pool.shape[:2]
     p_ = tables.shape[1]
-    block_k = max(1, min(int(block_k), page_size))
-    block_k = math.gcd(block_k, page_size)    # must tile the page
+    hd = h * d
+    block_k = math.gcd(max(1, int(block_k)), page_size)   # tiles the page
+    if block_k % 8:
+        # a block's rows are a multiple of the 8-sublane tile or the
+        # whole page (the TPU block-shape rule)
+        block_k = page_size
     kernel = functools.partial(
         _pa_kernel, sm_scale=float(sm_scale), block_k=block_k,
         page_size=page_size)
+    seg = (jnp.arange(hd, dtype=jnp.int32)[None, :] // d
+           == jnp.arange(h, dtype=jnp.int32)[:, None]).astype(jnp.float32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(s_, p_, page_size // block_k),
         in_specs=[
-            pl.BlockSpec((1, h, d), lambda s, p, b, tbl, ln: (s, 0, 0)),
-            pl.BlockSpec((1, block_k, h, d),
-                         lambda s, p, b, tbl, ln: (tbl[s, p], b, 0, 0)),
-            pl.BlockSpec((1, block_k, h, d),
-                         lambda s, p, b, tbl, ln: (tbl[s, p], b, 0, 0)),
+            pl.BlockSpec((1, 1, hd), lambda s, p, b, tbl, ln: (s, 0, 0)),
+            pl.BlockSpec((1, block_k, hd),
+                         lambda s, p, b, tbl, ln: (tbl[s, p], b, 0)),
+            pl.BlockSpec((1, block_k, hd),
+                         lambda s, p, b, tbl, ln: (tbl[s, p], b, 0)),
+            pl.BlockSpec((h, hd), lambda s, p, b, tbl, ln: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, h, d),
+        out_specs=pl.BlockSpec((1, 1, hd),
                                lambda s, p, b, tbl, ln: (s, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((h, d), jnp.float32),
-            pltpu.VMEM((h, _ACC_LANES), jnp.float32),
-            pltpu.VMEM((h, _ACC_LANES), jnp.float32),
+            pltpu.VMEM((1, hd), jnp.float32),
+            pltpu.VMEM((1, h), jnp.float32),
+            pltpu.VMEM((1, h), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s_, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((s_, 1, hd), q.dtype),
         interpret=jax.default_backend() != "tpu",
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      q, k_pool, v_pool)
+      q.reshape(s_, 1, hd),
+      k_pool.reshape(num_pages, page_size, hd),
+      v_pool.reshape(num_pages, page_size, hd), seg)
+    return out.reshape(s_, h, d)
 
 
 # -- kernel-registry integration -------------------------------------------
@@ -183,8 +209,9 @@ def _paged_make_args(case):
     dtype = case.get("dtype", "float32")
     num_pages = slots * pps + 1
     q = jnp.asarray(rng.randn(slots, h, d) * 0.5, dtype=dtype)
-    k_pool = jnp.asarray(rng.randn(num_pages, ps, h, d) * 0.5, dtype=dtype)
-    v_pool = jnp.asarray(rng.randn(num_pages, ps, h, d) * 0.5, dtype=dtype)
+    # the serving pool's layout: heads folded into the lane axis
+    k_pool = jnp.asarray(rng.randn(num_pages, ps, h * d) * 0.5, dtype=dtype)
+    v_pool = jnp.asarray(rng.randn(num_pages, ps, h * d) * 0.5, dtype=dtype)
     tables = jnp.asarray(
         rng.permutation(num_pages - 1)[:slots * pps].reshape(slots, pps),
         jnp.int32)
@@ -196,7 +223,7 @@ def _paged_make_args(case):
 
 
 _kernels.register_kernel(_kernels.KernelSpec(
-    "paged_attention", version=1,
+    "paged_attention", version=2,
     run=_paged_kernel_run, fallback=_paged_kernel_fallback,
     config_space={"block_k": (16, 32, 64, 128)},
     default_config={"block_k": 64},
@@ -234,7 +261,8 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
     """One attention step per slot against its paged KV history.
 
     ``q (slots, H, D)`` — one query token per slot; ``k_pool/v_pool
-    (num_pages, page_size, H, D)``; ``tables (slots, pages_per_slot)``
+    (num_pages, page_size, H*D)`` or ``(num_pages, page_size, H, D)``;
+    ``tables (slots, pages_per_slot)``
     int32 page ids; ``lengths (slots,)`` int32 valid context lengths
     (0 = inactive slot → zero output)."""
     scale = (sm_scale if sm_scale is not None

@@ -11,8 +11,8 @@ with ``x1/x2`` the two halves.  The fused path computes angles from an
 in-kernel iota (no host-materialized cos/sin tables) and streams
 ``(block_r, H, D)`` row blocks through VMEM; positions cross the
 boundary lane-broadcast like flash attention's lse (attention.py
-``_LSE_LANES``).  The XLA lowering (:func:`rope_reference`) is both
-the production fallback and the numerics oracle tests pin against.
+``_LSE_LANES``).  The XLA lowering (:func:`rope_reference`) is the
+numerics oracle tests pin against; no call site switches to it.
 
 Registered through ``mxnet_tpu.kernels`` as ``rope`` with a block-size
 config space; the decode serving plane (serving/decode/) applies it to
@@ -45,7 +45,7 @@ _rope_env_snapshot: tuple = (False,)          # impossible sentinel
 
 def rope_reference(x, positions, base=10000.0):
     """XLA RoPE on ``x (..., H, D)`` with ``positions`` shaped like
-    ``x.shape[:-2]`` (or scalar) — fallback and oracle."""
+    ``x.shape[:-2]`` (or scalar) — the oracle."""
     d = x.shape[-1]
     half = d // 2
     xf = x.astype(jnp.float32)
@@ -55,15 +55,20 @@ def rope_reference(x, positions, base=10000.0):
     inv = jnp.exp(k * (-math.log(base) / half))           # base^(-2i/D)
     ang = pos * inv                                       # (..., 1, half)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = xf[..., :half], xf[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin,
-                            x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+    # the halves as a (2, half) axis pair, not a slice + concatenate on
+    # the lane axis: for float32 (r, 8, 64) that form aborts the TPU
+    # compiler (libtpu 0.0.34, "Check failed: IsFusibleUnalignedDUS")
+    xr = xf.reshape(xf.shape[:-1] + (2, half))
+    x1, x2 = xr[..., 0, :], xr[..., 1, :]
+    out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-2)
+    return out.reshape(x.shape).astype(x.dtype)
 
 
 def _rope_kernel(x_ref, pos_ref, o_ref, *, base, half):
     x = x_ref[...].astype(jnp.float32)        # (block_r, H, D)
     pos = pos_ref[:, :1]                      # (block_r, 1): lane 0
-    k = lax.broadcasted_iota(jnp.float32, (1, 1, half), 2)
+    # the TPU iota is integer-only; cast after
+    k = lax.broadcasted_iota(jnp.int32, (1, 1, half), 2).astype(jnp.float32)
     inv = jnp.exp(k * (-math.log(base) / half))
     ang = pos[:, :, None] * inv               # (block_r, 1, half)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
@@ -77,12 +82,24 @@ def _ceil_to(x, m):
     return (x + m - 1) // m * m
 
 
+# one f32 (block_r, H, D) temporary, tile-padded, may take this much of
+# the 16 MiB scoped VMEM: the kernel keeps about ten alive (x, halves,
+# cos/sin, out, double-buffered blocks).  Found by compiling for a v5e:
+# 2 MiB is refused, 1 MiB passes at every (H, D) tried.
+_BLOCK_BYTES_MAX = 1 << 20
+
+
+def _max_block_r(h, d):
+    row_bytes = 4 * _ceil_to(h, 8) * _ceil_to(d, 128)
+    return max(8, _BLOCK_BYTES_MAX // row_bytes // 8 * 8)
+
+
 def _rope_pallas(x, positions, base, block_r):
     """x (R, H, D), positions (R,) → rotated (R, H, D)."""
     r, h, d = x.shape
     if d % 2:
         raise ValueError(f"rope requires an even head_dim, got {d}")
-    block_r = max(1, min(block_r, _ceil_to(r, 8)))
+    block_r = max(1, min(block_r, _ceil_to(r, 8), _max_block_r(h, d)))
     pad = _ceil_to(r, block_r) - r
     pos = jnp.asarray(positions).astype(jnp.float32)
     if pad:

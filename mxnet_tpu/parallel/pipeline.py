@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec
-from ._shard_map_compat import shard_map
+from jax import shard_map
 
 __all__ = ["gpipe_apply", "pipeline_forward", "interleaved_apply",
            "pipeline_forward_interleaved", "pipeline_forward_1f1b",

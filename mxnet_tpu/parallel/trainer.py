@@ -54,7 +54,7 @@ class SPMDTrainer:
         # device-side input preprocessing: a jittable fn applied to each
         # step's data INSIDE the compiled step.  Lets the input pipeline
         # ship compact dtypes (uint8 pixels at 1/4 the f32 bytes over
-        # PCIe/ICI/tunnel) and do normalize/transpose on-chip, where it
+        # PCIe/ICI) and do normalize/transpose on-chip, where it
         # fuses into the first conv.  (The reference bakes mean/std into
         # its C++ iter on the HOST — iter_image_recordio_2.cc normalize —
         # which quadruples the host->device transfer; on TPU the wire is
@@ -615,12 +615,12 @@ class SPMDTrainer:
 
     @staticmethod
     def _put(arr, sharding):
-        """Reshard ``arr`` onto ``sharding`` if it is committed
-        elsewhere (an NDArray input is committed to one device at
-        construction; jit with in_shardings rejects the mismatch
-        rather than auto-resharding).  No-op when already placed."""
-        cur = getattr(arr, "sharding", None)
-        if cur == sharding or not getattr(arr, "_committed", False):
+        """Place ``arr`` under ``sharding``.  No-op when already
+        there.  An uncommitted array is placed too: an array's mesh is
+        part of its traced type (jax >= 0.7), so a step first called
+        on unplaced parameters and then on its own mesh-placed outputs
+        traced and compiled twice."""
+        if getattr(arr, "sharding", None) == sharding:
             return arr
         return jax.device_put(arr, sharding)
 
@@ -977,35 +977,47 @@ class SPMDTrainer:
                     for k in self._pkeys]
         return NDArray(jitted(p_arrays, d))
 
-    def cost_analysis(self, data, label, n_steps=None):
-        """XLA cost analysis (flops/bytes) for the compiled step that
-        matches ``(data, label)``'s signature.  Used by bench.py for MFU
-        accounting; the step must have been run at least once.
-
-        Note: the AOT ``lower().compile()`` path does not share the jit
-        call cache, so this costs one extra compile per signature (a
-        disk hit when ``jax_compilation_cache_dir`` is set, as bench.py
-        does); the result is memoized."""
+    @staticmethod
+    def _step_sig(data, label, n_steps=None):
+        """``(data array, label array, step-cache key)`` of a call."""
         d = data._data if isinstance(data, NDArray) else jnp.asarray(data)
         l = label._data if isinstance(label, NDArray) else jnp.asarray(label)
         sig = (d.shape, str(d.dtype), l.shape, str(l.dtype))
         if n_steps is not None:
             sig = sig + (int(n_steps), False)
+        return d, l, sig
+
+    def compiled_step(self, data, label, n_steps=None):
+        """The AOT-compiled executable of the step that matches
+        ``(data, label)``'s signature (``as_text()`` is its HLO,
+        collectives included); the step must have been run at least
+        once.
+
+        Note: the AOT ``lower().compile()`` path does not share the jit
+        call cache, so this costs one extra compile per signature (a
+        disk hit when JAX's persistent compilation cache is on, see
+        ``base.use_compile_cache``)."""
+        d, l, sig = self._step_sig(data, label, n_steps)
+        jitted, _ = self._step_cache[sig]
+        if not hasattr(jitted, "lower"):
+            return jitted       # already AOT (artifact store on)
+        p_arrays = [self._params[k].data()._data for k in self._pkeys]
+        opt_state = [self._opt_state[k] for k in self._pkeys]
+        args = (next_key(), jnp.float32(self.optimizer.learning_rate),
+                jnp.float32(self.optimizer.wd), p_arrays, opt_state, d, l)
+        if self._amp_scaler is not None:
+            args = args + (self._amp_state_in(),)
+        return jitted.lower(*args).compile()
+
+    def cost_analysis(self, data, label, n_steps=None):
+        """XLA cost analysis (flops/bytes) for the compiled step that
+        matches ``(data, label)``'s signature (see
+        :meth:`compiled_step`); the result is memoized."""
+        sig = self._step_sig(data, label, n_steps)[2]
         cached = getattr(self, "_cost_cache", {}).get(sig)
         if cached is not None:
             return cached
-        jitted, _ = self._step_cache[sig]
-        p_arrays = [self._params[k].data()._data for k in self._pkeys]
-        opt_state = [self._opt_state[k] for k in self._pkeys]
-        lr = jnp.float32(self.optimizer.learning_rate)
-        wd = jnp.float32(self.optimizer.wd)
-        if self._amp_scaler is not None:
-            compiled = jitted.lower(next_key(), lr, wd, p_arrays,
-                                    opt_state, d, l,
-                                    self._amp_state_in()).compile()
-        else:
-            compiled = jitted.lower(next_key(), lr, wd, p_arrays,
-                                    opt_state, d, l).compile()
+        compiled = self.compiled_step(data, label, n_steps)
         ca = compiled.cost_analysis()
         if isinstance(ca, (list, tuple)):
             ca = ca[0] if ca else {}

@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
-from ._shard_map_compat import shard_map
+from jax import shard_map
 
 from ..ops.attention import attention_reference
 

@@ -28,6 +28,7 @@ flash attention in training.
 """
 from __future__ import annotations
 
+import functools
 import os
 import time
 from typing import Any, Dict, List, Optional, Sequence
@@ -68,6 +69,39 @@ def _rms(x, g, eps=1e-6):
     xf = x.astype(jnp.float32)
     scale = lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
     return (xf * scale).astype(x.dtype) * g
+
+
+@functools.partial(jax.jit, static_argnames=("n_heads", "rope_base"))
+def _dense_logits_last(params, tokens, *, n_heads, rope_base):
+    """Last-position logits of a dense causal forward over the whole
+    sequence — the O(T^2) full-recompute oracle the paged path is
+    pinned to.  One program per sequence length (the parameters are an
+    argument, not constants)."""
+    t = tokens.shape[0]
+    dim = params["embed"].shape[1]
+    hd = dim // n_heads
+    pos = jnp.arange(t, dtype=jnp.int32)
+    x = params["embed"][tokens]
+    scale = 1.0 / (hd ** 0.5)
+    for lp in params["layers"]:
+        h1 = _rms(x, lp["ln1"])
+        q = rope_reference((h1 @ lp["wq"]).reshape(t, n_heads, hd), pos,
+                           base=rope_base)
+        k = rope_reference((h1 @ lp["wk"]).reshape(t, n_heads, hd), pos,
+                           base=rope_base)
+        v = (h1 @ lp["wv"]).reshape(t, n_heads, hd)
+        s = jnp.einsum("qhd,khd->hqk", q.astype(jnp.float32),
+                       k.astype(jnp.float32)) * scale
+        qp = lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        kp = lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        s = jnp.where(qp >= kp, s, _NEG_INF)
+        p = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("hqk,khd->qhd", p, v.astype(jnp.float32))
+        x = x + o.reshape(t, dim).astype(x.dtype) @ lp["wo"]
+        h2 = _rms(x, lp["ln2"])
+        x = x + jax.nn.gelu(h2 @ lp["w1"]) @ lp["w2"]
+    x = _rms(x, params["lnf"])
+    return x[-1] @ params["embed"].T
 
 
 class DecodeModel:
@@ -120,32 +154,10 @@ class DecodeModel:
     # -- dense full-recompute oracle (tests pin the paged path to it) --------
 
     def _ref_logits_last(self, tokens):
-        """Last-position logits of a dense causal forward over the
-        whole sequence — O(T^2) recompute, eager, test-only."""
-        t = tokens.shape[0]
-        pos = jnp.arange(t, dtype=jnp.int32)
-        x = self.params["embed"][tokens]
-        h_, hd = self.n_heads, self.head_dim
-        scale = 1.0 / (hd ** 0.5)
-        for lp in self.params["layers"]:
-            h1 = _rms(x, lp["ln1"])
-            q = rope_reference((h1 @ lp["wq"]).reshape(t, h_, hd), pos,
-                               base=self.rope_base)
-            k = rope_reference((h1 @ lp["wk"]).reshape(t, h_, hd), pos,
-                               base=self.rope_base)
-            v = (h1 @ lp["wv"]).reshape(t, h_, hd)
-            s = jnp.einsum("qhd,khd->hqk", q.astype(jnp.float32),
-                           k.astype(jnp.float32)) * scale
-            qp = lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            kp = lax.broadcasted_iota(jnp.int32, s.shape, 2)
-            s = jnp.where(qp >= kp, s, _NEG_INF)
-            p = jax.nn.softmax(s, axis=-1)
-            o = jnp.einsum("hqk,khd->qhd", p, v.astype(jnp.float32))
-            x = x + o.reshape(t, self.dim).astype(x.dtype) @ lp["wo"]
-            h2 = _rms(x, lp["ln2"])
-            x = x + jax.nn.gelu(h2 @ lp["w1"]) @ lp["w2"]
-        x = _rms(x, self.params["lnf"])
-        return x[-1] @ self.params["embed"].T
+        """Last-position logits of the dense oracle for ``tokens``."""
+        return _dense_logits_last(self.params, tokens,
+                                  n_heads=self.n_heads,
+                                  rope_base=self.rope_base)
 
     def greedy_reference(self, prompt, max_new_tokens: int,
                          eos: Optional[int] = None) -> List[int]:
@@ -169,13 +181,16 @@ def _write_kv(pool, li, idx, k, v):
     """Scatter this step's K/V rows into layer ``li``'s page pool.
     ``idx`` carries the flat (page*page_size + offset) position per
     row, with out-of-range sentinels for masked rows (mode='drop')."""
-    layers, _, num_pages, ps, h_, hd = pool.shape
-    kflat = pool[li, 0].reshape(num_pages * ps, h_, hd)
-    vflat = pool[li, 1].reshape(num_pages * ps, h_, hd)
-    kflat = kflat.at[idx].set(k.astype(pool.dtype), mode="drop")
-    vflat = vflat.at[idx].set(v.astype(pool.dtype), mode="drop")
-    pool = pool.at[li, 0].set(kflat.reshape(num_pages, ps, h_, hd))
-    return pool.at[li, 1].set(vflat.reshape(num_pages, ps, h_, hd))
+    _, _, num_pages, ps, hd = pool.shape
+    rows = k.shape[0]
+    kflat = pool[li, 0].reshape(num_pages * ps, hd)
+    vflat = pool[li, 1].reshape(num_pages * ps, hd)
+    kflat = kflat.at[idx].set(k.reshape(rows, hd).astype(pool.dtype),
+                              mode="drop")
+    vflat = vflat.at[idx].set(v.reshape(rows, hd).astype(pool.dtype),
+                              mode="drop")
+    pool = pool.at[li, 0].set(kflat.reshape(num_pages, ps, hd))
+    return pool.at[li, 1].set(vflat.reshape(num_pages, ps, hd))
 
 
 def _decode_core(mdl: DecodeModel, params, pool, tokens, positions,
@@ -259,14 +274,17 @@ def _draft_core(mdl: DecodeModel, params, pool, tokens, base_pos,
                 tables, active, k: int):
     """k+1 chained draft decode steps (unrolled — ``k`` is static):
     proposes k tokens and leaves the draft pool position-aligned with
-    the target's write window (positions base..base+k)."""
+    the target's write window (positions base..base+k).  Returns the
+    verify window ``(S, k+1)``: the input token then the k proposals,
+    assembled here so no eager op (and no compile outside warm-up)
+    sits between the draft and verify dispatches."""
     tok = tokens
     outs = []
     for j in range(k + 1):
         pool, tok = _decode_core(mdl, params, pool, tok, base_pos + j,
                                  tables, active)
         outs.append(tok)
-    return pool, jnp.stack(outs[:k], axis=1)          # (S, k)
+    return pool, jnp.stack([tokens] + outs[:k], axis=1)   # (S, k+1)
 
 
 def _prefill_core(mdl: DecodeModel, params, pool, tokens, start,
@@ -361,14 +379,16 @@ class DecodeEngine:
             layers=model.n_layers, num_pages=self.num_pages,
             page_size=self.page_size, heads=model.n_heads,
             head_dim=model.head_dim, max_slots=self.max_slots,
-            pages_per_slot=pages_per_slot)
+            pages_per_slot=pages_per_slot,
+            dtype=model.params["embed"].dtype)
         self.draft_cache = None
         if draft_model is not None:
             self.draft_cache = PagedKVCache(
                 layers=draft_model.n_layers, num_pages=self.num_pages,
                 page_size=self.page_size, heads=draft_model.n_heads,
                 head_dim=draft_model.head_dim, max_slots=self.max_slots,
-                pages_per_slot=self.cache.pages_per_slot)
+                pages_per_slot=self.cache.pages_per_slot,
+                dtype=draft_model.params["embed"].dtype)
         self._exec: Dict[str, Any] = {}
         self.compiles = 0
 
@@ -527,12 +547,11 @@ class DecodeEngine:
         act = jnp.asarray(active, bool)
         dargs = (dm.params, self.draft_cache.pool, tok, pos,
                  self._tables(self.draft_cache), act)
-        dpool, props = self._call(
+        dpool, window = self._call(
             "draft",
             lambda p, kv, t, po, tb, a:
             _draft_core(dm, p, kv, t, po, tb, a, k), dargs)
         self.draft_cache.pool = dpool
-        window = jnp.concatenate([tok[:, None], props], axis=1)
         vargs = (mdl.params, self.cache.pool, window, pos,
                  self._tables(self.cache), act)
         pool, greedy, accepted = self._call(

@@ -1,9 +1,11 @@
 """Paged KV cache: pre-allocated device pool + host page allocator.
 
 The device side is ONE array per engine, ``(layers, 2, num_pages,
-page_size, heads, head_dim)`` (k and v stacked on axis 1), allocated
-once at construction and threaded through every compiled decode/
-prefill executable — sequence state never changes a shape.  The host
+page_size, heads * head_dim)`` (k and v stacked on axis 1; heads folded
+into the lane axis, the layout the ``paged_attention`` kernel reads
+without a relayout on the TPU), allocated once at construction and
+threaded through every compiled decode/prefill executable — sequence
+state never changes a shape.  The host
 side is a free-list page allocator with per-slot page tables: slots
 acquire pages at admission, the tables are passed to the executables
 as traced ``(max_slots, pages_per_slot)`` int32 arrays, and eviction
@@ -84,7 +86,7 @@ class PagedKVCache:
             else max(1, num_pages // max(1, max_slots)))
         self.pool = jnp.zeros(
             (self.layers, 2, self.num_pages, self.page_size,
-             self.heads, self.head_dim), dtype=dtype)
+             self.heads * self.head_dim), dtype=dtype)
         self.allocator = PageAllocator(self.num_pages)
         # traced inputs: page-table rows + a scratch row of zeros for
         # freed slots (page 0 ids are fine — masked by length 0)

@@ -19,7 +19,7 @@ def measure(size_mb: float, repeat: int, devices=None):
     import jax.numpy as jnp
     import numpy as onp
     from jax.sharding import Mesh, PartitionSpec as P
-    from mxnet_tpu.parallel._shard_map_compat import shard_map
+    from jax import shard_map
 
     devs = devices or jax.devices()
     n = len(devs)
