@@ -183,6 +183,7 @@ def _fa_forward_pallas(q, k, v, causal, sm_scale, block_q, block_k):
         ],
         scratch_shapes=scratch_shapes,
         interpret=jax.default_backend() != "tpu",
+        name="mxtpu_flash_fwd",
     )(q, k, v)
     lse = lse[..., 0]
     if pq:
@@ -420,6 +421,7 @@ def _fa_backward_pallas(causal, sm_scale, block_q, block_k, res, do,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interp,
+        name="mxtpu_flash_dkv",
     )(q, k, v, do, lse, delta)
 
     dq = pl.pallas_call(
@@ -432,6 +434,7 @@ def _fa_backward_pallas(causal, sm_scale, block_q, block_k, res, do,
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)],
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interp,
+        name="mxtpu_flash_dq",
     )(q, k, v, do, lse, delta)[0]
 
     if pq:

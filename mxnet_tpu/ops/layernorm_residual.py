@@ -76,6 +76,7 @@ def _lnr_pallas(x, residual, gamma, beta, eps, block_rows):
         out_specs=pl.BlockSpec((block_rows, f), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         interpret=jax.default_backend() != "tpu",
+        name="mxtpu_layernorm_residual",
     )(x, residual, gamma.reshape(1, f), beta.reshape(1, f))
     return out[:rows] if pr else out
 

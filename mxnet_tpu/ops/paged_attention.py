@@ -168,6 +168,7 @@ def _paged_attention_pallas(q, k_pool, v_pool, tables, lengths,
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_, 1, hd), q.dtype),
         interpret=jax.default_backend() != "tpu",
+        name="mxtpu_paged_attention",
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
       q.reshape(s_, 1, hd),
       k_pool.reshape(num_pages, page_size, hd),

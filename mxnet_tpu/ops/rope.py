@@ -116,6 +116,7 @@ def _rope_pallas(x, positions, base, block_r):
         out_specs=pl.BlockSpec((block_r, h, d), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         interpret=jax.default_backend() != "tpu",
+        name="mxtpu_rope",
     )(x, pos)
     return out[:r] if pad else out
 

@@ -39,7 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ... import telemetry
+from ... import telemetry, tracing
 from ...log import get_logger
 from ...ops.paged_attention import paged_attention
 from ...ops.rope import rope, rope_reference
@@ -441,6 +441,8 @@ class DecodeEngine:
             self._exec[key] = art.compiled
             return art.compiled
         donate = ((1,) if jax.default_backend() == "tpu" else ())
+        # the executable's name in a device trace: jit_mxtpu_<key>
+        fn.__name__ = fn.__qualname__ = f"mxtpu_{key}"
         t0 = time.perf_counter()
         ex = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
         telemetry.record_compile(time.perf_counter() - t0, "decode")
@@ -526,52 +528,58 @@ class DecodeEngine:
         """One non-speculative engine step over the full slot grid.
         Returns the next token per slot (host numpy)."""
         mdl = self.model
-        args = (mdl.params, self.cache.pool,
-                jnp.asarray(tokens, jnp.int32),
-                jnp.asarray(positions, jnp.int32),
-                self._tables(self.cache),
-                jnp.asarray(active, bool))
+        with tracing.span("decode.stage"):
+            args = (mdl.params, self.cache.pool,
+                    jnp.asarray(tokens, jnp.int32),
+                    jnp.asarray(positions, jnp.int32),
+                    self._tables(self.cache),
+                    jnp.asarray(active, bool))
         pool, nxt = self._call(
             "decode",
             lambda p, kv, t, po, tb, a:
             _decode_core(mdl, p, kv, t, po, tb, a), args)
         self.cache.pool = pool
-        return onp.asarray(nxt)
+        with tracing.span("decode.sync"):
+            return onp.asarray(nxt)
 
     def spec_step(self, tokens, base_pos, active):
         """Draft k proposals then verify in one target dispatch.
         Returns (greedy (S, k+1), accepted (S,)) host numpy."""
         mdl, dm, k = self.model, self.draft, self.spec_k
-        tok = jnp.asarray(tokens, jnp.int32)
-        pos = jnp.asarray(base_pos, jnp.int32)
-        act = jnp.asarray(active, bool)
-        dargs = (dm.params, self.draft_cache.pool, tok, pos,
-                 self._tables(self.draft_cache), act)
+        with tracing.span("decode.stage"):
+            tok = jnp.asarray(tokens, jnp.int32)
+            pos = jnp.asarray(base_pos, jnp.int32)
+            act = jnp.asarray(active, bool)
+            dargs = (dm.params, self.draft_cache.pool, tok, pos,
+                     self._tables(self.draft_cache), act)
         dpool, window = self._call(
             "draft",
             lambda p, kv, t, po, tb, a:
             _draft_core(dm, p, kv, t, po, tb, a, k), dargs)
         self.draft_cache.pool = dpool
-        vargs = (mdl.params, self.cache.pool, window, pos,
-                 self._tables(self.cache), act)
+        with tracing.span("decode.stage"):
+            vargs = (mdl.params, self.cache.pool, window, pos,
+                     self._tables(self.cache), act)
         pool, greedy, accepted = self._call(
             "verify",
             lambda p, kv, t, po, tb, a:
             _verify_core(mdl, p, kv, t, po, tb, a), vargs)
         self.cache.pool = pool
-        return onp.asarray(greedy), onp.asarray(accepted)
+        with tracing.span("decode.sync"):
+            return onp.asarray(greedy), onp.asarray(accepted)
 
     def prefill_chunk_step(self, slot: int, chunk, start: int) -> int:
         """Feed one prompt chunk for ``slot`` (padded into its pow2
         bucket); returns the greedy next token after the chunk."""
         mdl = self.model
-        bucket = self.prefill_bucket(len(chunk))
-        padded = onp.zeros((bucket,), onp.int32)
-        padded[:len(chunk)] = chunk
-        args = (mdl.params, self.cache.pool, jnp.asarray(padded),
-                jnp.asarray(start, jnp.int32),
-                jnp.asarray(len(chunk), jnp.int32),
-                jnp.asarray(self.cache.tables[slot], jnp.int32))
+        with tracing.span("decode.stage"):
+            bucket = self.prefill_bucket(len(chunk))
+            padded = onp.zeros((bucket,), onp.int32)
+            padded[:len(chunk)] = chunk
+            args = (mdl.params, self.cache.pool, jnp.asarray(padded),
+                    jnp.asarray(start, jnp.int32),
+                    jnp.asarray(len(chunk), jnp.int32),
+                    jnp.asarray(self.cache.tables[slot], jnp.int32))
         pool, nxt = self._call(
             f"prefill_b{bucket}",
             lambda p, kv, t, st, cl, tb:
@@ -579,17 +587,20 @@ class DecodeEngine:
         self.cache.pool = pool
         if self.draft_cache is not None:
             dm = self.draft
-            dargs = (dm.params, self.draft_cache.pool,
-                     jnp.asarray(padded), jnp.asarray(start, jnp.int32),
-                     jnp.asarray(len(chunk), jnp.int32),
-                     jnp.asarray(self.draft_cache.tables[slot],
-                                 jnp.int32))
+            with tracing.span("decode.stage"):
+                dargs = (dm.params, self.draft_cache.pool,
+                         jnp.asarray(padded),
+                         jnp.asarray(start, jnp.int32),
+                         jnp.asarray(len(chunk), jnp.int32),
+                         jnp.asarray(self.draft_cache.tables[slot],
+                                     jnp.int32))
             dpool, _ = self._call(
                 f"draft_prefill_b{bucket}",
                 lambda p, kv, t, st, cl, tb:
                 _prefill_core(dm, p, kv, t, st, cl, tb), dargs)
             self.draft_cache.pool = dpool
-        return int(nxt)
+        with tracing.span("decode.sync"):
+            return int(nxt)
 
     # -- slot page lifecycle -------------------------------------------------
 

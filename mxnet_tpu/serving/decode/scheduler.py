@@ -262,20 +262,95 @@ class DecodeScheduler:
     def step(self) -> dict:
         """One scheduler turn: expire → admit → prefill → decode →
         account.  Returns the decode extras dict it recorded."""
-        with self._step_lock:
-            return self._step_locked()
+        with self._step_lock, tracing.span("decode.step") as turn:
+            extra = self._step_locked()
+            turn.annotate(slots_active=extra["slots_active"])
+            return extra
 
     def _step_locked(self) -> dict:
+        # every phase is a tracing span (ring and, while a profiler
+        # capture runs, the xplane's host plane): what the host does
+        # while the device idles inside a turn is the shortest span
+        # covering the gap
         eng = self.engine
         t_step = time.perf_counter()
         token = telemetry.begin_step()
         now = time.perf_counter()
-        evictions = 0
         new_tokens = 0
         prefill_tokens = 0
         ttfts: List[float] = []
         completed = 0
 
+        with tracing.span("decode.expire"):
+            evictions = self._expire(now)
+
+        with tracing.span("decode.admit_phase") as sp:
+            sp.annotate(admitted=self._admit(now))
+
+        # 3. chunked prefill — one chunk per prefilling slot per step
+        for s, r in enumerate(self._slots):
+            if r is None or r.prefilled >= len(r.prompt):
+                continue
+            chunk = r.prompt[r.prefilled:
+                             r.prefilled + eng.prefill_chunk]
+            with tracing.span("decode.prefill", request_id=r.rid,
+                              slot=s, tokens=len(chunk)):
+                nxt = eng.prefill_chunk_step(s, chunk, r.prefilled)
+            r.prefilled += len(chunk)
+            prefill_tokens += len(chunk)
+            telemetry.counter("decode.prefill_tokens").inc(len(chunk))
+            if r.prefilled >= len(r.prompt):
+                # final chunk: first generated token → TTFT
+                r.ttft_ms = round(
+                    (time.perf_counter() - r.t_submit) * 1e3, 3)
+                ttfts.append(r.ttft_ms)
+                r.pos_next = len(r.prompt)
+                new_tokens += 1
+                if self._commit(s, r, int(nxt)):
+                    completed += 1
+
+        # 4. one batched decode step over every decoding slot
+        decoding = [s for s, r in enumerate(self._slots)
+                    if r is not None and r.pending is not None]
+        if decoding:
+            with tracing.span("decode.decode", decoding=len(decoding)):
+                emitted, finished = self._decode(decoding)
+            new_tokens += emitted
+            completed += finished
+
+        # 5. account
+        with tracing.span("decode.account"):
+            active = self.active()
+            telemetry.counter("decode.tokens").inc(new_tokens)
+            telemetry.counter("decode.steps").inc()
+            telemetry.gauge("decode.slots_active").set(active)
+            compiles = eng.compiles - self._last_compiles
+            self._last_compiles = eng.compiles
+            extra = {
+                "tokens": new_tokens,
+                "prefill_tokens": prefill_tokens,
+                "slots_active": active,
+                "max_slots": eng.max_slots,
+                "pages_used": eng.cache.pages_used(),
+                "num_pages": eng.num_pages,
+                "evictions": evictions,
+                "completed": completed,
+                "queue_depth": self.pending(),
+                "compiles": compiles,
+                "spec_proposed": self._spec_proposed,
+                "spec_accepted": self._spec_accepted,
+                "step_ms": round((time.perf_counter() - t_step) * 1e3, 3),
+            }
+            if ttfts:
+                extra["ttft_ms"] = ttfts
+            telemetry.end_step(token, "serving.DecodeScheduler",
+                               extra={"decode": extra})
+        return extra
+
+    def _expire(self, now: float) -> int:
+        """Phases 1 and 1b; returns the slots evicted."""
+        eng = self.engine
+        evictions = 0
         # 1. expire queued requests
         with self._cv:
             live = deque()
@@ -301,8 +376,13 @@ class DecodeScheduler:
             telemetry.counter("serving.timeouts").inc()
             self._finish_error(r, RequestTimeoutError(
                 "deadline expired mid-generation; slot evicted"))
+        return evictions
 
-        # 2. admit into free slots while the page budget fits
+    def _admit(self, now: float) -> int:
+        """Phase 2: admit into free slots while the page budget fits;
+        returns the requests admitted."""
+        eng = self.engine
+        admitted = 0
         with self._cv:
             for s in range(len(self._slots)):
                 if self._slots[s] is not None or not self._q:
@@ -319,107 +399,60 @@ class DecodeScheduler:
                     break
                 r.t_admit = now
                 self._slots[s] = r
+                admitted += 1
                 tracing.instant("decode.admit", request_id=r.rid,
                                 slot=s, prompt_tokens=len(r.prompt))
             self._gauge_q.set(len(self._q))
+        return admitted
 
-        # 3. chunked prefill — one chunk per prefilling slot per step
-        for s, r in enumerate(self._slots):
-            if r is None or r.prefilled >= len(r.prompt):
-                continue
-            chunk = r.prompt[r.prefilled:
-                             r.prefilled + eng.prefill_chunk]
-            t0 = time.perf_counter()
-            nxt = eng.prefill_chunk_step(s, chunk, r.prefilled)
-            tracing.record_span("decode.prefill", t0,
-                                time.perf_counter(), request_id=r.rid,
-                                slot=s, tokens=len(chunk))
-            r.prefilled += len(chunk)
-            prefill_tokens += len(chunk)
-            telemetry.counter("decode.prefill_tokens").inc(len(chunk))
-            if r.prefilled >= len(r.prompt):
-                # final chunk: first generated token → TTFT
-                r.ttft_ms = round(
-                    (time.perf_counter() - r.t_submit) * 1e3, 3)
-                ttfts.append(r.ttft_ms)
-                r.pos_next = len(r.prompt)
-                new_tokens += 1
-                if self._commit(s, r, int(nxt)):
-                    completed += 1
-
-        # 4. one batched decode step over every decoding slot
-        decoding = [s for s, r in enumerate(self._slots)
-                    if r is not None and r.pending is not None]
-        if decoding:
-            n = eng.max_slots
-            toks = onp.zeros((n,), onp.int32)
-            pos = onp.zeros((n,), onp.int32)
-            act = onp.zeros((n,), bool)
+    def _decode(self, decoding: List[int]):
+        """Phase 4: one batched token step (or draft→verify pair) over
+        the slots in ``decoding`` and its commits; returns (tokens
+        emitted, requests completed)."""
+        eng = self.engine
+        new_tokens = completed = 0
+        n = eng.max_slots
+        toks = onp.zeros((n,), onp.int32)
+        pos = onp.zeros((n,), onp.int32)
+        act = onp.zeros((n,), bool)
+        for s in decoding:
+            r = self._slots[s]
+            toks[s], pos[s], act[s] = r.pending, r.pos_next, True
+        if eng.spec_enabled:
+            greedy, accepted = eng.spec_step(toks, pos, act)
+            k = eng.spec_k
             for s in decoding:
                 r = self._slots[s]
-                toks[s], pos[s], act[s] = r.pending, r.pos_next, True
-            if eng.spec_enabled:
-                greedy, accepted = eng.spec_step(toks, pos, act)
-                k = eng.spec_k
-                for s in decoding:
-                    r = self._slots[s]
-                    take = int(accepted[s]) + 1
-                    self._spec_proposed += k
-                    self._spec_accepted += int(accepted[s])
-                    done = False
-                    for j in range(take):
-                        new_tokens += 1
-                        if self._commit(s, r, int(greedy[s, j])):
-                            completed += 1
-                            done = True
-                            break
-                    if not done:
-                        r.pos_next += take
-                telemetry.counter("decode.spec_proposed").inc(
-                    k * len(decoding))
-                telemetry.counter("decode.spec_accepted").inc(
-                    sum(int(accepted[s]) for s in decoding))
-                if self._spec_proposed:
-                    telemetry.gauge("decode.spec_accept_rate").set(
-                        round(self._spec_accepted
-                              / self._spec_proposed, 4))
-            else:
-                nxt = eng.decode_step(toks, pos, act)
-                for s in decoding:
-                    r = self._slots[s]
+                take = int(accepted[s]) + 1
+                self._spec_proposed += k
+                self._spec_accepted += int(accepted[s])
+                done = False
+                for j in range(take):
                     new_tokens += 1
-                    if self._commit(s, r, int(nxt[s])):
+                    if self._commit(s, r, int(greedy[s, j])):
                         completed += 1
-                    else:
-                        r.pos_next += 1
-
-        # 5. account
-        active = self.active()
-        telemetry.counter("decode.tokens").inc(new_tokens)
-        telemetry.counter("decode.steps").inc()
-        telemetry.gauge("decode.slots_active").set(active)
-        compiles = eng.compiles - self._last_compiles
-        self._last_compiles = eng.compiles
-        extra = {
-            "tokens": new_tokens,
-            "prefill_tokens": prefill_tokens,
-            "slots_active": active,
-            "max_slots": eng.max_slots,
-            "pages_used": eng.cache.pages_used(),
-            "num_pages": eng.num_pages,
-            "evictions": evictions,
-            "completed": completed,
-            "queue_depth": self.pending(),
-            "compiles": compiles,
-            "spec_proposed": self._spec_proposed,
-            "spec_accepted": self._spec_accepted,
-            "step_ms": round((time.perf_counter() - t_step) * 1e3, 3),
-        }
-        if ttfts:
-            extra["ttft_ms"] = ttfts
-        telemetry.end_step(token, "serving.DecodeScheduler",
-                           extra={"decode": extra})
-        return extra
+                        done = True
+                        break
+                if not done:
+                    r.pos_next += take
+            telemetry.counter("decode.spec_proposed").inc(
+                k * len(decoding))
+            telemetry.counter("decode.spec_accepted").inc(
+                sum(int(accepted[s]) for s in decoding))
+            if self._spec_proposed:
+                telemetry.gauge("decode.spec_accept_rate").set(
+                    round(self._spec_accepted
+                          / self._spec_proposed, 4))
+        else:
+            nxt = eng.decode_step(toks, pos, act)
+            for s in decoding:
+                r = self._slots[s]
+                new_tokens += 1
+                if self._commit(s, r, int(nxt[s])):
+                    completed += 1
+                else:
+                    r.pos_next += 1
+        return new_tokens, completed
 
     def _commit(self, s: int, r: _Request, tok: int) -> bool:
         """Append one emitted token; on eos/max_new finish the request,

@@ -9,11 +9,19 @@ runtime:
   (``time.perf_counter``), and completed spans land in a bounded ring
   buffer (``MXNET_TRACE_BUFFER``, default 4096 — O(1) memory on a
   million-step run, oldest spans overwritten and counted as dropped).
+  While a JAX profiler capture is running (``jax.profiler.start_trace``)
+  the same ``with`` is also a ``jax.profiler.TraceAnnotation`` named
+  ``"mxtpu." + name``: it lands on the xplane's ``/host:CPU`` plane, on
+  the profiler's clock, next to the device's operations — whether or not
+  ``MXNET_TRACE`` is set (unset, the ring stays empty and the xplane
+  alone holds the span).
 - ``begin("name") / end(sp)`` — explicit pair for spans that cross
   threads (the device-feed producer, serving request lifecycles).
-- ``record_span(name, t0, t1, **attrs)`` — book an interval that was
-  measured out-of-band (a consumer's queue wait, a request's
-  enqueue→reply window) without a live Span object on the hot path.
+  Ring only: an annotation begins and ends on one thread.
+- ``record_span(name, t0, t1, **attrs)`` / ``instant(name)`` — book an
+  interval that was measured out-of-band (a consumer's queue wait, a
+  request's enqueue→reply window) without a live Span object on the hot
+  path.  Ring only: the profiler takes no interval after the fact.
 - ``export(path)`` — Chrome-trace / Perfetto JSON (``traceEvents`` with
   complete ``"X"`` events); ``MXNET_TRACE_JSONL=<path>`` streams the
   same events one JSON object per line as they complete.
@@ -25,12 +33,14 @@ runtime:
   ``watchdog.stall_dumps``), then stays quiet for that incident.
 
 Hot-path contract (mirrors telemetry's disabled path): with
-``MXNET_TRACE`` unset/0 and no JSONL/watchdog configured, ``span()``
-returns one shared no-op singleton — no Span object, no ring append,
-no lock — so instrumented code pays a dict lookup and a call, below
+``MXNET_TRACE`` unset/0, no JSONL/watchdog configured and no profiler
+capture running, ``span()`` returns one shared no-op singleton — no
+Span object, no ring append, no lock — so instrumented code pays a dict
+lookup, one ``TraceAnnotation.is_enabled()`` (0.1 µs) and a call, below
 measurement noise next to an XLA dispatch.  ``MXNET_TRACE=0``
-force-disables everything (including watchdog span collection) even
-when the other switches are set.
+force-disables the recorder (including watchdog span collection) even
+when the other switches are set; a profiler capture that someone
+started still sees the spans.
 
 Span taxonomy (the ``cat`` field is the name's first dotted segment —
 see docs/ARCHITECTURE.md "Tracing & diagnostics" for the full table):
@@ -40,6 +50,7 @@ see docs/ARCHITECTURE.md "Tracing & diagnostics" for the full table):
 - ``compile.*`` — jit compile sites (eager op / cached step / serving)
 - ``comm.*``    — kvstore collectives, tagged ``payload_nbytes``
 - ``serving.*`` — request lifecycle: enqueue→coalesce→dispatch→reply
+- ``decode.*``  — one scheduler turn of the decode plane and its phases
 """
 from __future__ import annotations
 
@@ -51,6 +62,8 @@ import threading
 import time
 import traceback
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from . import telemetry
 
@@ -234,6 +247,22 @@ class _NullSpan:
 
 _NULL = _NullSpan()
 
+# the program's spans on the profiler's clock carry this prefix, so a
+# trace reader tells them from JAX's own host events
+ANNOTATION_PREFIX = "mxtpu."
+
+
+class _Annotation(TraceAnnotation):
+    """A span that lives in a running profiler capture only: what
+    ``span()`` returns while a capture runs and tracing is disabled."""
+
+    def __init__(self, name: str, attrs: dict):
+        super().__init__(ANNOTATION_PREFIX + name, **attrs)
+
+    def annotate(self, **attrs):
+        self.set_metadata(**attrs)
+        return self
+
 
 class Span:
     """One timed interval.  Use via ``with span(...)`` (nested, same
@@ -241,7 +270,7 @@ class Span:
     attributes that land in the Chrome event's ``args``."""
 
     __slots__ = ("name", "attrs", "t0", "t1", "tid", "span_id",
-                 "parent_id", "_stacked")
+                 "parent_id", "_stacked", "_ann")
 
     def __init__(self, name: str, attrs: dict):
         self.name = name
@@ -252,6 +281,7 @@ class Span:
         stack = getattr(_tls, "stack", None)
         self.parent_id = stack[-1].span_id if stack else None
         self._stacked = False
+        self._ann = None
         with _LOCK:
             _open[self.span_id] = self
         self.t0 = time.perf_counter()
@@ -262,16 +292,25 @@ class Span:
             stack = _tls.stack = []
         stack.append(self)
         self._stacked = True
+        if TraceAnnotation.is_enabled():
+            # a capture is running: the same interval on its clock
+            self._ann = _Annotation(self.name, self.attrs)
+            self._ann.__enter__()
         return self
 
     def __exit__(self, et, ev, tb):
         if et is not None:
             self.attrs.setdefault("error", et.__name__)
+        if self._ann is not None:
+            self._ann.__exit__(et, ev, tb)
+            self._ann = None
         self.finish()
         return False
 
     def annotate(self, **attrs):
         self.attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
         return self
 
     def finish(self):
@@ -295,17 +334,23 @@ class Span:
 
 
 def span(name: str, **attrs) -> Any:
-    """Nestable context-manager span; the shared no-op singleton when
-    tracing is disabled (no object churn on the hot path)."""
-    if not enabled():
-        return _NULL
-    return Span(name, attrs)
+    """Nestable context-manager span.  Tracing enabled: a ``Span`` for
+    the ring, which is also a ``TraceAnnotation`` named ``"mxtpu." +
+    name`` while a profiler capture runs.  Tracing disabled but a
+    capture running: that annotation alone.  Neither: the shared no-op
+    singleton (no object churn on the hot path)."""
+    if enabled():
+        return Span(name, attrs)
+    if TraceAnnotation.is_enabled():
+        return _Annotation(name, attrs)
+    return _NULL
 
 
 def begin(name: str, **attrs) -> Any:
     """Open a span WITHOUT entering it on this thread's stack — for
     intervals that end on another thread (serving requests, producer
-    handoffs).  Pair with ``end(sp)`` / ``sp.finish()``."""
+    handoffs).  Pair with ``end(sp)`` / ``sp.finish()``.  Ring only: a
+    profiler annotation begins and ends on one thread."""
     if not enabled():
         return _NULL
     return Span(name, attrs)
@@ -323,7 +368,8 @@ def end(sp, **attrs) -> None:
 def record_span(name: str, t_start: float, t_end: float, **attrs) -> None:
     """Book an interval measured out-of-band (``time.perf_counter``
     values).  Parented to the calling thread's current open span, so a
-    wait measured inside a step nests under it."""
+    wait measured inside a step nests under it.  Ring only: a profiler
+    capture takes no interval after the fact."""
     if not enabled():
         return
     stack = getattr(_tls, "stack", None)

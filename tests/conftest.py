@@ -60,3 +60,40 @@ def _seed_everything():
     import mxnet_tpu as mx
     mx.random.seed(seed)
     yield
+
+
+@pytest.fixture
+def xplane_capture(tmp_path):
+    """``with xplane_capture() as found:`` runs a JAX profiler capture
+    on the CPU.  Once the ``with`` is left, ``found`` holds one dict per
+    host event the program's own spans left in the xplane (names that
+    start with ``mxtpu.``): ``plane``, ``line`` (the thread), ``name``,
+    ``stats``, ``lo`` and ``hi`` in ns, in order of start."""
+    import contextlib
+    import glob
+
+    import jax
+
+    @contextlib.contextmanager
+    def capture():
+        found = []
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            yield found
+        finally:
+            jax.profiler.stop_trace()
+        path = max(glob.glob(os.path.join(
+            str(tmp_path), "plugins", "profile", "*", "*.xplane.pb")),
+            key=os.path.getmtime)
+        for plane in jax.profiler.ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("mxtpu."):
+                        found.append({
+                            "plane": plane.name, "line": line.name,
+                            "name": ev.name, "stats": dict(ev.stats),
+                            "lo": ev.start_ns,
+                            "hi": ev.start_ns + ev.duration_ns})
+        found.sort(key=lambda e: (e["lo"], -e["hi"]))
+
+    return capture

@@ -31,7 +31,7 @@ import numpy as onp
 import pytest
 
 import mxnet_tpu as mx  # noqa: F401  (registers ops + kernel specs)
-from mxnet_tpu import profiler, telemetry
+from mxnet_tpu import profiler, telemetry, tracing
 from mxnet_tpu.serving import (BadRequestError, DecodeEngine, DecodeModel,
                                DecodeScheduler, QueueFullError,
                                RequestTimeoutError, ServingClosedError,
@@ -227,6 +227,123 @@ def test_spec_identical_with_mismatched_draft(model, draft):
     assert got == [model.greedy_reference(p, 9) for p in prompts]
     assert st["spec_proposed"] > 0
     assert st["spec_accepted"] <= st["spec_proposed"]
+
+
+# -- names and phases a trace can read ---------------------------------------
+
+PHASES = ("decode.expire", "decode.admit_phase", "decode.prefill",
+          "decode.decode", "decode.account")
+
+
+@pytest.fixture
+def _traced():
+    tracing._env_default()
+    tracing.clear()
+    yield
+    tracing._env_default()
+    tracing.clear()
+
+
+def _mixed_turn(model, **kw):
+    """A scheduler whose next ``step()`` both prefills (a request just
+    submitted) and decodes (one admitted a turn earlier)."""
+    sch = _sched(_engine(model, **kw))
+    first, second = _prompts(2, lo=4, hi=8, seed=9)
+    sch.submit(first, max_new_tokens=6)
+    sch.step()
+    sch.submit(second, max_new_tokens=6)
+    return sch
+
+
+def test_one_turn_leaves_every_phase_span_nested(model, _traced):
+    """One ``step()`` under ``tracing.enable()``: the eight names, each
+    phase inside ``decode.step``, staging and sync inside the prefill or
+    the decode they belong to, one ``decode.prefill`` per chunk."""
+    sch = _mixed_turn(model)
+    tracing.enable()
+    tracing.clear()
+    sch.step()
+    evs = [e for e in tracing._completed_events()
+           if e["name"].startswith("decode.")]
+    tracing.disable()
+    _run(sch)
+    sch.close(drain=True)
+    by_id = {e["args"]["span_id"]: e for e in evs}
+
+    def parent(e):
+        return by_id[e["args"]["parent_id"]]["name"]
+
+    names = [e["name"] for e in evs]
+    step, = [e for e in evs if e["name"] == "decode.step"]
+    assert "parent_id" not in step["args"]
+    assert step["args"]["slots_active"] == 2     # as the step record has it
+    for phase in PHASES:
+        ev, = [e for e in evs if e["name"] == phase]    # once each
+        assert parent(ev) == "decode.step"
+        assert step["ts"] <= ev["ts"] and \
+            ev["ts"] + ev["dur"] <= step["ts"] + step["dur"] + 1
+    # the instant stays an instant, inside the admission phase
+    admit, = [e for e in evs if e["name"] == "decode.admit"]
+    assert admit["dur"] == 0 and parent(admit) == "decode.admit_phase"
+    # one chunk, one prefill span (a `with`, no record_span beside it)
+    prefill, = [e for e in evs if e["name"] == "decode.prefill"]
+    assert prefill["args"]["slot"] == 1
+    assert prefill["args"]["tokens"] >= 4
+    assert "request_id" in prefill["args"]
+    one = {e["name"]: e["args"] for e in evs}
+    assert one["decode.admit_phase"]["admitted"] == 1
+    # the slot that just prefilled its only chunk decodes in this turn too
+    assert one["decode.decode"]["decoding"] == 2
+    # each device call stages its arguments, then waits for the answer
+    for leaf in ("decode.stage", "decode.sync"):
+        got = sorted(parent(e) for e in evs if e["name"] == leaf)
+        assert got == ["decode.decode", "decode.prefill"], (leaf, got)
+    assert sorted(set(names)) == sorted(
+        PHASES + ("decode.step", "decode.admit", "decode.stage",
+                  "decode.sync"))
+
+
+def test_a_turn_under_a_capture_reaches_the_host_plane(
+        model, _traced, xplane_capture):
+    """MXNET_TRACE unset: a profiler capture still sees the turn and its
+    phases as ``mxtpu.decode.*`` on one thread of ``/host:CPU``; the
+    ring holds nothing."""
+    sch = _mixed_turn(model)
+    assert not tracing.enabled()
+    with xplane_capture() as found:
+        sch.step()
+    _run(sch)
+    sch.close(drain=True)
+    assert {e["plane"] for e in found} == {"/host:CPU"}
+    assert len({e["line"] for e in found}) == 1
+    step, = [e for e in found if e["name"] == "mxtpu.decode.step"]
+    for e in found:
+        assert step["lo"] <= e["lo"] and e["hi"] <= step["hi"]
+    assert {e["name"] for e in found} == {
+        "mxtpu." + n for n in PHASES + ("decode.step", "decode.stage",
+                                        "decode.sync")}
+    prefill, = [e for e in found if e["name"] == "mxtpu.decode.prefill"]
+    assert prefill["stats"]["slot"] == 1
+    assert tracing._completed_events() == []
+
+
+@pytest.fixture(scope="module")
+def spec_engine(model, draft):
+    eng = _engine(model, num_pages=64, draft_model=draft, spec_k=3)
+    eng.warmup([8])
+    return eng
+
+
+@pytest.mark.parametrize("key", ["decode", "draft", "verify",
+                                 "prefill_b16", "draft_prefill_b16"])
+def test_executable_carries_its_key_as_its_name(spec_engine, key):
+    """A device trace shows an executable as its module's name: each of
+    the engine's five is ``jit_mxtpu_<key>``, not ``jit__lambda_``."""
+    assert sorted(spec_engine._exec) == sorted(
+        ["decode", "draft", "verify", "prefill_b16", "draft_prefill_b16"])
+    text = spec_engine._exec[key].as_text()
+    assert f"HloModule jit_mxtpu_{key}," in text
+    assert "lambda" not in text.split("\n", 1)[0]
 
 
 # -- lifecycle ---------------------------------------------------------------

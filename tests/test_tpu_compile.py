@@ -103,3 +103,56 @@ def test_layer_norm_residual_compiles(v5e):
     g = ((512,), "bfloat16")
     _compile(lambda a, r, ga, be: _lnr_pallas(a, r, ga, be, 1e-5, 32),
              v5e, x, x, g, g)
+
+
+# -- the names a device trace shows ------------------------------------------
+# An ``XLA Ops`` event of a TPU trace is the text of its HLO instruction
+# and nothing else, so a kernel can be told from another only by the
+# ``name`` its ``pallas_call`` was given.  JAX wraps that name in its
+# transforms (``transpose_jvp_..._``) and numbers it, so readers match
+# by substring (chipbench/harness/named.py).
+
+def _named_texts(sharding):
+    """The compiled HLO of every kernel of the main path, by family."""
+    from mxnet_tpu.ops.layernorm_residual import _lnr_pallas
+    from mxnet_tpu.ops.paged_attention import _paged_attention_pallas
+    from mxnet_tpu.ops.rope import _rope_pallas
+    qkv = ((16, 1024, D), "bfloat16")
+    pool = ((SLOTS * 40, 16, H * D), "bfloat16")
+    x, g = ((256, 512), "bfloat16"), ((512,), "bfloat16")
+    return {
+        "flash": _compile(jax.grad(_flash_loss, argnums=(0, 1, 2)),
+                          sharding, qkv, qkv, qkv),
+        "paged_attention": _compile(
+            lambda q, k, v, t, l: _paged_attention_pallas(
+                q, k, v, t, l, D ** -0.5, 64),
+            sharding, ((SLOTS, H, D), "bfloat16"), pool, pool,
+            ((SLOTS, 40), "int32"), ((SLOTS,), "int32")),
+        "rope": _compile(lambda a, p: _rope_pallas(a, p, 10000.0, 128),
+                         sharding, ((SLOTS, H, D), "bfloat16"),
+                         ((SLOTS,), "int32")),
+        "layernorm_residual": _compile(
+            lambda a, r, ga, be: _lnr_pallas(a, r, ga, be, 1e-5, 32),
+            sharding, x, x, g, g),
+    }
+
+
+_TEXTS = {}
+
+
+@pytest.mark.parametrize("kernel", [
+    "flash_fwd", "flash_dkv", "flash_dq", "paged_attention", "rope",
+    "layernorm_residual"])
+def test_custom_call_carries_the_kernels_name(v5e, kernel):
+    import re
+    if not _TEXTS:
+        _TEXTS.update(_named_texts(v5e))
+    family = "flash" if kernel.startswith("flash") else kernel
+    named = re.findall(
+        r"^\s*(?:ROOT )?%(\S*mxtpu_" + kernel + r"\S*) = .*custom-call\("
+        r'.*custom_call_target="tpu_custom_call"', _TEXTS[family], re.M)
+    assert named, f"no Mosaic custom call named mxtpu_{kernel}"
+    # and no Mosaic call of the main path is left anonymous
+    calls = re.findall(r'^\s*(?:ROOT )?%(\S+) = .*custom_call_target='
+                       r'"tpu_custom_call"', _TEXTS[family], re.M)
+    assert all("mxtpu_" in c for c in calls), calls

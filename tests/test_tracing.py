@@ -178,6 +178,112 @@ def test_mxnet_trace_zero_wins_over_jsonl_and_watchdog(monkeypatch,
     assert tracing.enabled()
 
 
+# -- the profiler's clock ----------------------------------------------------
+
+def test_capture_alone_puts_the_span_on_the_host_plane(xplane_capture):
+    """MXNET_TRACE unset, a capture running: the span is an annotation
+    on the xplane's /host:CPU and the ring stays empty; before and after
+    the capture ``span()`` is the shared no-op."""
+    assert not tracing.enabled()
+    assert tracing.span("decode.step") is tracing._NULL
+    with xplane_capture() as found:
+        with tracing.span("decode.step", slots_active=3) as sp:
+            assert sp is not tracing._NULL
+            sp.annotate(admitted=2)
+    assert tracing.span("decode.step") is tracing._NULL
+    ev, = found
+    assert (ev["plane"], ev["name"]) == ("/host:CPU", "mxtpu.decode.step")
+    assert ev["stats"] == {"slots_active": 3, "admitted": 2}
+    assert _events() == []
+    assert tracing.open_spans() == []
+
+
+def test_capture_and_tracing_hold_the_span_once_each(xplane_capture):
+    tracing.enable()
+    with xplane_capture() as found:
+        with tracing.span("step.spmd", k=1) as outer:
+            outer.annotate(step=7)
+            with tracing.span("step.dispatch") as inner:
+                assert inner.parent_id == outer.span_id
+    outer_ev, inner_ev = found
+    assert (outer_ev["name"], inner_ev["name"]) == (
+        "mxtpu.step.spmd", "mxtpu.step.dispatch")
+    assert outer_ev["plane"] == inner_ev["plane"] == "/host:CPU"
+    assert outer_ev["line"] == inner_ev["line"]
+    assert outer_ev["lo"] <= inner_ev["lo"] <= inner_ev["hi"] <= \
+        outer_ev["hi"]
+    assert outer_ev["stats"] == {"k": 1, "step": 7}
+    ring = [e["name"] for e in _events()]
+    assert sorted(ring) == ["step.dispatch", "step.spmd"]
+    ev = next(e for e in _events() if e["name"] == "step.spmd")
+    assert ev["args"]["step"] == 7 and ev["args"]["k"] == 1
+
+
+def test_after_the_fact_and_cross_thread_spans_stay_ring_only(
+        xplane_capture):
+    """``begin``/``end``, ``record_span`` and ``instant`` book intervals
+    out of band: the profiler cannot take those, the ring does."""
+    tracing.enable()
+    with xplane_capture() as found:
+        sp = tracing.begin("serving.request")
+        t0 = time.perf_counter()
+        tracing.record_span("input.wait", t0, t0 + 0.001)
+        tracing.instant("decode.admit", slot=0)
+        tracing.end(sp)
+    assert found == []
+    assert sorted(e["name"] for e in _events()) == [
+        "decode.admit", "input.wait", "serving.request"]
+
+
+def test_capture_alone_survives_an_exception(xplane_capture):
+    with xplane_capture() as found:
+        with pytest.raises(ValueError):
+            with tracing.span("step.bad"):
+                raise ValueError("boom")
+        with tracing.span("step.next"):
+            pass
+    assert [e["name"] for e in found] == ["mxtpu.step.bad",
+                                          "mxtpu.step.next"]
+
+
+def test_mxnet_trace_zero_keeps_the_ring_off_under_a_capture(
+        monkeypatch, xplane_capture):
+    """MXNET_TRACE=0 turns the recorder off; a capture someone started
+    still sees the program's spans."""
+    monkeypatch.setenv("MXNET_TRACE", "0")
+    with xplane_capture() as found:
+        with tracing.span("step.spmd"):
+            pass
+    assert [e["name"] for e in found] == ["mxtpu.step.spmd"]
+    assert _events() == []
+
+
+def test_trainer_step_spans_reach_a_capture(xplane_capture):
+    """``SPMDTrainer.step`` needs no span site of its own for the
+    profiler: ``step.spmd`` and, inside it, ``step.dispatch`` land in a
+    capture with MXNET_TRACE unset."""
+    from mxnet_tpu.gluon import loss as gloss
+    from mxnet_tpu.parallel import SPMDTrainer, make_mesh
+    net = nn.Dense(4, in_units=8)
+    net.initialize()
+    trainer = SPMDTrainer(net, gloss.SoftmaxCrossEntropyLoss(),
+                          optimizer="sgd",
+                          optimizer_params={"learning_rate": 0.1},
+                          mesh=make_mesh({"dp": 1}))
+    x = onp.random.randn(8, 8).astype("float32")
+    y = onp.random.randint(0, 4, size=(8,)).astype("float32")
+    trainer.step(x, y)                  # the compile, outside the capture
+    with xplane_capture() as found:
+        for _ in range(2):
+            trainer.step(x, y)
+    assert [e["name"] for e in found] == [
+        "mxtpu.step.spmd", "mxtpu.step.dispatch"] * 2
+    for outer, inner in (found[:2], found[2:]):
+        assert outer["lo"] <= inner["lo"] <= inner["hi"] <= outer["hi"]
+        assert outer["stats"]["step"] in (2, 3)
+    assert _events() == []
+
+
 # -- export / JSONL ----------------------------------------------------------
 
 def test_export_chrome_trace_schema(tmp_path):
