@@ -566,7 +566,8 @@ def serve_generate_phase(sm: Smoke, server_box):
         keys = engine.warmup([d["prefill"]])
         for arr in jax.tree_util.tree_leaves(engine.model.params):
             check_on(arr, jax.devices()[:1], "decode model parameter")
-        warm, first_pool = engine.compiles, engine.cache.pool
+        warm = engine.compiles
+        first_pool = jax.tree_util.tree_leaves(engine.cache.pool)
         c_warm = sm.compiles()
         sched = DecodeScheduler(engine, max_new_tokens=d["max_new"])
         server.attach_decoder(sched)
@@ -584,11 +585,13 @@ def serve_generate_phase(sm: Smoke, server_box):
               f"engine.compiles grew {warm} -> {engine.compiles}")
         check(sm.compiles() == c_warm,
               f"{sm.compiles() - c_warm} compile(s) after warm-up")
-        check_on(engine.cache.pool, jax.devices()[:1], "KV pool")
-        # the donated-pool branch is the one a TPU runs: the first
-        # pool buffer was consumed by the first executable
-        check(first_pool.is_deleted() == on_tpu,
-              f"KV pool donated={first_pool.is_deleted()} on "
+        for buf in jax.tree_util.tree_leaves(engine.cache.pool):
+            check_on(buf, jax.devices()[:1], "KV pool buffer")
+        # the donated-pool branch is the one a TPU runs: every first
+        # buffer of the pool was consumed by the first executable
+        donated = {buf.is_deleted() for buf in first_pool}
+        check(donated == {on_tpu},
+              f"KV pool buffers donated={sorted(donated)} on "
               f"{jax.default_backend()}")
         return toks, keys, sched.stats()
 

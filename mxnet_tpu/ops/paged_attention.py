@@ -1,9 +1,11 @@
 """Paged attention: one query token per slot over a paged KV pool.
 
 The decode serving plane (serving/decode/) keeps every slot's KV
-history in a pre-allocated page pool ``(num_pages, page_size, H*D)``
-(heads folded into the lane axis; a ``(num_pages, page_size, H, D)``
-pool is accepted and viewed the same way) plus a per-slot page table
+history in pre-allocated page pools ``(num_pages, page_size, H*D)`` —
+one whole buffer per layer for K and one for V, which is what this
+kernel is handed (heads folded into the lane axis; a ``(num_pages,
+page_size, H, D)`` pool is accepted and viewed the same way) — plus a
+per-slot page table
 ``(max_slots, pages_per_slot)`` — sequence state lives behind traced
 integer indices, so one compiled ``decode_step`` serves any mix of
 lengths (the fixed-shape-executable invariant, docs/ARCHITECTURE.md

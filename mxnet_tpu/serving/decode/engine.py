@@ -177,51 +177,53 @@ class DecodeModel:
 
 # -- traced cores ------------------------------------------------------------
 
-def _write_kv(pool, li, idx, k, v):
-    """Scatter this step's K/V rows into layer ``li``'s page pool.
-    ``idx`` carries the flat (page*page_size + offset) position per
-    row, with out-of-range sentinels for masked rows (mode='drop')."""
-    _, _, num_pages, ps, hd = pool.shape
-    rows = k.shape[0]
-    kflat = pool[li, 0].reshape(num_pages * ps, hd)
-    vflat = pool[li, 1].reshape(num_pages * ps, hd)
-    kflat = kflat.at[idx].set(k.reshape(rows, hd).astype(pool.dtype),
-                              mode="drop")
-    vflat = vflat.at[idx].set(v.reshape(rows, hd).astype(pool.dtype),
-                              mode="drop")
-    pool = pool.at[li, 0].set(kflat.reshape(num_pages, ps, hd))
-    return pool.at[li, 1].set(vflat.reshape(num_pages, ps, hd))
+def _write_kv(kbuf, vbuf, page, offset, k, v):
+    """Scatter this step's K/V rows into ONE layer's own K and V
+    buffers, each ``(num_pages, page_size, H*D)``, and return both.
+    ``page``/``offset`` address one position per row; masked rows carry
+    the sentinel page ``num_pages`` — one past the buffer — and are
+    dropped (mode='drop').  Each buffer is a donated argument with this
+    scatter as its only writer and nothing left that reads the old
+    value, so XLA updates it in place: no copy of a buffer exists."""
+    hd = kbuf.shape[-1]
+    kbuf = kbuf.at[page, offset].set(
+        k.reshape(-1, hd).astype(kbuf.dtype), mode="drop")
+    vbuf = vbuf.at[page, offset].set(
+        v.reshape(-1, hd).astype(vbuf.dtype), mode="drop")
+    return kbuf, vbuf
 
 
 def _decode_core(mdl: DecodeModel, params, pool, tokens, positions,
                  tables, active):
     """Consume one token per slot at ``positions`` (writing its KV),
-    return (pool, argmax next token per slot)."""
+    return (pool, argmax next token per slot).  ``pool`` is the cache's
+    pytree: one ``(k, v)`` pair of whole buffers per layer."""
     s_ = tokens.shape[0]
     h_, hd = mdl.n_heads, mdl.head_dim
-    num_pages, ps = pool.shape[2], pool.shape[3]
+    num_pages, ps = pool[0][0].shape[:2]
     x = params["embed"][tokens]
     lengths = jnp.where(active, positions + 1, 0).astype(jnp.int32)
     pagerow = jnp.take_along_axis(
         tables, (positions // ps)[:, None], axis=1)[:, 0]
-    flat = pagerow * ps + positions % ps
-    idx = jnp.where(active, flat, num_pages * ps).astype(jnp.int32)
-    for li, lp in enumerate(params["layers"]):
+    page = jnp.where(active, pagerow, num_pages).astype(jnp.int32)
+    offset = positions % ps
+    out = []
+    for (kbuf, vbuf), lp in zip(pool, params["layers"]):
         h1 = _rms(x, lp["ln1"])
         q = rope((h1 @ lp["wq"]).reshape(s_, h_, hd), positions,
                  base=mdl.rope_base)
         k = rope((h1 @ lp["wk"]).reshape(s_, h_, hd), positions,
                  base=mdl.rope_base)
         v = (h1 @ lp["wv"]).reshape(s_, h_, hd)
-        pool = _write_kv(pool, li, idx, k, v)
-        attn = paged_attention(q, pool[li, 0], pool[li, 1], tables,
-                               lengths)
+        kbuf, vbuf = _write_kv(kbuf, vbuf, page, offset, k, v)
+        out.append((kbuf, vbuf))
+        attn = paged_attention(q, kbuf, vbuf, tables, lengths)
         x = x + attn.reshape(s_, mdl.dim).astype(x.dtype) @ lp["wo"]
         h2 = _rms(x, lp["ln2"])
         x = x + jax.nn.gelu(h2 @ lp["w1"]) @ lp["w2"]
     x = _rms(x, params["lnf"])
     logits = x @ params["embed"].T
-    return pool, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return tuple(out), jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
 def _verify_core(mdl: DecodeModel, params, pool, tokens, base_pos,
@@ -234,29 +236,31 @@ def _verify_core(mdl: DecodeModel, params, pool, tokens, base_pos,
     bitwise those the non-speculative path would emit."""
     s_, w_ = tokens.shape
     h_, hd = mdl.n_heads, mdl.head_dim
-    num_pages, ps = pool.shape[2], pool.shape[3]
+    num_pages, ps = pool[0][0].shape[:2]
     pos = base_pos[:, None] + jnp.arange(w_, dtype=jnp.int32)[None, :]
     x = params["embed"][tokens]                       # (S, W, dim)
     pagerow = jnp.take_along_axis(tables, pos // ps, axis=1)
-    flat = pagerow * ps + pos % ps
-    idx = jnp.where(active[:, None], flat,
-                    num_pages * ps).astype(jnp.int32).reshape(s_ * w_)
-    for li, lp in enumerate(params["layers"]):
+    page = jnp.where(active[:, None], pagerow,
+                     num_pages).astype(jnp.int32).reshape(s_ * w_)
+    offset = (pos % ps).reshape(s_ * w_)
+    out = []
+    for (kbuf, vbuf), lp in zip(pool, params["layers"]):
         h1 = _rms(x, lp["ln1"])
         q = rope((h1 @ lp["wq"]).reshape(s_, w_, h_, hd), pos,
                  base=mdl.rope_base)
         k = rope((h1 @ lp["wk"]).reshape(s_, w_, h_, hd), pos,
                  base=mdl.rope_base)
         v = (h1 @ lp["wv"]).reshape(s_, w_, h_, hd)
-        pool = _write_kv(pool, li, idx,
-                         k.reshape(s_ * w_, h_, hd),
-                         v.reshape(s_ * w_, h_, hd))
+        kbuf, vbuf = _write_kv(kbuf, vbuf, page, offset,
+                               k.reshape(s_ * w_, h_, hd),
+                               v.reshape(s_ * w_, h_, hd))
+        out.append((kbuf, vbuf))
         cols = []
         for j in range(w_):
             lens_j = jnp.where(active, base_pos + j + 1,
                                0).astype(jnp.int32)
-            cols.append(paged_attention(q[:, j], pool[li, 0],
-                                        pool[li, 1], tables, lens_j))
+            cols.append(paged_attention(q[:, j], kbuf, vbuf, tables,
+                                        lens_j))
         attn = jnp.stack(cols, axis=1)                # (S, W, H, hd)
         x = x + attn.reshape(s_, w_, mdl.dim).astype(x.dtype) @ lp["wo"]
         h2 = _rms(x, lp["ln2"])
@@ -267,7 +271,7 @@ def _verify_core(mdl: DecodeModel, params, pool, tokens, base_pos,
     drafts = tokens[:, 1:]
     eq = (drafts == greedy[:, :-1]).astype(jnp.int32)
     accepted = jnp.cumprod(eq, axis=1).sum(axis=1)    # (S,)
-    return pool, greedy, accepted
+    return tuple(out), greedy, accepted
 
 
 def _draft_core(mdl: DecodeModel, params, pool, tokens, base_pos,
@@ -296,29 +300,30 @@ def _prefill_core(mdl: DecodeModel, params, pool, tokens, start,
     on the final chunk)."""
     b_ = tokens.shape[0]
     h_, hd = mdl.n_heads, mdl.head_dim
-    num_pages, ps = pool.shape[2], pool.shape[3]
+    num_pages, ps = pool[0][0].shape[:2]
     scale = 1.0 / (hd ** 0.5)
     pos = start + jnp.arange(b_, dtype=jnp.int32)
     valid = jnp.arange(b_) < chunk_len
     total = start + chunk_len
     x = params["embed"][tokens]
-    page = table[pos // ps]
-    idx = jnp.where(valid, page * ps + pos % ps,
-                    num_pages * ps).astype(jnp.int32)
+    page = jnp.where(valid, table[pos // ps], num_pages).astype(jnp.int32)
+    offset = pos % ps
     p_ = table.shape[0]
-    for li, lp in enumerate(params["layers"]):
+    out = []
+    for (kbuf, vbuf), lp in zip(pool, params["layers"]):
         h1 = _rms(x, lp["ln1"])
         q = rope((h1 @ lp["wq"]).reshape(b_, h_, hd), pos,
                  base=mdl.rope_base)
         k = rope((h1 @ lp["wk"]).reshape(b_, h_, hd), pos,
                  base=mdl.rope_base)
         v = (h1 @ lp["wv"]).reshape(b_, h_, hd)
-        pool = _write_kv(pool, li, idx, k, v)
+        kbuf, vbuf = _write_kv(kbuf, vbuf, page, offset, k, v)
+        out.append((kbuf, vbuf))
         # chunk attends its causal prefix (earlier chunks included)
         # over the slot's gathered pages — the chunk itself was just
         # written, so one mask covers intra- and cross-chunk keys
-        kctx = pool[li, 0][table].reshape(p_ * ps, h_, hd)
-        vctx = pool[li, 1][table].reshape(p_ * ps, h_, hd)
+        kctx = kbuf[table].reshape(p_ * ps, h_, hd)
+        vctx = vbuf[table].reshape(p_ * ps, h_, hd)
         s = jnp.einsum("bhd,khd->bhk", q.astype(jnp.float32),
                        kctx.astype(jnp.float32)) * scale
         kpos = lax.broadcasted_iota(jnp.int32, s.shape, 2)
@@ -337,7 +342,7 @@ def _prefill_core(mdl: DecodeModel, params, pool, tokens, start,
     last = lax.dynamic_index_in_dim(x, jnp.maximum(chunk_len - 1, 0),
                                     axis=0, keepdims=False)
     logits = last @ params["embed"].T
-    return pool, jnp.argmax(logits).astype(jnp.int32)
+    return tuple(out), jnp.argmax(logits).astype(jnp.int32)
 
 
 # -- the engine --------------------------------------------------------------

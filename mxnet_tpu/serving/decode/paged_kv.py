@@ -1,18 +1,22 @@
 """Paged KV cache: pre-allocated device pool + host page allocator.
 
-The device side is ONE array per engine, ``(layers, 2, num_pages,
-page_size, heads * head_dim)`` (k and v stacked on axis 1; heads folded
-into the lane axis, the layout the ``paged_attention`` kernel reads
-without a relayout on the TPU), allocated once at construction and
-threaded through every compiled decode/prefill executable — sequence
-state never changes a shape.  The host
+The device side is ONE pytree per engine, ``pool[layer] = (k, v)``:
+a buffer of its own for every layer's K and for its V, each
+``(num_pages, page_size, heads * head_dim)`` (heads folded into the
+lane axis, the layout the ``paged_attention`` kernel reads without a
+relayout on the TPU), allocated once at construction and threaded,
+donated, through every compiled decode/prefill executable — sequence
+state never changes a shape.  A buffer is the unit an executable works
+on: it scatters the step's rows into it in place and hands it whole to
+the kernel, which a slice of one larger array would not allow without
+a copy (a custom call's operand is a whole buffer).  The host
 side is a free-list page allocator with per-slot page tables: slots
 acquire pages at admission, the tables are passed to the executables
 as traced ``(max_slots, pages_per_slot)`` int32 arrays, and eviction
 returns pages to the free list for the next request (recycling — no
 device traffic on either path).
 
-Row ``num_pages`` — one past the pool — is the scatter sentinel: KV
+Page ``num_pages`` — one past a buffer — is the scatter sentinel: KV
 writes for inactive slots / padded prefill rows are directed there and
 dropped by XLA (``mode="drop"``), so masking never needs a branch.
 """
@@ -66,6 +70,10 @@ class PageAllocator:
 class PagedKVCache:
     """One engine's KV state: device pool + slot page tables.
 
+    ``pool`` is the device state, a tuple over layers of ``(k, v)``
+    buffers; an executable that was given it returns its successor,
+    which the engine stores back.
+
     ``pages_per_slot`` bounds a single slot's table width (the traced
     table shape); a slot's token capacity is
     ``pages_per_slot * page_size``."""
@@ -84,9 +92,11 @@ class PagedKVCache:
         self.pages_per_slot = int(
             pages_per_slot if pages_per_slot is not None
             else max(1, num_pages // max(1, max_slots)))
-        self.pool = jnp.zeros(
-            (self.layers, 2, self.num_pages, self.page_size,
-             self.heads * self.head_dim), dtype=dtype)
+        shape = (self.num_pages, self.page_size,
+                 self.heads * self.head_dim)
+        self.pool = tuple(
+            (jnp.zeros(shape, dtype=dtype), jnp.zeros(shape, dtype=dtype))
+            for _ in range(self.layers))
         self.allocator = PageAllocator(self.num_pages)
         # traced inputs: page-table rows + a scratch row of zeros for
         # freed slots (page 0 ids are fine — masked by length 0)

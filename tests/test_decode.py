@@ -152,6 +152,100 @@ def test_paged_attention_ragged_parity_vs_oracle():
     assert not onp.asarray(out)[1].any()    # length-0 slot → zeros
 
 
+# -- the pool's layout: one whole buffer per layer for K and for V -----------
+
+def _whole_buffer_equations(jaxpr, nelem):
+    """Primitive names of every equation, nested ones included, that
+    produces an array of at least ``nelem`` elements.  A Pallas call's
+    body is the kernel's own (blocks, not buffers) and is not entered."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if any(getattr(v.aval, "size", 0) >= nelem for v in eqn.outvars):
+            found.append(eqn.primitive.name)
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in eqn.params.values():
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                found += _whole_buffer_equations(sub, nelem)
+    return found
+
+
+@pytest.mark.parametrize("core", ["decode", "prefill", "verify"])
+def test_cores_touch_a_layer_buffer_only_by_its_scatter(model, core):
+    """No core slices a layer's K or V out of a larger array, reshapes
+    it or sets it back: per layer the only equations as large as a
+    buffer are the two scatters of ``_write_kv``.  (A slice of buffer
+    size is a 201 MB copy in front of the Mosaic call on the chip.)"""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.serving.decode import engine as E
+    # half the pool per slot table, so no gather is as large as a buffer
+    eng = _engine(model, pages_per_slot=4)
+    slots, pool = eng.max_slots, eng.cache.pool
+    ints = jnp.zeros((slots,), jnp.int32)
+    tables, act = eng._tables(eng.cache), jnp.ones((slots,), bool)
+    if core == "decode":
+        fn, args = E._decode_core, (ints, ints, tables, act)
+    elif core == "verify":
+        fn, args = E._verify_core, (jnp.zeros((slots, 3), jnp.int32),
+                                    ints, tables, act)
+    else:
+        fn, args = E._prefill_core, (jnp.zeros((8,), jnp.int32),
+                                     jnp.int32(0), jnp.int32(5),
+                                     tables[0])
+    jaxpr = jax.make_jaxpr(
+        lambda p, kv, *a: fn(model, p, kv, *a))(model.params, pool, *args)
+    big = _whole_buffer_equations(jaxpr.jaxpr, pool[0][0].size)
+    assert big == ["scatter"] * (2 * model.n_layers), big
+
+
+@pytest.mark.parametrize("spec", [False, True], ids=["plain", "spec"])
+def test_pool_is_one_k_and_one_v_buffer_per_layer(model, draft, spec):
+    """What every executable is handed and hands back: a tuple of
+    ``(k, v)`` per layer, each ``(num_pages, page_size, H*D)``, the same
+    structure after a prefill chunk and a decode (or speculative) step,
+    and the step's rows written where the page table points."""
+    import jax
+    eng = _engine(model, **(dict(draft_model=draft, spec_k=2)
+                            if spec else {}))
+    caches = [(eng.cache, model)]
+    if spec:
+        caches.append((eng.draft_cache, draft))
+    before = [jax.tree_util.tree_structure(c.pool) for c, _ in caches]
+    prompt = _prompts(1, lo=5, hi=5, seed=11)[0]
+    eng.acquire_slot(0, 8)      # holds page 0 and stays inactive
+    eng.acquire_slot(1, len(prompt) + 4)
+    tok = eng.prefill_chunk_step(1, prompt, 0)
+    tokens = onp.zeros((eng.max_slots,), onp.int32)
+    pos = onp.zeros((eng.max_slots,), onp.int32)
+    active = onp.zeros((eng.max_slots,), bool)
+    tokens[1], pos[1], active[1] = tok, len(prompt), True
+    if spec:
+        eng.spec_step(tokens, pos, active)
+    else:
+        eng.decode_step(tokens, pos, active)
+    for (c, m), structure in zip(caches, before):
+        assert jax.tree_util.tree_structure(c.pool) == structure
+        assert [len(pair) for pair in c.pool] == [2] * m.n_layers
+        shape = (eng.num_pages, eng.page_size, m.n_heads * m.head_dim)
+        assert all(leaf.shape == shape
+                   for leaf in jax.tree_util.tree_leaves(c.pool))
+        # the prompt and the step's positions (one, or the window of
+        # spec_k + 1) in the slot's first page; every other page, the
+        # inactive slots' among them, untouched: their rows were dropped
+        first = c.slot_pages(1)[0]
+        written = len(prompt) + (eng.spec_k + 1 if spec else 1)
+        for leaf in jax.tree_util.tree_leaves(c.pool):
+            rows = onp.asarray(leaf).any(axis=-1)      # (pages, page_size)
+            assert rows[first, :written].all()
+            assert not rows[first, written:].any()
+            rows[first] = False
+            assert not rows.any()
+    eng.release_slot(0)
+    eng.release_slot(1)
+
+
 # -- continuous batching vs the dense oracle --------------------------------
 
 def test_scheduler_matches_greedy_reference(model):

@@ -1,5 +1,6 @@
 """The main path's Pallas kernels, compiled (not interpreted) for a
-described TPU v5e at the shapes ``chip_smoke.py`` runs — no chip needed.
+described TPU v5e at the shapes ``chip_smoke.py`` runs, and the decode
+executables at the benchmark cell's pool — no chip needed.
 
 Interpret mode accepts what the chip's compiler refuses (a batched
 matmul with no free lhs dim, a float iota, a block past the scoped-VMEM
@@ -103,6 +104,46 @@ def test_layer_norm_residual_compiles(v5e):
     g = ((512,), "bfloat16")
     _compile(lambda a, r, ga, be: _lnr_pallas(a, r, ga, be, 1e-5, 32),
              v5e, x, x, g, g)
+
+
+# -- the decode plane's executables at the benchmark cell's pool --------------
+# gpt2_decode_chat: 6144 pages x 16 x (16 heads x 64), bf16, 96 slots x 64
+# pages; 2 of its 24 layers, since every layer's buffers are treated alike.
+
+@pytest.mark.parametrize("key", ["decode", "prefill_b128"])
+def test_decode_executables_update_the_pool_in_place(v5e, key):
+    """``memory_analysis`` of the compiled executable: the whole pool is
+    aliased to the outputs, and the temporaries hold no copy of even one
+    layer buffer (a slice in front of the Mosaic call, or a buffer set
+    back after the scatter, is 201 MB each)."""
+    from mxnet_tpu.serving import DecodeModel
+    from mxnet_tpu.serving.decode import engine as E
+    layers, slots, pps = 2, 96, 64
+    mdl = DecodeModel(512, dim=1024, n_heads=16, n_layers=layers,
+                      mlp_ratio=4, dtype="bfloat16")
+
+    def spec(shape, dtype="bfloat16"):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=v5e)
+
+    params = jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype),
+                                    mdl.params)
+    buf = spec((6144, 16, 1024))
+    pool = tuple((buf, buf) for _ in range(layers))
+    if key == "decode":
+        fn, args = E._decode_core, (
+            spec((slots,), "int32"), spec((slots,), "int32"),
+            spec((slots, pps), "int32"), spec((slots,), "bool"))
+    else:
+        fn, args = E._prefill_core, (
+            spec((128,), "int32"), spec((), "int32"), spec((), "int32"),
+            spec((pps,), "int32"))
+    # donated as DecodeEngine._get_exec donates it on a TPU
+    mem = jax.jit(lambda p, kv, *a: fn(mdl, p, kv, *a),
+                  donate_argnums=(1,)).lower(
+                      params, pool, *args).compile().memory_analysis()
+    one = buf.size * buf.dtype.itemsize          # 201 MB
+    assert mem.alias_size_in_bytes == 2 * layers * one
+    assert mem.temp_size_in_bytes < one, mem.temp_size_in_bytes
 
 
 # -- the names a device trace shows ------------------------------------------
