@@ -62,6 +62,13 @@ FULL = {
                "slots": 8, "page_size": 16, "pages_per_slot": 40,
                "prefill": 128, "spec_k": 4, "max_new": 32,
                "prompts": (16, 48, 100, 128, 200, 256, 384, 512)},
+    # Falcon-H1-34B's widths at the benchmark cell's geometry
+    # (chipbench/configs/falcon_h1_34b.json): 20 query heads over 4 KV
+    # heads of 128, 96 slots x 8 pages of 128; 32 state-space heads of
+    # 128 x 256 in 2 groups
+    "hybrid": {"slots": 96, "page_size": 128, "pages_per_slot": 8,
+               "h": 20, "kv_h": 4, "d": 128,
+               "ssm_h": 32, "ssm_p": 128, "ssm_n": 256, "ssm_g": 2},
 }
 TINY = {
     "flash": {"bh": 2, "sq": 256, "sk": 256, "d": 16},
@@ -77,6 +84,9 @@ TINY = {
                "slots": 4, "page_size": 8, "pages_per_slot": 8,
                "prefill": 16, "spec_k": 2, "max_new": 6,
                "prompts": (3, 9, 20, 33)},
+    "hybrid": {"slots": 4, "page_size": 8, "pages_per_slot": 4,
+               "h": 10, "kv_h": 2, "d": 16,
+               "ssm_h": 4, "ssm_p": 16, "ssm_n": 16, "ssm_g": 2},
 }
 
 # largest |kernel - oracle| over largest |oracle|, bf16 operands against
@@ -204,7 +214,7 @@ def kernels_phase(sm: Smoke):
 
     from mxnet_tpu import kernels
 
-    dec = sm.cfg["decode"]
+    dec, hyb = sm.cfg["decode"], sm.cfg["hybrid"]
     cases = {
         "flash_attention": dict(sm.cfg["flash"], causal=True),
         "paged_attention": {"slots": dec["slots"],
@@ -212,14 +222,20 @@ def kernels_phase(sm: Smoke):
                             "page_size": dec["page_size"],
                             "h": dec["heads"],
                             "d": dec["dim"] // dec["heads"]},
+        # grouped-query: the same kernel against pools of fewer heads
+        "paged_attention.gqa": {k: hyb[k] for k in (
+            "slots", "pages_per_slot", "page_size", "h", "kv_h", "d")},
         "rope": {"r": dec["slots"] * (dec["spec_k"] + 1),
                  "h": dec["heads"], "d": dec["dim"] // dec["heads"]},
         "layer_norm_residual": sm.cfg["lnr"],
+        "ssm_update": {"slots": hyb["slots"], "h": hyb["ssm_h"],
+                       "p": hyb["ssm_p"], "n": hyb["ssm_n"],
+                       "g": hyb["ssm_g"]},
     }
     out = {"dtype": "bfloat16", "tol": TOL_BF16, "tol_grad": TOL_BF16_GRAD,
            "shapes": {}, "max_err": {}}
     for name, case in cases.items():
-        spec = kernels.get_kernel(name)
+        spec = kernels.get_kernel(name.split(".")[0])
         arrays, params = spec.make_args(dict(case, dtype="bfloat16"))
         out["shapes"][name] = [list(a.shape) for a in arrays]
 
@@ -232,8 +248,13 @@ def kernels_phase(sm: Smoke):
         got = jax.jit(run)(*arrays)
         with jax.default_matmul_precision("highest"):
             ref = jax.jit(oracle)(*_f32(arrays))
-        check_on(got, jax.devices()[:1], f"{name} output")
-        err = _scaled_err(got, ref)
+        # a kernel with several outputs (ssm_update: state and y) is
+        # held to the tolerance on each
+        outs = jax.tree_util.tree_leaves(got)
+        for o in outs:
+            check_on(o, jax.devices()[:1], f"{name} output")
+        err = max(_scaled_err(o, r) for o, r in
+                  zip(outs, jax.tree_util.tree_leaves(ref)))
         out["max_err"][name] = err
         check(err <= TOL_BF16, f"{name}: error {err:.3g} > {TOL_BF16}")
         if name != "flash_attention":
@@ -775,6 +796,10 @@ def main(argv=None):
     ap.add_argument("--rehearse", action="store_true",
                     help="tiny sizes on any backend; labelled, never ok")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--only", default="",
+                    help="a builder's convenience, no part of the smoke's "
+                         "verdict: run only the phases whose name starts "
+                         "with this (kernels: ~1 min of a chip)")
     args = ap.parse_args(argv)
 
     import jax
@@ -788,6 +813,10 @@ def main(argv=None):
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
               "count": len(devs)}
     sm = Smoke(TINY if args.rehearse else FULL, args.rehearse, args.seed)
+    if args.only:
+        every = sm.phase
+        sm.phase = lambda name, fn: (every(name, fn)
+                                     if name.startswith(args.only) else None)
     sm.emit({"phase": "device", "device": device, "jax": jax.__version__,
              "compile_cache": cache_dir})
     problem = None

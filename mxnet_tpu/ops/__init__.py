@@ -23,5 +23,6 @@ from . import contrib_extra  # noqa: F401
 from . import layernorm_residual  # noqa: F401
 from . import rope    # noqa: F401
 from . import paged_attention  # noqa: F401
+from . import ssm      # noqa: F401
 
 __all__ = ["register", "get", "list_ops", "invoke", "apply_jax"]

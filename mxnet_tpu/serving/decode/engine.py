@@ -25,6 +25,19 @@ Attention inside ``decode_step``/``verify`` runs through the
 rotary embeddings through the ``rope`` registrant (ops/rope.py), so
 block configs resolve through the kernel autotune cache exactly like
 flash attention in training.
+
+The engine is handed its model (:class:`DecodePlaneModel`): the
+parameters as a pytree, the geometry of its K/V heads, the kinds of
+per-slot recurrent state its layers keep beside K/V, and the traced
+cores of the decode step and of one prefill chunk.  The engine owns
+the cache the model's cores read and write, the executables (named
+``mxtpu_decode``, ``mxtpu_prefill_b<n>``, and ``mxtpu_state_reset`` for
+a model with recurrent state) and their donation; it knows nothing of
+a layer.  :class:`DecodeModel` is the built-in multi-head transformer;
+``falcon_h1.FalconH1`` is a hybrid of grouped-query attention and
+Mamba-2 heads.  Draft and verify are :class:`DecodeModel`'s alone: a
+rejected draft of a model with recurrent state would need the state
+from before it, and nothing snapshots it.
 """
 from __future__ import annotations
 
@@ -45,7 +58,7 @@ from ...ops.paged_attention import paged_attention
 from ...ops.rope import rope, rope_reference
 from .paged_kv import PagedKVCache
 
-__all__ = ["DecodeModel", "DecodeEngine"]
+__all__ = ["DecodePlaneModel", "DecodeModel", "DecodeEngine"]
 
 _NEG_INF = -1e30
 
@@ -69,6 +82,60 @@ def _rms(x, g, eps=1e-6):
     xf = x.astype(jnp.float32)
     scale = lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
     return (xf * scale).astype(x.dtype) * g
+
+
+class DecodePlaneModel:
+    """What :class:`DecodeEngine` asks of a model.
+
+    Attributes: ``params`` (a pytree of device arrays, the first
+    argument of every executable), ``vocab_size``, ``n_layers``,
+    ``kv_heads`` and ``head_dim`` (the K/V buffers are ``(num_pages,
+    page_size, kv_heads * head_dim)``), and ``state_spec``: the kinds of
+    per-slot recurrent state every layer keeps beside K and V, ``(name,
+    shape of one slot, dtype)`` each, in the order the layer's buffers
+    follow K and V in ``pool[layer]``.  Empty: none.
+
+    The traced cores take the cache's ``pool`` and return its
+    successor; each buffer has one writer and no reader of its old
+    value left, so a donated buffer is updated in place."""
+
+    state_spec: tuple = ()
+
+    def fingerprint(self) -> tuple:
+        """Everything the cores bake into an executable besides the
+        shapes of its arguments (the artifact store's key)."""
+        raise NotImplementedError
+
+    def decode_core(self, params, pool, tokens, positions, tables, active):
+        """One token per slot: ``(pool, next token per slot)``."""
+        raise NotImplementedError
+
+    def prefill_core(self, params, pool, tokens, start, chunk_len, table,
+                     *slot):
+        """One prompt chunk of one slot: ``(pool, next token)``.  The
+        slot's index follows ``table`` for a model with recurrent
+        state, which is addressed by it."""
+        raise NotImplementedError
+
+    def _ref_logits_last(self, tokens):
+        """Last-position logits of the model's dense forward over the
+        whole of ``tokens``, no cache: the in-program oracle."""
+        raise NotImplementedError
+
+    def greedy_reference(self, prompt, max_new_tokens: int,
+                         eos: Optional[int] = None) -> List[int]:
+        """Reference greedy generation (dense forward, full recompute
+        per token).  Returns the generated tokens only."""
+        toks = [int(t) for t in prompt]
+        out: List[int] = []
+        for _ in range(int(max_new_tokens)):
+            nxt = int(jnp.argmax(self._ref_logits_last(
+                jnp.asarray(toks, jnp.int32))))
+            out.append(nxt)
+            toks.append(nxt)
+            if eos is not None and nxt == int(eos):
+                break
+        return out
 
 
 @functools.partial(jax.jit, static_argnames=("n_heads", "rope_base"))
@@ -104,7 +171,7 @@ def _dense_logits_last(params, tokens, *, n_heads, rope_base):
     return x[-1] @ params["embed"].T
 
 
-class DecodeModel:
+class DecodeModel(DecodePlaneModel):
     """A small causal LM as a plain parameter pytree + pure functions.
 
     Deliberately framework-free (no gluon Block machinery): the decode
@@ -122,7 +189,7 @@ class DecodeModel:
             raise ValueError("head_dim must be even for rope")
         self.vocab_size = int(vocab_size)
         self.dim = int(dim)
-        self.n_heads = int(n_heads)
+        self.n_heads = self.kv_heads = int(n_heads)
         self.n_layers = int(n_layers)
         self.head_dim = dim // n_heads
         self.rope_base = float(rope_base)
@@ -151,6 +218,18 @@ class DecodeModel:
             "lnf": jnp.ones((dim,), dtype=dtype),
         }
 
+    def fingerprint(self) -> tuple:
+        return (self.vocab_size, self.dim, self.n_heads, self.n_layers,
+                self.head_dim, self.rope_base)
+
+    def decode_core(self, params, pool, tokens, positions, tables, active):
+        return _decode_core(self, params, pool, tokens, positions, tables,
+                            active)
+
+    def prefill_core(self, params, pool, tokens, start, chunk_len, table):
+        return _prefill_core(self, params, pool, tokens, start, chunk_len,
+                             table)
+
     # -- dense full-recompute oracle (tests pin the paged path to it) --------
 
     def _ref_logits_last(self, tokens):
@@ -159,27 +238,12 @@ class DecodeModel:
                                   n_heads=self.n_heads,
                                   rope_base=self.rope_base)
 
-    def greedy_reference(self, prompt, max_new_tokens: int,
-                         eos: Optional[int] = None) -> List[int]:
-        """Reference greedy generation (dense attention, full recompute
-        per token).  Returns the generated tokens only."""
-        toks = [int(t) for t in prompt]
-        out: List[int] = []
-        for _ in range(int(max_new_tokens)):
-            nxt = int(jnp.argmax(self._ref_logits_last(
-                jnp.asarray(toks, jnp.int32))))
-            out.append(nxt)
-            toks.append(nxt)
-            if eos is not None and nxt == int(eos):
-                break
-        return out
-
 
 # -- traced cores ------------------------------------------------------------
 
 def _write_kv(kbuf, vbuf, page, offset, k, v):
     """Scatter this step's K/V rows into ONE layer's own K and V
-    buffers, each ``(num_pages, page_size, H*D)``, and return both.
+    buffers, each ``(num_pages, page_size, Hkv*D)``, and return both.
     ``page``/``offset`` address one position per row; masked rows carry
     the sentinel page ``num_pages`` — one past the buffer — and are
     dropped (mode='drop').  Each buffer is a donated argument with this
@@ -345,6 +409,13 @@ def _prefill_core(mdl: DecodeModel, params, pool, tokens, start,
     return tuple(out), jnp.argmax(logits).astype(jnp.int32)
 
 
+def _state_reset_core(state, slot):
+    """Zero one slot's rows of every layer's recurrent-state buffers
+    (``state[layer]`` is ``pool[layer]`` without K and V)."""
+    return tuple(tuple(buf.at[slot].set(0) for buf in layer)
+                 for layer in state)
+
+
 # -- the engine --------------------------------------------------------------
 
 class DecodeEngine:
@@ -353,7 +424,7 @@ class DecodeEngine:
     ``MXNET_DECODE_SLOTS`` / ``MXNET_DECODE_PAGES`` /
     ``MXNET_DECODE_PAGE_SIZE`` / ``MXNET_DECODE_SPEC_K``."""
 
-    def __init__(self, model: DecodeModel, *,
+    def __init__(self, model: DecodePlaneModel, *,
                  draft_model: Optional[DecodeModel] = None,
                  spec_k: Optional[int] = None,
                  max_slots: Optional[int] = None,
@@ -364,6 +435,15 @@ class DecodeEngine:
                  prefill_floor: int = 16):
         self.model = model
         self.draft = draft_model
+        if model.state_spec:
+            if draft_model is not None or spec_k:
+                raise ValueError(
+                    f"{type(model).__name__} keeps recurrent state "
+                    f"({', '.join(n for n, _, _ in model.state_spec)}) that "
+                    f"a rejected draft token would already have advanced; "
+                    f"speculative decode (draft_model / spec_k) needs state "
+                    f"snapshots, which the decode plane does not have")
+            spec_k = 0
         self.max_slots = (int(max_slots) if max_slots is not None
                           else _env_int("MXNET_DECODE_SLOTS", 8))
         self.page_size = (int(page_size) if page_size is not None
@@ -382,10 +462,11 @@ class DecodeEngine:
                 raise ValueError("draft/target vocab sizes differ")
         self.cache = PagedKVCache(
             layers=model.n_layers, num_pages=self.num_pages,
-            page_size=self.page_size, heads=model.n_heads,
+            page_size=self.page_size, heads=model.kv_heads,
             head_dim=model.head_dim, max_slots=self.max_slots,
             pages_per_slot=pages_per_slot,
-            dtype=model.params["embed"].dtype)
+            dtype=model.params["embed"].dtype,
+            state_spec=model.state_spec)
         self.draft_cache = None
         if draft_model is not None:
             self.draft_cache = PagedKVCache(
@@ -414,12 +495,8 @@ class DecodeEngine:
 
     @staticmethod
     def _model_fp(mdl):
-        """Architecture fingerprint of one model for artifact keys —
-        everything the traced cores bake in besides the arg shapes."""
-        if mdl is None:
-            return None
-        return (mdl.vocab_size, mdl.dim, mdl.n_heads, mdl.n_layers,
-                mdl.head_dim, mdl.rope_base)
+        """Architecture fingerprint of one model for artifact keys."""
+        return None if mdl is None else mdl.fingerprint()
 
     def _artifact_sig(self, key: str, args):
         """Content signature of one decode executable: the exec key,
@@ -432,10 +509,11 @@ class DecodeEngine:
                 tuple((tuple(jnp.shape(l)), str(jnp.result_type(l)))
                       for l in leaves))
 
-    def _get_exec(self, key: str, fn, args):
+    def _get_exec(self, key: str, fn, args, donate=(1,)):
         """Load-or-compile one executable WITHOUT running it.  Order:
         in-process memo → artifact store (deserialize; ``compiles``
-        stays 0) → jit compile (ticks ``compiles``, commits back)."""
+        stays 0) → jit compile (ticks ``compiles``, commits back).
+        ``donate`` is the position of the cache's buffers in ``args``."""
         ex = self._exec.get(key)
         if ex is not None:
             return ex
@@ -445,7 +523,7 @@ class DecodeEngine:
         if art is not None:
             self._exec[key] = art.compiled
             return art.compiled
-        donate = ((1,) if jax.default_backend() == "tpu" else ())
+        donate = (donate if jax.default_backend() == "tpu" else ())
         # the executable's name in a device trace: jit_mxtpu_<key>
         fn.__name__ = fn.__qualname__ = f"mxtpu_{key}"
         t0 = time.perf_counter()
@@ -456,11 +534,31 @@ class DecodeEngine:
         artifacts.save("decode_exec", asig, ex, meta={"exec_key": key})
         return ex
 
-    def _call(self, key: str, fn, args):
-        return self._get_exec(key, fn, args)(*args)
+    def _call(self, key: str, fn, args, donate=(1,)):
+        return self._get_exec(key, fn, args, donate)(*args)
+
+    # -- per-slot recurrent state -------------------------------------------
+
+    def _state(self):
+        """The recurrent-state buffers of every layer, without K/V."""
+        return tuple(layer[2:] for layer in self.cache.pool)
+
+    def _reset_state(self, slot: int) -> None:
+        """Zero ``slot``'s rows of every state buffer, in place."""
+        state = self._call(
+            "state_reset", lambda *a: _state_reset_core(*a),
+            (self._state(), jnp.asarray(slot, jnp.int32)), donate=(0,))
+        self.cache.pool = tuple(layer[:2] + st for layer, st
+                                in zip(self.cache.pool, state))
 
     def _tables(self, cache) -> jnp.ndarray:
         return jnp.asarray(cache.tables, jnp.int32)
+
+    def _slot_arg(self, slot: int) -> tuple:
+        """The slot's index as a prefill argument, for a model whose
+        state it addresses; nothing for one that is all pages."""
+        return ((jnp.asarray(slot, jnp.int32),) if self.model.state_spec
+                else ())
 
     def warmup(self, prefill_lengths: Sequence[int] = (1,)) -> List[str]:
         """Materialize every executable this engine will dispatch —
@@ -481,12 +579,15 @@ class DecodeEngine:
         pos = jnp.zeros((s,), jnp.int32)
         act = jnp.zeros((s,), bool)
         self._get_exec(
-            "decode",
-            lambda p, kv, t, po, tb, a:
-            _decode_core(mdl, p, kv, t, po, tb, a),
+            "decode", lambda *a: mdl.decode_core(*a),
             (mdl.params, self.cache.pool, tok, pos,
              self._tables(self.cache), act))
         keys.append("decode")
+        if mdl.state_spec:
+            self._get_exec("state_reset", lambda *a: _state_reset_core(*a),
+                           (self._state(), jnp.asarray(0, jnp.int32)),
+                           donate=(0,))
+            keys.append("state_reset")
         if self.spec_enabled:
             dm, k = self.draft, self.spec_k
             self._get_exec(
@@ -510,10 +611,9 @@ class DecodeEngine:
             clen = jnp.asarray(1, jnp.int32)
             row = jnp.asarray(self.cache.tables[0], jnp.int32)
             self._get_exec(
-                f"prefill_b{bucket}",
-                lambda p, kv, t, st, cl, tb:
-                _prefill_core(mdl, p, kv, t, st, cl, tb),
-                (mdl.params, self.cache.pool, padded, start, clen, row))
+                f"prefill_b{bucket}", lambda *a: mdl.prefill_core(*a),
+                (mdl.params, self.cache.pool, padded, start, clen, row)
+                + self._slot_arg(0))
             keys.append(f"prefill_b{bucket}")
             if self.draft_cache is not None:
                 dm = self.draft
@@ -539,10 +639,8 @@ class DecodeEngine:
                     jnp.asarray(positions, jnp.int32),
                     self._tables(self.cache),
                     jnp.asarray(active, bool))
-        pool, nxt = self._call(
-            "decode",
-            lambda p, kv, t, po, tb, a:
-            _decode_core(mdl, p, kv, t, po, tb, a), args)
+        pool, nxt = self._call("decode", lambda *a: mdl.decode_core(*a),
+                               args)
         self.cache.pool = pool
         with tracing.span("decode.sync"):
             return onp.asarray(nxt)
@@ -584,11 +682,10 @@ class DecodeEngine:
             args = (mdl.params, self.cache.pool, jnp.asarray(padded),
                     jnp.asarray(start, jnp.int32),
                     jnp.asarray(len(chunk), jnp.int32),
-                    jnp.asarray(self.cache.tables[slot], jnp.int32))
-        pool, nxt = self._call(
-            f"prefill_b{bucket}",
-            lambda p, kv, t, st, cl, tb:
-            _prefill_core(mdl, p, kv, t, st, cl, tb), args)
+                    jnp.asarray(self.cache.tables[slot], jnp.int32)) \
+                + self._slot_arg(slot)
+        pool, nxt = self._call(f"prefill_b{bucket}",
+                               lambda *a: mdl.prefill_core(*a), args)
         self.cache.pool = pool
         if self.draft_cache is not None:
             dm = self.draft
@@ -611,6 +708,10 @@ class DecodeEngine:
 
     def acquire_slot(self, slot: int, tokens: int) -> None:
         self.cache.acquire(slot, tokens)
+        if self.cache.state_spec:
+            # whoever held the slot last left its state behind
+            with tracing.span("decode.state_reset", slot=slot):
+                self._reset_state(slot)
         if self.draft_cache is not None:
             try:
                 self.draft_cache.acquire(slot, tokens)
@@ -639,4 +740,7 @@ class DecodeEngine:
                 "num_pages": self.num_pages,
                 "pages_used": self.cache.pages_used(),
                 "slot_capacity": self.slot_capacity,
-                "spec_k": self.spec_k if self.spec_enabled else 0}
+                "spec_k": self.spec_k if self.spec_enabled else 0,
+                "state_bytes": self.cache.state_bytes,
+                "state_slots_live": self.cache.state_slots_live(),
+                "state_resets": self.cache.state_resets}

@@ -1,12 +1,23 @@
 """Paged KV cache: pre-allocated device pool + host page allocator.
 
-The device side is ONE pytree per engine, ``pool[layer] = (k, v)``:
-a buffer of its own for every layer's K and for its V, each
-``(num_pages, page_size, heads * head_dim)`` (heads folded into the
-lane axis, the layout the ``paged_attention`` kernel reads without a
-relayout on the TPU), allocated once at construction and threaded,
+The device side is ONE pytree per engine, ``pool[layer] = (k, v,
+*state)``: a buffer of its own for every layer's K and for its V, each
+``(num_pages, page_size, heads * head_dim)`` (``heads`` the KV heads —
+fewer than the query heads under grouped-query attention — folded into
+the lane axis, the layout the ``paged_attention`` kernel reads without
+a relayout on the TPU), allocated once at construction and threaded,
 donated, through every compiled decode/prefill executable — sequence
-state never changes a shape.  A buffer is the unit an executable works
+state never changes a shape.
+
+A model whose layers also carry recurrent state (a state-space
+layer's state matrix, a convolution's tail) names each kind in
+``state_spec`` and gets, after K and V, one more buffer per kind and
+layer, ``(max_slots, *shape)``: addressed by the slot itself, not
+through pages, its size does not grow with the sequence.  It lives
+and dies with the slot: :meth:`PagedKVCache.acquire` counts the slot
+as live (the engine zeroes its rows then, on the device),
+:meth:`release` gives it back, and the executables leave an inactive
+slot's rows as they are.  A buffer is the unit an executable works
 on: it scatters the step's rows into it in place and hands it whole to
 the kernel, which a slice of one larger array would not allow without
 a copy (a custom call's operand is a whole buffer).  The host
@@ -23,7 +34,7 @@ dropped by XLA (``mode="drop"``), so masking never needs a branch.
 from __future__ import annotations
 
 import threading
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as onp
 
@@ -70,9 +81,12 @@ class PageAllocator:
 class PagedKVCache:
     """One engine's KV state: device pool + slot page tables.
 
-    ``pool`` is the device state, a tuple over layers of ``(k, v)``
-    buffers; an executable that was given it returns its successor,
-    which the engine stores back.
+    ``pool`` is the device state, a tuple over layers of ``(k, v,
+    *state)`` buffers; an executable that was given it returns its
+    successor, which the engine stores back.  ``heads`` counts KV
+    heads.  ``state_spec`` lists the kinds of per-slot recurrent state
+    a layer holds, ``(name, shape of one slot, dtype)`` each; empty for
+    a model that has none.
 
     ``pages_per_slot`` bounds a single slot's table width (the traced
     table shape); a slot's token capacity is
@@ -81,7 +95,8 @@ class PagedKVCache:
     def __init__(self, *, layers: int, num_pages: int, page_size: int,
                  heads: int, head_dim: int, max_slots: int,
                  pages_per_slot: Optional[int] = None,
-                 dtype="float32"):
+                 dtype="float32",
+                 state_spec: Sequence[Tuple[str, tuple, str]] = ()):
         import jax.numpy as jnp
         self.layers = int(layers)
         self.num_pages = int(num_pages)
@@ -94,9 +109,17 @@ class PagedKVCache:
             else max(1, num_pages // max(1, max_slots)))
         shape = (self.num_pages, self.page_size,
                  self.heads * self.head_dim)
+        self.state_spec = tuple((str(n), tuple(int(d) for d in sh), str(dt))
+                                for n, sh, dt in state_spec)
         self.pool = tuple(
             (jnp.zeros(shape, dtype=dtype), jnp.zeros(shape, dtype=dtype))
+            + tuple(jnp.zeros((self.max_slots,) + sh, dtype=dt)
+                    for _, sh, dt in self.state_spec)
             for _ in range(self.layers))
+        self.state_resets = 0
+        # bytes of recurrent state on the device, all layers and slots
+        self.state_bytes = sum(buf.size * buf.dtype.itemsize
+                               for layer in self.pool for buf in layer[2:])
         self.allocator = PageAllocator(self.num_pages)
         # traced inputs: page-table rows + a scratch row of zeros for
         # freed slots (page 0 ids are fine — masked by length 0)
@@ -111,6 +134,10 @@ class PagedKVCache:
 
     def pages_used(self) -> int:
         return self.allocator.used
+
+    def state_slots_live(self) -> int:
+        """Slots whose recurrent state belongs to a request."""
+        return len(self._slot_pages) if self.state_spec else 0
 
     def pages_for(self, tokens: int) -> int:
         return -(-int(tokens) // self.page_size)
@@ -132,6 +159,11 @@ class PagedKVCache:
         row[:need] = pages
         self.tables[slot] = row
         telemetry.gauge("decode.pages_used").set(self.pages_used())
+        if self.state_spec:
+            self.state_resets += 1
+            telemetry.counter("decode.state_resets").inc()
+            telemetry.gauge("decode.state_slots_live").set(
+                self.state_slots_live())
 
     def release(self, slot: int) -> int:
         """Return ``slot``'s pages to the free list; returns the count
@@ -142,6 +174,9 @@ class PagedKVCache:
         self.allocator.free(pages)
         self.tables[slot] = 0
         telemetry.gauge("decode.pages_used").set(self.pages_used())
+        if self.state_spec:
+            telemetry.gauge("decode.state_slots_live").set(
+                self.state_slots_live())
         return len(pages)
 
     def slot_pages(self, slot: int) -> List[int]:

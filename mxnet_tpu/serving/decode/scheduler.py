@@ -8,6 +8,15 @@ the active-slot mask, per-slot positions and page tables are traced
 int arrays, so admission and completion never recompile; a request
 joining mid-flight costs one table row, not an XLA trace.
 
+A slot is a row of the executables' grid, and what it owns on the
+device is the engine's to know: pages of K/V addressed through its
+table row and, for a model with recurrent layers, its rows of the
+per-slot state buffers, which the engine zeroes at admission
+(``decode.state_reset``) and which no page addresses.  The scheduler
+batches over slots of either kind alike.  Speculation is the one thing
+it cannot give a model with recurrent state (the engine refuses the
+pairing: a rejected draft would need the state from before it).
+
 Each step (one turn of :meth:`step`, driven by the background thread
 or manually):
 
@@ -339,6 +348,9 @@ class DecodeScheduler:
                 "compiles": compiles,
                 "spec_proposed": self._spec_proposed,
                 "spec_accepted": self._spec_accepted,
+                "state_bytes": eng.cache.state_bytes,
+                "state_slots_live": eng.cache.state_slots_live(),
+                "state_resets": eng.cache.state_resets,
                 "step_ms": round((time.perf_counter() - t_step) * 1e3, 3),
             }
             if ttfts:

@@ -280,11 +280,12 @@ exempt([
    "(test_layer_norm_residual_op_and_grads)")
 
 exempt([
-    "rope", "paged_attention",
+    "rope", "paged_attention", "ssm_update",
 ], "decode-serving inference kernels (rotary embedding, paged-KV "
-   "attention): forward-only registrants pinned against their XLA "
-   "oracles in test_kernels/test_decode; no training path invokes "
-   "them, so there is no vjp to fd-check")
+   "attention, the state-space decode update): forward-only "
+   "registrants pinned against their XLA oracles in "
+   "test_kernels/test_decode/test_decode_hybrid; no training path "
+   "invokes them, so there is no vjp to fd-check")
 
 exempt([
     "_subgraph_exec",

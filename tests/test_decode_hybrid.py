@@ -1,0 +1,567 @@
+"""The decode plane with a second kind of model: Falcon-H1's hybrid
+block (grouped-query attention beside Mamba-2 heads) behind the model
+protocol, per-slot recurrent state beside the paged K/V, the
+``ssm_update`` kernel and grouped-query ``paged_attention``.
+
+All at the benchmark configuration's ``rehearsal`` size (same ratios as
+the published model: 5 query heads a KV head, 2 groups, convolution 4),
+seeded random weights, on the CPU with the kernels interpreted:
+
+- prefill in chunks then decode through the cache against the plain
+  reference's full pass (``chipbench/reference/falcon_h1_ref.py``), on
+  LOGITS, float32 and bfloat16 each with its own tolerance, prompts
+  shorter than, equal to and longer than a chunk, two slots admitted at
+  different times sharing decode steps;
+- ``ssm_update`` against its oracle, the chunked scan against the
+  time-step recurrence over ragged lengths and a non-zero initial state;
+- grouped-query ``paged_attention`` against its oracle, multi-head kept;
+- a slot released and re-acquired starts from zero state;
+- speculation with a recurrent model is refused;
+- through ``DecodeScheduler`` and ``ServingServer`` the model answers on
+  the entry points ``DecodeModel`` has, token for token its dense oracle.
+"""
+import importlib.util
+import json
+import pathlib
+
+import numpy as onp
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import mxnet_tpu as mx  # noqa: F401  (registers ops + kernel specs)
+from mxnet_tpu import kernels, telemetry, tracing
+from mxnet_tpu.ops import ssm
+from mxnet_tpu.ops.paged_attention import (paged_attention,
+                                           paged_attention_reference)
+from mxnet_tpu.serving import (DecodeEngine, DecodeModel, DecodeScheduler,
+                               FalconH1, ServingServer)
+
+REPO = pathlib.Path(__file__).resolve().parent.parent
+CHUNK = 16
+
+
+def _load(path, name):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture(scope="module")
+def ref():
+    return _load(REPO / "chipbench" / "reference" / "falcon_h1_ref.py",
+                 "falcon_h1_ref")
+
+
+def _config(multipliers="published"):
+    with open(REPO / "chipbench" / "configs" / "falcon_h1_34b.json") as f:
+        cfg = json.load(f)
+    cfg.update(cfg.pop("rehearsal"))
+    if multipliers == "ones":
+        # every mixer at full weight in the residual stream: an error in
+        # one of them cannot hide behind a small multiplier
+        for k in list(cfg):
+            if k.endswith("_multiplier"):
+                cfg[k] = 1.0
+        cfg["ssm_multipliers"] = [1.0] * 5
+        cfg["mlp_multipliers"] = [1.0, 1.0]
+    return cfg
+
+
+@pytest.fixture(scope="module")
+def models():
+    made = {}
+
+    def get(dtype="float32", multipliers="published"):
+        key = (dtype, multipliers)
+        if key not in made:
+            cfg = _config(multipliers)
+            made[key] = (FalconH1(cfg, seed=5, dtype=dtype), cfg)
+        return made[key]
+
+    return get
+
+
+def _engine(model, **kw):
+    kw.setdefault("max_slots", 3)
+    kw.setdefault("page_size", 8)
+    kw.setdefault("pages_per_slot", 8)
+    kw.setdefault("num_pages", 24)
+    kw.setdefault("prefill_chunk", CHUNK)
+    kw.setdefault("prefill_floor", 8)
+    return DecodeEngine(model, **kw)
+
+
+def _tokens(n, seed, vocab=128):
+    return [int(t) for t in
+            onp.random.RandomState(seed).randint(0, vocab, size=n)]
+
+
+class _Through:
+    """Drives an engine's cache by hand, keeping the logits the engine's
+    own executables reduce to a token."""
+
+    def __init__(self, model, eng):
+        self.model, self.eng = model, eng
+        self.decode = jax.jit(model.decode_logits)
+        self.prefill = jax.jit(model.prefill_logits)
+
+    def feed_prompt(self, slot, prompt):
+        """Chunked prefill of ``prompt``; the logits after its last
+        token."""
+        eng, logits = self.eng, None
+        for start in range(0, len(prompt), CHUNK):
+            chunk = prompt[start:start + CHUNK]
+            padded = onp.zeros((eng.prefill_bucket(len(chunk)),), onp.int32)
+            padded[:len(chunk)] = chunk
+            eng.cache.pool, logits = self.prefill(
+                self.model.params, eng.cache.pool, jnp.asarray(padded),
+                jnp.asarray(start, jnp.int32),
+                jnp.asarray(len(chunk), jnp.int32),
+                jnp.asarray(eng.cache.tables[slot], jnp.int32),
+                jnp.asarray(slot, jnp.int32))
+        return onp.asarray(logits, onp.float32)
+
+    def step(self, feed):
+        """One decode step; ``feed`` maps slot -> (token, position).
+        Logits per slot fed."""
+        n = self.eng.max_slots
+        tok, pos = onp.zeros((n,), onp.int32), onp.zeros((n,), onp.int32)
+        act = onp.zeros((n,), bool)
+        for s, (t, p) in feed.items():
+            tok[s], pos[s], act[s] = t, p, True
+        self.eng.cache.pool, logits = self.decode(
+            self.model.params, self.eng.cache.pool, jnp.asarray(tok),
+            jnp.asarray(pos), jnp.asarray(self.eng.cache.tables, jnp.int32),
+            jnp.asarray(act))
+        return {s: onp.asarray(logits[s], onp.float32) for s in feed}
+
+
+def _scaled(got, want):
+    return float(onp.abs(got - want).max() / onp.abs(want).max())
+
+
+# Largest |logit - reference| over largest |reference|.
+# float32: the cached path and the reference differ only in the order of
+# float32 sums (chunked scan or kernel against the recurrence, online
+# against plain softmax): measured 5.2e-7 to 8.7e-7 over these cases.
+# 1e-5 leaves an order of magnitude for another machine's sums and sits
+# a thousand times under what bfloat16 shows, so a path that quietly
+# ran in bfloat16 fails it.
+# bfloat16: the weights are the same bfloat16 numbers on both sides; the
+# program rounds every activation to 8 bits of mantissa (2^-9 = 2e-3 a
+# rounding) where the reference keeps float32, through two layers and a
+# head: measured 9.8e-3 to 1.5e-2 over these cases, so 3e-2.
+# What a wrong term reads (the reference against itself, float32):
+# without the convolution's oldest tap 0.47, with the keys zeroed 0.43.
+# The weights are drawn at the fan-in scale over their multiplier, as a
+# muP-trained checkpoint has them, so every branch weighs in the logits
+# at the published multipliers (test_every_branch_weighs_in_the_logits);
+# with every multiplier at 1 the model computes the same function from
+# other numbers, which holds each multiplier to the reference's place
+# for it.
+TOL = {"float32": 1e-5, "bfloat16": 3e-2}
+
+
+@pytest.mark.parametrize("multipliers", ["published", "ones"])
+@pytest.mark.parametrize("prompt_len", [5, CHUNK, 37],
+                         ids=["short", "one_chunk", "three_chunks"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_prefill_then_cached_decode_matches_the_reference_on_logits(
+        models, ref, dtype, prompt_len, multipliers):
+    """Slot 1 prefills and decodes; two steps in, slot 2 is admitted with
+    a prompt of its own, and both decode in the same steps."""
+    model, cfg = models(dtype, multipliers)
+    new = 6
+    seq_a = _tokens(prompt_len + new, seed=prompt_len)
+    seq_b = _tokens(21 + new, seed=100 + prompt_len)
+    with jax.default_matmul_precision("highest"):
+        want_a = onp.asarray(ref.forward(model.params,
+                                         jnp.asarray(seq_a), cfg))
+        want_b = onp.asarray(ref.forward(model.params,
+                                         jnp.asarray(seq_b), cfg))
+    eng = _engine(model)
+    run = _Through(model, eng)
+    errs = []
+    eng.acquire_slot(1, len(seq_a))
+    errs.append(_scaled(run.feed_prompt(1, seq_a[:prompt_len]),
+                        want_a[prompt_len - 1]))
+    pa, pb = prompt_len, 21
+    for _ in range(2):
+        got = run.step({1: (seq_a[pa], pa)})
+        errs.append(_scaled(got[1], want_a[pa]))
+        pa += 1
+    eng.acquire_slot(2, len(seq_b))
+    errs.append(_scaled(run.feed_prompt(2, seq_b[:21]), want_b[20]))
+    while pa < len(seq_a):
+        got = run.step({1: (seq_a[pa], pa), 2: (seq_b[pb], pb)})
+        errs.append(_scaled(got[1], want_a[pa]))
+        errs.append(_scaled(got[2], want_b[pb]))
+        pa, pb = pa + 1, pb + 1
+    assert max(errs) <= TOL[dtype], errs
+
+
+@pytest.mark.parametrize("matrix", ["wk", "wo", "w_out", "w_down"])
+def test_every_branch_weighs_in_the_logits(models, matrix):
+    """With one branch's matrix zeroed (the keys, the attention heads'
+    output, the state-space heads' output, the MLP's) the logits move by
+    a tenth of their size or more: measured 0.43, 0.57, 0.83, 0.54.  A
+    comparison on logits then sees a fault in any of them; drawn at the
+    plain fan-in scale the keys read 4e-4 here."""
+    model, _ = models("float32")
+    seq = jnp.asarray(_tokens(43, seed=37))
+    without = dict(model.params, layers=[
+        dict(lp, **{matrix: jnp.zeros_like(lp[matrix])})
+        for lp in model.params["layers"]])
+    with jax.default_matmul_precision("highest"):
+        want = onp.asarray(model.dense_logits(model.params, seq))
+        got = onp.asarray(model.dense_logits(without, seq))
+    assert _scaled(got, want) >= 0.1
+
+
+def test_dense_oracle_is_the_reference(models, ref):
+    model, cfg = models("float32")
+    seq = jnp.asarray(_tokens(40, seed=1))
+    with jax.default_matmul_precision("highest"):
+        want = onp.asarray(ref.forward(model.params, seq, cfg))
+        got = onp.asarray(model.dense_logits(model.params, seq))
+    assert _scaled(got, want) <= 1e-5
+
+
+def test_a_released_slot_starts_again_from_zero_state(models, ref):
+    model, cfg = models("float32", "ones")
+    eng = _engine(model)
+    run = _Through(model, eng)
+    first, second = _tokens(30, seed=3), _tokens(19, seed=4)
+    eng.acquire_slot(0, 40)
+    run.feed_prompt(0, first)
+    run.step({0: (7, 30)})
+    ssm_buf, conv_buf = eng.cache.pool[0][2:]
+    assert float(jnp.abs(ssm_buf[0]).max()) > 0
+    assert float(jnp.abs(conv_buf[0]).max()) > 0
+    assert float(jnp.abs(ssm_buf[1:]).max()) == 0      # the others: never
+    eng.release_slot(0)
+    eng.acquire_slot(0, 40)
+    for layer in eng.cache.pool:
+        for buf in layer[2:]:
+            assert float(jnp.abs(buf[0]).max()) == 0
+    with jax.default_matmul_precision("highest"):
+        want = onp.asarray(ref.forward(model.params, jnp.asarray(second),
+                                       cfg))
+    assert _scaled(run.feed_prompt(0, second), want[-1]) <= TOL["float32"]
+    st = eng.stats()
+    assert st["state_resets"] == 2 and st["state_slots_live"] == 1
+    assert st["state_bytes"] == sum(
+        b.size * b.dtype.itemsize for layer in eng.cache.pool
+        for b in layer[2:]) > 0
+
+
+def test_inactive_slots_keep_their_state(models):
+    model, _ = models("float32")
+    eng = _engine(model)
+    run = _Through(model, eng)
+    eng.acquire_slot(0, 30)
+    eng.acquire_slot(2, 30)
+    run.feed_prompt(0, _tokens(9, seed=5))
+    run.feed_prompt(2, _tokens(17, seed=6))
+    before = [[onp.asarray(b[2]) for b in layer[2:]]
+              for layer in eng.cache.pool]
+    run.step({0: (3, 9)})
+    for layer, was in zip(eng.cache.pool, before):
+        for buf, old in zip(layer[2:], was):
+            onp.testing.assert_array_equal(onp.asarray(buf[2]), old)
+            assert float(jnp.abs(buf[1]).max()) == 0
+
+
+@pytest.mark.parametrize("kw", [{"spec_k": 2},
+                                {"draft_model": "draft"},
+                                {"draft_model": "draft", "spec_k": 3}],
+                         ids=["spec_k", "draft", "both"])
+def test_speculation_with_recurrent_state_is_refused(models, kw):
+    model, _ = models("float32")
+    if "draft_model" in kw:
+        kw = dict(kw, draft_model=DecodeModel(128, dim=16, n_heads=2,
+                                              n_layers=1))
+    with pytest.raises(ValueError, match="recurrent state.*snapshots"):
+        _engine(model, **kw)
+    assert _engine(model).spec_enabled is False       # and plain is fine
+
+
+def test_a_config_that_asks_for_what_is_not_there_is_refused():
+    with pytest.raises(ValueError, match="lacks"):
+        FalconH1({"vocab_size": 8})
+    with pytest.raises(ValueError, match="mamba_norm_before_gate"):
+        FalconH1(dict(_config(), mamba_norm_before_gate=True))
+
+
+def test_abstract_model_has_shapes_and_no_weights():
+    model = FalconH1(_config(), abstract=True)
+    leaves = jax.tree_util.tree_leaves(model.params)
+    assert all(isinstance(a, jax.ShapeDtypeStruct) for a in leaves)
+    family = _load(REPO / "chipbench" / "models" / "falcon_h1.py",
+                   "falcon_h1_family")
+    assert sum(a.size for a in leaves) == family.param_count(_config())
+
+
+def test_param_count_by_hand():
+    family = _load(REPO / "chipbench" / "models" / "falcon_h1.py",
+                   "falcon_h1_family")
+    with open(REPO / "chipbench" / "configs" / "falcon_h1_34b.json") as f:
+        cfg = json.load(f)
+    # attention 5120x2560 + 2 x 5120x512 + 2560x5120 = 31,457,280; mixer
+    # 5120x9248 + 5120x4 + 5120 + 4096x5120 + 4096 + 96 = 68,351,072; MLP
+    # 3 x 5120x21504 = 330,301,440; two norms 10,240
+    assert family.layer_param_count(cfg) == 430_120_032
+    assert family.vocab_param_count(cfg) == 2 * 261_120 * 5_120 \
+        == 2_673_868_800
+    assert family.param_count(cfg) == cfg["parameters"] \
+        == 6 * 430_120_032 + 2_673_868_800 + 5_120
+    # one slot's state: 32 x 128 x 256 float32 = 4 MiB, read and written
+    assert family.ssm_state_bytes(cfg) == 4 << 20
+    assert family.ssm_update_bytes(cfg) == 2 * (4 << 20) \
+        + 4 * (2 * 4096 + 32 + 1024)
+    assert family.ssm_update_flops(cfg, 96) == 5 * 96 * 32 * 128 * 256
+
+
+# -- through the scheduler and the server ------------------------------------
+
+def _run(sch):
+    while sch._has_work():
+        sch.step()
+
+
+@pytest.fixture
+def _clean():
+    telemetry.clear_sinks()
+    yield
+    telemetry.clear_sinks()
+    telemetry.enabled()
+
+
+def test_scheduler_matches_the_dense_oracle_and_never_recompiles(
+        models, _clean):
+    model, _ = models("float32", "ones")
+    eng = _engine(model, max_slots=2)
+    assert eng.warmup([8, CHUNK]) == ["decode", "state_reset", "prefill_b8",
+                                      "prefill_b16"]
+    compiled = eng.compiles
+
+    class Sink:
+        records = []
+
+        def emit(self, record):
+            if "decode" in record:
+                self.records.append(record["decode"])
+
+    telemetry.add_sink(Sink())
+    sch = DecodeScheduler(eng, start=False)
+    prompts = [_tokens(n, seed=n) for n in (3, 16, 23, 40, 9)]
+    futs = [sch.submit(p, max_new_tokens=5) for p in prompts[:3]]
+    sch.step()
+    sch.step()
+    futs += [sch.submit(p, max_new_tokens=5) for p in prompts[3:]]
+    _run(sch)
+    for p, f in zip(prompts, futs):
+        assert f.result(0) == model.greedy_reference(p, 5)
+    assert eng.compiles == compiled
+    last = Sink.records[-1]
+    # the benchmark's five keys, and the state's counters beside them
+    assert {"ttft_ms", "tokens", "step_ms", "slots_active",
+            "queue_depth"} <= set().union(*(set(r) for r in Sink.records))
+    assert last["state_resets"] == 5 and last["state_slots_live"] == 0
+    assert last["state_bytes"] == eng.stats()["state_bytes"] > 0
+    assert sch.stats()["pages_used"] == 0
+
+
+def test_admission_zeroes_the_state_under_its_span(models, _clean):
+    model, _ = models("float32")
+    sch = DecodeScheduler(_engine(model), start=False)
+    sch.submit(_tokens(5, seed=1), max_new_tokens=2)
+    tracing.enable()
+    tracing.clear()
+    try:
+        _run(sch)
+        evs = sorted((e for e in tracing._completed_events()
+                      if e["name"].startswith("decode.")),
+                     key=lambda e: e["ts"])
+    finally:
+        tracing._env_default()
+        tracing.clear()
+    by_id = {e["args"]["span_id"]: e for e in evs}
+    reset, = [e for e in evs if e["name"] == "decode.state_reset"]
+    assert reset["args"]["slot"] == 0
+    assert by_id[reset["args"]["parent_id"]]["name"] == "decode.admit_phase"
+    prefill = [e for e in evs if e["name"] == "decode.prefill"][0]
+    assert reset["ts"] + reset["dur"] <= prefill["ts"] + 1
+
+
+def test_decode_model_keeps_no_state_and_its_counters_read_zero():
+    eng = DecodeEngine(DecodeModel(48, dim=32, n_heads=4, n_layers=2),
+                       max_slots=2, num_pages=8, page_size=8)
+    assert eng.cache.state_spec == () and len(eng.cache.pool[0]) == 2
+    eng.acquire_slot(0, 8)
+    st = eng.stats()
+    assert (st["state_bytes"], st["state_slots_live"],
+            st["state_resets"]) == (0, 0, 0)
+    assert "state_reset" not in eng.warmup([8])
+
+
+def test_server_generate_answers_for_the_hybrid_model(models, _clean):
+    from mxnet_tpu.gluon import nn
+    model, _ = models("float32", "ones")
+    mx.random.seed(0)
+    net = nn.Sequential()
+    net.add(nn.Dense(4, in_units=8))
+    net.initialize()
+    srv = ServingServer(net, engine_args={"example_shape": (8,),
+                                          "dtype": "float32"})
+    sch = DecodeScheduler(_engine(model), start=True)
+    srv.attach_decoder(sch)
+    p = _tokens(21, seed=8)
+    assert srv.generate(p, max_new_tokens=4) == model.greedy_reference(p, 4)
+    srv.stop(drain=True)
+    assert sch.closed
+
+
+# -- the state-space update and scan -----------------------------------------
+
+def _ssm_args(slots, active, dtype="float32", seed=0):
+    spec = kernels.get_kernel("ssm_update")
+    arrays, _ = spec.make_args({"slots": slots, "h": 4, "p": 16, "n": 32,
+                                "g": 2, "dtype": dtype})
+    return arrays[:-1] + (jnp.asarray(active),)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("active", [
+    [False, True, True, False, True, False],     # ragged
+    [True] * 6, [False] * 6,                     # all, none
+    [False, False, False, False, False, True],   # only the last
+    [True, False, False, False, False, False]],  # only the first
+    ids=["ragged", "all", "none", "last", "first"])
+def test_ssm_update_matches_its_oracle(active, dtype):
+    args = _ssm_args(6, active, dtype)
+    state, y = ssm.ssm_update(*args)
+    want_state, want_y = ssm.ssm_update_reference(*args)
+    onp.testing.assert_allclose(onp.asarray(state), onp.asarray(want_state),
+                                rtol=2e-5, atol=2e-5)
+    onp.testing.assert_allclose(onp.asarray(y), onp.asarray(want_y),
+                                rtol=2e-5, atol=2e-5)
+    idle = ~onp.asarray(active)
+    onp.testing.assert_array_equal(onp.asarray(state)[idle],
+                                   onp.asarray(args[0])[idle])
+    assert not onp.asarray(y)[idle].any()
+
+
+@pytest.mark.parametrize("block_h", [1, 2, 8])
+def test_ssm_update_head_blocks_stay_inside_a_group(block_h):
+    args = _ssm_args(4, [True, False, True, True])
+    state, y = ssm.ssm_update(*args, block_h=block_h)
+    want_state, want_y = ssm.ssm_update_reference(*args)
+    onp.testing.assert_allclose(onp.asarray(state), onp.asarray(want_state),
+                                rtol=2e-5, atol=2e-5)
+    onp.testing.assert_allclose(onp.asarray(y), onp.asarray(want_y),
+                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("cuts", [(0, 40), (0, 16, 32, 40), (0, 1, 5, 6, 40),
+                                  (0, 3, 40)],
+                         ids=["whole", "chunks", "ragged", "short_first"])
+@pytest.mark.parametrize("start", ["zero", "carried"])
+def test_chunked_scan_matches_the_recurrence(cuts, start):
+    rng = onp.random.RandomState(len(cuts))
+    t_, h, p, n, g = 40, 4, 16, 32, 2
+    x = jnp.asarray(rng.randn(t_, h, p), jnp.float32)
+    dt = jnp.asarray(rng.uniform(1e-3, 1e-1, (t_, h)), jnp.float32)
+    a = -jnp.asarray(rng.uniform(1, 16, (h,)), jnp.float32)
+    b = jnp.asarray(rng.randn(t_, g, n), jnp.float32)
+    c = jnp.asarray(rng.randn(t_, g, n), jnp.float32)
+    d = jnp.asarray(rng.randn(h), jnp.float32)
+    s0 = (jnp.zeros((h, p, n)) if start == "zero"
+          else jnp.asarray(rng.randn(h, p, n), jnp.float32))
+    want_s, want_y = ssm.ssm_scan_reference(s0, x, dt, a, b, c, d)
+    s, ys = s0, []
+    for lo, hi in zip(cuts, cuts[1:]):
+        # each piece padded to 16 rows with dt = 0, as a prefill bucket is
+        pad = -(hi - lo) % 16
+        piece = [jnp.pad(v[lo:hi], ((0, pad),) + ((0, 0),) * (v.ndim - 1))
+                 for v in (x, dt, b, c)]
+        s, y = ssm.ssm_chunk_scan(s, piece[0], piece[1], a, piece[2],
+                                  piece[3], d)
+        ys.append(y[:hi - lo])
+    onp.testing.assert_allclose(onp.asarray(s), onp.asarray(want_s),
+                                rtol=1e-4, atol=1e-5)
+    onp.testing.assert_allclose(onp.asarray(jnp.concatenate(ys)),
+                                onp.asarray(want_y), rtol=1e-4, atol=1e-4)
+
+
+def test_one_decode_update_is_one_step_of_the_recurrence():
+    args = _ssm_args(3, [True, True, True])
+    state, x, dt, a, b, c, d, _ = args
+    new, y = ssm.ssm_update(*args)
+    for s in range(3):
+        want_s, want_y = ssm.ssm_scan_reference(
+            state[s], x[s][None], dt[s][None], a, b[s][None], c[s][None], d)
+        onp.testing.assert_allclose(onp.asarray(new[s]), onp.asarray(want_s),
+                                    rtol=2e-5, atol=2e-5)
+        onp.testing.assert_allclose(onp.asarray(y[s]),
+                                    onp.asarray(want_y[0]),
+                                    rtol=2e-5, atol=2e-5)
+
+
+# -- grouped-query paged attention -------------------------------------------
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5),
+                                       ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("d", [32, 128], ids=["d32", "lanes128"])
+@pytest.mark.parametrize("h,kv_h", [(4, 4), (10, 2), (8, 2), (6, 1),
+                                    (20, 4)],
+                         ids=["mha", "gqa5", "gqa4", "mqa", "published"])
+def test_paged_attention_grouped_query_matches_its_oracle(h, kv_h, d, dtype,
+                                                          tol):
+    """Both forms of the kernel: heads narrower than a lane tile, and
+    heads of a whole tile (the published 128), whose scores run on the
+    MXU and whose index maps stop at the last live block."""
+    spec = kernels.get_kernel("paged_attention")
+    arrays, _ = spec.make_args({"slots": 5, "pages_per_slot": 4,
+                                "page_size": 16, "h": h, "kv_h": kv_h,
+                                "d": d, "dtype": dtype})
+    q, k_pool, v_pool, tables, lengths = arrays
+    assert k_pool.shape[-1] == kv_h * d           # sized by the KV heads
+    out = paged_attention(q, k_pool, v_pool, tables, lengths, block_k=8)
+    want = paged_attention_reference(q, k_pool, v_pool, tables, lengths)
+    assert out.shape == q.shape
+    onp.testing.assert_allclose(onp.asarray(out, "float32"),
+                                onp.asarray(want, "float32"),
+                                rtol=tol, atol=tol)
+    assert not onp.asarray(out, "float32")[0].any()      # the idle slot
+
+
+def test_grouped_query_is_multi_head_over_repeated_pools():
+    """What the kernel must NOT do in HBM, done here to pin the head
+    order: query head h reads KV head h // R."""
+    spec = kernels.get_kernel("paged_attention")
+    (q, k_pool, v_pool, tables, lengths), _ = spec.make_args(
+        {"slots": 4, "pages_per_slot": 2, "page_size": 16, "h": 6,
+         "kv_h": 2, "d": 16})
+    out = paged_attention(q, k_pool, v_pool, tables, lengths, block_k=16)
+
+    def repeated(pool):
+        pages, ps, _ = pool.shape
+        return jnp.repeat(pool.reshape(pages, ps, 2, 16), 3,
+                          axis=2).reshape(pages, ps, 6 * 16)
+
+    want = paged_attention(q, repeated(k_pool), repeated(v_pool), tables,
+                           lengths, block_k=16)
+    onp.testing.assert_allclose(onp.asarray(out), onp.asarray(want),
+                                rtol=1e-6, atol=1e-6)
+
+
+def test_a_pool_that_is_no_whole_number_of_heads_is_refused():
+    q = jnp.zeros((2, 6, 16))
+    pool = jnp.zeros((4, 8, 4 * 16))              # 4 does not divide 6
+    with pytest.raises(ValueError, match="divides"):
+        paged_attention(q, pool, pool, jnp.zeros((2, 2), jnp.int32),
+                        jnp.zeros((2,), jnp.int32), block_k=8)

@@ -86,6 +86,70 @@ def test_paged_attention_compiles(v5e, page_size):
         ((SLOTS, pps), "int32"), ((SLOTS,), "int32"))
 
 
+# Falcon-H1-34B's widths (chipbench/configs/falcon_h1_34b.json) at the
+# benchmark cell's geometry: 96 slots x 8 pages of 128
+FH_SLOTS, FH_PAGES, FH_PAGE = 96, 8, 128
+
+
+@pytest.mark.parametrize("block_k", [64, 128])
+def test_paged_attention_grouped_query_compiles(v5e, block_k):
+    """20 query heads over pools of 4 KV heads of 128: 512 lanes."""
+    from mxnet_tpu.ops.paged_attention import (_paged_attention_pallas,
+                                               paged_attention_reference)
+    pool = ((FH_SLOTS * FH_PAGES, FH_PAGE, 4 * 128), "bfloat16")
+    args = (((FH_SLOTS, 20, 128), "bfloat16"), pool, pool,
+            ((FH_SLOTS, FH_PAGES), "int32"), ((FH_SLOTS,), "int32"))
+    _compile(lambda q, k, v, t, l: _paged_attention_pallas(
+        q, k, v, t, l, 128 ** -0.5, block_k), v5e, *args)
+    # the oracle too: chip_smoke.py runs it in float32 on the chip
+    f32 = tuple((shape, "float32" if dt == "bfloat16" else dt)
+                for shape, dt in args)
+    jax.jit(paged_attention_reference).lower(*[
+        jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=v5e)
+        for shape, dt in f32]).compile()
+
+
+def _ssm_specs(sharding, dtype="bfloat16"):
+    s_, h, p, n, g = FH_SLOTS, 32, 128, 256, 2
+    return [jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=sharding)
+            for shape, dt in (
+                ((s_, h, p, n), "float32"), ((s_, h, p), dtype),
+                ((s_, h), "float32"), ((h,), "float32"),
+                ((s_, g, n), dtype), ((s_, g, n), dtype),
+                ((h,), "float32"), ((s_,), "bool"))]
+
+
+@pytest.mark.parametrize("block_h", [8, 16])
+def test_ssm_update_compiles_in_place(v5e, block_h):
+    """The state buffer (96 x 32 x 128 x 256 float32, 403 MB) is donated:
+    the kernel's output is the buffer itself and nothing of its size is
+    left among the temporaries."""
+    from mxnet_tpu.ops.ssm import _ssm_kernel_run
+    compiled = jax.jit(
+        lambda *a: _ssm_kernel_run({"block_h": block_h}, *a),
+        donate_argnums=(0,)).lower(*_ssm_specs(v5e)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "mxtpu_ssm_update" in text
+    mem = compiled.memory_analysis()
+    state = 96 * 32 * 128 * 256 * 4
+    assert mem.alias_size_in_bytes == state
+    assert mem.temp_size_in_bytes < state // 96, mem.temp_size_in_bytes
+
+
+def test_ssm_oracles_compile(v5e):
+    """The kernel's oracle at the kernel's shapes in float32, and the
+    chunked scan and the recurrence at one prefill chunk of 128."""
+    from mxnet_tpu.ops import ssm
+    jax.jit(ssm.ssm_update_reference).lower(
+        *_ssm_specs(v5e, "float32")).compile()
+    t_, h, p, n, g = 128, 32, 128, 256, 2
+    chunk = [jax.ShapeDtypeStruct(shape, jnp.float32, sharding=v5e)
+             for shape in ((h, p, n), (t_, h, p), (t_, h), (h,),
+                           (t_, g, n), (t_, g, n), (h,))]
+    jax.jit(ssm.ssm_chunk_scan).lower(*chunk).compile()
+    jax.jit(ssm.ssm_scan_reference).lower(*chunk).compile()
+
+
 @pytest.mark.parametrize("rows,h,d,block_r", [
     (SLOTS, H, D, 128),           # decode step: one row per slot
     (SLOTS * 5, H, D, 128),       # verify window, spec_k = 4
@@ -146,6 +210,72 @@ def test_decode_executables_update_the_pool_in_place(v5e, key):
     assert mem.temp_size_in_bytes < one, mem.temp_size_in_bytes
 
 
+# falcon_h1_decode_chat: 768 pages x 128 x (4 KV heads x 128) bf16 and,
+# per layer, 96 slots of (32 x 128 x 256) + (3 x 5120) float32 state;
+# 2 of its 6 layers, the published widths and the whole vocabulary (the
+# parameters are shapes: nothing of their 4.4 GB is allocated).
+
+@pytest.mark.parametrize("key", ["decode", "prefill_b128", "prefill_b16",
+                                 "state_reset"])
+def test_hybrid_executables_update_kv_and_state_in_place(v5e, key):
+    """K/V and both state buffers are aliased to the outputs, and the
+    temporaries stay under one state-space buffer (403 MB): no
+    executable holds a copy of a buffer it was donated."""
+    import json
+    from mxnet_tpu.serving import FalconH1
+    from mxnet_tpu.serving.decode import engine as E
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "chipbench", "configs",
+            "falcon_h1_34b.json")) as f:
+        cfg = dict(json.load(f), num_hidden_layers=2)
+    mdl = FalconH1(cfg, abstract=True)
+
+    def spec(shape, dtype="bfloat16"):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=v5e)
+
+    params = jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype),
+                                    mdl.params)
+    kv = spec((FH_SLOTS * FH_PAGES, FH_PAGE, 4 * 128))
+    ssm_buf = spec((FH_SLOTS, 32, 128, 256), "float32")
+    conv_buf = spec((FH_SLOTS, 3, 5120), "float32")
+    assert tuple(sh for _, sh, _ in mdl.state_spec) == (
+        ssm_buf.shape[1:], conv_buf.shape[1:])
+    pool = tuple((kv, kv, ssm_buf, conv_buf) for _ in range(2))
+    nbytes = lambda a: a.size * a.dtype.itemsize           # noqa: E731
+    if key == "state_reset":
+        donated = tuple(layer[2:] for layer in pool)
+        lowered = jax.jit(lambda *a: E._state_reset_core(*a),
+                          donate_argnums=(0,)).lower(
+                              donated, spec((), "int32"))
+    elif key == "decode":
+        donated = pool
+        lowered = jax.jit(lambda *a: mdl.decode_core(*a),
+                          donate_argnums=(1,)).lower(
+            params, pool, spec((FH_SLOTS,), "int32"),
+            spec((FH_SLOTS,), "int32"),
+            spec((FH_SLOTS, FH_PAGES), "int32"), spec((FH_SLOTS,), "bool"))
+    else:
+        donated = pool
+        bucket = int(key.rsplit("b", 1)[1])
+        lowered = jax.jit(lambda *a: mdl.prefill_core(*a),
+                          donate_argnums=(1,)).lower(
+            params, pool, spec((bucket,), "int32"), spec((), "int32"),
+            spec((), "int32"), spec((FH_PAGES,), "int32"),
+            spec((), "int32"))
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == sum(
+        nbytes(b) for layer in donated for b in layer)
+    assert mem.temp_size_in_bytes < nbytes(ssm_buf), mem.temp_size_in_bytes
+    if key == "decode":
+        import re
+        calls = re.findall(r'^\s*(?:ROOT )?%(\S+) = .*custom_call_target='
+                           r'"tpu_custom_call"', compiled.as_text(), re.M)
+        # per layer: rope on q and on k, paged attention, the state update
+        assert len(calls) == 4 * 2 and all("mxtpu_" in c for c in calls)
+        assert sum("mxtpu_ssm_update" in c for c in calls) == 2
+
+
 # -- the names a device trace shows ------------------------------------------
 # An ``XLA Ops`` event of a TPU trace is the text of its HLO instruction
 # and nothing else, so a kernel can be told from another only by the
@@ -158,6 +288,7 @@ def _named_texts(sharding):
     from mxnet_tpu.ops.layernorm_residual import _lnr_pallas
     from mxnet_tpu.ops.paged_attention import _paged_attention_pallas
     from mxnet_tpu.ops.rope import _rope_pallas
+    from mxnet_tpu.ops.ssm import _ssm_kernel_run
     qkv = ((16, 1024, D), "bfloat16")
     pool = ((SLOTS * 40, 16, H * D), "bfloat16")
     x, g = ((256, 512), "bfloat16"), ((512,), "bfloat16")
@@ -175,6 +306,9 @@ def _named_texts(sharding):
         "layernorm_residual": _compile(
             lambda a, r, ga, be: _lnr_pallas(a, r, ga, be, 1e-5, 32),
             sharding, x, x, g, g),
+        "ssm_update": jax.jit(
+            lambda *a: _ssm_kernel_run({"block_h": 8}, *a)).lower(
+                *_ssm_specs(sharding)).compile().as_text(),
     }
 
 
@@ -183,7 +317,7 @@ _TEXTS = {}
 
 @pytest.mark.parametrize("kernel", [
     "flash_fwd", "flash_dkv", "flash_dq", "paged_attention", "rope",
-    "layernorm_residual"])
+    "layernorm_residual", "ssm_update"])
 def test_custom_call_carries_the_kernels_name(v5e, kernel):
     import re
     if not _TEXTS:
