@@ -11,37 +11,46 @@ integer indices, so one compiled ``decode_step`` serves any mix of
 lengths (the fixed-shape-executable invariant, docs/ARCHITECTURE.md
 "Decode serving").
 
-The Pallas path rides ``PrefetchScalarGridSpec``: the page table and
-per-slot lengths are scalar-prefetched, and the K/V BlockSpec index
-maps dereference ``table[slot, page]`` directly, so the pipeline DMAs
-exactly the pages each slot owns — no gather materialization.  Grid is
-``(slots, pages_per_slot, page_size // block_k)`` with online-softmax
-f32 accumulators in VMEM scratch persisting across the two inner
-dims; pages wholly past a slot's length are skipped via ``pl.when``.
-Slots with length 0 (inactive) produce exact zeros, matching the
-oracle.
+The Pallas kernel's work follows the live lengths, not the table's
+size.  Its grid is one step a slot; the page table and the lengths are
+scalar-prefetched; the pools stay whole operands in HBM.  Inside a
+slot's step (:func:`_pa_walker`) a loop runs over the slot's
+``cdiv(length, block_k)`` live blocks and no further: each block's
+pages are copied from ``pool[table[slot, page]]`` into one of two VMEM
+buffers while the block before it is worked on, and a slot's last block
+starts the first block of the next live slot, so the copies do not
+drain at a slot's end.  An idle slot (length 0) costs an empty grid
+step: no copy, no arithmetic, exact zeros out, matching the oracle.  A
+block is ``block_k`` rows: several whole pages (``block_k //
+page_size``, one copy a page) or a part of one page; online-softmax
+float32 accumulators live in VMEM scratch across a slot's blocks, and
+the tail block's rows past the length are masked.
 
 Grouped-query attention: ``q`` may carry ``R`` times the pool's KV
 heads (query head ``h`` reads KV head ``h // R``).  The pool is sized
 by the KV heads and nothing is repeated in HBM: the query heads are
 dealt into ``R`` rows of ``Hkv*D`` lanes (row ``r`` holds query head
 ``g*R + r`` over KV head ``g``'s lanes), each K/V block is fetched once
-and every row runs the multi-head arithmetic against it.  ``R == 1``
-is multi-head attention, one row.
+and every row runs against it.  ``R == 1`` is multi-head attention,
+one row.
 
-Heads of a whole number of lane tiles (``D % 128 == 0``) take a second
-form of the kernel (:func:`_pa_kernel_lanes`): a KV head's ``D`` lanes
+What is done with a block comes in two bodies under the one walker,
+chosen by the head's width, a shape.  Heads narrower than a lane tile
+stay folded into the lane axis (:func:`_folded_body`): a ``(block_k,
+Hkv*D)`` tile is multiplied on the vector unit once per row, and 0/1
+matmuls reduce and broadcast by head.  Heads of a whole number of lane
+tiles (``D % 128 == 0``, :func:`_lanes_body`): a KV head's ``D`` lanes
 are an aligned slice of the block, so the scores of its ``R`` query
 heads are one small matmul against that slice and the values another,
-on the MXU in the pool's dtype, where the first form multiplies a
-``(block_k, Hkv*D)`` tile on the vector unit once per row.  Its index
-maps also stop at the slot's last live block: a grid step past it asks
-for the block that is already there and moves nothing.
+on the MXU in the pool's dtype.
+
+A copy moves whole lane tiles: on a TPU a pool whose ``Hkv*D`` is no
+multiple of 128 is gathered by XLA instead (the oracle below).
 
 The XLA lowering (:func:`paged_attention_reference`) gathers
 ``pool[tables]`` and runs a masked softmax — the numerics oracle the
 parity tests pin the kernel against across ragged lengths.  No call
-site switches to it.
+site chooses it.
 """
 from __future__ import annotations
 
@@ -105,8 +114,8 @@ def paged_attention_reference(q, k_pool, v_pool, tables, lengths,
     return out.astype(q.dtype)
 
 
-def _pa_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, seg_ref, o_ref,
-               acc_ref, m_ref, l_ref, *, sm_scale, block_k, page_size, rep):
+def _folded_body(q_ref, seg_ref, o_ref, acc_ref, m_ref, l_ref, *,
+                 sm_scale, rep):
     """Heads stay folded into the lane axis: every operand is a 2-D
     ``(rows, H*D)`` or ``(rows, H)`` tile, ``H`` the KV heads; ``q``,
     the output and the accumulators hold one such row for each of the
@@ -117,20 +126,6 @@ def _pa_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, seg_ref, o_ref,
     (no free lhs dim for one query row; ``(block_k, H, D)`` tiles need
     a sublane<->major shape cast that bf16 packing rules out), and this
     form needs no transpose or reshape for any (H, D, dtype)."""
-    s_i = pl.program_id(0)
-    p_i = pl.program_id(1)
-    b_i = pl.program_id(2)
-    np_ = pl.num_programs(1)
-    nb = pl.num_programs(2)
-
-    @pl.when((p_i == 0) & (b_i == 0))
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    length = len_ref[s_i]
-    start = p_i * page_size + b_i * block_k
 
     def per_head(x, contract_lanes):
         # contract_lanes: (rows, H*D) -> (rows, H); else (rows, H) ->
@@ -140,10 +135,9 @@ def _pa_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, seg_ref, o_ref,
                                precision=lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)
 
-    @pl.when(start < length)
-    def _body():
-        k = k_ref[0].astype(jnp.float32)          # (block_k, H*D)
-        v = v_ref[0].astype(jnp.float32)
+    def block(k_ref, v_ref, start, length):
+        k = k_ref[...].astype(jnp.float32)        # (block_k, H*D)
+        v = v_ref[...].astype(jnp.float32)
         for r in range(rep):
             row = slice(r, r + 1)
             q = q_ref[0, row].astype(jnp.float32)     # (1, H*D)
@@ -160,8 +154,7 @@ def _pa_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, seg_ref, o_ref,
             pv = (per_head(p, False) * v).sum(axis=0, keepdims=True)
             acc_ref[row] = acc_ref[row] * per_head(corr, False) + pv
 
-    @pl.when((p_i == np_ - 1) & (b_i == nb - 1))
-    def _finish():
+    def finish():
         for r in range(rep):
             row = slice(r, r + 1)
             l = l_ref[row]
@@ -169,10 +162,10 @@ def _pa_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, seg_ref, o_ref,
             o_ref[0, row] = (acc_ref[row]
                              / per_head(l, False)).astype(o_ref.dtype)
 
+    return block, finish
 
-def _pa_kernel_lanes(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                     acc_ref, m_ref, l_ref, *, sm_scale, block_k,
-                     page_size, heads, d):
+
+def _lanes_body(q_ref, o_ref, acc_ref, m_ref, l_ref, *, sm_scale, heads, d):
     """Lane-aligned heads: KV head ``g`` is the lanes ``[g*d, (g+1)*d)``
     of every operand.  ``q``, ``o`` and ``acc`` hold the head's query
     rows (padded to 8) on the sublanes; the running maximum and sum are
@@ -180,30 +173,15 @@ def _pa_kernel_lanes(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     operands in the pool's dtype with float32 accumulation (bfloat16
     products are exact in float32; a float32 pool asks for the MXU's
     exact passes)."""
-    s_i = pl.program_id(0)
-    p_i = pl.program_id(1)
-    b_i = pl.program_id(2)
-    np_ = pl.num_programs(1)
-    nb = pl.num_programs(2)
 
-    @pl.when((p_i == 0) & (b_i == 0))
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    length = len_ref[s_i]
-    start = p_i * page_size + b_i * block_k
-    exact = (lax.Precision.HIGHEST if k_ref.dtype == jnp.float32
-             else lax.Precision.DEFAULT)
-
-    @pl.when(start < length)
-    def _body():
+    def block(k_ref, v_ref, start, length):
+        exact = (lax.Precision.HIGHEST if k_ref.dtype == jnp.float32
+                 else lax.Precision.DEFAULT)
         for g in range(heads):
             lanes = slice(g * d, (g + 1) * d)
             q = q_ref[0, :, lanes].astype(k_ref.dtype)        # (rows, d)
-            k = k_ref[0, :, lanes]                            # (block_k, d)
-            v = v_ref[0, :, lanes]
+            k = k_ref[:, lanes]                               # (block_k, d)
+            v = v_ref[:, lanes]
             s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 precision=exact,
                                 preferred_element_type=jnp.float32)
@@ -222,62 +200,105 @@ def _pa_kernel_lanes(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                                  preferred_element_type=jnp.float32)
             acc_ref[:, lanes] = acc_ref[:, lanes] * corr[:, :1] + pv
 
-    @pl.when((p_i == np_ - 1) & (b_i == nb - 1))
-    def _finish():
+    def finish():
         for g in range(heads):
             lanes = slice(g * d, (g + 1) * d)
             l = l_ref[g][:, :1]
             l = jnp.where(l == 0.0, 1.0, l)
             o_ref[0, :, lanes] = (acc_ref[:, lanes] / l).astype(o_ref.dtype)
 
+    return block, finish
 
-def _paged_attention_lanes(q, k_pool, v_pool, tables, lengths, sm_scale,
-                           block_k, h, rep):
-    """The lane-aligned form: ``q (S, rep, h*d)`` dealt as in
-    :func:`_paged_attention_pallas`, rows padded to a sublane tile."""
-    s_, _, hd = q.shape
-    d = hd // h
-    num_pages, page_size = k_pool.shape[:2]
-    p_ = tables.shape[1]
-    nb = page_size // block_k
-    rows = -(-rep // 8) * 8
-    if rows != rep:
-        q = jnp.pad(q, ((0, 0), (0, rows - rep), (0, 0)))
 
-    def kv_map(s, p, b, tbl, ln):
-        # the slot's last live block, for every step past it
-        live = jnp.maximum(ln[s] - 1, 0) // block_k
-        at = jnp.minimum(p * nb + b, live)
-        return tbl[s, at // nb], at % nb, 0
+def _pa_walker(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *refs, body, block_k):
+    """One grid step a slot; inside it a loop over the slot's live
+    blocks alone, each copied from the pools (whole operands, in HBM)
+    into one of two VMEM buffers while the block before it is worked on.
+    The last block of a slot starts the first block of the next live
+    slot, so idle slots in between cost an empty grid step and the copy
+    engine does not drain at a slot's end.  ``at_ref`` carries that
+    hand-over from step to step: the buffer the slot's first block is
+    in, and whether its copy is already in flight.
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(s_, p_, nb),
-        in_specs=[
-            pl.BlockSpec((1, rows, hd), lambda s, p, b, tbl, ln: (s, 0, 0)),
-            pl.BlockSpec((1, block_k, hd), kv_map),
-            pl.BlockSpec((1, block_k, hd), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, rows, hd),
-                               lambda s, p, b, tbl, ln: (s, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((rows, hd), jnp.float32),
-            pltpu.VMEM((h, rows, 128), jnp.float32),
-            pltpu.VMEM((h, rows, 128), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_pa_kernel_lanes, sm_scale=float(sm_scale),
-                          block_k=block_k, page_size=page_size, heads=h,
-                          d=d),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s_, rows, hd), q.dtype),
-        interpret=jax.default_backend() != "tpu",
-        name="mxtpu_paged_attention",
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), q,
-      k_pool.reshape(num_pages, page_size, hd),
-      v_pool.reshape(num_pages, page_size, hd))
-    return out[:, :rep]
+    A block is ``block_k`` rows: whole pages (one copy a page) or a
+    part of one page.  Its rows past the length are masked by the body;
+    a page index past the table's width reads the last column, whose
+    rows are all masked."""
+    *consts, o_ref, acc_ref, m_ref, l_ref, k_buf, v_buf, sems, at_ref = refs
+    slots, pages = tbl_ref.shape
+    page_size = k_hbm.shape[1]
+    rows = min(block_k, page_size)                # rows of one copy
+    block, finish = body(q_ref, *consts, o_ref, acc_ref, m_ref, l_ref)
+    s_i = pl.program_id(0)
+    length = len_ref[s_i]
+    n = pl.cdiv(length, block_k)
+
+    def copies(slot, blk, buf):
+        made = []
+        for j in range(block_k // rows):
+            if rows == page_size:
+                page, src = blk * (block_k // rows) + j, slice(None)
+            else:
+                per_page = page_size // rows
+                page = blk // per_page
+                src = pl.ds(pl.multiple_of((blk % per_page) * rows, rows),
+                            rows)
+            page = tbl_ref[slot, jnp.minimum(page, pages - 1)]
+            dst = pl.ds(j * rows, rows)
+            for kind, (pool, buffer) in enumerate(((k_hbm, k_buf),
+                                                   (v_hbm, v_buf))):
+                made.append(pltpu.make_async_copy(
+                    pool.at[page, src], buffer.at[buf, dst],
+                    sems.at[kind, buf]))
+        return made
+
+    def start(slot, blk, buf):
+        for copy in copies(slot, blk, buf):
+            copy.start()
+
+    def next_live(slot):
+        return lax.while_loop(
+            lambda j: (j < slots) & (len_ref[jnp.minimum(j, slots - 1)] == 0),
+            lambda j: j + 1, slot + 1)
+
+    @pl.when(s_i == 0)
+    def _reset():
+        at_ref[0] = 0
+        at_ref[1] = 0
+
+    @pl.when(length == 0)
+    def _idle():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(length > 0)
+    def _live():
+        first = at_ref[0]
+
+        @pl.when(at_ref[1] == 0)      # the first live slot of the call
+        def _():
+            start(s_i, 0, first)
+
+        then = next_live(s_i)
+        at_ref[1] = (then < slots).astype(jnp.int32)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+        def step(i, buf):
+            more = i + 1 < n          # else: the next live slot's first
+
+            @pl.when(more | (then < slots))
+            def _():
+                start(jnp.where(more, s_i, then), jnp.where(more, i + 1, 0),
+                      1 - buf)
+
+            for copy in copies(s_i, i, buf):
+                copy.wait()
+            block(k_buf.at[buf], v_buf.at[buf], i * block_k, length)
+            return 1 - buf
+
+        at_ref[0] = lax.fori_loop(0, n, step, first)
+        finish()
 
 
 def _paged_attention_pallas(q, k_pool, v_pool, tables, lengths,
@@ -288,54 +309,71 @@ def _paged_attention_pallas(q, k_pool, v_pool, tables, lengths,
     h = _kv_heads(q, k_pool)
     rep = hq // h
     hd = h * d
-    block_k = math.gcd(max(1, int(block_k)), page_size)   # tiles the page
-    if block_k % 8:
-        # a block's rows are a multiple of the 8-sublane tile or the
-        # whole page (the TPU block-shape rule)
-        block_k = page_size
+    on_tpu = jax.default_backend() == "tpu"
+    if hd % 128 and on_tpu:
+        # Mosaic copies whole lane tiles: a page of a pool narrower than
+        # one, or of one and a part, is no source of a copy.  Such a pool
+        # is gathered by XLA; the interpreter walks it like any other.
+        return paged_attention_reference(q, k_pool, v_pool, tables,
+                                         lengths, sm_scale=sm_scale)
+    block_k = max(1, int(block_k))
+    if block_k >= page_size and page_size % 8 == 0:
+        # whole pages, as many as the block holds and a slot has
+        block_k = min(block_k // page_size, p_) * page_size
+    else:
+        # a part of a page: its rows tile the page and are a multiple of
+        # the 8-sublane tile, or the block is the page
+        block_k = math.gcd(block_k, page_size)
+        if block_k % 8:
+            block_k = page_size
+    # query head g*rep + r -> row r, KV head g's lanes
+    q = q.reshape(s_, h, rep, d).swapaxes(1, 2).reshape(s_, rep, hd)
     if d % 128 == 0:
-        qr = q.reshape(s_, h, rep, d).swapaxes(1, 2).reshape(s_, rep, hd)
-        out = _paged_attention_lanes(qr, k_pool, v_pool, tables, lengths,
-                                     sm_scale, block_k, h, rep)
-        return out.reshape(s_, rep, h, d).swapaxes(1, 2).reshape(s_, hq, d)
-    kernel = functools.partial(
-        _pa_kernel, sm_scale=float(sm_scale), block_k=block_k,
-        page_size=page_size, rep=rep)
-    seg = (jnp.arange(hd, dtype=jnp.int32)[None, :] // d
-           == jnp.arange(h, dtype=jnp.int32)[:, None]).astype(jnp.float32)
+        rows = -(-rep // 8) * 8
+        q = jnp.pad(q, ((0, 0), (0, rows - rep), (0, 0)))
+        body = functools.partial(_lanes_body, sm_scale=float(sm_scale),
+                                 heads=h, d=d)
+        consts, const_specs = (), []
+        stats = (h, rows, 128)
+    else:
+        rows = rep
+        body = functools.partial(_folded_body, sm_scale=float(sm_scale),
+                                 rep=rep)
+        seg = (jnp.arange(hd, dtype=jnp.int32)[None, :] // d
+               == jnp.arange(h, dtype=jnp.int32)[:, None])
+        consts = (seg.astype(jnp.float32),)
+        const_specs = [pl.BlockSpec((h, hd), lambda s, tbl, ln: (0, 0))]
+        stats = (rep, h)
+    per_slot = pl.BlockSpec((1, rows, hd), lambda s, tbl, ln: (s, 0, 0))
+    whole = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(s_, p_, page_size // block_k),
-        in_specs=[
-            pl.BlockSpec((1, rep, hd), lambda s, p, b, tbl, ln: (s, 0, 0)),
-            pl.BlockSpec((1, block_k, hd),
-                         lambda s, p, b, tbl, ln: (tbl[s, p], b, 0)),
-            pl.BlockSpec((1, block_k, hd),
-                         lambda s, p, b, tbl, ln: (tbl[s, p], b, 0)),
-            pl.BlockSpec((h, hd), lambda s, p, b, tbl, ln: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, rep, hd),
-                               lambda s, p, b, tbl, ln: (s, 0, 0)),
+        grid=(s_,),
+        in_specs=[per_slot, whole, whole, *const_specs],
+        out_specs=per_slot,
         scratch_shapes=[
-            pltpu.VMEM((rep, hd), jnp.float32),
-            pltpu.VMEM((rep, h), jnp.float32),
-            pltpu.VMEM((rep, h), jnp.float32),
+            pltpu.VMEM((rows, hd), jnp.float32),
+            pltpu.VMEM(stats, jnp.float32),
+            pltpu.VMEM(stats, jnp.float32),
+            pltpu.VMEM((2, block_k, hd), k_pool.dtype),
+            pltpu.VMEM((2, block_k, hd), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((2,), jnp.int32),
         ],
     )
-    if rep > 1:     # query head g*rep + r -> row r, KV head g's lanes
-        q = q.reshape(s_, h, rep, d).swapaxes(1, 2)
     out = pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s_, rep, hd), q.dtype),
-        interpret=jax.default_backend() != "tpu",
+        functools.partial(_pa_walker, body=body, block_k=block_k),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((s_, rows, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=not on_tpu,
         name="mxtpu_paged_attention",
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      q.reshape(s_, rep, hd),
+    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), q,
       k_pool.reshape(num_pages, page_size, hd),
-      v_pool.reshape(num_pages, page_size, hd), seg)
-    if rep > 1:
-        out = out.reshape(s_, rep, h, d).swapaxes(1, 2)
-    return out.reshape(s_, hq, d)
+      v_pool.reshape(num_pages, page_size, hd), *consts)
+    return out[:, :rep].reshape(s_, rep, h, d).swapaxes(1, 2).reshape(
+        s_, hq, d)
 
 
 # -- kernel-registry integration -------------------------------------------
@@ -391,17 +429,21 @@ def _paged_make_args(case):
 
 
 _kernels.register_kernel(_kernels.KernelSpec(
-    "paged_attention", version=2,
+    "paged_attention", version=3,       # 3: the walker; a block may span pages
     run=_paged_kernel_run, fallback=_paged_kernel_fallback,
     config_space={"block_k": (16, 32, 64, 128)},
     default_config={"block_k": 64},
     signature=_paged_signature, make_args=_paged_make_args,
-    tune_grid=({"slots": 8, "pages_per_slot": 4, "page_size": 64,
+    # pages of 16 (a block of 1 to 8 pages), 64 and 128 (a part of a
+    # page, or the page), 32 under grouped-query heads of both bodies
+    tune_grid=({"slots": 6, "pages_per_slot": 8, "page_size": 16,
+                "h": 4, "d": 64},
+               {"slots": 8, "pages_per_slot": 4, "page_size": 64,
                 "h": 4, "d": 64},
                {"slots": 4, "pages_per_slot": 8, "page_size": 128,
                 "h": 8, "d": 64},
                {"slots": 5, "pages_per_slot": 4, "page_size": 32,
-                "h": 10, "kv_h": 2, "d": 32},
+                "h": 20, "kv_h": 4, "d": 32},
                {"slots": 5, "pages_per_slot": 3, "page_size": 32,
                 "h": 10, "kv_h": 2, "d": 128}),
 ))

@@ -477,6 +477,11 @@ class DecodeEngine:
                 dtype=draft_model.params["embed"].dtype)
         self._exec: Dict[str, Any] = {}
         self.compiles = 0
+        # share of the page table under live context in the last decode
+        # step (what the paged kernel walks), and its sum over the steps
+        self.kv_live_share = 0.0
+        self._kv_live_sum = 0.0
+        self._decode_steps = 0
 
     # -- properties ----------------------------------------------------------
 
@@ -633,6 +638,7 @@ class DecodeEngine:
         """One non-speculative engine step over the full slot grid.
         Returns the next token per slot (host numpy)."""
         mdl = self.model
+        self._count_live(positions, active)
         with tracing.span("decode.stage"):
             args = (mdl.params, self.cache.pool,
                     jnp.asarray(tokens, jnp.int32),
@@ -645,10 +651,22 @@ class DecodeEngine:
         with tracing.span("decode.sync"):
             return onp.asarray(nxt)
 
+    def _count_live(self, positions, active):
+        """Pages holding an active slot's context (its pending token's
+        position included) over the pages of the whole table: host
+        integers, no device read."""
+        at = onp.asarray(positions)[onp.asarray(active, bool)]
+        live = int((at // self.page_size + 1).sum())
+        self.kv_live_share = live / (self.max_slots
+                                     * self.cache.pages_per_slot)
+        self._kv_live_sum += self.kv_live_share
+        self._decode_steps += 1
+
     def spec_step(self, tokens, base_pos, active):
         """Draft k proposals then verify in one target dispatch.
         Returns (greedy (S, k+1), accepted (S,)) host numpy."""
         mdl, dm, k = self.model, self.draft, self.spec_k
+        self._count_live(base_pos, active)
         with tracing.span("decode.stage"):
             tok = jnp.asarray(tokens, jnp.int32)
             pos = jnp.asarray(base_pos, jnp.int32)
@@ -743,4 +761,6 @@ class DecodeEngine:
                 "spec_k": self.spec_k if self.spec_enabled else 0,
                 "state_bytes": self.cache.state_bytes,
                 "state_slots_live": self.cache.state_slots_live(),
-                "state_resets": self.cache.state_resets}
+                "state_resets": self.cache.state_resets,
+                "kv_live_share": (self._kv_live_sum / self._decode_steps
+                                  if self._decode_steps else 0.0)}

@@ -351,6 +351,8 @@ class DecodeScheduler:
                 "state_bytes": eng.cache.state_bytes,
                 "state_slots_live": eng.cache.state_slots_live(),
                 "state_resets": eng.cache.state_resets,
+                "kv_live_share": (round(eng.kv_live_share, 6)
+                                  if decoding else 0.0),
                 "step_ms": round((time.perf_counter() - t_step) * 1e3, 3),
             }
             if ttfts:

@@ -131,25 +131,53 @@ def test_paged_kv_slot_acquire_release():
     assert c.pages_used() == 0
 
 
-def test_paged_attention_ragged_parity_vs_oracle():
+# (page_size, pages_per_slot, block_k, lengths): block_k None leaves it to
+# the registry.  A slot's capacity is page_size * pages_per_slot.
+_PA_CASES = {
+    "ragged": (4, 3, None, [5, 0, 12]),
+    # the walk's edges at a block of 1, 2 and 4 pages of 16: nothing,
+    # one row, a whole block, a block and a row, the slot's capacity
+    "block_1_page": (16, 8, 16, [0, 1, 16, 17, 128]),
+    "block_2_pages": (16, 8, 32, [0, 1, 32, 33, 128]),
+    "block_4_pages": (16, 8, 64, [0, 1, 64, 65, 128]),
+    # a block wider than the table: cut to the slot's pages
+    "block_past_table": (16, 2, 128, [32, 3, 0, 17]),
+    # a table no whole number of blocks: the tail block's last page is
+    # past the table's width
+    "table_of_3_pages": (16, 3, 32, [48, 33, 0, 1]),
+    "half_a_page_of_128": (128, 2, 64, [0, 1, 64, 65, 128, 129, 256]),
+    "all_idle": (16, 4, 32, [0, 0, 0, 0]),
+    "live_first": (16, 4, 32, [37, 0, 0, 0, 0]),
+    "live_last": (16, 4, 32, [0, 0, 0, 0, 37]),
+    "live_alternating": (16, 4, 32, [0, 9, 0, 64, 0, 33, 0]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PA_CASES))
+def test_paged_attention_ragged_parity_vs_oracle(case):
     """Kernel vs gather-oracle over ragged lengths, including an
-    inactive (length-0) slot, through the public entry point."""
+    inactive (length-0) slot, through the public entry point; the page
+    tables are a random permutation of the pool."""
     from mxnet_tpu.ops.paged_attention import (paged_attention,
                                                paged_attention_reference)
     import jax.numpy as jnp
+    ps, p_, block_k, lengths = _PA_CASES[case]
     rs = onp.random.RandomState(3)
-    s_, p_, pages, ps, h, d = 3, 3, 12, 4, 2, 8
+    s_, h, d = len(lengths), 2, 8
+    pages = s_ * p_ + 3
     q = jnp.asarray(rs.randn(s_, h, d), jnp.float32)
     kp = jnp.asarray(rs.randn(pages, ps, h, d), jnp.float32)
     vp = jnp.asarray(rs.randn(pages, ps, h, d), jnp.float32)
     tables = jnp.asarray(
         rs.permutation(pages)[:s_ * p_].reshape(s_, p_), jnp.int32)
-    lengths = jnp.asarray([5, 0, 12], jnp.int32)
-    out = paged_attention(q, kp, vp, tables, lengths)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    out = paged_attention(q, kp, vp, tables, lengths, block_k=block_k)
     ref = paged_attention_reference(q, kp, vp, tables, lengths)
     onp.testing.assert_allclose(onp.asarray(out), onp.asarray(ref),
                                 rtol=2e-4, atol=2e-4)
-    assert not onp.asarray(out)[1].any()    # length-0 slot → zeros
+    idle = onp.asarray(lengths) == 0
+    assert not onp.asarray(out)[idle].any()    # length-0 slot → zeros
+    assert onp.asarray(out)[~idle].any(axis=(1, 2)).all()
 
 
 # -- the pool's layout: one whole buffer per layer for K and for V -----------
@@ -255,6 +283,41 @@ def test_scheduler_matches_greedy_reference(model):
     sch.close(drain=True)
     for p, g in zip(prompts, got):
         assert g == model.greedy_reference(p, 10)
+
+
+@pytest.mark.parametrize("prompts,max_new,want", [
+    # slot 0: a prompt of 6, then positions 6..10; slot 1: a prompt of
+    # 15 in two chunks of 8, so from the second turn, positions 15, 16.
+    # Pages of 8 under each turn's lengths (position + 1):
+    # 1, 1+2, 2+3, 2, 2
+    ([6, 15], [6, 3], [1, 3, 5, 2, 2]),
+    # alone, its first turn decodes nothing
+    ([15], [3], [0, 2, 3]),
+], ids=["two_slots", "prefill_only_turn"])
+def test_kv_live_share_counts_the_pages_under_live_lengths(
+        model, prompts, max_new, want):
+    """The step record's ``kv_live_share`` and its running mean in
+    ``engine.stats()`` against lengths counted by hand; the keys the
+    benchmark reads stay where they were."""
+    eng = _engine(model, prefill_chunk=8, prefill_floor=8)
+    sch = _sched(eng)
+    table = eng.max_slots * eng.cache.pages_per_slot
+    assert eng.stats()["kv_live_share"] == 0.0
+    for n, m in zip(prompts, max_new):
+        sch.submit(list(range(1, n + 1)), max_new_tokens=m)
+    records = []
+    while sch._has_work():
+        records.append(sch.step())
+    sch.close(drain=True)
+    assert [r["kv_live_share"] for r in records] == [
+        round(w / table, 6) for w in want]
+    decoded = [w for w in want if w]
+    assert eng.stats()["kv_live_share"] == pytest.approx(
+        sum(decoded) / len(decoded) / table)
+    for r in records:
+        assert {"tokens", "step_ms", "slots_active",
+                "queue_depth"} <= set(r)
+    assert sum("ttft_ms" in r for r in records) == len(prompts)
 
 
 def test_eos_stops_generation(model):

@@ -519,18 +519,23 @@ def test_one_decode_update_is_one_step_of_the_recurrence():
 @pytest.mark.parametrize("h,kv_h", [(4, 4), (10, 2), (8, 2), (6, 1),
                                     (20, 4)],
                          ids=["mha", "gqa5", "gqa4", "mqa", "published"])
-def test_paged_attention_grouped_query_matches_its_oracle(h, kv_h, d, dtype,
-                                                          tol):
-    """Both forms of the kernel: heads narrower than a lane tile, and
-    heads of a whole tile (the published 128), whose scores run on the
-    MXU and whose index maps stop at the last live block."""
+@pytest.mark.parametrize("page_size,block_k", [(16, 8), (16, 32), (128, 64)],
+                         ids=["half_of_16", "2_pages_of_16", "half_of_128"])
+def test_paged_attention_grouped_query_matches_its_oracle(
+        page_size, block_k, h, kv_h, d, dtype, tol):
+    """Both bodies of the kernel under the one walker: heads narrower
+    than a lane tile, and heads of a whole tile (the published 128),
+    whose scores run on the MXU; a block of half a page (of 16, and of
+    the benchmark cell's 128) and of two pages."""
     spec = kernels.get_kernel("paged_attention")
-    arrays, _ = spec.make_args({"slots": 5, "pages_per_slot": 4,
-                                "page_size": 16, "h": h, "kv_h": kv_h,
+    arrays, _ = spec.make_args({"slots": 5,
+                                "pages_per_slot": 4 if page_size == 16 else 2,
+                                "page_size": page_size, "h": h, "kv_h": kv_h,
                                 "d": d, "dtype": dtype})
     q, k_pool, v_pool, tables, lengths = arrays
     assert k_pool.shape[-1] == kv_h * d           # sized by the KV heads
-    out = paged_attention(q, k_pool, v_pool, tables, lengths, block_k=8)
+    out = paged_attention(q, k_pool, v_pool, tables, lengths,
+                          block_k=block_k)
     want = paged_attention_reference(q, k_pool, v_pool, tables, lengths)
     assert out.shape == q.shape
     onp.testing.assert_allclose(onp.asarray(out, "float32"),

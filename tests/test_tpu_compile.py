@@ -86,6 +86,36 @@ def test_paged_attention_compiles(v5e, page_size):
         ((SLOTS, pps), "int32"), ((SLOTS,), "int32"))
 
 
+@pytest.mark.parametrize("block_k", [16, 32, 64, 128])
+def test_paged_attention_compiles_at_the_decode_cell(v5e, block_k):
+    """``gpt2_decode_chat``: 96 slots x 64 pages of 16, 16 heads of 64
+    folded into 1024 lanes; a block of 1, 2, 4 and 8 pages, each page a
+    copy of its own from the pool left in HBM."""
+    from mxnet_tpu.ops.paged_attention import _paged_attention_pallas
+    pool = ((6144, 16, 16 * 64), "bfloat16")
+    text = _compile(
+        lambda q, k, v, t, l: _paged_attention_pallas(
+            q, k, v, t, l, 64 ** -0.5, block_k),
+        v5e, ((96, 16, 64), "bfloat16"), pool, pool,
+        ((96, 64), "int32"), ((96,), "int32"))
+    assert text.count("tpu_custom_call") == 1
+
+
+def test_a_pool_of_no_whole_lane_tile_is_gathered_by_xla(v5e):
+    """Mosaic slices whole lane tiles: a pool 64 lanes wide is no source
+    of a copy, so on a TPU it takes the XLA lowering (the interpreter
+    walks it like any other)."""
+    from mxnet_tpu.ops.paged_attention import _paged_attention_pallas
+    pool = jax.ShapeDtypeStruct((40, 16, 2 * 32), jnp.bfloat16, sharding=v5e)
+    args = [jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=v5e)
+            for shape, dt in (((5, 10, 32), "bfloat16"), ((5, 8), "int32"),
+                              ((5,), "int32"))]
+    text = jax.jit(lambda q, k, v, t, l: _paged_attention_pallas(
+        q, k, v, t, l, 32 ** -0.5, 64)).lower(
+            args[0], pool, pool, *args[1:]).compile().as_text()
+    assert "tpu_custom_call" not in text
+
+
 # Falcon-H1-34B's widths (chipbench/configs/falcon_h1_34b.json) at the
 # benchmark cell's geometry: 96 slots x 8 pages of 128
 FH_SLOTS, FH_PAGES, FH_PAGE = 96, 8, 128
@@ -208,6 +238,9 @@ def test_decode_executables_update_the_pool_in_place(v5e, key):
     one = buf.size * buf.dtype.itemsize          # 201 MB
     assert mem.alias_size_in_bytes == 2 * layers * one
     assert mem.temp_size_in_bytes < one, mem.temp_size_in_bytes
+    # and no more of them than before the kernel took the pools as whole
+    # operands in HBM (PR 30): 3,064,320 and 3,354,624 bytes at 2 layers
+    assert mem.temp_size_in_bytes < 4 * 2 ** 20, mem.temp_size_in_bytes
 
 
 # falcon_h1_decode_chat: 768 pages x 128 x (4 KV heads x 128) bf16 and,
