@@ -2,10 +2,12 @@
 cache with optional speculative decode.
 
 - :mod:`paged_kv` — pre-allocated device page pool + host free-list
-  allocator with per-slot page tables;
-- :mod:`engine` — the model protocol, the built-in small causal LM and
-  the fixed-shape compiled decode / prefill / draft / verify
-  executables;
+  allocator with per-slot page tables, and the traced side of the same
+  format: how a token's K/V reaches a pool and how a query attends over
+  it (decode, prefill chunk, verify window);
+- :mod:`engine` — the model protocol and the fixed-shape compiled
+  decode / prefill / draft / verify executables;
+- :mod:`decode_model` — the built-in small causal LM, one block;
 - :mod:`falcon_h1` — a second model behind the same protocol: grouped-
   query attention beside Mamba-2 heads, with per-slot recurrent state;
 - :mod:`scheduler` — the continuous batcher (``DecodeScheduler``):
@@ -14,7 +16,8 @@ cache with optional speculative decode.
 See docs/ARCHITECTURE.md "Decode serving".
 """
 from .paged_kv import OutOfPagesError, PageAllocator, PagedKVCache
-from .engine import DecodeEngine, DecodeModel, DecodePlaneModel
+from .engine import DecodeEngine, DecodePlaneModel
+from .decode_model import DecodeModel
 from .falcon_h1 import FalconH1
 from .scheduler import DecodeScheduler
 

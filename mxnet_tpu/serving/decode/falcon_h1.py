@@ -31,8 +31,9 @@ mlp_multipliers[1]``.
 
 One block function (:meth:`FalconH1._block`) serves the three paths;
 what differs between them is handed to it: how attention reaches its
-keys and values, and how the mixer's convolution and recurrence reach
-their state.
+keys and values (built by ``paged_kv``, which alone knows a page), and
+how the mixer's convolution and recurrence reach their state (the
+``mix`` closures here).
 
 - decode: one token a slot.  K/V rows scattered into the layer's paged
   buffers and read by ``paged_attention``; the convolution's tail and
@@ -61,10 +62,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ...ops.paged_attention import paged_attention
-from ...ops.rope import rope, rope_reference
 from ...ops.ssm import ssm_chunk_scan, ssm_scan_reference, ssm_update
-from .engine import _NEG_INF, DecodePlaneModel, _rms, _write_kv
+from .decode_model import rms_norm
+from .engine import DecodePlaneModel
+from .paged_kv import chunk_attention, dense_attention, slot_attention
 
 __all__ = ["FalconH1"]
 
@@ -238,12 +239,13 @@ class FalconH1(DecodePlaneModel):
 
     # -- the block ---------------------------------------------------------------
 
-    def _block(self, lp, x, attend, mix):
+    def _block(self, lp, x, kv, attend, mix):
         """One layer over rows ``x (..., dim)``.
 
-        ``attend(q, k, v)`` takes the projected heads ``(..., heads,
-        head_dim)`` before rotation and returns the attention output
-        ``(..., n_heads, head_dim)`` and what it wrote of K/V;
+        ``attend(q, k, v, *kv)`` takes the projected heads ``(...,
+        heads, head_dim)`` before rotation and the layer's K/V buffers
+        (none for the dense path) and returns the attention output
+        ``(..., n_heads, head_dim)`` and the buffers' successors;
         ``mix(xbc, dt)`` takes the mixer's convolution input ``(...,
         conv width)`` and ``dt (..., heads)`` past its softplus, both
         float32, and returns the recurrence's ``y (..., heads, head
@@ -251,14 +253,14 @@ class FalconH1(DecodePlaneModel):
         K/V, state)``."""
         c = self.config
         lead = x.shape[:-1]
-        h = _rms(x, lp["ln1"], self.eps)
+        h = rms_norm(x, lp["ln1"], self.eps)
         # attention heads
         ha = _scaled(h, c["attention_in_multiplier"])
         q = (ha @ lp["wq"]).reshape(lead + (self.n_heads, self.head_dim))
         k = _scaled(ha @ lp["wk"], c["key_multiplier"]).reshape(
             lead + (self.kv_heads, self.head_dim))
         v = (ha @ lp["wv"]).reshape(lead + (self.kv_heads, self.head_dim))
-        attn, kv = attend(q, k, v)
+        attn, kv = attend(q, k, v, *kv)
         attn = attn.reshape(lead + (-1,)).astype(x.dtype) @ lp["wo"]
         # Mamba-2 heads, on the same h
         u = _scaled(h, c["ssm_in_multiplier"]) @ lp["w_in"]
@@ -276,7 +278,7 @@ class FalconH1(DecodePlaneModel):
         x = (x + _scaled(y @ lp["w_out"], c["ssm_out_multiplier"])
              + _scaled(attn, c["attention_out_multiplier"]))
         # SwiGLU
-        h2 = _rms(x, lp["ln2"], self.eps)
+        h2 = rms_norm(x, lp["ln2"], self.eps)
         m_gate, m_down = c["mlp_multipliers"]
         mlp = (h2 @ lp["w_up"]) * jax.nn.silu(
             _scaled(h2 @ lp["w_gate"], m_gate))
@@ -299,7 +301,7 @@ class FalconH1(DecodePlaneModel):
                        self.config["embedding_multiplier"])
 
     def _logits(self, params, x):
-        x = _rms(x, params["lnf"], self.eps)
+        x = rms_norm(x, params["lnf"], self.eps)
         return _scaled(x @ params["head"],
                        self.config["lm_head_multiplier"])
 
@@ -312,25 +314,11 @@ class FalconH1(DecodePlaneModel):
 
     def decode_logits(self, params, pool, tokens, positions, tables, active):
         """The decode step up to its logits ``(slots, vocab)``."""
-        num_pages, ps = pool[0][0].shape[:2]
+        attend = slot_attention(pool, positions, tables, active,
+                                rope_base=self.rope_base)
         x = self._embed(params, tokens)
-        lengths = jnp.where(active, positions + 1, 0).astype(jnp.int32)
-        pagerow = jnp.take_along_axis(
-            tables, (positions // ps)[:, None], axis=1)[:, 0]
-        page = jnp.where(active, pagerow, num_pages).astype(jnp.int32)
-        offset = positions % ps
         out = []
         for (kbuf, vbuf, sbuf, cbuf), lp in zip(pool, params["layers"]):
-
-            def attend(q, k, v, kbuf=kbuf, vbuf=vbuf):
-                q = rope(q, positions, base=self.rope_base)
-                k = rope(k, positions, base=self.rope_base)
-                kbuf, vbuf = _write_kv(kbuf, vbuf, page, offset, k, v)
-                # one block a page: what the kernel's walk costs is its
-                # steps (slots x pages x blocks), whatever the lengths
-                return (paged_attention(q, kbuf, vbuf, tables, lengths,
-                                        block_k=min(ps, 128)),
-                        (kbuf, vbuf))
 
             def mix(xbc, dt, sbuf=sbuf, cbuf=cbuf, lp=lp):
                 taps = jnp.concatenate([cbuf, xbc[:, None, :]], axis=1)
@@ -341,7 +329,7 @@ class FalconH1(DecodePlaneModel):
                                      b, c, lp["d"], active)
                 return y, (sbuf, cbuf)
 
-            x, kv, state = self._block(lp, x, attend, mix)
+            x, kv, state = self._block(lp, x, (kbuf, vbuf), attend, mix)
             out.append(kv + state)
         return tuple(out), self._logits(params, x)
 
@@ -358,43 +346,12 @@ class FalconH1(DecodePlaneModel):
         """One chunk up to the logits ``(vocab,)`` after its last valid
         token."""
         b_ = tokens.shape[0]
-        hd, kvh = self.head_dim, self.kv_heads
-        rep = self.n_heads // kvh
-        num_pages, ps = pool[0][0].shape[:2]
-        scale = 1.0 / (hd ** 0.5)
-        pos = start + jnp.arange(b_, dtype=jnp.int32)
+        attend = chunk_attention(pool, start, chunk_len, table, b_,
+                                 rope_base=self.rope_base)
         valid = jnp.arange(b_) < chunk_len
-        total = start + chunk_len
         x = self._embed(params, tokens)
-        page = jnp.where(valid, table[pos // ps],
-                         num_pages).astype(jnp.int32)
-        offset = pos % ps
-        p_ = table.shape[0]
         out = []
         for (kbuf, vbuf, sbuf, cbuf), lp in zip(pool, params["layers"]):
-
-            def attend(q, k, v, kbuf=kbuf, vbuf=vbuf):
-                q = rope(q, pos, base=self.rope_base)
-                k = rope(k, pos, base=self.rope_base)
-                kbuf, vbuf = _write_kv(kbuf, vbuf, page, offset, k, v)
-                # the chunk attends its causal prefix (earlier chunks
-                # included) over the slot's gathered pages; each K/V head
-                # serves its ``rep`` query heads
-                kctx = kbuf[table].reshape(p_ * ps, kvh, hd)
-                vctx = vbuf[table].reshape(p_ * ps, kvh, hd)
-                qg = q.reshape(b_, kvh, rep, hd).astype(jnp.float32)
-                s = jnp.einsum("bgrd,kgd->bgrk", qg,
-                               kctx.astype(jnp.float32)) * scale
-                kpos = lax.broadcasted_iota(jnp.int32, s.shape, 3)
-                mask = (kpos <= pos[:, None, None, None]) & (kpos < total)
-                s = jnp.where(mask, s, _NEG_INF)
-                m = s.max(axis=-1, keepdims=True)
-                pr = jnp.where(mask, jnp.exp(s - m), 0.0)
-                l = pr.sum(axis=-1, keepdims=True)
-                l = jnp.where(l == 0.0, 1.0, l)
-                o = jnp.einsum("bgrk,kgd->bgrd", pr / l,
-                               vctx.astype(jnp.float32))
-                return o.reshape(b_, self.n_heads, hd), (kbuf, vbuf)
 
             def mix(xbc, dt, sbuf=sbuf, cbuf=cbuf, lp=lp):
                 # the slot's tail, then the chunk: the convolution sees
@@ -416,7 +373,7 @@ class FalconH1(DecodePlaneModel):
                 sbuf = lax.dynamic_update_index_in_dim(sbuf, state, slot, 0)
                 return y, (sbuf, cbuf)
 
-            x, kv, state = self._block(lp, x, attend, mix)
+            x, kv, state = self._block(lp, x, (kbuf, vbuf), attend, mix)
             out.append(kv + state)
         last = lax.dynamic_index_in_dim(x, jnp.maximum(chunk_len - 1, 0),
                                         axis=0, keepdims=False)
@@ -429,23 +386,8 @@ class FalconH1(DecodePlaneModel):
         attention over the sequence itself, the recurrence over time
         from a zero state."""
         t_ = tokens.shape[0]
-        hd, kvh = self.head_dim, self.kv_heads
-        rep = self.n_heads // kvh
-        pos = jnp.arange(t_, dtype=jnp.int32)
+        attend = dense_attention(t_, rope_base=self.rope_base)
         x = self._embed(params, tokens)
-
-        def attend(q, k, v):
-            q = rope_reference(q, pos, base=self.rope_base)
-            k = rope_reference(k, pos, base=self.rope_base)
-            qg = q.reshape(t_, kvh, rep, hd).astype(jnp.float32)
-            s = jnp.einsum("qgrd,kgd->grqk", qg,
-                           k.astype(jnp.float32)) / (hd ** 0.5)
-            qp = lax.broadcasted_iota(jnp.int32, s.shape, 2)
-            kp = lax.broadcasted_iota(jnp.int32, s.shape, 3)
-            pr = jax.nn.softmax(jnp.where(qp >= kp, s, _NEG_INF), axis=-1)
-            o = jnp.einsum("grqk,kgd->qgrd", pr, v.astype(jnp.float32))
-            return o.reshape(t_, self.n_heads, hd), None
-
         for lp in params["layers"]:
 
             def mix(xbc, dt, lp=lp):
@@ -461,7 +403,7 @@ class FalconH1(DecodePlaneModel):
                     zero, xs, dt, -jnp.exp(lp["a_log"]), b, c, lp["d"])
                 return y, None
 
-            x, _, _ = self._block(lp, x, attend, mix)
+            x, _, _ = self._block(lp, x, (), attend, mix)
         return self._logits(params, x)
 
     @functools.cached_property

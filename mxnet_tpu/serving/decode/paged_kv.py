@@ -1,4 +1,5 @@
-"""Paged KV cache: pre-allocated device pool + host page allocator.
+"""Paged KV cache: pre-allocated device pool + host page allocator, and
+what an executable does with a pool it was handed.
 
 The device side is ONE pytree per engine, ``pool[layer] = (k, v,
 *state)``: a buffer of its own for every layer's K and for its V, each
@@ -30,6 +31,17 @@ device traffic on either path).
 Page ``num_pages`` — one past a buffer — is the scatter sentinel: KV
 writes for inactive slots / padded prefill rows are directed there and
 dropped by XLA (``mode="drop"``), so masking never needs a branch.
+
+The traced side (the second half of this module) is the only code
+besides the ``paged_attention`` kernel that knows any of the above.  A
+model's core builds ONE attention for the executable it is traced into
+(:func:`slot_attention` for decode, :func:`chunk_attention` for
+prefill, :func:`window_attention` for verify) from the positions and
+page tables the executable was handed, and calls it in every layer as
+``attend(q, k, v, kbuf, vbuf)``: the projected heads before rotation
+and the layer's own buffers in, the attention output ``(..., query
+heads, head_dim)`` and the buffers' successors out.  Query heads may be
+a multiple of the pool's KV heads (grouped-query attention).
 """
 from __future__ import annotations
 
@@ -38,10 +50,20 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as onp
 
+import jax
+import jax.numpy as jnp
+from jax import lax
+
 from ... import telemetry
 from ...base import MXNetError
+from ...ops.paged_attention import paged_attention
+from ...ops.rope import rope, rope_reference
 
-__all__ = ["PageAllocator", "PagedKVCache", "OutOfPagesError"]
+__all__ = ["PageAllocator", "PagedKVCache", "OutOfPagesError",
+           "slot_attention", "chunk_attention", "window_attention",
+           "dense_attention"]
+
+_NEG_INF = -1e30
 
 
 class OutOfPagesError(MXNetError):
@@ -97,7 +119,6 @@ class PagedKVCache:
                  pages_per_slot: Optional[int] = None,
                  dtype="float32",
                  state_spec: Sequence[Tuple[str, tuple, str]] = ()):
-        import jax.numpy as jnp
         self.layers = int(layers)
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
@@ -181,3 +202,142 @@ class PagedKVCache:
 
     def slot_pages(self, slot: int) -> List[int]:
         return list(self._slot_pages.get(slot, ()))
+
+
+# -- the traced side -----------------------------------------------------------
+
+def _rotate_write(q, k, v, kbuf, vbuf, pos, page, offset, rope_base):
+    """Rotate q and k at ``pos`` and scatter this step's K/V rows into
+    ONE layer's own K and V buffers, each ``(num_pages, page_size,
+    Hkv*D)``: ``(q, kbuf, vbuf)``.  ``page``/``offset`` address one
+    position per row; masked rows carry the sentinel page ``num_pages``
+    — one past the buffer — and are dropped (mode='drop').  Each buffer
+    is a donated argument with this scatter as its only writer and
+    nothing left that reads the old value, so XLA updates it in place:
+    no copy of a buffer exists."""
+    q = rope(q, pos, base=rope_base)
+    k = rope(k, pos, base=rope_base)
+    hd = kbuf.shape[-1]
+    kbuf = kbuf.at[page, offset].set(
+        k.reshape(-1, hd).astype(kbuf.dtype), mode="drop")
+    vbuf = vbuf.at[page, offset].set(
+        v.reshape(-1, hd).astype(vbuf.dtype), mode="drop")
+    return q, kbuf, vbuf
+
+
+def _kernel(q, kbuf, vbuf, tables, lengths):
+    """One query a slot over its ``lengths`` rows.  A page of 128 rows
+    or more is walked 128 rows a block (half a page a block cost 159 us
+    a call against 103: PERF.md section 6, PR 30, finding 2); how many
+    smaller pages make a block is the kernel registry's choice (by
+    default 64 rows, four pages of 16)."""
+    return paged_attention(q, kbuf, vbuf, tables, lengths,
+                           block_k=128 if kbuf.shape[1] >= 128 else None)
+
+
+def slot_attention(pool, positions, tables, active, *, rope_base):
+    """Decode: one token per slot at ``positions (slots,)``, written
+    there through ``tables (slots, pages_per_slot)``, attending over
+    the slot's ``positions + 1`` rows.  An inactive slot writes nothing
+    and yields zeros."""
+    num_pages, ps = pool[0][0].shape[:2]
+    lengths = jnp.where(active, positions + 1, 0).astype(jnp.int32)
+    pagerow = jnp.take_along_axis(
+        tables, (positions // ps)[:, None], axis=1)[:, 0]
+    page = jnp.where(active, pagerow, num_pages).astype(jnp.int32)
+    offset = positions % ps
+
+    def attend(q, k, v, kbuf, vbuf):
+        q, kbuf, vbuf = _rotate_write(q, k, v, kbuf, vbuf, positions, page,
+                                      offset, rope_base)
+        return _kernel(q, kbuf, vbuf, tables, lengths), (kbuf, vbuf)
+
+    return attend
+
+
+def window_attention(pool, base_pos, width: int, tables, active, *,
+                     rope_base):
+    """Verify: ``width`` consecutive positions per slot from ``base_pos
+    (slots,)`` on, heads ``(slots, width, heads, head_dim)``.  The whole
+    window is written first; offset ``j`` then attends over ``base_pos
+    + j + 1`` rows through the SAME kernel call as the decode step, so
+    an accepted token is bitwise the one that step would have emitted."""
+    num_pages, ps = pool[0][0].shape[:2]
+    pos = base_pos[:, None] + jnp.arange(width, dtype=jnp.int32)[None, :]
+    pagerow = jnp.take_along_axis(tables, pos // ps, axis=1)
+    page = jnp.where(active[:, None], pagerow,
+                     num_pages).astype(jnp.int32).reshape(-1)
+    offset = (pos % ps).reshape(-1)
+    lengths = [jnp.where(active, base_pos + j + 1, 0).astype(jnp.int32)
+               for j in range(width)]
+
+    def attend(q, k, v, kbuf, vbuf):
+        q, kbuf, vbuf = _rotate_write(q, k, v, kbuf, vbuf, pos, page,
+                                      offset, rope_base)
+        cols = [_kernel(q[:, j], kbuf, vbuf, tables, lengths[j])
+                for j in range(width)]
+        return jnp.stack(cols, axis=1), (kbuf, vbuf)
+
+    return attend
+
+
+def chunk_attention(pool, start, chunk_len, table, bucket: int, *,
+                    rope_base):
+    """Prefill: ``bucket`` rows of ONE slot, the first ``chunk_len``
+    (traced) of them a prompt's positions from ``start`` on, the rest
+    padding that writes nothing; ``table (pages_per_slot,)`` is the
+    slot's page row.  The chunk attends its causal prefix, earlier
+    chunks included, over the slot's gathered pages: the chunk itself
+    was just written, so one mask covers intra- and cross-chunk keys.
+    Float32 softmax; each K/V head serves its ``rep`` query heads."""
+    num_pages, ps = pool[0][0].shape[:2]
+    pos = start + jnp.arange(bucket, dtype=jnp.int32)
+    valid = jnp.arange(bucket) < chunk_len
+    total = start + chunk_len
+    page = jnp.where(valid, table[pos // ps], num_pages).astype(jnp.int32)
+    offset = pos % ps
+    rows = table.shape[0] * ps
+
+    def attend(q, k, v, kbuf, vbuf):
+        (heads, hd), kvh = q.shape[1:], k.shape[1]
+        q, kbuf, vbuf = _rotate_write(q, k, v, kbuf, vbuf, pos, page,
+                                      offset, rope_base)
+        kctx = kbuf[table].reshape(rows, kvh, hd)
+        vctx = vbuf[table].reshape(rows, kvh, hd)
+        qg = q.reshape(bucket, kvh, heads // kvh, hd).astype(jnp.float32)
+        s = jnp.einsum("bgrd,kgd->bgrk", qg,
+                       kctx.astype(jnp.float32)) * (1.0 / (hd ** 0.5))
+        kpos = lax.broadcasted_iota(jnp.int32, s.shape, 3)
+        mask = (kpos <= pos[:, None, None, None]) & (kpos < total)
+        s = jnp.where(mask, s, _NEG_INF)
+        m = s.max(axis=-1, keepdims=True)
+        pr = jnp.where(mask, jnp.exp(s - m), 0.0)
+        l = pr.sum(axis=-1, keepdims=True)
+        l = jnp.where(l == 0.0, 1.0, l)
+        o = jnp.einsum("bgrk,kgd->bgrd", pr / l, vctx.astype(jnp.float32))
+        return o.reshape(bucket, heads, hd), (kbuf, vbuf)
+
+    return attend
+
+
+def dense_attention(length: int, *, rope_base):
+    """The oracles' ``attend(q, k, v)``: causal attention of one
+    sequence over its own K and V.  No pool, page or kernel
+    (``rope_reference``, XLA's softmax): it shares nothing with the
+    paged paths that tests pin to it."""
+    pos = jnp.arange(length, dtype=jnp.int32)
+
+    def attend(q, k, v):
+        (heads, hd), kvh = q.shape[1:], k.shape[1]
+        q = rope_reference(q, pos, base=rope_base)
+        k = rope_reference(k, pos, base=rope_base)
+        qg = q.reshape(length, kvh, heads // kvh, hd).astype(jnp.float32)
+        s = jnp.einsum("qgrd,kgd->grqk", qg,
+                       k.astype(jnp.float32)) * (1.0 / (hd ** 0.5))
+        qp = lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        kp = lax.broadcasted_iota(jnp.int32, s.shape, 3)
+        pr = jax.nn.softmax(jnp.where(qp >= kp, s, _NEG_INF), axis=-1)
+        o = jnp.einsum("grqk,kgd->qgrd", pr, v.astype(jnp.float32))
+        return o.reshape(length, heads, hd), ()
+
+    return attend

@@ -30,6 +30,9 @@ import time
 import numpy as onp
 import pytest
 
+import jax
+import jax.numpy as jnp
+
 import mxnet_tpu as mx  # noqa: F401  (registers ops + kernel specs)
 from mxnet_tpu import profiler, telemetry, tracing
 from mxnet_tpu.serving import (BadRequestError, DecodeEngine, DecodeModel,
@@ -40,6 +43,7 @@ from mxnet_tpu.serving.decode import OutOfPagesError
 from mxnet_tpu.serving.decode.paged_kv import PageAllocator, PagedKVCache
 
 VOCAB = 48
+REPO = pathlib.Path(__file__).resolve().parent.parent
 
 
 @pytest.fixture(autouse=True)
@@ -182,50 +186,117 @@ def test_paged_attention_ragged_parity_vs_oracle(case):
 
 # -- the pool's layout: one whole buffer per layer for K and for V -----------
 
-def _whole_buffer_equations(jaxpr, nelem):
-    """Primitive names of every equation, nested ones included, that
-    produces an array of at least ``nelem`` elements.  A Pallas call's
-    body is the kernel's own (blocks, not buffers) and is not entered."""
-    found = []
+def _equations(jaxpr):
+    """Every equation of ``jaxpr``, nested ones included.  A Pallas
+    call's body is the kernel's own (blocks, not buffers) and is not
+    entered."""
     for eqn in jaxpr.eqns:
-        if any(getattr(v.aval, "size", 0) >= nelem for v in eqn.outvars):
-            found.append(eqn.primitive.name)
+        yield eqn
         if eqn.primitive.name == "pallas_call":
             continue
         for sub in eqn.params.values():
             sub = getattr(sub, "jaxpr", sub)
             if hasattr(sub, "eqns"):
-                found += _whole_buffer_equations(sub, nelem)
-    return found
+                yield from _equations(sub)
 
 
-@pytest.mark.parametrize("core", ["decode", "prefill", "verify"])
-def test_cores_touch_a_layer_buffer_only_by_its_scatter(model, core):
-    """No core slices a layer's K or V out of a larger array, reshapes
-    it or sets it back: per layer the only equations as large as a
-    buffer are the two scatters of ``_write_kv``.  (A slice of buffer
-    size is a 201 MB copy in front of the Mosaic call on the chip.)"""
-    import jax
-    import jax.numpy as jnp
-    from mxnet_tpu.serving.decode import engine as E
-    # half the pool per slot table, so no gather is as large as a buffer
-    eng = _engine(model, pages_per_slot=4)
-    slots, pool = eng.max_slots, eng.cache.pool
+def _whole_buffer_equations(jaxpr, nelem):
+    """Primitive names of every equation that produces an array of at
+    least ``nelem`` elements."""
+    return [eqn.primitive.name for eqn in _equations(jaxpr)
+            if any(getattr(v.aval, "size", 0) >= nelem
+                   for v in eqn.outvars)]
+
+
+def _tiny_hybrid():
+    """``FalconH1`` at the benchmark configuration's ``rehearsal`` size,
+    as ``tests/test_decode_hybrid.py`` builds it."""
+    from mxnet_tpu.serving import FalconH1
+    with open(REPO / "chipbench" / "configs" / "falcon_h1_34b.json") as f:
+        cfg = json.load(f)
+    cfg.update(cfg.pop("rehearsal"))
+    return FalconH1(cfg, seed=5, dtype="float32")
+
+
+def _core_call(eng, core):
+    """One of the model's traced cores and abstract-enough arguments
+    for it, past ``params`` and ``pool``."""
+    slots, mdl = eng.max_slots, eng.model
     ints = jnp.zeros((slots,), jnp.int32)
     tables, act = eng._tables(eng.cache), jnp.ones((slots,), bool)
     if core == "decode":
-        fn, args = E._decode_core, (ints, ints, tables, act)
-    elif core == "verify":
-        fn, args = E._verify_core, (jnp.zeros((slots, 3), jnp.int32),
-                                    ints, tables, act)
+        return mdl.decode_core, (ints, ints, tables, act)
+    if core == "verify":
+        return mdl.verify_core, (jnp.zeros((slots, 3), jnp.int32), ints,
+                                 tables, act)
+    slot = (jnp.int32(0),) if mdl.state_spec else ()
+    return mdl.prefill_core, (jnp.zeros((8,), jnp.int32), jnp.int32(0),
+                              jnp.int32(5), tables[0]) + slot
+
+
+@pytest.mark.parametrize("family,core", [
+    ("transformer", "decode"), ("transformer", "prefill"),
+    ("transformer", "verify"), ("hybrid", "decode"), ("hybrid", "prefill")])
+def test_cores_touch_a_layer_buffer_only_by_its_scatter(model, family, core):
+    """No core slices a layer's K or V out of a larger array, reshapes
+    it or sets it back: per layer the only equations as large as a
+    buffer are the two scatters of the paged format's one write and,
+    for a model with recurrent state, the update of the state-space
+    state (the kernel's aliased output in a decode step, the slot's
+    rows set back after a chunk).  (A slice of buffer size is a 201 MB
+    copy in front of the Mosaic call on the chip.)"""
+    if family == "hybrid":
+        # 8 slots x (4 heads x 16 x 16) of state, as large as a K/V buffer
+        model, kw = _tiny_hybrid(), dict(max_slots=8, num_pages=32)
+        state = {"decode": ["pallas_call"],
+                 "prefill": ["dynamic_update_slice"]}[core]
     else:
-        fn, args = E._prefill_core, (jnp.zeros((8,), jnp.int32),
-                                     jnp.int32(0), jnp.int32(5),
-                                     tables[0])
-    jaxpr = jax.make_jaxpr(
-        lambda p, kv, *a: fn(model, p, kv, *a))(model.params, pool, *args)
+        kw, state = {}, []
+    # a fraction of the pool per slot table, so no gather is as large
+    # as a buffer
+    eng = _engine(model, pages_per_slot=4, **kw)
+    pool = eng.cache.pool
+    assert all(buf.size >= pool[0][0].size for buf in pool[0][:3])
+    fn, args = _core_call(eng, core)
+    jaxpr = jax.make_jaxpr(fn)(model.params, pool, *args)
     big = _whole_buffer_equations(jaxpr.jaxpr, pool[0][0].size)
-    assert big == ["scatter"] * (2 * model.n_layers), big
+    assert sorted(big) == sorted(
+        (["scatter"] * 2 + state) * model.n_layers), big
+
+
+def _kernel_blocks(jaxpr):
+    """Rows of the K block (a VMEM scratch ``(2, block_k, Hkv*D)``) of
+    every ``paged_attention`` kernel call in ``jaxpr``."""
+    found = []
+    for eqn in _equations(jaxpr):
+        if (eqn.primitive.name == "pallas_call"
+                and eqn.params["name"] == "mxtpu_paged_attention"):
+            inner = eqn.params["jaxpr"]
+            n = eqn.params["grid_mapping"].num_scratch_operands
+            found.append(inner.invars[len(inner.invars) - n + 3]
+                         .aval.shape[1])
+    return found
+
+
+@pytest.mark.parametrize("family", ["transformer", "hybrid"])
+@pytest.mark.parametrize("page_size,rows", [(16, 64), (128, 128)])
+def test_paged_kernel_block_follows_the_page_size(model, family, page_size,
+                                                  rows):
+    """The block is the paged format's choice, not a model's: four
+    pages of 16 (the kernel registry's default of 64 rows), one page of
+    128 (``gpt2_decode_chat`` and ``falcon_h1_decode_chat``), whatever
+    model asks, in the decode step and in every offset of a verify
+    window."""
+    if family == "hybrid":
+        model = _tiny_hybrid()
+    eng = _engine(model, page_size=page_size, num_pages=16,
+                  pages_per_slot=4)
+    for core in ["decode"] + ["verify"] * (family == "transformer"):
+        fn, args = _core_call(eng, core)
+        blocks = _kernel_blocks(jax.make_jaxpr(fn)(
+            model.params, eng.cache.pool, *args).jaxpr)
+        width = 3 if core == "verify" else 1
+        assert blocks == [rows] * (model.n_layers * width), blocks
 
 
 @pytest.mark.parametrize("spec", [False, True], ids=["plain", "spec"])

@@ -564,6 +564,42 @@ def test_grouped_query_is_multi_head_over_repeated_pools():
                                 rtol=1e-6, atol=1e-6)
 
 
+@pytest.mark.parametrize("start,chunk_len", [(0, 16), (16, 11), (5, 1)])
+def test_chunk_attention_grouped_is_multi_head_over_repeated_heads(
+        start, chunk_len):
+    """The prefill chunk's attention, the form both models share: 6
+    query heads over 2 K/V heads against the same queries over those
+    heads repeated to 6 (``rep == 1``, ``DecodeModel``'s case), over a
+    context that an earlier call left in the slot's pages."""
+    from mxnet_tpu.serving.decode.paged_kv import chunk_attention
+    rs = onp.random.RandomState(4)
+    pages, ps, d, bucket = 6, 8, 16, 16
+    table = jnp.asarray([4, 1, 3, 0], jnp.int32)
+
+    def run(rep):
+        """Positions [0, start) written by one call, the chunk by the
+        next; K/V heads repeated ``rep`` times before they are handed
+        over.  The output of the second call."""
+        kvh = 2 * rep
+        buf = jnp.zeros((pages, ps, kvh * d), jnp.float32)
+        kv, out = (buf, buf), None
+        rs.seed(4)
+        for at, n in ((0, start), (start, chunk_len)):
+            q = jnp.asarray(rs.randn(bucket, 6, d), jnp.float32)
+            k, v = (jnp.repeat(jnp.asarray(rs.randn(bucket, 2, d),
+                                           jnp.float32), rep, axis=1)
+                    for _ in range(2))
+            attend = chunk_attention(
+                (kv,), jnp.int32(at), jnp.int32(n), table, bucket,
+                rope_base=10000.0)
+            out, kv = attend(q, k, v, *kv)
+        return onp.asarray(out)[:chunk_len]
+
+    grouped, multi_head = run(1), run(3)
+    assert onp.abs(grouped).max() > 0.1
+    onp.testing.assert_allclose(grouped, multi_head, rtol=1e-6, atol=1e-6)
+
+
 def test_a_pool_that_is_no_whole_number_of_heads_is_refused():
     q = jnp.zeros((2, 6, 16))
     pool = jnp.zeros((4, 8, 4 * 16))              # 4 does not divide 6

@@ -211,7 +211,6 @@ def test_decode_executables_update_the_pool_in_place(v5e, key):
     layer buffer (a slice in front of the Mosaic call, or a buffer set
     back after the scatter, is 201 MB each)."""
     from mxnet_tpu.serving import DecodeModel
-    from mxnet_tpu.serving.decode import engine as E
     layers, slots, pps = 2, 96, 64
     mdl = DecodeModel(512, dim=1024, n_heads=16, n_layers=layers,
                       mlp_ratio=4, dtype="bfloat16")
@@ -224,17 +223,16 @@ def test_decode_executables_update_the_pool_in_place(v5e, key):
     buf = spec((6144, 16, 1024))
     pool = tuple((buf, buf) for _ in range(layers))
     if key == "decode":
-        fn, args = E._decode_core, (
+        fn, args = mdl.decode_core, (
             spec((slots,), "int32"), spec((slots,), "int32"),
             spec((slots, pps), "int32"), spec((slots,), "bool"))
     else:
-        fn, args = E._prefill_core, (
+        fn, args = mdl.prefill_core, (
             spec((128,), "int32"), spec((), "int32"), spec((), "int32"),
             spec((pps,), "int32"))
     # donated as DecodeEngine._get_exec donates it on a TPU
-    mem = jax.jit(lambda p, kv, *a: fn(mdl, p, kv, *a),
-                  donate_argnums=(1,)).lower(
-                      params, pool, *args).compile().memory_analysis()
+    mem = jax.jit(lambda *a: fn(*a), donate_argnums=(1,)).lower(
+        params, pool, *args).compile().memory_analysis()
     one = buf.size * buf.dtype.itemsize          # 201 MB
     assert mem.alias_size_in_bytes == 2 * layers * one
     assert mem.temp_size_in_bytes < one, mem.temp_size_in_bytes
