@@ -8,7 +8,14 @@ use and reused forever (the fixed-shape-executable invariant):
 
 - ``decode`` — one token per active slot over the full ``(max_slots,)``
   grid: active-slot mask, per-slot positions and page tables are traced
-  int arrays, so admission/completion NEVER recompiles;
+  int arrays, so admission/completion NEVER recompiles.  They are the
+  *resident state*, which lives on the device from turn to turn: the
+  executable takes it, donated like the pool, and returns its successor
+  (every active slot's token replaced by the one it just emitted, its
+  position advanced), so a turn is dispatched without reading the turn
+  before it and a steady turn uploads nothing;
+- ``state_edit`` — one row of the resident state rewritten, when a slot
+  changes hands: the only way the host touches that state;
 - ``prefill_b<n>`` — one prompt chunk for one slot, chunk length padded
   into pow2 sequence buckets (chunked prefill: long prompts are fed
   bucket-by-bucket so running decodes aren't stalled behind one long
@@ -133,6 +140,31 @@ class DecodePlaneModel:
 
 # -- what the engine itself traces -----------------------------------------------
 
+def _chained_decode_core(mdl: DecodePlaneModel, params, pool, state):
+    """The model's decode step over the resident ``state = (tokens,
+    positions, active, tables)`` and the state the next turn starts
+    from: ``(pool, state, next token per slot)``.  The tokens go back
+    twice: into the state, which the next dispatch consumes, and as an
+    array of their own for the host to read after it."""
+    tokens, positions, active, tables = state
+    pool, nxt = mdl.decode_core(params, pool, tokens, positions, tables,
+                                active)
+    state = (jnp.where(active, nxt, tokens),
+             positions + active.astype(positions.dtype), active, tables)
+    return pool, state, nxt
+
+
+def _state_edit_core(state, token, patch):
+    """One slot's row of the resident state rewritten.  ``patch`` is
+    ``(slot, position, active, *table row)`` in one int32 array (one
+    upload); ``token`` a scalar of its own because an admitted slot's is
+    a prefill executable's output and never leaves the device."""
+    tokens, positions, active, tables = state
+    slot = patch[0]
+    return (tokens.at[slot].set(token), positions.at[slot].set(patch[1]),
+            active.at[slot].set(patch[2] != 0), tables.at[slot].set(patch[3:]))
+
+
 def _draft_core(mdl: DecodePlaneModel, k: int, params, pool, tokens,
                 base_pos, tables, active):
     """k+1 chained decode steps of the drafting model (unrolled — ``k``
@@ -232,11 +264,28 @@ class DecodeEngine:
                 dtype=draft_model.params["embed"].dtype)
         self._exec: Dict[str, Any] = {}
         self.compiles = 0
+        # the decode executable's inputs, resident on the device: the
+        # executable returns their successor, `state_edit` rewrites one
+        # slot's row, and nothing else writes them.  The host keeps what
+        # it can know without a read: who is active, and where.
+        slots, width = self.max_slots, self.cache.pages_per_slot
+        self._resident = (jnp.zeros((slots,), jnp.int32),
+                          jnp.zeros((slots,), jnp.int32),
+                          jnp.zeros((slots,), bool),
+                          jnp.zeros((slots, width), jnp.int32))
+        self._positions = onp.zeros((slots,), onp.int32)
+        self._active = onp.zeros((slots,), bool)
+        self._no_token = jnp.zeros((), jnp.int32)
+        self._unread = None     # the last decode's tokens, until read
         # share of the page table under live context in the last decode
-        # step (what the paged kernel walks), and its sum over the steps
+        # step (what the paged kernel walks), and its sum over the steps;
+        # whether that step was dispatched with the one before it unread
         self.kv_live_share = 0.0
         self._kv_live_sum = 0.0
+        self.chained = 0
+        self._chained_steps = 0
         self._decode_steps = 0
+        self.state_edits = 0
 
     # -- properties ----------------------------------------------------------
 
@@ -271,7 +320,9 @@ class DecodeEngine:
 
     def _core(self, key: str):
         """The traced function behind an executable's key."""
-        named = {"decode": self.model.decode_core,
+        named = {"decode": functools.partial(_chained_decode_core,
+                                             self.model),
+                 "state_edit": _state_edit_core,
                  "state_reset": _state_reset_core,
                  "draft": functools.partial(_draft_core, self.draft,
                                             self.spec_k),
@@ -331,6 +382,36 @@ class DecodeEngine:
     def _tables(self, cache) -> jnp.ndarray:
         return jnp.asarray(cache.tables, jnp.int32)
 
+    # -- the resident decode state --------------------------------------------
+
+    def _edit_state(self, slot: int, token, position: int, active: bool,
+                    row) -> None:
+        """Rewrite ``slot``'s row of the resident state on the device:
+        its token, position, whether it decodes, its page-table row."""
+        with tracing.span("decode.stage"):
+            patch = onp.concatenate((
+                onp.asarray([slot, position, active], onp.int32), row))
+        self._resident = self._call(
+            "state_edit", (self._resident, token, patch), donate=(0,))
+        self._positions[slot], self._active[slot] = position, active
+        self.state_edits += 1
+
+    def activate_slot(self, slot: int, token, position: int) -> None:
+        """``slot`` decodes from the next ``decode_step`` on: ``token``
+        (a prefill's output, still on the device) at ``position``,
+        through the pages the cache gave it."""
+        self._edit_state(slot, token, position, True,
+                         self.cache.tables[slot])
+
+    def deactivate_slot(self, slot: int) -> None:
+        """``slot`` decodes no more and addresses no page.  A
+        ``decode_step`` already dispatched still advances it once: the
+        edit follows it on the device's one stream, as does whatever a
+        successor in the slot or in its pages is given after."""
+        if self._active[slot]:
+            self._edit_state(slot, self._no_token, 0, False,
+                             onp.zeros_like(self.cache.tables[slot]))
+
     @staticmethod
     def _prefill_args(mdl, cache, padded, start: int, n: int, slot: int):
         """What a prefill executable of ``mdl`` over ``cache`` is called
@@ -345,35 +426,41 @@ class DecodeEngine:
 
     def warmup(self, prefill_lengths: Sequence[int] = (1,)) -> List[str]:
         """Materialize every executable this engine will dispatch —
-        decode (+ draft/verify under speculation) and one prefill per
-        bucket covering ``prefill_lengths`` — WITHOUT running any of
-        them.  Against a populated artifact store each one deserializes
-        (``compiles`` stays 0); otherwise this pays the compiles ahead
-        of traffic.  Also prefetches the kernel-autotune cache.
+        decode and its state's edit (draft and verify instead under
+        speculation) and one prefill per bucket covering
+        ``prefill_lengths`` — WITHOUT running any of them.  Against a
+        populated artifact store each one deserializes (``compiles``
+        stays 0); otherwise this pays the compiles ahead of traffic.  Also prefetches the kernel-autotune cache.
         Returns the exec keys materialized."""
         from ... import kernels
         n_kern = kernels.warm_cache()
         if n_kern:
             get_logger("mxnet_tpu.serving.decode").info(
                 "warmup: %d tuned kernel config(s) preloaded", n_kern)
-        keys = ["decode"]
-        tok = pos = jnp.zeros((self.max_slots,), jnp.int32)
-        tables = self._tables(self.cache)   # the draft's have its shape
-        act = jnp.zeros((self.max_slots,), bool)
-        self._get_exec("decode", (self.model.params, self.cache.pool, tok,
-                                  pos, tables, act))
-        if self.model.state_spec:
-            self._get_exec("state_reset",
-                           (self._state(), jnp.asarray(0, jnp.int32)),
-                           donate=(0,))
-            keys.append("state_reset")
+        keys = []
         if self.spec_enabled:
+            tok = pos = jnp.zeros((self.max_slots,), jnp.int32)
+            tables = self._tables(self.cache)   # the draft's have its shape
+            act = jnp.zeros((self.max_slots,), bool)
             self._get_exec("draft", (self.draft.params, self.draft_cache.pool,
                                      tok, pos, tables, act))
             window = jnp.zeros((self.max_slots, self.spec_k + 1), jnp.int32)
             self._get_exec("verify", (self.model.params, self.cache.pool,
                                       window, pos, tables, act))
             keys += ["draft", "verify"]
+        else:
+            self._get_exec("decode", (self.model.params, self.cache.pool,
+                                      self._resident), donate=(1, 2))
+            self._get_exec("state_edit", (
+                self._resident, self._no_token,
+                onp.zeros((3 + self.cache.pages_per_slot,), onp.int32)),
+                donate=(0,))
+            keys += ["decode", "state_edit"]
+        if self.model.state_spec:
+            self._get_exec("state_reset",
+                           (self._state(), jnp.asarray(0, jnp.int32)),
+                           donate=(0,))
+            keys.append("state_reset")
         for bucket in sorted({self.prefill_bucket(int(n))
                               for n in prefill_lengths}):
             padded = onp.zeros((bucket,), onp.int32)
@@ -388,19 +475,30 @@ class DecodeEngine:
 
     # -- device steps --------------------------------------------------------
 
-    def decode_step(self, tokens, positions, active):
-        """One non-speculative engine step over the full slot grid.
-        Returns the next token per slot (host numpy)."""
-        self._count_live(positions, active)
-        with tracing.span("decode.stage"):
-            args = (self.model.params, self.cache.pool,
-                    jnp.asarray(tokens, jnp.int32),
-                    jnp.asarray(positions, jnp.int32),
-                    self._tables(self.cache),
-                    jnp.asarray(active, bool))
-        self.cache.pool, nxt = self._call("decode", args)
+    def decode_step(self):
+        """Dispatch one non-speculative engine step over the full slot
+        grid, from the resident state and into it.  Returns the next
+        token per slot, on the device and not waited for: :meth:`read`
+        it after the next turn's dispatch."""
+        self._count_live(self._positions, self._active)
+        self.chained = int(self._unread is not None)
+        self._chained_steps += self.chained
+        self.cache.pool, self._resident, nxt = self._call(
+            "decode", (self.model.params, self.cache.pool, self._resident),
+            donate=(1, 2))
+        self._positions += self._active
+        self._unread = nxt
+        return nxt
+
+    def read(self, nxt, firsts):
+        """The turn's one blocking read: a ``decode_step``'s tokens
+        (or None) and a list of prefill chunks' first tokens, as host
+        values."""
         with tracing.span("decode.sync"):
-            return onp.asarray(nxt)
+            out = jax.device_get((nxt, firsts))
+        if nxt is self._unread:
+            self._unread = None
+        return out
 
     def _count_live(self, positions, active):
         """Pages holding an active slot's context (its pending token's
@@ -431,9 +529,10 @@ class DecodeEngine:
         with tracing.span("decode.sync"):
             return onp.asarray(greedy), onp.asarray(accepted)
 
-    def prefill_chunk_step(self, slot: int, chunk, start: int) -> int:
+    def prefill_chunk_step(self, slot: int, chunk, start: int):
         """Feed one prompt chunk for ``slot`` (padded into its pow2
-        bucket); returns the greedy next token after the chunk."""
+        bucket); returns the greedy next token after the chunk, on the
+        device and not waited for."""
         with tracing.span("decode.stage"):
             bucket = self.prefill_bucket(len(chunk))
             padded = onp.zeros((bucket,), onp.int32)
@@ -447,8 +546,7 @@ class DecodeEngine:
                                            padded, start, len(chunk), slot)
             self.draft_cache.pool, _ = self._call(
                 f"draft_prefill_b{bucket}", dargs)
-        with tracing.span("decode.sync"):
-            return int(nxt)
+        return nxt
 
     # -- slot page lifecycle -------------------------------------------------
 
@@ -466,6 +564,7 @@ class DecodeEngine:
                 raise
 
     def release_slot(self, slot: int) -> int:
+        self.deactivate_slot(slot)
         n = self.cache.release(slot)
         if self.draft_cache is not None:
             self.draft_cache.release(slot)
@@ -491,4 +590,7 @@ class DecodeEngine:
                 "state_slots_live": self.cache.state_slots_live(),
                 "state_resets": self.cache.state_resets,
                 "kv_live_share": (self._kv_live_sum / self._decode_steps
-                                  if self._decode_steps else 0.0)}
+                                  if self._decode_steps else 0.0),
+                "chained_share": (self._chained_steps / self._decode_steps
+                                  if self._decode_steps else 0.0),
+                "state_edits": self.state_edits}

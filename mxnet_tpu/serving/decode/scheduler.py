@@ -26,19 +26,37 @@ or manually):
 2. admit — free slots pull from the queue when the page budget
    (prompt + max_new [+ spec window]) fits; pages are acquired in full
    at admission so generation can never run out mid-flight;
-3. prefill — each admitted slot feeds ONE pow2-bucketed prompt chunk
-   (chunked prefill: long prompts interleave with running decodes
-   instead of stalling them); the final chunk yields the first token
-   (TTFT);
-4. decode — one batched token step over every decoding slot, either
-   plain ``decode_step`` or the speculative draft→verify pair
-   (``k`` proposals drafted, verified in one target dispatch,
-   accepted prefix committed — greedy output is token-identical to
-   the non-speculative path);
-5. account — one telemetry step record (source
+3. prefill — each admitted slot is dispatched ONE pow2-bucketed prompt
+   chunk (chunked prefill: long prompts interleave with running decodes
+   instead of stalling them); the final chunk's token stays on the
+   device, as the slot's row of the engine's resident decode state;
+4. decode — one batched token step over every decoding slot is
+   dispatched from that state, and nothing of it is waited for;
+5. commit — the ONE blocking read of the turn, and it is of the turn
+   before: its tokens (prompts' first tokens among them: TTFT) reach
+   their requests, finished requests leave their slots.  The device
+   runs this turn while the host commits the last and dispatches the
+   next behind it; the depth is one turn and fixed;
+6. account — one telemetry step record (source
    ``serving.DecodeScheduler``) with the decode extras the report
-   tools reconcile, plus ``serving.request`` span closure and SLO
-   request feed (TTFT + latency) for finished slots.
+   tools reconcile: ``tokens`` are those committed in this turn;
+   plus ``serving.request`` span closure and SLO request feed (TTFT +
+   latency) for finished slots.
+
+What the one-turn delay rests on: every executable of the engine runs
+on one device stream, in the order dispatched.  That a request ends by
+its count is known before its last token is: its slot is switched off
+behind the decode that computes that token, and no step is wasted.  One
+that ends by ``eos`` or is evicted has a step in flight that advances
+its slot once more; that token is dropped at commit, its K/V row lands
+inside the slot's own page budget, and whoever is given the slot or
+its pages next is dispatched (``state_reset``, chunks) after it.
+
+Under speculation the turn stays synchronous (prefill read, then the
+draft→verify pair: ``k`` proposals drafted, verified in one target
+dispatch, accepted prefix committed — greedy output is token-identical
+to the non-speculative path): the host needs the accepted lengths to
+place the next window.
 """
 from __future__ import annotations
 
@@ -64,7 +82,7 @@ __all__ = ["DecodeScheduler"]
 class _Request:
     __slots__ = ("prompt", "max_new", "eos", "future", "deadline",
                  "t_submit", "t_admit", "rid", "span", "ttft_ms",
-                 "generated", "prefilled", "pending", "pos_next")
+                 "generated", "prefilled", "slot", "dispatched")
 
     def __init__(self, prompt, max_new, eos, deadline, rid):
         self.prompt = prompt
@@ -77,10 +95,10 @@ class _Request:
         self.rid = rid
         self.span = None
         self.ttft_ms = None
-        self.generated: List[int] = []
-        self.prefilled = 0       # prompt tokens written so far
-        self.pending = None      # committed-but-unconsumed token
-        self.pos_next = 0        # position the pending token occupies
+        self.generated: List[int] = []   # tokens committed
+        self.prefilled = 0       # prompt tokens dispatched so far
+        self.slot = None
+        self.dispatched = 0      # tokens whose step has been dispatched
 
 
 class DecodeScheduler:
@@ -106,6 +124,9 @@ class DecodeScheduler:
         self._cv = threading.Condition()
         self._step_lock = threading.Lock()
         self._slots: List[Optional[_Request]] = [None] * engine.max_slots
+        # the turn dispatched and not yet read: (tokens on the device,
+        # the requests that decoded, the prompts that ended), or None
+        self._inflight: Optional[tuple] = None
         self._closed = False
         self._drain = True
         self._thread: Optional[threading.Thread] = None
@@ -113,6 +134,7 @@ class DecodeScheduler:
         self._spec_proposed = 0
         self._spec_accepted = 0
         self._last_compiles = engine.compiles
+        self._last_edits = engine.state_edits
         if start:
             self.start()
 
@@ -157,6 +179,7 @@ class DecodeScheduler:
                     self._finish_error(
                         r, ServingClosedError(
                             "server shut down mid-generation"))
+                self._inflight = None      # nobody is left to tell
         if self._thread is not None and self._thread.is_alive():
             self._thread.join(timeout)
         if drain:
@@ -176,9 +199,11 @@ class DecodeScheduler:
         return sum(1 for r in self._slots if r is not None)
 
     def _has_work(self) -> bool:
+        """A request queued or in a slot, or a turn in flight whose
+        tokens nobody has read."""
         with self._cv:
-            return bool(self._q) or any(
-                r is not None for r in self._slots)
+            return (bool(self._q) or self._inflight is not None
+                    or any(r is not None for r in self._slots))
 
     # -- admission ----------------------------------------------------------
 
@@ -269,8 +294,9 @@ class DecodeScheduler:
     # -- the step ------------------------------------------------------------
 
     def step(self) -> dict:
-        """One scheduler turn: expire → admit → prefill → decode →
-        account.  Returns the decode extras dict it recorded."""
+        """One scheduler turn: expire → admit → dispatch (prefill,
+        decode) → commit the turn before → account.  Returns the decode
+        extras dict it recorded."""
         with self._step_lock, tracing.span("decode.step") as turn:
             extra = self._step_locked()
             turn.annotate(slots_active=extra["slots_active"])
@@ -285,10 +311,10 @@ class DecodeScheduler:
         t_step = time.perf_counter()
         token = telemetry.begin_step()
         now = time.perf_counter()
-        new_tokens = 0
-        prefill_tokens = 0
-        ttfts: List[float] = []
-        completed = 0
+        # the step record, filled in as the phases go: `tokens`,
+        # `completed` and `ttft_ms` where tokens are committed
+        extra = {"tokens": 0, "prefill_tokens": 0, "completed": 0,
+                 "ttft_ms": []}
 
         with tracing.span("decode.expire"):
             evictions = self._expire(now)
@@ -296,54 +322,28 @@ class DecodeScheduler:
         with tracing.span("decode.admit_phase") as sp:
             sp.annotate(admitted=self._admit(now))
 
-        # 3. chunked prefill — one chunk per prefilling slot per step
-        for s, r in enumerate(self._slots):
-            if r is None or r.prefilled >= len(r.prompt):
-                continue
-            chunk = r.prompt[r.prefilled:
-                             r.prefilled + eng.prefill_chunk]
-            with tracing.span("decode.prefill", request_id=r.rid,
-                              slot=s, tokens=len(chunk)):
-                nxt = eng.prefill_chunk_step(s, chunk, r.prefilled)
-            r.prefilled += len(chunk)
-            prefill_tokens += len(chunk)
-            telemetry.counter("decode.prefill_tokens").inc(len(chunk))
-            if r.prefilled >= len(r.prompt):
-                # final chunk: first generated token → TTFT
-                r.ttft_ms = round(
-                    (time.perf_counter() - r.t_submit) * 1e3, 3)
-                ttfts.append(r.ttft_ms)
-                r.pos_next = len(r.prompt)
-                new_tokens += 1
-                if self._commit(s, r, int(nxt)):
-                    completed += 1
+        firsts = self._prefill(extra)
+        decoding = (self._spec_turn if eng.spec_enabled
+                    else self._chained_turn)(firsts, extra)
 
-        # 4. one batched decode step over every decoding slot
-        decoding = [s for s, r in enumerate(self._slots)
-                    if r is not None and r.pending is not None]
-        if decoding:
-            with tracing.span("decode.decode", decoding=len(decoding)):
-                emitted, finished = self._decode(decoding)
-            new_tokens += emitted
-            completed += finished
-
-        # 5. account
+        # 6. account
         with tracing.span("decode.account"):
             active = self.active()
-            telemetry.counter("decode.tokens").inc(new_tokens)
+            telemetry.counter("decode.tokens").inc(extra["tokens"])
             telemetry.counter("decode.steps").inc()
             telemetry.gauge("decode.slots_active").set(active)
             compiles = eng.compiles - self._last_compiles
             self._last_compiles = eng.compiles
-            extra = {
-                "tokens": new_tokens,
-                "prefill_tokens": prefill_tokens,
+            edits = eng.state_edits - self._last_edits
+            self._last_edits = eng.state_edits
+            if not extra["ttft_ms"]:
+                del extra["ttft_ms"]
+            extra.update({
                 "slots_active": active,
                 "max_slots": eng.max_slots,
                 "pages_used": eng.cache.pages_used(),
                 "num_pages": eng.num_pages,
                 "evictions": evictions,
-                "completed": completed,
                 "queue_depth": self.pending(),
                 "compiles": compiles,
                 "spec_proposed": self._spec_proposed,
@@ -353,10 +353,10 @@ class DecodeScheduler:
                 "state_resets": eng.cache.state_resets,
                 "kv_live_share": (round(eng.kv_live_share, 6)
                                   if decoding else 0.0),
+                "chained": eng.chained if decoding else 0,
+                "state_edits": edits,
                 "step_ms": round((time.perf_counter() - t_step) * 1e3, 3),
-            }
-            if ttfts:
-                extra["ttft_ms"] = ttfts
+            })
             telemetry.end_step(token, "serving.DecodeScheduler",
                                extra={"decode": extra})
         return extra
@@ -412,6 +412,7 @@ class DecodeScheduler:
                     self._q.appendleft(r)
                     break
                 r.t_admit = now
+                r.slot = s
                 self._slots[s] = r
                 admitted += 1
                 tracing.instant("decode.admit", request_id=r.rid,
@@ -419,68 +420,126 @@ class DecodeScheduler:
             self._gauge_q.set(len(self._q))
         return admitted
 
-    def _decode(self, decoding: List[int]):
-        """Phase 4: one batched token step (or draft→verify pair) over
-        the slots in ``decoding`` and its commits; returns (tokens
-        emitted, requests completed)."""
+    def _prefill(self, extra: dict) -> list:
+        """Phase 3: one chunk per prefilling slot, dispatched and not
+        waited for.  Returns ``(request, its first token on the
+        device)`` for every prompt whose last chunk this was."""
         eng = self.engine
-        new_tokens = completed = 0
+        firsts = []
+        for s, r in enumerate(self._slots):
+            if r is None or r.prefilled >= len(r.prompt):
+                continue
+            chunk = r.prompt[r.prefilled:
+                             r.prefilled + eng.prefill_chunk]
+            with tracing.span("decode.prefill", request_id=r.rid,
+                              slot=s, tokens=len(chunk)):
+                tok = eng.prefill_chunk_step(s, chunk, r.prefilled)
+            r.prefilled += len(chunk)
+            extra["prefill_tokens"] += len(chunk)
+            telemetry.counter("decode.prefill_tokens").inc(len(chunk))
+            if r.prefilled >= len(r.prompt):
+                firsts.append((r, tok))
+        return firsts
+
+    def _chained_turn(self, firsts: list, extra: dict) -> int:
+        """Phases 4 and 5: switch the slots of ``firsts`` on, dispatch
+        one batched token step from the engine's resident state, and
+        only then read the turn before and hand its tokens to their
+        requests.  Returns the number of slots that decode."""
+        eng = self.engine
+        for r, tok in firsts:
+            # the token stays on the device, as the slot's row of the
+            # resident state; a request of one token needs no step
+            r.dispatched = 1
+            if r.max_new > 1:
+                eng.activate_slot(r.slot, tok, len(r.prompt))
+        decoding = [r for r in self._slots
+                    if r is not None and 0 < r.dispatched < r.max_new]
+        nxt = None
+        if decoding:
+            with tracing.span("decode.decode", decoding=len(decoding)):
+                nxt = eng.decode_step()
+                for r in decoding:
+                    r.dispatched += 1
+                    if r.dispatched == r.max_new:
+                        # its last token is in flight: no step after this
+                        eng.deactivate_slot(r.slot)
+        before, self._inflight = self._inflight, (
+            (nxt, decoding, firsts) if decoding or firsts else None)
+        if before is not None:
+            nxt, decoded, firsts = before
+            nxt, first_toks = eng.read(nxt, [t for _, t in firsts])
+            self._commit_firsts(firsts, first_toks, extra)
+            for r in decoded:
+                # one that ended since the dispatch (``eos``, a deadline)
+                # was advanced once more: that token is dropped
+                if not r.future.done():
+                    extra["tokens"] += 1
+                    extra["completed"] += self._commit(r, int(nxt[r.slot]))
+        return len(decoding)
+
+    def _commit_firsts(self, firsts, toks, extra: dict) -> None:
+        """The first generated token of each prompt in ``firsts``, now
+        that the host has it: TTFT."""
+        for (r, _), tok in zip(firsts, toks):
+            if r.future.done():     # evicted with its chunk in flight
+                continue
+            r.ttft_ms = round((time.perf_counter() - r.t_submit) * 1e3, 3)
+            extra["ttft_ms"].append(r.ttft_ms)
+            extra["tokens"] += 1
+            extra["completed"] += self._commit(r, int(tok))
+
+    def _spec_turn(self, firsts: list, extra: dict) -> int:
+        """The speculative turn's phases 4 and 5, synchronous: the
+        prompts' first tokens are read (the draft needs them on the
+        host), then one draft→verify pair over every decoding slot is
+        dispatched, read at once and committed.  Returns the number of
+        slots that decoded."""
+        eng = self.engine
+        if firsts:
+            self._commit_firsts(
+                firsts, eng.read(None, [t for _, t in firsts])[1], extra)
+        decoding = [r for r in self._slots if r is not None and r.generated]
+        if not decoding:
+            return 0
         n = eng.max_slots
         toks = onp.zeros((n,), onp.int32)
         pos = onp.zeros((n,), onp.int32)
         act = onp.zeros((n,), bool)
-        for s in decoding:
-            r = self._slots[s]
-            toks[s], pos[s], act[s] = r.pending, r.pos_next, True
-        if eng.spec_enabled:
+        for r in decoding:
+            toks[r.slot] = r.generated[-1]
+            pos[r.slot] = len(r.prompt) + len(r.generated) - 1
+            act[r.slot] = True
+        with tracing.span("decode.decode", decoding=len(decoding)):
             greedy, accepted = eng.spec_step(toks, pos, act)
             k = eng.spec_k
-            for s in decoding:
-                r = self._slots[s]
-                take = int(accepted[s]) + 1
+            for r in decoding:
                 self._spec_proposed += k
-                self._spec_accepted += int(accepted[s])
-                done = False
-                for j in range(take):
-                    new_tokens += 1
-                    if self._commit(s, r, int(greedy[s, j])):
-                        completed += 1
-                        done = True
+                self._spec_accepted += int(accepted[r.slot])
+                for j in range(int(accepted[r.slot]) + 1):
+                    extra["tokens"] += 1
+                    if self._commit(r, int(greedy[r.slot, j])):
+                        extra["completed"] += 1
                         break
-                if not done:
-                    r.pos_next += take
-            telemetry.counter("decode.spec_proposed").inc(
-                k * len(decoding))
-            telemetry.counter("decode.spec_accepted").inc(
-                sum(int(accepted[s]) for s in decoding))
-            if self._spec_proposed:
-                telemetry.gauge("decode.spec_accept_rate").set(
-                    round(self._spec_accepted
-                          / self._spec_proposed, 4))
-        else:
-            nxt = eng.decode_step(toks, pos, act)
-            for s in decoding:
-                r = self._slots[s]
-                new_tokens += 1
-                if self._commit(s, r, int(nxt[s])):
-                    completed += 1
-                else:
-                    r.pos_next += 1
-        return new_tokens, completed
+        telemetry.counter("decode.spec_proposed").inc(k * len(decoding))
+        telemetry.counter("decode.spec_accepted").inc(
+            sum(int(accepted[r.slot]) for r in decoding))
+        if self._spec_proposed:
+            telemetry.gauge("decode.spec_accept_rate").set(
+                round(self._spec_accepted / self._spec_proposed, 4))
+        return len(decoding)
 
-    def _commit(self, s: int, r: _Request, tok: int) -> bool:
+    def _commit(self, r: _Request, tok: int) -> bool:
         """Append one emitted token; on eos/max_new finish the request,
         release its pages and free the slot.  Returns True when the
-        request completed, else leaves ``tok`` as the slot's pending
-        token (the caller advances ``pos_next``)."""
+        request completed."""
         r.generated.append(tok)
         if (len(r.generated) >= r.max_new
                 or (r.eos is not None and tok == r.eos)):
-            self.engine.release_slot(s)
-            self._slots[s] = None
+            self.engine.release_slot(r.slot)
+            self._slots[r.slot] = None
             self._finish_ok(r)
             return True
-        r.pending = tok
         return False
 
     # -- background loop -----------------------------------------------------
@@ -489,8 +548,7 @@ class DecodeScheduler:
         idle_wait = _getenv_float("MXNET_DECODE_IDLE_WAIT_S", 0.005)
         while True:
             with self._cv:
-                has_work = bool(self._q) or any(
-                    r is not None for r in self._slots)
+                has_work = self._has_work()
                 if self._closed and not (self._drain and has_work):
                     break
                 if not has_work:

@@ -25,6 +25,7 @@ Tier-1 acceptance lives here, all in-process (CPU, no sockets):
 import importlib.util
 import json
 import pathlib
+import re
 import time
 
 import numpy as onp
@@ -316,14 +317,15 @@ def test_pool_is_one_k_and_one_v_buffer_per_layer(model, draft, spec):
     eng.acquire_slot(0, 8)      # holds page 0 and stays inactive
     eng.acquire_slot(1, len(prompt) + 4)
     tok = eng.prefill_chunk_step(1, prompt, 0)
-    tokens = onp.zeros((eng.max_slots,), onp.int32)
-    pos = onp.zeros((eng.max_slots,), onp.int32)
-    active = onp.zeros((eng.max_slots,), bool)
-    tokens[1], pos[1], active[1] = tok, len(prompt), True
     if spec:
+        tokens = onp.zeros((eng.max_slots,), onp.int32)
+        pos = onp.zeros((eng.max_slots,), onp.int32)
+        active = onp.zeros((eng.max_slots,), bool)
+        tokens[1], pos[1], active[1] = tok, len(prompt), True
         eng.spec_step(tokens, pos, active)
     else:
-        eng.decode_step(tokens, pos, active)
+        eng.activate_slot(1, tok, len(prompt))
+        eng.decode_step()
     for (c, m), structure in zip(caches, before):
         assert jax.tree_util.tree_structure(c.pool) == structure
         assert [len(pair) for pair in c.pool] == [2] * m.n_layers
@@ -356,24 +358,29 @@ def test_scheduler_matches_greedy_reference(model):
         assert g == model.greedy_reference(p, 10)
 
 
-@pytest.mark.parametrize("prompts,max_new,want", [
+@pytest.mark.parametrize("prompts,max_new,want,chained,edits", [
     # slot 0: a prompt of 6, then positions 6..10; slot 1: a prompt of
     # 15 in two chunks of 8, so from the second turn, positions 15, 16.
     # Pages of 8 under each turn's lengths (position + 1):
-    # 1, 1+2, 2+3, 2, 2
-    ([6, 15], [6, 3], [1, 3, 5, 2, 2]),
+    # 1, 1+2, 2+3, 2, 2; the last turn dispatches nothing and commits
+    # the one before.  Edits of the resident state: slot 0 switched on,
+    # slot 1 on, slot 1 off behind its third token's step, slot 0 off
+    ([6, 15], [6, 3], [1, 3, 5, 2, 2, 0], [0, 1, 1, 1, 1, 0],
+     [1, 1, 1, 0, 1, 0]),
     # alone, its first turn decodes nothing
-    ([15], [3], [0, 2, 3]),
+    ([15], [3], [0, 2, 3, 0], [0, 0, 1, 0], [0, 1, 1, 0]),
 ], ids=["two_slots", "prefill_only_turn"])
 def test_kv_live_share_counts_the_pages_under_live_lengths(
-        model, prompts, max_new, want):
+        model, prompts, max_new, want, chained, edits):
     """The step record's ``kv_live_share`` and its running mean in
-    ``engine.stats()`` against lengths counted by hand; the keys the
-    benchmark reads stay where they were."""
+    ``engine.stats()`` against lengths counted by hand, ``chained`` and
+    ``state_edits`` beside it; the keys the benchmark reads stay where
+    they were."""
     eng = _engine(model, prefill_chunk=8, prefill_floor=8)
     sch = _sched(eng)
     table = eng.max_slots * eng.cache.pages_per_slot
     assert eng.stats()["kv_live_share"] == 0.0
+    assert eng.stats()["chained_share"] == 0.0
     for n, m in zip(prompts, max_new):
         sch.submit(list(range(1, n + 1)), max_new_tokens=m)
     records = []
@@ -382,13 +389,22 @@ def test_kv_live_share_counts_the_pages_under_live_lengths(
     sch.close(drain=True)
     assert [r["kv_live_share"] for r in records] == [
         round(w / table, 6) for w in want]
+    assert [r["chained"] for r in records] == chained
+    assert [r["state_edits"] for r in records] == edits
     decoded = [w for w in want if w]
     assert eng.stats()["kv_live_share"] == pytest.approx(
         sum(decoded) / len(decoded) / table)
+    assert eng.stats()["chained_share"] == pytest.approx(
+        sum(chained) / len(decoded))
+    assert eng.stats()["state_edits"] == sum(edits)
     for r in records:
         assert {"tokens", "step_ms", "slots_active",
                 "queue_depth"} <= set(r)
     assert sum("ttft_ms" in r for r in records) == len(prompts)
+    # a record counts tokens where they are committed, a turn after
+    # their dispatch: nothing in the first, the last one's in the last
+    assert sum(r["tokens"] for r in records) == sum(max_new)
+    assert records[0]["tokens"] == 0 and records[-1]["tokens"] > 0
 
 
 def test_eos_stops_generation(model):
@@ -423,6 +439,200 @@ def test_warm_admissions_never_recompile(model):
     assert eng.compiles == warm         # steady state: 0 new compiles
     assert eng.cache.pages_used() == 0
     sch.close(drain=True)
+
+
+# -- the chained turn: dispatch n, then read and commit n-1 -------------------
+
+@pytest.fixture(scope="module")
+def warm(model):
+    """One warmed engine a family, shared by the chained-turn tests:
+    each leaves every slot released, and what an earlier one left in
+    the resident state of an idle slot is part of the test."""
+    made = {}
+
+    def get(family):
+        if family not in made:
+            mdl = model if family == "transformer" else _tiny_hybrid()
+            eng = _engine(mdl, max_slots=3, num_pages=24, pages_per_slot=8,
+                          prefill_chunk=16, prefill_floor=8)
+            eng.warmup([8, 16])
+            made[family] = (mdl, eng)
+        mdl, eng = made[family]
+        assert eng.cache.pages_used() == 0 and not eng._active.any()
+        # on the device as on the host: nobody decodes, no row has a page
+        assert not onp.asarray(eng._resident[2]).any()
+        assert not onp.asarray(eng._resident[3]).any()
+        return mdl, eng
+
+    return get
+
+
+FAMILIES = pytest.mark.parametrize("family", ["transformer", "hybrid"])
+
+
+def _steps_until(sch, done, limit=200):
+    records = []
+    while not done():
+        records.append(sch.step())
+        assert len(records) < limit
+    return records
+
+
+@FAMILIES
+def test_chained_open_schedule_matches_the_oracle(warm, family):
+    """Requests arriving at seeded turns into three slots, prompts of
+    one and two chunks, ``max_new`` from 1 on: admissions and finishes
+    while a turn is in flight, slots and pages reused.  Request by
+    request the dense oracle's tokens, and those of a run that serves
+    them one at a time; no executable compiled after warm-up."""
+    mdl, eng = warm(family)
+    compiled = eng.compiles
+    rs = onp.random.RandomState(21)
+    prompts = _prompts(8, lo=3, hi=30, seed=21)
+    max_new = [int(n) for n in rs.randint(1, 9, size=8)]
+    due = sorted(int(t) for t in rs.randint(0, 14, size=8))
+    sch = _sched(eng)
+    futs, turn = [], 0
+    while len(futs) < len(prompts) or sch._has_work():
+        while len(futs) < len(prompts) and due[len(futs)] <= turn:
+            i = len(futs)
+            futs.append(sch.submit(prompts[i], max_new_tokens=max_new[i]))
+        sch.step()
+        turn += 1
+        assert turn < 200
+    got = [f.result(0) for f in futs]
+    assert got == [mdl.greedy_reference(p, n)
+                   for p, n in zip(prompts, max_new)]
+    assert got == [_gen(sch, [p], max_new=n)[0]
+                   for p, n in zip(prompts, max_new)]
+    sch.close(drain=True)
+    assert eng.compiles == compiled and eng.cache.pages_used() == 0
+    assert eng.stats()["chained_share"] > 0.5
+
+
+@FAMILIES
+def test_eos_with_the_next_turn_in_flight(warm, family):
+    """The host learns of ``eos`` a turn after the step that emitted
+    it, with one more step of the slot already dispatched: that step's
+    token reaches nobody and no record, the pages return, and the
+    slot's next tenant (a recurrent model's: from zero state) decodes
+    its own reference."""
+    mdl, eng = warm(family)
+    p, successor = _prompts(2, lo=5, hi=8, seed=4)
+    ref = mdl.greedy_reference(p, 12)
+    eos = ref[3]
+    cut = ref.index(eos)
+    steps0 = eng._decode_steps
+    sch = _sched(eng)
+    fut = sch.submit(p, max_new_tokens=12, eos=eos)
+    records = _steps_until(sch, fut.done)
+    assert fut.result(0) == ref[:cut + 1]
+    # tokens 2..cut+1 took a step each, and one more was in flight (two
+    # where the prompt's own token ends it: it is read with the second)
+    assert eng._decode_steps - steps0 == max(cut + 1, 2)
+    assert sum(r["tokens"] for r in records) == cut + 1
+    assert eng.cache.pages_used() == 0 and not eng._active.any()
+    fut = sch.submit(successor, max_new_tokens=5)
+    assert sch.step()["slots_active"] == 1 and sch._slots[0] is not None
+    records = _steps_until(sch, lambda: not sch._has_work())
+    assert fut.result(0) == mdl.greedy_reference(successor, 5)
+    assert records[-1]["tokens"] > 0 and records[-1]["chained"] == 0
+    sch.close(drain=True)
+
+
+@FAMILIES
+@pytest.mark.parametrize("max_new,decodes,edits", [(1, 0, 0), (2, 1, 2)])
+def test_chained_shortest_requests(warm, family, max_new, decodes, edits):
+    """One token: the prompt's own, no slot is ever switched on.  Two:
+    on behind the last chunk, off behind the one step."""
+    mdl, eng = warm(family)
+    p = _prompts(1, lo=20, hi=20, seed=31)[0]      # two chunks
+    steps0 = eng._decode_steps
+    sch = _sched(eng)
+    fut = sch.submit(p, max_new_tokens=max_new)
+    records = _steps_until(sch, lambda: not sch._has_work())
+    sch.close(drain=True)
+    assert fut.result(0) == mdl.greedy_reference(p, max_new)
+    assert eng._decode_steps - steps0 == decodes
+    assert sum(r["state_edits"] for r in records) == edits
+    assert [r["tokens"] for r in records] == [0, 0, max_new]
+    assert len(records[-1]["ttft_ms"]) == 1 and eng.cache.pages_used() == 0
+
+
+@FAMILIES
+def test_deadline_eviction_with_a_turn_in_flight(warm, family):
+    mdl, eng = warm(family)
+    p, successor = _prompts(2, lo=5, hi=8, seed=12)
+    e0 = telemetry.counter("decode.evictions").value
+    sch = _sched(eng)
+    fut = sch.submit(p, max_new_tokens=20, timeout_ms=60_000.0)
+    for _ in range(3):
+        sch.step()
+    assert sch._inflight is not None and eng._active[0]
+    sch._slots[0].deadline = time.perf_counter() - 1.0
+    late = sch.submit(successor, max_new_tokens=4)
+    rec = sch.step()        # evicts, admits the successor into the slot
+    with pytest.raises(RequestTimeoutError):
+        fut.result(0)
+    # the step in flight was the evicted request's alone: dropped
+    assert rec["evictions"] == 1 and rec["tokens"] == 0
+    assert telemetry.counter("decode.evictions").value == e0 + 1
+    assert sch._slots[0] is not None and sch._slots[0].future is late
+    _run(sch)
+    assert late.result(0) == mdl.greedy_reference(successor, 4)
+    assert eng.cache.pages_used() == 0
+    sch.close(drain=True)
+
+
+@FAMILIES
+def test_a_steady_turn_uploads_nothing_and_blocks_once(
+        warm, family, _traced, monkeypatch):
+    """Two slots decoding, nobody arriving or leaving: the turn hands
+    no host array to any executable (the transfer guard refuses one),
+    stages nothing, and waits once, for the turn before."""
+    mdl, eng = warm(family)
+    prompts = _prompts(2, lo=4, hi=8, seed=9)
+    sch = _sched(eng)
+    futs = [sch.submit(p, max_new_tokens=8) for p in prompts]
+    sch.step()
+    sch.step()
+    reads = []
+    get = jax.device_get
+    monkeypatch.setattr(jax, "device_get",
+                        lambda x: reads.append(x) or get(x))
+    tracing.enable()
+    tracing.clear()
+    with jax.transfer_guard_host_to_device("disallow_explicit"):
+        rec = sch.step()
+    names = [e["name"] for e in tracing._completed_events()]
+    tracing.disable()
+    monkeypatch.undo()
+    assert (rec["chained"], rec["state_edits"], rec["tokens"]) == (1, 0, 2)
+    assert len(reads) == 1
+    assert names.count("decode.step") == names.count("decode.sync") == 1
+    assert "decode.stage" not in names and "decode.prefill" not in names
+    # and this backend's guard does refuse an upload
+    with jax.transfer_guard_host_to_device("disallow_explicit"):
+        with pytest.raises(Exception, match="host-to-device"):
+            jnp.asarray(onp.zeros((3,), onp.int32))
+    sch.close(drain=False)
+    assert all(f.done() for f in futs)
+    assert eng.cache.pages_used() == 0 and not eng._active.any()
+
+
+@FAMILIES
+def test_close_drain_commits_the_turn_in_flight(warm, family):
+    mdl, eng = warm(family)
+    p = _prompts(1, lo=5, hi=8, seed=13)[0]
+    sch = _sched(eng)
+    fut = sch.submit(p, max_new_tokens=6)
+    sch.step()
+    sch.step()
+    assert sch._inflight is not None and sch._has_work()
+    sch.close(drain=True)
+    assert fut.result(0) == mdl.greedy_reference(p, 6)
+    assert sch._inflight is None and not sch._has_work()
+    assert eng.cache.pages_used() == 0
 
 
 # -- speculative decode ------------------------------------------------------
@@ -485,8 +695,9 @@ def _mixed_turn(model, **kw):
 
 def test_one_turn_leaves_every_phase_span_nested(model, _traced):
     """One ``step()`` under ``tracing.enable()``: the eight names, each
-    phase inside ``decode.step``, staging and sync inside the prefill or
-    the decode they belong to, one ``decode.prefill`` per chunk."""
+    phase inside ``decode.step``, one ``decode.prefill`` per chunk with
+    its staging inside, and ONE ``decode.sync``: the turn's only wait,
+    for the turn before."""
     sch = _mixed_turn(model)
     tracing.enable()
     tracing.clear()
@@ -502,7 +713,7 @@ def test_one_turn_leaves_every_phase_span_nested(model, _traced):
         return by_id[e["args"]["parent_id"]]["name"]
 
     names = [e["name"] for e in evs]
-    step, = [e for e in evs if e["name"] == "decode.step"]
+    step, = [e for e in evs if e["name"] == "decode.step"]   # one a turn
     assert "parent_id" not in step["args"]
     assert step["args"]["slots_active"] == 2     # as the step record has it
     for phase in PHASES:
@@ -522,10 +733,14 @@ def test_one_turn_leaves_every_phase_span_nested(model, _traced):
     assert one["decode.admit_phase"]["admitted"] == 1
     # the slot that just prefilled its only chunk decodes in this turn too
     assert one["decode.decode"]["decoding"] == 2
-    # each device call stages its arguments, then waits for the answer
-    for leaf in ("decode.stage", "decode.sync"):
-        got = sorted(parent(e) for e in evs if e["name"] == leaf)
-        assert got == ["decode.decode", "decode.prefill"], (leaf, got)
+    # the chunk stages its tokens, the edit that switches its slot on
+    # its patch; the decode stages nothing; the turn waits once, last
+    assert [parent(e) for e in evs if e["name"] == "decode.stage"] == [
+        "decode.prefill", "decode.step"]
+    sync, = [e for e in evs if e["name"] == "decode.sync"]
+    assert parent(sync) == "decode.step"
+    decode, = [e for e in evs if e["name"] == "decode.decode"]
+    assert decode["ts"] + decode["dur"] <= sync["ts"] + 1
     assert sorted(set(names)) == sorted(
         PHASES + ("decode.step", "decode.admit", "decode.stage",
                   "decode.sync"))
@@ -556,22 +771,49 @@ def test_a_turn_under_a_capture_reaches_the_host_plane(
 
 
 @pytest.fixture(scope="module")
-def spec_engine(model, draft):
-    eng = _engine(model, num_pages=64, draft_model=draft, spec_k=3)
-    eng.warmup([8])
-    return eng
+def warm_engines(model, draft):
+    plain = _engine(model)
+    spec = _engine(model, num_pages=64, draft_model=draft, spec_k=3)
+    return {"plain": (plain, plain.warmup([8])),
+            "spec": (spec, spec.warmup([8]))}
 
 
-@pytest.mark.parametrize("key", ["decode", "draft", "verify",
-                                 "prefill_b16", "draft_prefill_b16"])
-def test_executable_carries_its_key_as_its_name(spec_engine, key):
+def _trace_patterns():
+    """The executables a per-layer metric reads, by the pattern its file
+    under ``chipbench/layer_metrics/`` gives the trace reader."""
+    out = {}
+    for name in ("decode_exec_ms_p50", "prefill_exec_ms_p50"):
+        with open(REPO / "chipbench" / "layer_metrics" / f"{name}.json") as f:
+            out[name] = json.load(f)["args"]["pattern"]
+    return out
+
+
+@pytest.mark.parametrize("kind,key,metric", [
+    ("plain", "decode", "decode_exec_ms_p50"),
+    ("plain", "state_edit", None),
+    ("plain", "prefill_b16", "prefill_exec_ms_p50"),
+    ("spec", "draft", None),
+    ("spec", "verify", None),
+    ("spec", "prefill_b16", "prefill_exec_ms_p50"),
+    ("spec", "draft_prefill_b16", None)])
+def test_executable_carries_its_key_as_its_name(warm_engines, kind, key,
+                                                metric):
     """A device trace shows an executable as its module's name: each of
-    the engine's five is ``jit_mxtpu_<key>``, not ``jit__lambda_``."""
-    assert sorted(spec_engine._exec) == sorted(
-        ["decode", "draft", "verify", "prefill_b16", "draft_prefill_b16"])
-    text = spec_engine._exec[key].as_text()
+    the engine's is ``jit_mxtpu_<key>``, not ``jit__lambda_``, warm-up
+    materialises all a turn can dispatch (the chained turn's pair, or
+    the speculative turn's), and the benchmark's patterns match the
+    decode and prefill executables and nothing else."""
+    eng, keys = warm_engines[kind]
+    assert sorted(eng._exec) == sorted(keys) == sorted(
+        {"plain": ["decode", "state_edit", "prefill_b16"],
+         "spec": ["draft", "verify", "prefill_b16",
+                  "draft_prefill_b16"]}[kind])
+    text = eng._exec[key].as_text()
     assert f"HloModule jit_mxtpu_{key}," in text
     assert "lambda" not in text.split("\n", 1)[0]
+    event = f"jit_mxtpu_{key}(1234567890)"       # as XLA Modules has it
+    for name, pattern in _trace_patterns().items():
+        assert bool(re.search(pattern, event)) == (name == metric)
 
 
 # -- lifecycle ---------------------------------------------------------------

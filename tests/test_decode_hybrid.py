@@ -344,8 +344,8 @@ def test_scheduler_matches_the_dense_oracle_and_never_recompiles(
         models, _clean):
     model, _ = models("float32", "ones")
     eng = _engine(model, max_slots=2)
-    assert eng.warmup([8, CHUNK]) == ["decode", "state_reset", "prefill_b8",
-                                      "prefill_b16"]
+    assert eng.warmup([8, CHUNK]) == ["decode", "state_edit", "state_reset",
+                                      "prefill_b8", "prefill_b16"]
     compiled = eng.compiles
 
     class Sink:
@@ -395,6 +395,45 @@ def test_admission_zeroes_the_state_under_its_span(models, _clean):
     assert by_id[reset["args"]["parent_id"]]["name"] == "decode.admit_phase"
     prefill = [e for e in evs if e["name"] == "decode.prefill"][0]
     assert reset["ts"] + reset["dur"] <= prefill["ts"] + 1
+
+
+def test_a_slots_next_tenant_is_dispatched_behind_the_stray_step(
+        models, _clean):
+    """What the chained turn's correctness rests on, for a model with
+    state: when ``eos`` is read, one more step of the slot is already in
+    flight and advances its state; the edit that switches the slot off,
+    the next tenant's ``state_reset``, its chunk and the edit that
+    switches it on all follow that step on the device's one stream, in
+    this order, and the tenant decodes from zero state."""
+    model, _ = models("float32", "ones")
+    eng = _engine(model, max_slots=1)
+    eng.warmup([8])
+    first, second = _tokens(6, seed=21), _tokens(7, seed=22)
+    ref = model.greedy_reference(first, 8)
+    eos = ref[2]
+    calls = []
+    call = eng._call
+
+    def logged(key, args, donate=(1,)):
+        calls.append(key)
+        return call(key, args, donate)
+
+    eng._call = logged
+    sch = DecodeScheduler(eng, start=False)
+    fut = sch.submit(first, max_new_tokens=8, eos=eos)
+    while not fut.done():
+        sch.step()
+    assert fut.result(0) == ref[:ref.index(eos) + 1]
+    ssm_buf = eng.cache.pool[0][2]
+    assert float(jnp.abs(ssm_buf[0]).max()) > 0     # left behind
+    mark = len(calls)
+    assert calls[mark - 2:] == ["decode", "state_edit"]   # stray, then off
+    late = sch.submit(second, max_new_tokens=3)
+    _run(sch)
+    assert calls[mark:mark + 4] == ["state_reset", "prefill_b8",
+                                    "state_edit", "decode"]
+    assert late.result(0) == model.greedy_reference(second, 3)
+    assert eng.stats()["state_resets"] == 2 and sch.stats()["pages_used"] == 0
 
 
 def test_decode_model_keeps_no_state_and_its_counters_read_zero():

@@ -204,6 +204,21 @@ def test_layer_norm_residual_compiles(v5e):
 # gpt2_decode_chat: 6144 pages x 16 x (16 heads x 64), bf16, 96 slots x 64
 # pages; 2 of its 24 layers, since every layer's buffers are treated alike.
 
+def _resident(spec, slots, pps):
+    """The engine's resident decode state: tokens, positions, active,
+    page tables."""
+    return (spec((slots,), "int32"), spec((slots,), "int32"),
+            spec((slots,), "bool"), spec((slots, pps), "int32"))
+
+
+def _lower_chained_decode(mdl, params, pool, state):
+    """``decode`` as ``DecodeEngine`` compiles it on a TPU: the model's
+    core inside the engine's wrapper, pool and resident state donated."""
+    from mxnet_tpu.serving.decode import engine as E
+    return jax.jit(lambda *a: E._chained_decode_core(mdl, *a),
+                   donate_argnums=(1, 2)).lower(params, pool, state)
+
+
 @pytest.mark.parametrize("key", ["decode", "prefill_b128"])
 def test_decode_executables_update_the_pool_in_place(v5e, key):
     """``memory_analysis`` of the compiled executable: the whole pool is
@@ -222,19 +237,19 @@ def test_decode_executables_update_the_pool_in_place(v5e, key):
                                     mdl.params)
     buf = spec((6144, 16, 1024))
     pool = tuple((buf, buf) for _ in range(layers))
+    # donated as DecodeEngine._get_exec donates them on a TPU
     if key == "decode":
-        fn, args = mdl.decode_core, (
-            spec((slots,), "int32"), spec((slots,), "int32"),
-            spec((slots, pps), "int32"), spec((slots,), "bool"))
+        lowered = _lower_chained_decode(mdl, params, pool,
+                                        _resident(spec, slots, pps))
     else:
-        fn, args = mdl.prefill_core, (
-            spec((128,), "int32"), spec((), "int32"), spec((), "int32"),
-            spec((pps,), "int32"))
-    # donated as DecodeEngine._get_exec donates it on a TPU
-    mem = jax.jit(lambda *a: fn(*a), donate_argnums=(1,)).lower(
-        params, pool, *args).compile().memory_analysis()
+        lowered = jax.jit(lambda *a: mdl.prefill_core(*a),
+                          donate_argnums=(1,)).lower(
+            params, pool, spec((128,), "int32"), spec((), "int32"),
+            spec((), "int32"), spec((pps,), "int32"))
+    mem = lowered.compile().memory_analysis()
     one = buf.size * buf.dtype.itemsize          # 201 MB
-    assert mem.alias_size_in_bytes == 2 * layers * one
+    state = mem.alias_size_in_bytes - 2 * layers * one
+    assert 0 <= state < 2 ** 16 and (state > 0) == (key == "decode")
     assert mem.temp_size_in_bytes < one, mem.temp_size_in_bytes
     # and no more of them than before the kernel took the pools as whole
     # operands in HBM (PR 30): 3,064,320 and 3,354,624 bytes at 2 layers
@@ -280,11 +295,8 @@ def test_hybrid_executables_update_kv_and_state_in_place(v5e, key):
                               donated, spec((), "int32"))
     elif key == "decode":
         donated = pool
-        lowered = jax.jit(lambda *a: mdl.decode_core(*a),
-                          donate_argnums=(1,)).lower(
-            params, pool, spec((FH_SLOTS,), "int32"),
-            spec((FH_SLOTS,), "int32"),
-            spec((FH_SLOTS, FH_PAGES), "int32"), spec((FH_SLOTS,), "bool"))
+        lowered = _lower_chained_decode(
+            mdl, params, pool, _resident(spec, FH_SLOTS, FH_PAGES))
     else:
         donated = pool
         bucket = int(key.rsplit("b", 1)[1])
@@ -295,8 +307,9 @@ def test_hybrid_executables_update_kv_and_state_in_place(v5e, key):
             spec((), "int32"))
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes == sum(
+    state = mem.alias_size_in_bytes - sum(
         nbytes(b) for layer in donated for b in layer)
+    assert 0 <= state < 2 ** 16 and (state > 0) == (key == "decode")
     assert mem.temp_size_in_bytes < nbytes(ssm_buf), mem.temp_size_in_bytes
     if key == "decode":
         import re
@@ -305,6 +318,71 @@ def test_hybrid_executables_update_kv_and_state_in_place(v5e, key):
         # per layer: rope on q and on k, paged attention, the state update
         assert len(calls) == 4 * 2 and all("mxtpu_" in c for c in calls)
         assert sum("mxtpu_ssm_update" in c for c in calls) == 2
+
+
+# -- the chained decode executable at the two decode cells' whole geometry ----
+
+@pytest.mark.parametrize("cell,pool_bytes", [
+    ("gpt2_decode_chat", 9_663_676_416),
+    ("falcon_h1_decode_chat", 3_659_268_096)])
+def test_chained_decode_aliases_the_pool_and_the_resident_state(
+        v5e, cell, pool_bytes):
+    """Every layer of the cell: the whole pool and all four arrays of
+    the resident state are the outputs' own buffers (a donated state
+    that XLA copied would show as an alias short of it), the tokens for
+    the host are one output more, the temporaries stay under one pool
+    buffer, and ``state_edit`` rewrites the state where it is."""
+    import json
+    import re
+    from mxnet_tpu.serving import DecodeModel, FalconH1
+    from mxnet_tpu.serving.decode import engine as E
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chipbench")
+    with open(os.path.join(bench, "workloads", f"{cell}.json")) as f:
+        geo = json.load(f)["engine"]
+    slots, pps = geo["max_slots"], geo["pages_per_slot"]
+
+    def spec(shape, dtype="bfloat16"):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype),
+                                    sharding=v5e)
+
+    if cell == "gpt2_decode_chat":
+        layers = 24
+        mdl = DecodeModel(512, dim=1024, n_heads=16, n_layers=1,
+                          mlp_ratio=4, dtype="bfloat16")
+        mdl.params = dict(mdl.params,
+                          layers=mdl.params["layers"] * layers)
+        mdl.n_layers = layers
+        state_spec = ()
+    else:
+        with open(os.path.join(bench, "configs", "falcon_h1_34b.json")) as f:
+            mdl = FalconH1(json.load(f), abstract=True)
+        layers, state_spec = mdl.n_layers, mdl.state_spec
+    params = jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype),
+                                    mdl.params)
+    kv = spec((geo["num_pages"], geo["page_size"],
+               mdl.kv_heads * mdl.head_dim))
+    pool = tuple((kv, kv) + tuple(spec((slots,) + sh, dt)
+                                  for _, sh, dt in state_spec)
+                 for _ in range(layers))
+    nbytes = lambda a: a.size * a.dtype.itemsize           # noqa: E731
+    assert sum(nbytes(b) for layer in pool for b in layer) == pool_bytes
+    state = _resident(spec, slots, pps)
+    compiled = _lower_chained_decode(mdl, params, pool, state).compile()
+    mem = compiled.memory_analysis()
+    # small arrays are padded to a tile: at least their bytes, under 64 KiB
+    resident = mem.alias_size_in_bytes - pool_bytes
+    assert sum(nbytes(a) for a in state) <= resident < 2 ** 16, resident
+    header = compiled.as_text().split("\n", 1)[0]
+    aliased = re.findall(r"\{(\d+)\}: \(\d+, \{[\d, ]*\}", header)
+    n_pool = len(jax.tree_util.tree_leaves(pool))
+    assert len(aliased) == n_pool + 4, header[:400]
+    assert mem.temp_size_in_bytes < max(
+        nbytes(b) for b in pool[0]), mem.temp_size_in_bytes
+    edit = jax.jit(lambda *a: E._state_edit_core(*a),
+                   donate_argnums=(0,)).lower(
+        state, spec((), "int32"), spec((3 + pps,), "int32")).compile()
+    assert edit.memory_analysis().alias_size_in_bytes == resident
 
 
 # -- the names a device trace shows ------------------------------------------
