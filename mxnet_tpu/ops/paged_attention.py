@@ -51,6 +51,15 @@ The XLA lowering (:func:`paged_attention_reference`) gathers
 ``pool[tables]`` and runs a masked softmax — the numerics oracle the
 parity tests pin the kernel against across ragged lengths.  No call
 site chooses it.
+
+A latent cache (:func:`latent_attention`, ``mxtpu_latent_attention`` in
+a device trace) is a third body on the same walker and ONE paged buffer
+a layer: a row ``[c_kv | k_rope | padding]`` is the key of every query
+head and its first ``rank`` lanes are every head's value, so a block is
+copied once and read for the scores and for the values.  The queries
+come absorbed (``q_nope`` through the key half of the up-projection),
+``rank + rope`` lanes a head; the output stays in the latent space and
+the caller takes it through the value half.
 """
 from __future__ import annotations
 
@@ -67,7 +76,8 @@ from jax.experimental.pallas import tpu as pltpu
 from .. import kernels as _kernels
 from .registry import register
 
-__all__ = ["paged_attention", "paged_attention_reference"]
+__all__ = ["paged_attention", "paged_attention_reference",
+           "latent_attention", "latent_attention_reference"]
 
 _NEG_INF = -1e30
 
@@ -210,10 +220,50 @@ def _lanes_body(q_ref, o_ref, acc_ref, m_ref, l_ref, *, sm_scale, heads, d):
     return block, finish
 
 
-def _pa_walker(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *refs, body, block_k):
+def _latent_body(q_ref, o_ref, acc_ref, m_ref, l_ref, *, sm_scale, rank):
+    """One shared key row for every query head (a latent cache): the
+    heads are the rows of ``q``, ``o`` and ``acc`` (padded to 8), a
+    block's ``(block_k, width)`` tile is their keys whole and their
+    values in its first ``rank`` lanes.  Two matmuls a block on the MXU
+    in the pool's dtype with float32 accumulation; the running maximum
+    and sum are kept lane-broadcast, ``(rows, 128)``."""
+
+    def block(kv_ref, start, length):
+        exact = (lax.Precision.HIGHEST if kv_ref.dtype == jnp.float32
+                 else lax.Precision.DEFAULT)
+        kv = kv_ref[...]                                      # (block_k, W)
+        q = q_ref[0].astype(kv.dtype)                         # (rows, W)
+        s = lax.dot_general(q, kv, (((1,), (1,)), ((), ())),
+                            precision=exact,
+                            preferred_element_type=jnp.float32)
+        s = s * sm_scale                                      # (rows, block_k)
+        kpos = start + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        mask = kpos < length
+        s = jnp.where(mask, s, _NEG_INF)
+        m_prev = m_ref[...]                                   # (rows, 128)
+        m_cur = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        corr = jnp.exp(m_prev - m_cur)
+        p = jnp.where(mask, jnp.exp(s - m_cur[:, :1]), 0.0)
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
+        m_ref[...] = m_cur
+        pv = lax.dot_general(p.astype(kv.dtype), kv[:, :rank],
+                             (((1,), (0,)), ((), ())), precision=exact,
+                             preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * corr[:, :1] + pv
+
+    def finish():
+        l = l_ref[...][:, :1]
+        l = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+    return block, finish
+
+
+def _pa_walker(tbl_ref, len_ref, q_ref, *refs, body, block_k, pools=2):
     """One grid step a slot; inside it a loop over the slot's live
-    blocks alone, each copied from the pools (whole operands, in HBM)
-    into one of two VMEM buffers while the block before it is worked on.
+    blocks alone, each copied from the ``pools`` paged buffers (K and V;
+    one for a latent cache; whole operands, in HBM) into one of two
+    VMEM buffers a pool while the block before it is worked on.
     The last block of a slot starts the first block of the next live
     slot, so idle slots in between cost an empty grid step and the copy
     engine does not drain at a slot's end.  ``at_ref`` carries that
@@ -224,9 +274,11 @@ def _pa_walker(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *refs, body, block_k):
     part of one page.  Its rows past the length are masked by the body;
     a page index past the table's width reads the last column, whose
     rows are all masked."""
-    *consts, o_ref, acc_ref, m_ref, l_ref, k_buf, v_buf, sems, at_ref = refs
+    hbm, refs = refs[:pools], refs[pools:]
+    *consts, o_ref, acc_ref, m_ref, l_ref = refs[:-(pools + 2)]
+    *bufs, sems, at_ref = refs[-(pools + 2):]
     slots, pages = tbl_ref.shape
-    page_size = k_hbm.shape[1]
+    page_size = hbm[0].shape[1]
     rows = min(block_k, page_size)                # rows of one copy
     block, finish = body(q_ref, *consts, o_ref, acc_ref, m_ref, l_ref)
     s_i = pl.program_id(0)
@@ -245,8 +297,7 @@ def _pa_walker(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *refs, body, block_k):
                             rows)
             page = tbl_ref[slot, jnp.minimum(page, pages - 1)]
             dst = pl.ds(j * rows, rows)
-            for kind, (pool, buffer) in enumerate(((k_hbm, k_buf),
-                                                   (v_hbm, v_buf))):
+            for kind, (pool, buffer) in enumerate(zip(hbm, bufs)):
                 made.append(pltpu.make_async_copy(
                     pool.at[page, src], buffer.at[buf, dst],
                     sems.at[kind, buf]))
@@ -294,11 +345,23 @@ def _pa_walker(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *refs, body, block_k):
 
             for copy in copies(s_i, i, buf):
                 copy.wait()
-            block(k_buf.at[buf], v_buf.at[buf], i * block_k, length)
+            block(*(b.at[buf] for b in bufs), i * block_k, length)
             return 1 - buf
 
         at_ref[0] = lax.fori_loop(0, n, step, first)
         finish()
+
+
+def _block_rows(block_k, page_size, pages):
+    """The rows of a block the walker can copy, nearest ``block_k``."""
+    block_k = max(1, int(block_k))
+    if block_k >= page_size and page_size % 8 == 0:
+        # whole pages, as many as the block holds and a slot has
+        return min(block_k // page_size, pages) * page_size
+    # a part of a page: its rows tile the page and are a multiple of
+    # the 8-sublane tile, or the block is the page
+    block_k = math.gcd(block_k, page_size)
+    return page_size if block_k % 8 else block_k
 
 
 def _paged_attention_pallas(q, k_pool, v_pool, tables, lengths,
@@ -316,16 +379,7 @@ def _paged_attention_pallas(q, k_pool, v_pool, tables, lengths,
         # is gathered by XLA; the interpreter walks it like any other.
         return paged_attention_reference(q, k_pool, v_pool, tables,
                                          lengths, sm_scale=sm_scale)
-    block_k = max(1, int(block_k))
-    if block_k >= page_size and page_size % 8 == 0:
-        # whole pages, as many as the block holds and a slot has
-        block_k = min(block_k // page_size, p_) * page_size
-    else:
-        # a part of a page: its rows tile the page and are a multiple of
-        # the 8-sublane tile, or the block is the page
-        block_k = math.gcd(block_k, page_size)
-        if block_k % 8:
-            block_k = page_size
+    block_k = _block_rows(block_k, page_size, p_)
     # query head g*rep + r -> row r, KV head g's lanes
     q = q.reshape(s_, h, rep, d).swapaxes(1, 2).reshape(s_, rep, hd)
     if d % 128 == 0:
@@ -495,3 +549,151 @@ register("paged_attention", aliases=("_npx_paged_attention",))(
     block_k=None:
     paged_attention(q, k_pool, v_pool, tables, lengths,
                     sm_scale=sm_scale, block_k=block_k))
+
+
+# -- the latent cache ----------------------------------------------------------
+
+def latent_attention_reference(q, pool, tables, lengths, *, rank, sm_scale):
+    """Gather-based oracle of :func:`latent_attention`: ``q (S, H, W)``
+    absorbed queries, ``pool (pages, ps, W)`` rows ``[c_kv | k_rope |
+    padding]``, ``tables (S, P)``, ``lengths (S,)`` -> ``(S, H, rank)``:
+    every head scores the whole row and sums its first ``rank`` lanes.
+    Length-0 slots yield zeros."""
+    s_, _, w = q.shape
+    ps, p_ = pool.shape[1], tables.shape[1]
+    kv = pool[tables].reshape(s_, p_ * ps, w).astype(jnp.float32)
+    scores = jnp.einsum("shw,skw->shk", q.astype(jnp.float32), kv,
+                        precision=lax.Precision.HIGHEST) * sm_scale
+    kpos = lax.broadcasted_iota(jnp.int32, scores.shape, 2)
+    mask = kpos < lengths[:, None, None]
+    scores = jnp.where(mask, scores, _NEG_INF)
+    m = scores.max(axis=-1, keepdims=True)
+    p = jnp.where(mask, jnp.exp(scores - m), 0.0)
+    l = p.sum(axis=-1, keepdims=True)
+    l = jnp.where(l == 0.0, 1.0, l)
+    out = jnp.einsum("shk,skr->shr", p / l, kv[..., :rank],
+                     precision=lax.Precision.HIGHEST)
+    return out.astype(q.dtype)
+
+
+def _latent_attention_pallas(q, pool, tables, lengths, rank, sm_scale,
+                             block_k):
+    s_, h, w = q.shape
+    page_size, p_ = pool.shape[1], tables.shape[1]
+    on_tpu = jax.default_backend() == "tpu"
+    if on_tpu and (w % 128 or rank % 128):
+        # a copy moves whole lane tiles and the values are a lane-aligned
+        # slice of the row: serving/decode/paged_kv.py pads its rows so
+        raise ValueError(
+            f"latent_attention: a row of {w} lanes with {rank} of values "
+            f"is no whole number of 128-lane tiles; pad the row "
+            f"(paged_kv.latent_width)")
+    block_k = _block_rows(block_k, page_size, p_)
+    rows = -(-h // 8) * 8
+    q = jnp.pad(q, ((0, 0), (0, rows - h), (0, 0)))
+    body = functools.partial(_latent_body, sm_scale=float(sm_scale),
+                             rank=rank)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(s_,),
+        in_specs=[pl.BlockSpec((1, rows, w), lambda s, tbl, ln: (s, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, rows, rank), lambda s, tbl, ln: (s, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((rows, rank), jnp.float32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((2, block_k, w), pool.dtype),
+            pltpu.SemaphoreType.DMA((1, 2)),
+            pltpu.SMEM((2,), jnp.int32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_pa_walker, body=body, block_k=block_k, pools=1),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((s_, rows, rank), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=not on_tpu,
+        name="mxtpu_latent_attention",
+    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), q, pool)
+    return out[:, :h]
+
+
+def _latent_signature(q, pool, tables, lengths, *, rank, sm_scale):
+    from ..amp import policy as _amp_policy
+    return (f"s{q.shape[0]}_h{q.shape[1]}_w{q.shape[2]}_r{rank}"
+            f"_ps{pool.shape[1]}_p{tables.shape[1]}",
+            _amp_policy.kernel_key_dtype(str(q.dtype)))
+
+
+def _latent_kernel_run(config, q, pool, tables, lengths, *, rank, sm_scale):
+    return _latent_attention_pallas(q, pool, tables, lengths, int(rank),
+                                    float(sm_scale), int(config["block_k"]))
+
+
+def _latent_make_args(case):
+    import numpy as onp
+    rng = onp.random.RandomState(29)
+    slots, pps, ps = case["slots"], case["pages_per_slot"], case["page_size"]
+    h, rank, rope = case["h"], case["rank"], case["rope"]
+    dtype = case.get("dtype", "float32")
+    w = -(-(rank + rope) // 128) * 128
+    num_pages = slots * pps + 1
+    lanes = onp.arange(w) < rank + rope          # the padding holds zeros
+    q = jnp.asarray(rng.randn(slots, h, w) * 0.5 * lanes, dtype=dtype)
+    pool = jnp.asarray(rng.randn(num_pages, ps, w) * 0.5 * lanes,
+                       dtype=dtype)
+    tables = jnp.asarray(
+        rng.permutation(num_pages - 1)[:slots * pps].reshape(slots, pps),
+        jnp.int32)
+    lengths = rng.randint(0, pps * ps + 1, size=(slots,))
+    lengths[0] = 0
+    return ((q, pool, tables, jnp.asarray(lengths, jnp.int32)),
+            {"rank": rank, "sm_scale": (rank + rope) ** -0.5})
+
+
+_kernels.register_kernel(_kernels.KernelSpec(
+    "latent_attention", version=1,
+    run=_latent_kernel_run, fallback=latent_attention_reference,
+    config_space={"block_k": (128, 256, 512)},
+    default_config={"block_k": 512},
+    signature=_latent_signature, make_args=_latent_make_args,
+    # pages of 8 and 16 (several a block), 128 (the page is the block or
+    # four make one); heads fewer than a sublane tile and more
+    tune_grid=({"slots": 5, "pages_per_slot": 6, "page_size": 8,
+                "h": 4, "rank": 32, "rope": 8},
+               {"slots": 4, "pages_per_slot": 8, "page_size": 16,
+                "h": 16, "rank": 128, "rope": 64},
+               {"slots": 3, "pages_per_slot": 5, "page_size": 128,
+                "h": 8, "rank": 128, "rope": 64}),
+))
+
+
+def latent_attention(q, pool, tables, lengths, *, rank, sm_scale,
+                     block_k=None):
+    """One attention step per slot over a paged latent cache.
+
+    ``q (slots, H, W)``: a slot's absorbed queries, ``[q_nope W_k |
+    q_rope | zeros]`` a head; ``pool (num_pages, page_size, W)``: a
+    token's row ``[c_kv (rank) | k_rope | zeros]``, one for all heads;
+    ``tables (slots, pages_per_slot)``, ``lengths (slots,)`` as
+    :func:`paged_attention`'s.  Returns ``(slots, H, rank)``: the
+    softmax-weighted sum of the rows' first ``rank`` lanes, for the
+    caller to up-project.  ``W`` and ``rank`` are multiples of 128 on a
+    TPU (else the XLA gather runs)."""
+    if block_k is None:
+        sig, dt = _latent_signature(q, pool, tables, lengths, rank=rank,
+                                    sm_scale=sm_scale)
+        block_k = _kernels.resolve(
+            "latent_attention", sig, dt,
+            tune_args=((q, pool, tables, lengths),
+                       {"rank": rank, "sm_scale": sm_scale}))["block_k"]
+    return _latent_attention_pallas(q, pool, tables, lengths, int(rank),
+                                    float(sm_scale), int(block_k))
+
+
+register("latent_attention", aliases=("_npx_latent_attention",))(
+    lambda q, pool, tables, lengths, rank, sm_scale, block_k=None:
+    latent_attention(q, pool, tables, lengths, rank=rank, sm_scale=sm_scale,
+                     block_k=block_k))

@@ -14,6 +14,14 @@ boundary lane-broadcast like flash attention's lse (attention.py
 ``_LSE_LANES``).  The XLA lowering (:func:`rope_reference`) is the
 numerics oracle tests pin against; no call site switches to it.
 
+A model whose frequencies are no power law of one ``base`` (YaRN
+blends them pair by pair, :func:`yarn_frequencies`) rotates by a table
+of them in XLA (:func:`rope_table`): the kernel computes its angles from
+``base`` inside, and the one model that needs a table rotates 64 lanes
+of a head, half a lane tile, which XLA fuses into the projection's
+epilogue where the kernel would be a launch of its own on a block padded
+to twice its size.
+
 Registered through ``mxnet_tpu.kernels`` as ``rope`` with a block-size
 config space; the decode serving plane (serving/decode/) applies it to
 every q/k projection, and training attention stacks can call
@@ -33,7 +41,7 @@ from jax.experimental import pallas as pl
 from .. import kernels as _kernels
 from .registry import register
 
-__all__ = ["rope", "rope_reference"]
+__all__ = ["rope", "rope_reference", "rope_table", "yarn_frequencies"]
 
 # positions cross the pallas boundary lane-broadcast (TPU (8, 128)
 # block-tiling rule — see attention.py _LSE_LANES)
@@ -59,6 +67,46 @@ def rope_reference(x, positions, base=10000.0):
     # the lane axis: for float32 (r, 8, 64) that form aborts the TPU
     # compiler (libtpu 0.0.34, "Check failed: IsFusibleUnalignedDUS")
     xr = xf.reshape(xf.shape[:-1] + (2, half))
+    x1, x2 = xr[..., 0, :], xr[..., 1, :]
+    out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-2)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def yarn_frequencies(dim, base, *, factor, original_max_position_embeddings,
+                     beta_fast=32.0, beta_slow=1.0, **_):
+    """YaRN's rotation frequencies of ``dim // 2`` pairs, as a numpy
+    float32 vector.  Pair ``i`` turns ``base**(-2i/dim)`` radians a
+    position; one that completes more than ``beta_fast`` turns inside
+    ``original_max_position_embeddings`` positions keeps that, one that
+    completes fewer than ``beta_slow`` is slowed by ``factor``, and
+    between the two pair indices where those turn counts fall (the
+    lower rounded down, the upper up) the blend is linear in the pair
+    index.  Further keys of a ``rope_scaling`` dict (``mscale``,
+    ``type``) are not this function's."""
+    import numpy as onp
+    half = dim // 2
+    plain = float(base) ** (-onp.arange(half, dtype=onp.float64) * 2 / dim)
+
+    def pair_at(turns):
+        return dim * math.log(original_max_position_embeddings
+                              / (turns * 2 * math.pi)) / (2 * math.log(base))
+
+    low = max(math.floor(pair_at(beta_fast)), 0)
+    high = min(math.ceil(pair_at(beta_slow)), dim - 1)
+    ramp = onp.clip((onp.arange(half) - low) / max(high - low, 1e-3), 0, 1)
+    return (plain / factor * ramp + plain * (1 - ramp)).astype(onp.float32)
+
+
+def rope_table(x, positions, inv_freq, scale=1.0):
+    """Split-half rotation of ``x (..., H, D)`` at ``positions`` (shaped
+    like ``x.shape[:-2]``) by a table ``inv_freq (D/2,)`` of radians a
+    position; ``scale`` multiplies cos and sin.  XLA, float32 inside."""
+    half = x.shape[-1] // 2
+    pos = jnp.broadcast_to(jnp.asarray(positions), x.shape[:-2])
+    ang = pos.astype(jnp.float32)[..., None, None] * jnp.asarray(
+        inv_freq, jnp.float32)
+    cos, sin = jnp.cos(ang) * scale, jnp.sin(ang) * scale
+    xr = x.astype(jnp.float32).reshape(x.shape[:-1] + (2, half))
     x1, x2 = xr[..., 0, :], xr[..., 1, :]
     out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-2)
     return out.reshape(x.shape).astype(x.dtype)

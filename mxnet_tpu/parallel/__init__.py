@@ -21,7 +21,8 @@ from .pipeline import (gpipe_apply, pipeline_forward,
                        pipeline_value_and_grad_1f1b, one_f_one_b_apply,
                        one_f_one_b_ticks,
                        interleave_params, interleaved_ticks, gpipe_ticks)
-from .moe import switch_moe, moe_expert_sharding
+from .moe import (switch_moe, moe_expert_sharding, route_topk,
+                  held_experts)
 
 __all__ = ["make_mesh", "default_mesh", "data_parallel_spec", "replicated",
            "MeshPlan", "Mesh4DTrainer", "mesh_plan_from_env",
@@ -33,4 +34,4 @@ __all__ = ["make_mesh", "default_mesh", "data_parallel_spec", "replicated",
            "pipeline_forward_interleaved", "pipeline_value_and_grad_1f1b",
            "one_f_one_b_apply", "one_f_one_b_ticks",
            "interleave_params", "interleaved_ticks", "gpipe_ticks",
-           "moe_expert_sharding"]
+           "moe_expert_sharding", "route_topk", "held_experts"]

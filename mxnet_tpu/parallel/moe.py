@@ -10,6 +10,13 @@ collectives.
 
 Shapes: tokens (N, H); gate (H, E); experts w1 (E, H, F), b1 (E, F),
 w2 (E, F, H), b2 (E, H).
+
+Beside it, for serving, the dropless layer of the sigmoid-routed
+families (:func:`route_topk`, :func:`held_experts`): a chip that holds
+``held`` of the layer's experts routes every row over ALL of them at the
+published top-k and computes the part of the result its own experts
+give.  No capacity, no dropped token, no exchange: what the absent
+experts would add is another chip's to compute.
 """
 from __future__ import annotations
 
@@ -22,7 +29,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from .. import telemetry
 
-__all__ = ["switch_moe", "moe_expert_sharding"]
+__all__ = ["switch_moe", "moe_expert_sharding", "route_topk",
+           "held_experts"]
 
 
 def switch_moe(x, gate_w, w1, b1, w2, b2, capacity_factor: float = 1.25,
@@ -97,3 +105,71 @@ def moe_expert_sharding(mesh: Mesh, axis_name: str = "ep"):
     rep = NamedSharding(mesh, PartitionSpec())
     ex = NamedSharding(mesh, PartitionSpec(axis_name))
     return rep, ex, ex, ex, ex
+
+
+# -- dropless top-k over a share of the experts ---------------------------------
+
+def route_topk(scores, top_k: int, *, n_group: int = 1, topk_group: int = 1,
+               normalize: bool = True, scale: float = 1.0):
+    """Select ``top_k`` experts a row from ``scores (rows, experts)``
+    (float32, already through their sigmoid): ``(index (rows, top_k),
+    weight (rows, top_k))``, the weight an expert's own score, divided
+    by the selected scores' sum (``normalize``) and times ``scale``.
+
+    ``n_group > 1`` is the family's group-limited form of the same
+    function: the experts in ``n_group`` equal groups, a group scored by
+    the sum of its two largest scores, the ``topk_group`` best groups
+    kept and the selection made inside them.  ``n_group == 1`` is the
+    plain top-k over all experts."""
+    rows, experts = scores.shape
+    pick = scores
+    if n_group > 1:
+        per = experts // n_group
+        grouped = scores.reshape(rows, n_group, per)
+        rank = jax.lax.top_k(grouped, 2)[0].sum(axis=-1)    # (rows, groups)
+        kept = jax.lax.top_k(rank, topk_group)[1]           # (rows, kept)
+        open_ = (kept[:, :, None] == jnp.arange(n_group)).any(axis=1)
+        pick = jnp.where(jnp.repeat(open_, per, axis=1), scores, -1.0)
+    index = jax.lax.top_k(pick, top_k)[1]
+    weight = jnp.take_along_axis(scores, index, axis=1)
+    if normalize:
+        weight = weight / (weight.sum(axis=-1, keepdims=True) + 1e-20)
+    return index, weight * scale
+
+
+def held_experts(h, index, weight, experts, first: int, valid=None):
+    """The routed result's part that the experts held here give:
+    ``sum_i weight_i E_i(h)`` over the selected experts ``i`` in
+    ``[first, first + len(experts))``, ``E(h) = (silu(h W_gate) * h
+    W_up) W_down``.  ``h (rows, hidden)``; ``index``/``weight (rows,
+    top_k)`` from :func:`route_topk`; ``experts`` a sequence of
+    ``(W_gate, W_up, W_down)``, expert ``first + j`` at ``j``.  Returns
+    ``(y (rows, hidden) float32, counters)``.
+
+    Every held expert is computed for every row and a row's product is
+    taken at its routing weight, 0 where it was not routed there: exact
+    over the routed pairs, a fixed shape, and no gather.  The experts'
+    weights are read once whatever the rows, which is what a decode
+    step's few rows are bound by (PERF.md section 6, PR 33).
+
+    ``counters`` (float32 scalars, over the rows of ``valid``, all rows
+    if None): ``local_pairs`` routed (row, expert) pairs that met a held
+    expert, ``pairs`` all routed pairs, ``rows_mean`` and ``rows_max``
+    rows a held expert got, ``idle`` held experts that got none."""
+    rows = h.shape[0]
+    live = jnp.ones((rows,), bool) if valid is None else valid
+    y = jnp.zeros(h.shape, jnp.float32)
+    got = []
+    for j, (w_gate, w_up, w_down) in enumerate(experts):
+        here = index == first + j                           # (rows, top_k)
+        w_j = jnp.where(here, weight, 0.0).sum(axis=-1)     # (rows,)
+        a = jax.nn.silu(h @ w_gate) * (h @ w_up)
+        y = y + w_j[:, None] * jnp.dot(
+            a, w_down, preferred_element_type=jnp.float32)
+        got.append((here.any(axis=-1) & live).sum())
+    got = jnp.stack(got).astype(jnp.float32)                # (held,)
+    counters = {"local_pairs": got.sum(),
+                "pairs": live.sum().astype(jnp.float32) * index.shape[1],
+                "rows_mean": got.mean(), "rows_max": got.max(),
+                "idle": (got == 0).sum().astype(jnp.float32)}
+    return y, counters

@@ -10,6 +10,8 @@ cache with optional speculative decode.
 - :mod:`decode_model` — the built-in small causal LM, one block;
 - :mod:`falcon_h1` — a second model behind the same protocol: grouped-
   query attention beside Mamba-2 heads, with per-slot recurrent state;
+- :mod:`axk1` — a third: latent attention over a latent page, and a
+  share of sigmoid-routed experts beside a shared one;
 - :mod:`scheduler` — the continuous batcher (``DecodeScheduler``):
   per-step admission/eviction, chunked prefill, speculative accept.
 
@@ -19,8 +21,9 @@ from .paged_kv import OutOfPagesError, PageAllocator, PagedKVCache
 from .engine import DecodeEngine, DecodePlaneModel
 from .decode_model import DecodeModel
 from .falcon_h1 import FalconH1
+from .axk1 import AXK1
 from .scheduler import DecodeScheduler
 
 __all__ = ["PageAllocator", "PagedKVCache", "OutOfPagesError",
-           "DecodePlaneModel", "DecodeModel", "FalconH1", "DecodeEngine",
+           "DecodePlaneModel", "DecodeModel", "FalconH1", "AXK1", "DecodeEngine",
            "DecodeScheduler"]

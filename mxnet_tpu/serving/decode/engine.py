@@ -30,19 +30,25 @@ use and reused forever (the fixed-shape-executable invariant):
 - ``state_reset`` — a model's recurrent state zeroed for one slot.
 
 The engine is handed its model (:class:`DecodePlaneModel`; the target
-and the draft alike): the parameters as a pytree, the geometry of its
-K/V heads, the kinds of per-slot recurrent state its layers keep beside
-K/V, and the traced cores.  The engine owns the cache the cores read
+and the draft alike): the parameters as a pytree, the kind of paged
+buffers its layers keep (K and V by the geometry of its K/V heads, or a
+latent page by its row's lanes), the kinds of per-slot recurrent state
+its layers keep beside them, and the traced cores.  A decode core may
+hand back, beside its tokens, a small dict of scalar counters (an
+expert layer's routing, say): the engine knows them by name only, reads
+them in the transfer that reads the turn's tokens, and keeps their last
+values and running means.  The engine owns the cache the cores read
 and write, the executables and their donation; it knows nothing of a
 layer, and nothing of how a token's K/V reaches a pool or a query
 attends over it: that is ``paged_kv``'s, under it the
 ``paged_attention`` and ``rope`` kernel registrants'.  The models are
-``decode_model.DecodeModel`` and ``falcon_h1.FalconH1``.  A model with
+``decode_model.DecodeModel``, ``falcon_h1.FalconH1`` and ``axk1.AXK1``.  A model with
 recurrent state cannot be a speculation's target: a rejected draft
 would need the state from before it, and nothing snapshots it.
 """
 from __future__ import annotations
 
+import collections
 import functools
 import os
 import time
@@ -80,11 +86,13 @@ class DecodePlaneModel:
 
     Attributes: ``params`` (a pytree of device arrays, the first
     argument of every executable), ``vocab_size``, ``n_layers``,
-    ``kv_heads`` and ``head_dim`` (the K/V buffers are ``(num_pages,
-    page_size, kv_heads * head_dim)``), and ``state_spec``: the kinds of
-    per-slot recurrent state every layer keeps beside K and V, ``(name,
-    shape of one slot, dtype)`` each, in the order the layer's buffers
-    follow K and V in ``pool[layer]``.  Empty: none.
+    ``page_widths``: the lanes of a row of each paged buffer a layer
+    keeps, ``(num_pages, page_size, lanes)`` each (by default K and V,
+    ``kv_heads * head_dim`` lanes; a model with a latent page gives its
+    one width), and ``state_spec``: the kinds of per-slot recurrent
+    state every layer keeps beside them, ``(name, shape of one slot,
+    dtype)`` each, in the order the layer's buffers follow the paged
+    ones in ``pool[layer]``.  Empty: none.
 
     The traced cores take the cache's ``pool`` and return its
     successor; each buffer has one writer and no reader of its old
@@ -95,13 +103,19 @@ class DecodePlaneModel:
 
     state_spec: tuple = ()
 
+    @property
+    def page_widths(self) -> tuple:
+        return (self.kv_heads * self.head_dim,) * 2
+
     def fingerprint(self) -> tuple:
         """Everything the cores bake into an executable besides the
         shapes of its arguments (the artifact store's key)."""
         raise NotImplementedError
 
     def decode_core(self, params, pool, tokens, positions, tables, active):
-        """One token per slot: ``(pool, next token per slot)``."""
+        """One token per slot: ``(pool, next token per slot)``, or
+        ``(pool, next token per slot, counters)`` with ``counters`` a
+        dict of scalars by name (the same names every step)."""
         raise NotImplementedError
 
     def prefill_core(self, params, pool, tokens, start, chunk_len, table,
@@ -143,15 +157,16 @@ class DecodePlaneModel:
 def _chained_decode_core(mdl: DecodePlaneModel, params, pool, state):
     """The model's decode step over the resident ``state = (tokens,
     positions, active, tables)`` and the state the next turn starts
-    from: ``(pool, state, next token per slot)``.  The tokens go back
-    twice: into the state, which the next dispatch consumes, and as an
-    array of their own for the host to read after it."""
+    from: ``(pool, state, next token per slot, counters)``.  The tokens
+    go back twice: into the state, which the next dispatch consumes, and
+    as an array of their own for the host to read after it; the model's
+    counters (none: an empty dict, no output) ride with the latter."""
     tokens, positions, active, tables = state
-    pool, nxt = mdl.decode_core(params, pool, tokens, positions, tables,
-                                active)
+    pool, nxt, *counters = mdl.decode_core(params, pool, tokens, positions,
+                                           tables, active)
     state = (jnp.where(active, nxt, tokens),
              positions + active.astype(positions.dtype), active, tables)
-    return pool, state, nxt
+    return pool, state, nxt, (counters[0] if counters else {})
 
 
 def _state_edit_core(state, token, patch):
@@ -178,7 +193,7 @@ def _draft_core(mdl: DecodePlaneModel, k: int, params, pool, tokens,
     outs = []
     for j in range(k + 1):
         pool, tok = mdl.decode_core(params, pool, tok, base_pos + j,
-                                    tables, active)
+                                    tables, active)[:2]
         outs.append(tok)
     return pool, jnp.stack([tokens] + outs[:k], axis=1)   # (S, k+1)
 
@@ -197,12 +212,24 @@ def _verify_core(mdl: DecodePlaneModel, params, pool, tokens, base_pos,
 
 def _state_reset_core(state, slot):
     """Zero one slot's rows of every layer's recurrent-state buffers
-    (``state[layer]`` is ``pool[layer]`` without K and V)."""
+    (``state[layer]`` is ``pool[layer]`` without its paged buffers)."""
     return tuple(tuple(buf.at[slot].set(0) for buf in layer)
                  for layer in state)
 
 
 # -- the engine --------------------------------------------------------------
+
+def _add(sums: Dict[str, list], values: Dict[str, float]) -> None:
+    """One more sample of each named figure into ``[sum, count]``."""
+    for name, value in values.items():
+        cell = sums.setdefault(name, [0.0, 0])
+        cell[0] += value
+        cell[1] += 1
+
+
+def _means(sums: Dict[str, list]) -> Dict[str, float]:
+    return {name: total / n for name, (total, n) in sums.items()}
+
 
 class DecodeEngine:
     """Owns the model(s), the paged KV pools, and the compiled
@@ -249,17 +276,17 @@ class DecodeEngine:
                 raise ValueError("draft/target vocab sizes differ")
         self.cache = PagedKVCache(
             layers=model.n_layers, num_pages=self.num_pages,
-            page_size=self.page_size, heads=model.kv_heads,
-            head_dim=model.head_dim, max_slots=self.max_slots,
-            pages_per_slot=pages_per_slot,
+            page_size=self.page_size, page_widths=model.page_widths,
+            max_slots=self.max_slots, pages_per_slot=pages_per_slot,
             dtype=model.params["embed"].dtype,
             state_spec=model.state_spec)
         self.draft_cache = None
         if draft_model is not None:
             self.draft_cache = PagedKVCache(
                 layers=draft_model.n_layers, num_pages=self.num_pages,
-                page_size=self.page_size, heads=draft_model.kv_heads,
-                head_dim=draft_model.head_dim, max_slots=self.max_slots,
+                page_size=self.page_size,
+                page_widths=draft_model.page_widths,
+                max_slots=self.max_slots,
                 pages_per_slot=self.cache.pages_per_slot,
                 dtype=draft_model.params["embed"].dtype)
         self._exec: Dict[str, Any] = {}
@@ -276,12 +303,27 @@ class DecodeEngine:
         self._positions = onp.zeros((slots,), onp.int32)
         self._active = onp.zeros((slots,), bool)
         self._no_token = jnp.zeros((), jnp.int32)
-        self._unread = None     # the last decode's tokens, until read
+        # decode steps dispatched and not yet read, oldest first: (the
+        # step's tokens on the device, the model's counters beside them,
+        # whether a profiler capture ran).  The chain is one deep: the
+        # step before is read after this one's dispatch, so two at most
+        self._in_flight: collections.deque = collections.deque(maxlen=2)
+        # the model's counters as last read, and their running sums
+        # behind stats(), [sum, reads] by name: over the engine's life
+        # and over the steps dispatched under a profiler capture
+        self.counters: Dict[str, float] = {}
+        self._life: Dict[str, list] = {}
+        self._traced: Dict[str, list] = {}
         # share of the page table under live context in the last decode
         # step (what the paged kernel walks), and its sum over the steps;
         # whether that step was dispatched with the one before it unread
         self.kv_live_share = 0.0
         self._kv_live_sum = 0.0
+        # the tokens of that context (what an attention reads), summed
+        # over the steps, and again over those under a profiler capture
+        self._live_tokens_sum = 0
+        self._traced_tokens_sum = 0
+        self._traced_steps = 0
         self.chained = 0
         self._chained_steps = 0
         self._decode_steps = 0
@@ -312,7 +354,10 @@ class DecodeEngine:
         both model architectures, the engine's KV/spec geometry, and
         the exact arg pytree (structure + leaf shapes/dtypes)."""
         leaves, treedef = jax.tree_util.tree_flatten(args)
-        return (key, self._model_fp(self.model), self._model_fp(self.draft),
+        # "out2": the decode executable returns a model's counters too;
+        # an artifact from before has another output tree
+        return (key, "out2", self._model_fp(self.model),
+                self._model_fp(self.draft),
                 self.spec_k, self.max_slots, self.page_size, self.num_pages,
                 str(treedef),
                 tuple((tuple(jnp.shape(l)), str(jnp.result_type(l)))
@@ -368,15 +413,18 @@ class DecodeEngine:
     # -- per-slot recurrent state -------------------------------------------
 
     def _state(self):
-        """The recurrent-state buffers of every layer, without K/V."""
-        return tuple(layer[2:] for layer in self.cache.pool)
+        """The recurrent-state buffers of every layer, without the
+        paged ones."""
+        paged = len(self.cache.page_widths)
+        return tuple(layer[paged:] for layer in self.cache.pool)
 
     def _reset_state(self, slot: int) -> None:
         """Zero ``slot``'s rows of every state buffer, in place."""
         state = self._call(
             "state_reset", (self._state(), jnp.asarray(slot, jnp.int32)),
             donate=(0,))
-        self.cache.pool = tuple(layer[:2] + st for layer, st
+        paged = len(self.cache.page_widths)
+        self.cache.pool = tuple(layer[:paged] + st for layer, st
                                 in zip(self.cache.pool, state))
 
     def _tables(self, cache) -> jnp.ndarray:
@@ -480,35 +528,54 @@ class DecodeEngine:
         grid, from the resident state and into it.  Returns the next
         token per slot, on the device and not waited for: :meth:`read`
         it after the next turn's dispatch."""
-        self._count_live(self._positions, self._active)
-        self.chained = int(self._unread is not None)
+        traced = tracing.capturing()
+        self._count_live(self._positions, self._active, traced)
+        self.chained = int(bool(self._in_flight))
         self._chained_steps += self.chained
-        self.cache.pool, self._resident, nxt = self._call(
+        self.cache.pool, self._resident, nxt, counters = self._call(
             "decode", (self.model.params, self.cache.pool, self._resident),
             donate=(1, 2))
         self._positions += self._active
-        self._unread = nxt
+        self._in_flight.append((nxt, counters, traced))
         return nxt
 
     def read(self, nxt, firsts):
         """The turn's one blocking read: a ``decode_step``'s tokens
         (or None) and a list of prefill chunks' first tokens, as host
-        values."""
+        values.  The model's counters of that step come in the same
+        transfer, into ``counters`` and the running means of
+        :meth:`stats`."""
+        counters, traced = {}, False
+        if any(step[0] is nxt for step in self._in_flight):
+            while True:         # and forget a step that was never read
+                tokens, counters, traced = self._in_flight.popleft()
+                if tokens is nxt:
+                    break
         with tracing.span("decode.sync"):
-            out = jax.device_get((nxt, firsts))
-        if nxt is self._unread:
-            self._unread = None
-        return out
+            nxt_host, firsts_host, counters = jax.device_get(
+                (nxt, firsts, counters))
+        if counters:
+            self.counters = {k: float(v) for k, v in counters.items()}
+            _add(self._life, self.counters)
+            if traced:
+                _add(self._traced, self.counters)
+        return nxt_host, firsts_host
 
-    def _count_live(self, positions, active):
+    def _count_live(self, positions, active, traced=False):
         """Pages holding an active slot's context (its pending token's
-        position included) over the pages of the whole table: host
-        integers, no device read."""
+        position included) over the pages of the whole table, and the
+        tokens of that context (what an attention reads): host integers,
+        no device read."""
         at = onp.asarray(positions)[onp.asarray(active, bool)]
         live = int((at // self.page_size + 1).sum())
         self.kv_live_share = live / (self.max_slots
                                      * self.cache.pages_per_slot)
         self._kv_live_sum += self.kv_live_share
+        live_tokens = int((at + 1).sum())
+        self._live_tokens_sum += live_tokens
+        if traced:
+            self._traced_tokens_sum += live_tokens
+            self._traced_steps += 1
         self._decode_steps += 1
 
     def spec_step(self, tokens, base_pos, active):
@@ -586,11 +653,26 @@ class DecodeEngine:
                 "pages_used": self.cache.pages_used(),
                 "slot_capacity": self.slot_capacity,
                 "spec_k": self.spec_k if self.spec_enabled else 0,
+                "page_bytes": self.cache.page_bytes,
                 "state_bytes": self.cache.state_bytes,
                 "state_slots_live": self.cache.state_slots_live(),
                 "state_resets": self.cache.state_resets,
                 "kv_live_share": (self._kv_live_sum / self._decode_steps
                                   if self._decode_steps else 0.0),
+                # the decoding slots' summed context lengths a decode
+                # step and the model's counters: means over the engine's
+                # life, and over the steps dispatched while a profiler
+                # capture ran (what a trace's kernel times belong to)
+                "live_tokens_mean": (
+                    self._live_tokens_sum / self._decode_steps
+                    if self._decode_steps else 0.0),
+                "counters": _means(self._life),
+                "traced": {
+                    "decode_steps": self._traced_steps,
+                    "live_tokens_mean": (
+                        self._traced_tokens_sum / self._traced_steps
+                        if self._traced_steps else 0.0),
+                    "counters": _means(self._traced)},
                 "chained_share": (self._chained_steps / self._decode_steps
                                   if self._decode_steps else 0.0),
                 "state_edits": self.state_edits}

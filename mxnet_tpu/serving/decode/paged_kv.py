@@ -32,6 +32,15 @@ Page ``num_pages`` — one past a buffer — is the scatter sentinel: KV
 writes for inactive slots / padded prefill rows are directed there and
 dropped by XLA (``mode="drop"``), so masking never needs a branch.
 
+A layer's paged buffers come in one of two kinds, and the model says
+which (``DecodePlaneModel.page_widths``, the lanes of a row of each):
+K and V, two buffers of ``heads * head_dim`` lanes, or a LATENT page,
+one buffer whose row ``[c_kv | k_rope | zeros]`` is the key of every
+query head and, in its first ``rank`` lanes, every head's value
+(multi-head latent attention; :func:`latent_width` pads the row to
+whole lane tiles, which the kernel's copies move).  Pages, tables, the
+sentinel and the allocator are the same for both.
+
 The traced side (the second half of this module) is the only code
 besides the ``paged_attention`` kernel that knows any of the above.  A
 model's core builds ONE attention for the executable it is traced into
@@ -41,7 +50,11 @@ page tables the executable was handed, and calls it in every layer as
 ``attend(q, k, v, kbuf, vbuf)``: the projected heads before rotation
 and the layer's own buffers in, the attention output ``(..., query
 heads, head_dim)`` and the buffers' successors out.  Query heads may be
-a multiple of the pool's KV heads (grouped-query attention).
+a multiple of the pool's KV heads (grouped-query attention).  Over a
+latent page the three are :func:`latent_slot_attention`,
+:func:`latent_chunk_attention` and the oracle's
+:func:`latent_dense_attention`, called as ``attend(q_nope, q_rope,
+c_kv, k_rope, w_kvb, buf)``.
 """
 from __future__ import annotations
 
@@ -56,12 +69,13 @@ from jax import lax
 
 from ... import telemetry
 from ...base import MXNetError
-from ...ops.paged_attention import paged_attention
-from ...ops.rope import rope, rope_reference
+from ...ops.paged_attention import latent_attention, paged_attention
+from ...ops.rope import rope, rope_reference, rope_table
 
 __all__ = ["PageAllocator", "PagedKVCache", "OutOfPagesError",
            "slot_attention", "chunk_attention", "window_attention",
-           "dense_attention"]
+           "dense_attention", "latent_width", "latent_slot_attention",
+           "latent_chunk_attention", "latent_dense_attention"]
 
 _NEG_INF = -1e30
 
@@ -103,44 +117,49 @@ class PageAllocator:
 class PagedKVCache:
     """One engine's KV state: device pool + slot page tables.
 
-    ``pool`` is the device state, a tuple over layers of ``(k, v,
+    ``pool`` is the device state, a tuple over layers of ``(*paged,
     *state)`` buffers; an executable that was given it returns its
-    successor, which the engine stores back.  ``heads`` counts KV
-    heads.  ``state_spec`` lists the kinds of per-slot recurrent state
-    a layer holds, ``(name, shape of one slot, dtype)`` each; empty for
-    a model that has none.
+    successor, which the engine stores back.  ``page_widths`` gives the
+    lanes of a row of each paged buffer of a layer: K and V are two of
+    ``kv_heads * head_dim``, a latent page is one.  ``state_spec`` lists
+    the kinds of per-slot recurrent state a layer holds, ``(name, shape
+    of one slot, dtype)`` each; empty for a model that has none.
 
     ``pages_per_slot`` bounds a single slot's table width (the traced
     table shape); a slot's token capacity is
     ``pages_per_slot * page_size``."""
 
     def __init__(self, *, layers: int, num_pages: int, page_size: int,
-                 heads: int, head_dim: int, max_slots: int,
+                 max_slots: int, page_widths: Sequence[int],
                  pages_per_slot: Optional[int] = None,
                  dtype="float32",
                  state_spec: Sequence[Tuple[str, tuple, str]] = ()):
         self.layers = int(layers)
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
-        self.heads = int(heads)
-        self.head_dim = int(head_dim)
+        self.page_widths = tuple(int(w) for w in page_widths)
         self.max_slots = int(max_slots)
         self.pages_per_slot = int(
             pages_per_slot if pages_per_slot is not None
             else max(1, num_pages // max(1, max_slots)))
-        shape = (self.num_pages, self.page_size,
-                 self.heads * self.head_dim)
         self.state_spec = tuple((str(n), tuple(int(d) for d in sh), str(dt))
                                 for n, sh, dt in state_spec)
         self.pool = tuple(
-            (jnp.zeros(shape, dtype=dtype), jnp.zeros(shape, dtype=dtype))
+            tuple(jnp.zeros((self.num_pages, self.page_size, w), dtype=dtype)
+                  for w in self.page_widths)
             + tuple(jnp.zeros((self.max_slots,) + sh, dtype=dt)
                     for _, sh, dt in self.state_spec)
             for _ in range(self.layers))
         self.state_resets = 0
-        # bytes of recurrent state on the device, all layers and slots
-        self.state_bytes = sum(buf.size * buf.dtype.itemsize
-                               for layer in self.pool for buf in layer[2:])
+        paged = len(self.page_widths)
+
+        def nbytes(bufs):
+            return sum(buf.size * buf.dtype.itemsize for buf in bufs)
+
+        # bytes on the device, all layers: the pages, and the recurrent
+        # state of every slot
+        self.page_bytes = sum(nbytes(layer[:paged]) for layer in self.pool)
+        self.state_bytes = sum(nbytes(layer[paged:]) for layer in self.pool)
         self.allocator = PageAllocator(self.num_pages)
         # traced inputs: page-table rows + a scratch row of zeros for
         # freed slots (page 0 ids are fine — masked by length 0)
@@ -339,5 +358,136 @@ def dense_attention(length: int, *, rope_base):
         pr = jax.nn.softmax(jnp.where(qp >= kp, s, _NEG_INF), axis=-1)
         o = jnp.einsum("grqk,kgd->qgrd", pr, v.astype(jnp.float32))
         return o.reshape(length, heads, hd), ()
+
+    return attend
+
+
+# -- the latent page -------------------------------------------------------------
+
+def latent_width(rank: int, rope_dim: int) -> int:
+    """Lanes of a latent page's row: ``[c_kv (rank) | k_rope (rope_dim)
+    | zeros]``, padded to whole lane tiles of 128 (the kernel copies a
+    page by lane tiles; 576 lanes are stored as 640)."""
+    return -(-(int(rank) + int(rope_dim)) // 128) * 128
+
+
+def _latent_write(q_nope, q_rope, c_kv, k_rope, w_kvb, buf, pos, page,
+                  offset, inv_freq):
+    """Rotate the rope lanes of the queries and of the one shared key
+    at ``pos``, scatter the rows ``[c_kv | k_rope | 0]`` into the
+    layer's one buffer at ``page``/``offset`` (the sentinel page drops a
+    masked row), and absorb the up-projection's key half into the
+    queries: ``(q (rows, heads, width), the value half (rank, heads,
+    v_dim), buf)``.  ``w_kvb (rank, heads, nope + v_dim)``."""
+    rank, nope = c_kv.shape[-1], q_nope.shape[-1]
+    width, dtype = buf.shape[-1], buf.dtype
+    q_rope = rope_table(q_rope, pos, inv_freq)
+    k_rope = rope_table(k_rope[:, None, :], pos, inv_freq)[:, 0]
+    pad = width - rank - k_rope.shape[-1]
+    row = jnp.concatenate(
+        [c_kv, k_rope, jnp.zeros(c_kv.shape[:-1] + (pad,), c_kv.dtype)],
+        axis=-1).astype(dtype)
+    buf = buf.at[page, offset].set(row, mode="drop")
+    q_lat = jnp.einsum("bhd,rhd->bhr", q_nope, w_kvb[..., :nope])
+    q = jnp.concatenate(
+        [q_lat, q_rope, jnp.zeros(q_rope.shape[:-1] + (pad,), q_lat.dtype)],
+        axis=-1).astype(dtype)
+    return q, w_kvb[..., nope:], buf
+
+
+def latent_slot_attention(pool, positions, tables, active, *, inv_freq,
+                          sm_scale):
+    """Decode over a latent page: one token per slot at ``positions``,
+    its row written through ``tables``, every head attending over the
+    slot's ``positions + 1`` rows in the absorbed form (scores ``q_nope
+    W_k . c_kv + q_rope . k_rope``, the output summed in the latent
+    space and taken through ``W_v``): the ``latent_attention`` kernel
+    reads each page once for both.  An inactive slot writes nothing and
+    yields zeros."""
+    num_pages, ps = pool[0][0].shape[:2]
+    lengths = jnp.where(active, positions + 1, 0).astype(jnp.int32)
+    pagerow = jnp.take_along_axis(
+        tables, (positions // ps)[:, None], axis=1)[:, 0]
+    page = jnp.where(active, pagerow, num_pages).astype(jnp.int32)
+    offset = positions % ps
+
+    def attend(q_nope, q_rope, c_kv, k_rope, w_kvb, buf):
+        q, w_v, buf = _latent_write(q_nope, q_rope, c_kv, k_rope, w_kvb,
+                                    buf, positions, page, offset, inv_freq)
+        o = latent_attention(q, buf, tables, lengths, rank=c_kv.shape[-1],
+                             sm_scale=sm_scale)
+        return jnp.einsum("bhr,rhd->bhd", o, w_v), (buf,)
+
+    return attend
+
+
+def latent_chunk_attention(pool, start, chunk_len, table, bucket: int, *,
+                           inv_freq, sm_scale):
+    """Prefill over a latent page: ``bucket`` rows of ONE slot, as
+    :func:`chunk_attention`'s.  The absorbed form again, walked a page
+    at a time over the slot's LIVE pages only (``start + chunk_len``
+    rows, a traced count) under an online softmax: the plain form would
+    up-project every gathered row to ``heads x (nope + v)`` and a
+    slot's table spans positions a prompt never reaches, where this
+    reads what the decode step will read and keeps a page's scores,
+    ``(bucket, heads, page_size)``, as its largest temporary."""
+    num_pages, ps = pool[0][0].shape[:2]
+    pos = start + jnp.arange(bucket, dtype=jnp.int32)
+    valid = jnp.arange(bucket) < chunk_len
+    total = start + chunk_len
+    page = jnp.where(valid, table[pos // ps], num_pages).astype(jnp.int32)
+    offset = pos % ps
+
+    def attend(q_nope, q_rope, c_kv, k_rope, w_kvb, buf):
+        rank, heads = c_kv.shape[-1], q_nope.shape[1]
+        q, w_v, buf = _latent_write(q_nope, q_rope, c_kv, k_rope, w_kvb,
+                                    buf, pos, page, offset, inv_freq)
+
+        def one_page(i, carry):
+            m_prev, l, acc = carry
+            rows = buf[table[i]]                              # (ps, width)
+            s = jnp.einsum("bhw,kw->bhk", q, rows,
+                           preferred_element_type=jnp.float32) * sm_scale
+            kpos = i * ps + lax.broadcasted_iota(jnp.int32, s.shape, 2)
+            mask = (kpos <= pos[:, None, None]) & (kpos < total)
+            s = jnp.where(mask, s, _NEG_INF)
+            m_cur = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            corr = jnp.exp(m_prev - m_cur)
+            p = jnp.where(mask, jnp.exp(s - m_cur), 0.0)
+            pv = jnp.einsum("bhk,kr->bhr", p.astype(rows.dtype),
+                            rows[:, :rank],
+                            preferred_element_type=jnp.float32)
+            return (m_cur, l * corr + p.sum(axis=-1, keepdims=True),
+                    acc * corr + pv)
+
+        init = (jnp.full((bucket, heads, 1), _NEG_INF, jnp.float32),
+                jnp.zeros((bucket, heads, 1), jnp.float32),
+                jnp.zeros((bucket, heads, rank), jnp.float32))
+        _, l, acc = lax.fori_loop(0, (total + ps - 1) // ps, one_page, init)
+        o = (acc / jnp.where(l == 0.0, 1.0, l)).astype(q.dtype)
+        return jnp.einsum("bhr,rhd->bhd", o, w_v), (buf,)
+
+    return attend
+
+
+def latent_dense_attention(length: int, *, inv_freq, sm_scale):
+    """The oracle's ``attend(q_nope, q_rope, c_kv, k_rope, w_kvb)`` over
+    one whole sequence, in the PLAIN form: every row up-projected to
+    its heads' keys and values, causal softmax in float32.  No pool,
+    page, kernel or absorption: what the cached paths are pinned to."""
+    pos = jnp.arange(length, dtype=jnp.int32)
+    f32 = jnp.float32
+
+    def attend(q_nope, q_rope, c_kv, k_rope, w_kvb):
+        nope = q_nope.shape[-1]
+        q_r = rope_table(q_rope.astype(f32), pos, inv_freq)
+        k_r = rope_table(k_rope.astype(f32)[:, None, :], pos, inv_freq)[:, 0]
+        kv = jnp.einsum("kr,rhd->khd", c_kv.astype(f32), w_kvb.astype(f32))
+        s = (jnp.einsum("qhd,khd->hqk", q_nope.astype(f32), kv[..., :nope])
+             + jnp.einsum("qhd,kd->hqk", q_r, k_r)) * sm_scale
+        qp = lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        kp = lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        pr = jax.nn.softmax(jnp.where(qp >= kp, s, _NEG_INF), axis=-1)
+        return jnp.einsum("hqk,khd->qhd", pr, kv[..., nope:]), ()
 
     return attend

@@ -355,6 +355,7 @@ class DecodeScheduler:
                                   if decoding else 0.0),
                 "chained": eng.chained if decoding else 0,
                 "state_edits": edits,
+                "counters": dict(eng.counters),
                 "step_ms": round((time.perf_counter() - t_step) * 1e3, 3),
             })
             telemetry.end_step(token, "serving.DecodeScheduler",

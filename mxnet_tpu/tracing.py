@@ -68,7 +68,7 @@ from jax.profiler import TraceAnnotation
 from . import telemetry
 
 __all__ = ["Span", "span", "begin", "end", "record_span", "instant",
-           "enabled",
+           "enabled", "capturing",
            "enable", "disable", "export", "recent", "open_spans",
            "aggregate", "clear", "span_count", "dropped_count",
            "bucket_totals_ms", "start_watchdog", "stop_watchdog",
@@ -331,6 +331,12 @@ class Span:
         args.update(self.attrs)
         _store(self.name, self.t0, self.t1, self.tid, args,
                span_id=self.span_id)
+
+
+def capturing() -> bool:
+    """Whether a profiler capture runs: spans are annotations on its
+    clock, and a counter kept "under a capture" belongs to its trace."""
+    return TraceAnnotation.is_enabled()
 
 
 def span(name: str, **attrs) -> Any:

@@ -280,9 +280,9 @@ exempt([
    "(test_layer_norm_residual_op_and_grads)")
 
 exempt([
-    "rope", "paged_attention", "ssm_update",
-], "decode-serving inference kernels (rotary embedding, paged-KV "
-   "attention, the state-space decode update): forward-only "
+    "rope", "paged_attention", "ssm_update", "latent_attention",
+], "decode-serving inference kernels (rotary embedding, paged-KV and "
+   "latent-cache attention, the state-space decode update): forward-only "
    "registrants pinned against their XLA oracles in "
    "test_kernels/test_decode/test_decode_hybrid; no training path "
    "invokes them, so there is no vjp to fd-check")

@@ -119,7 +119,7 @@ def test_paged_kv_slot_acquire_release():
     # pool deliberately smaller than max_slots * pages_per_slot so a
     # full-budget acquire can exhaust the free list
     c = PagedKVCache(layers=2, num_pages=6, page_size=4, max_slots=2,
-                     pages_per_slot=4, heads=2, head_dim=8)
+                     pages_per_slot=4, page_widths=(16, 16))
     assert c.slot_capacity == 4 * 4     # pages_per_slot * page_size
     c.acquire(0, 9)                     # 9 tokens → 3 pages
     assert c.pages_used() == 3

@@ -22,7 +22,7 @@ from mxnet_tpu.ops import attention as att
 from mxnet_tpu.ops.layernorm_residual import layer_norm_residual
 
 KERNELS = ("flash_attention", "layer_norm_residual", "zero_flatten_pad",
-           "rope", "paged_attention", "ssm_update")
+           "rope", "paged_attention", "ssm_update", "latent_attention")
 
 
 @pytest.fixture

@@ -139,6 +139,33 @@ def test_paged_attention_grouped_query_compiles(v5e, block_k):
         for shape, dt in f32]).compile()
 
 
+@pytest.mark.parametrize("block_k", [128, 512])
+def test_latent_attention_compiles_at_the_reasoning_cell(v5e, block_k):
+    # axk1_decode_reasoning: 128 slots x 32 pages of 128 rows, 64 heads
+    # over one row of 640 lanes ([512 | 64 | 64 of padding]); a block is
+    # one page or four
+    from mxnet_tpu.ops.paged_attention import _latent_attention_pallas
+    text = _compile(
+        lambda q, p, t, l: _latent_attention_pallas(q, p, t, l, 512,
+                                                    192 ** -0.5, block_k),
+        v5e, ((128, 64, 640), "bfloat16"), ((4096, 128, 640), "bfloat16"),
+        ((128, 32), "int32"), ((128,), "int32"))
+    assert "mxtpu_latent_attention" in text
+
+
+def test_a_latent_row_of_no_whole_lane_tile_is_refused(v5e):
+    # 576 lanes as the model defines the row: no source of a copy, and
+    # no silent gather in its place (paged_kv.latent_width pads the row)
+    from mxnet_tpu.ops.paged_attention import _latent_attention_pallas
+    args = [jax.ShapeDtypeStruct(sh, jnp.dtype(dt), sharding=v5e)
+            for sh, dt in (((8, 64, 576), "bfloat16"),
+                           ((64, 128, 576), "bfloat16"),
+                           ((8, 8), "int32"), ((8,), "int32"))]
+    with pytest.raises(ValueError, match="128-lane tiles"):
+        jax.jit(lambda q, p, t, l: _latent_attention_pallas(
+            q, p, t, l, 512, 192 ** -0.5, 128)).lower(*args)
+
+
 def _ssm_specs(sharding, dtype="bfloat16"):
     s_, h, p, n, g = FH_SLOTS, 32, 128, 256, 2
     return [jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=sharding)
@@ -320,6 +347,67 @@ def test_hybrid_executables_update_kv_and_state_in_place(v5e, key):
         assert sum("mxtpu_ssm_update" in c for c in calls) == 2
 
 
+# axk1_decode_reasoning: one latent page buffer a layer, 4096 pages x 128
+# x 640 lanes bf16 (671 MB); 2 of its 7 layers, the dense one and an
+# expert layer with its 12 held experts, the published widths and the
+# vocabulary's slice (shapes only).  All seven layers, compiled here by
+# hand (PERF.md section 4, PR 33): arguments 14.380 GB of which the
+# pool's 4.698 GB is aliased; temporaries 99.6 MB (decode), 152.0 MB
+# (prefill b256), 65.0 MB (b32).
+
+@pytest.mark.parametrize("key,temp_mb", [("decode", 64), ("prefill_b256", 140),
+                                         ("prefill_b32", 48)])
+def test_latent_executables_update_the_cache_in_place(v5e, key, temp_mb):
+    """The latent pages are aliased to the outputs whole, the
+    temporaries hold no copy of a layer's buffer and stay near what
+    they were when the cell was added (47.7, 103.7 and 35.1 MB at these
+    two layers), and the decode step's attention is the named kernel,
+    once a layer."""
+    import json
+    import re
+    from mxnet_tpu.serving import AXK1
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chipbench")
+    with open(os.path.join(bench, "configs", "axk1_519b.json")) as f:
+        cfg = dict(json.load(f), num_hidden_layers=2)
+    with open(os.path.join(bench, "workloads",
+                           "axk1_decode_reasoning.json")) as f:
+        geo = json.load(f)["engine"]
+    mdl = AXK1(cfg, abstract=True)
+    slots, pps = geo["max_slots"], geo["pages_per_slot"]
+
+    def spec(shape, dtype="bfloat16"):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=v5e)
+
+    params = jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype),
+                                    mdl.params)
+    buf = spec((geo["num_pages"], geo["page_size"], 640))
+    assert mdl.page_widths == (640,)
+    pool = ((buf,), (buf,))
+    one = buf.size * buf.dtype.itemsize                  # 671 MB
+    if key == "decode":
+        lowered = _lower_chained_decode(mdl, params, pool,
+                                        _resident(spec, slots, pps))
+    else:
+        bucket = int(key.rsplit("b", 1)[1])
+        lowered = jax.jit(lambda *a: mdl.prefill_core(*a),
+                          donate_argnums=(1,)).lower(
+            params, pool, spec((bucket,), "int32"), spec((), "int32"),
+            spec((), "int32"), spec((pps,), "int32"))
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    state = mem.alias_size_in_bytes - 2 * one
+    assert 0 <= state < 2 ** 16 and (state > 0) == (key == "decode")
+    assert mem.temp_size_in_bytes < temp_mb * 1e6, mem.temp_size_in_bytes
+    calls = re.findall(r'^\s*(?:ROOT )?%(\S+) = .*custom_call_target='
+                       r'"tpu_custom_call"', compiled.as_text(), re.M)
+    if key == "decode":
+        assert len(calls) == 2
+        assert all("mxtpu_latent_attention" in c for c in calls)
+    else:       # a chunk walks the slot's live pages in XLA
+        assert not calls
+
+
 # -- the chained decode executable at the two decode cells' whole geometry ----
 
 @pytest.mark.parametrize("cell,pool_bytes", [
@@ -395,7 +483,8 @@ def test_chained_decode_aliases_the_pool_and_the_resident_state(
 def _named_texts(sharding):
     """The compiled HLO of every kernel of the main path, by family."""
     from mxnet_tpu.ops.layernorm_residual import _lnr_pallas
-    from mxnet_tpu.ops.paged_attention import _paged_attention_pallas
+    from mxnet_tpu.ops.paged_attention import (_latent_attention_pallas,
+                                               _paged_attention_pallas)
     from mxnet_tpu.ops.rope import _rope_pallas
     from mxnet_tpu.ops.ssm import _ssm_kernel_run
     qkv = ((16, 1024, D), "bfloat16")
@@ -408,6 +497,12 @@ def _named_texts(sharding):
             lambda q, k, v, t, l: _paged_attention_pallas(
                 q, k, v, t, l, D ** -0.5, 64),
             sharding, ((SLOTS, H, D), "bfloat16"), pool, pool,
+            ((SLOTS, 40), "int32"), ((SLOTS,), "int32")),
+        "latent_attention": _compile(
+            lambda q, p, t, l: _latent_attention_pallas(
+                q, p, t, l, 128, 192 ** -0.5, 64),
+            sharding, ((SLOTS, H, 256), "bfloat16"),
+            ((SLOTS * 40, 16, 256), "bfloat16"),
             ((SLOTS, 40), "int32"), ((SLOTS,), "int32")),
         "rope": _compile(lambda a, p: _rope_pallas(a, p, 10000.0, 128),
                          sharding, ((SLOTS, H, D), "bfloat16"),
@@ -426,7 +521,7 @@ _TEXTS = {}
 
 @pytest.mark.parametrize("kernel", [
     "flash_fwd", "flash_dkv", "flash_dq", "paged_attention", "rope",
-    "layernorm_residual", "ssm_update"])
+    "layernorm_residual", "ssm_update", "latent_attention"])
 def test_custom_call_carries_the_kernels_name(v5e, kernel):
     import re
     if not _TEXTS:
