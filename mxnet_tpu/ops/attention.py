@@ -13,6 +13,36 @@ pallas boundary lane-broadcast (see `_LSE_LANES`) to satisfy the TPU
 (8, 128) block-tiling rule — statically guarded on CPU by
 tests/test_pallas_tiling_guard.py.
 
+The schedule.  The work of the three kernels follows the scores that
+count, not the rectangle.  For a call's ``(causal, seq_q, seq_k,
+block_q, block_k)``, all known when it is traced, :func:`_live_tiles`
+sorts every tile of 128 rows by 128 keys of the padded score matrix
+into *dead* (wholly above the diagonal, or wholly in the padding: never
+visited), *edge* (the diagonal, the end of the real keys or the end of
+the real rows crosses it: the one kind that builds a mask, and only the
+comparisons that crossing needs) and *interior* (every score counts: no
+``iota``, no compare, no ``select``).  A grid step's block is unrolled
+over its live tiles: neighbours of one kind are merged into one matmul
+(:func:`_tile_groups`), so a block wholly under the diagonal runs the
+whole-block body less its mask, and a block the diagonal crosses runs,
+for each 128 rows, one online-softmax update over the keys at or under
+them.  The forward walks its blocks at that grain; the two backward
+kernels walk theirs at 256 (``_BWD_TILE``: fewer and larger float32
+products for four more dead tiles of 128 in a head of 1024, measured).
+Blocks that stand alike to the diagonal and to the padded rim
+share one unrolled body, chosen by the program ids
+(:func:`_block_cases`); under ``causal`` with ``block_q != block_k``
+the diagonal's offset inside a block varies with the step, and a whole
+block is then one tile whose kind (masked edge or plain interior) is
+read from the program ids.  A grid step whose whole block is dead runs
+nothing and fetches nothing: its index maps stay on the nearest live
+block of the same inner loop, and the pipeline skips a fetch whose block
+did not change.  ``_live_tiles`` also counts ``(visited, edge, all)``:
+36 / 8 / 64 for a causal 1024 x 1024 call whatever its blocks, 64 / 0 /
+64 without ``causal`` (no mask at all).  A masked score adds an exact 0
+to every sum it is in, so leaving it out changes at most the order of a
+float32 reduction inside a block.
+
 Parity targets (API, not implementation):
 - `_contrib_interleaved_matmul_selfatt_qk/valatt`,
   `_contrib_interleaved_matmul_encdec_qk/valatt`
@@ -78,12 +108,226 @@ def attention_reference(q, k, v, causal=False, sm_scale=None, bias=None):
 
 
 # --------------------------------------------------------------------------
+# the live-tile schedule: which tiles of the score matrix a call visits,
+# and which of them build a mask (module docstring, "The schedule")
+# --------------------------------------------------------------------------
+
+_TILE = 128  # the schedule's grain: rows and keys of one score tile
+# The grain each kernel walks its blocks at, measured on a v5e (PERF.md,
+# PR 34).  A coarser grain visits more dead scores (10 tiles of 256 are
+# 40 of 128 where the triangle holds 36) in fewer, larger matmuls: the
+# backward kernels' float32 transposed products (P^T dO, dS^T Q) stream
+# a tile's rows past each block of weights and are fed badly by 128; the
+# forward's products are in the input dtype and it gains from the finer
+# triangle.
+_FWD_TILE, _BWD_TILE = _TILE, 2 * _TILE
+
+
+def _tile_kind(causal, q0, q1, k0, k1, seq_q, seq_k):
+    """What the scores of rows ``[q0, q1)`` against keys ``[k0, k1)``
+    need: ``(live, diag, k_crop, q_crop)``.  ``live``: at least one of
+    them counts.  The other three name the masks an edge needs: the
+    diagonal crosses the real part of the tile, keys past ``seq_k``,
+    rows past ``seq_q``; none set on a live tile means every score
+    counts (interior).  Plain comparisons, so the bounds may be Python
+    ints (the static schedule) or traced scalars (a whole block of the
+    fallback grid)."""
+    live = (q0 < seq_q) & (k0 < seq_k)
+    diag = False
+    if causal:      # the diagonal is qpos >= kpos from the top-left corner
+        live = live & (k0 < q1) & (k0 < seq_q)
+        diag = (k1 - 1 > q0) & (seq_k - 1 > q0)
+    return live, diag, k1 > seq_k, q1 > seq_q
+
+
+@functools.lru_cache(maxsize=256)
+def _live_tiles(causal, seq_q, seq_k, block_q, block_k, grain=_TILE):
+    """The static schedule of one call: ``(blocks, (visited, edge,
+    all))``.  ``blocks[i][j]`` lists the tiles grid block ``(i, j)``
+    visits as ``(r, c, (diag, k_crop, q_crop))``, ``r`` and ``c`` in
+    tiles of ``gcd(block, grain)`` rows and keys inside the block; a
+    dead tile (wholly above the diagonal or wholly in the padding) is
+    not listed, an edge tile has a flag set (:func:`_tile_kind`).  The
+    triple counts tiles over the whole padded rectangle; it is static,
+    so it is the mechanism's "how often it engages" figure."""
+    gq, gk = math.gcd(block_q, grain), math.gcd(block_k, grain)
+    nq, nk = -(-seq_q // block_q), -(-seq_k // block_k)
+    visited = edge = 0
+    blocks = []
+    for i in range(nq):
+        row = []
+        for j in range(nk):
+            tiles = []
+            for r in range(block_q // gq):
+                q0 = i * block_q + r * gq
+                for c in range(block_k // gk):
+                    k0 = j * block_k + c * gk
+                    live, *flags = _tile_kind(causal, q0, q0 + gq, k0,
+                                              k0 + gk, seq_q, seq_k)
+                    if live:
+                        tiles.append((r, c, tuple(flags)))
+                        edge += any(flags)
+            visited += len(tiles)
+            row.append(tuple(tiles))
+        blocks.append(tuple(row))
+    return tuple(blocks), (visited, edge,
+                           nq * nk * (block_q // gq) * (block_k // gk))
+
+
+def _last_live_k(i, j, causal, block_q, block_k):
+    """The k block grid step ``(i, j)`` fetches: ``j``, but a dead step
+    of a causal grid (they close a row of the forward and ``dq`` grids)
+    stays on the row's last live block, and the pipeline skips a fetch
+    whose block did not change."""
+    return jnp.minimum(j, ((i + 1) * block_q - 1) // block_k) if causal else j
+
+
+def _first_live_q(j, i, causal, block_q, block_k, nq):
+    """The q block step ``(j, i)`` of the ``dkv`` grid fetches: dead
+    steps open a row there, and stay on its first live block."""
+    if not causal:
+        return i
+    return jnp.minimum(jnp.maximum(i, (j * block_k) // block_q), nq - 1)
+
+
+@functools.lru_cache(maxsize=256)
+def _alike_blocks(causal, seq_q, seq_k, block_q, block_k, grain, crop_q):
+    """The grid's blocks grouped by how they stand to the diagonal (under
+    it, on it, above it) and to the padded rim (last row, last column):
+    ``{(rel, last_q, last_k): (tiles, (a, b))}``, ``(a, b)`` one block of
+    the group; ``None`` where two blocks of a group visit different tiles
+    or stand differently to a diagonal they mask."""
+    blocks, _ = _live_tiles(causal, seq_q, seq_k, block_q, block_k, grain)
+    nq, nk = len(blocks), len(blocks[0])
+    rim_q, rim_k = seq_q % block_q != 0, seq_k % block_k != 0
+    groups, offsets = {}, {}
+    for a in range(nq):
+        for b in range(nk):
+            key = ((a > b) - (a < b) if causal else 0,
+                   rim_q and a == nq - 1, rim_k and b == nk - 1)
+            tiles = tuple((r, c, (d, kc, qc and crop_q))
+                          for r, c, (d, kc, qc) in blocks[a][b])
+            # a diagonal's mask also depends on where the block stands
+            off = (a * block_q - b * block_k
+                   if any(t[2][0] for t in tiles) else None)
+            if groups.setdefault(key, (tiles, (a, b)))[0] != tiles \
+                    or offsets.setdefault(key, off) != off:
+                return None
+    return groups
+
+
+def _block_cases(i, j, *, causal, seq_q, seq_k, block_q, block_k,
+                 grain=_TILE, crop_q=True):
+    """What grid step ``(i, j)`` (program ids) does, as ``[(pred, tiles,
+    (gq, gk), (q0, k0))]``: under ``pred`` (``True``: always) visit
+    ``tiles`` of ``gq`` rows by ``gk`` keys, the block's first score at
+    ``(q0, k0)``.  Blocks that stand alike to the diagonal and to the
+    padded rim visit the same tiles, so a call has a handful of cases
+    and a dead block has none.  Where they do not (``block_q !=
+    block_k`` under ``causal``: the diagonal's offset inside a block
+    varies with the step) a block is one tile whose kind is read from
+    the program ids: masked if an edge, plain if interior.  ``grain`` is
+    the calling kernel's (``_FWD_TILE``, ``_BWD_TILE``); ``crop_q=False``
+    (the forward, whose padded rows are cropped by the caller) leaves the
+    ``qpos < seq_q`` mask out."""
+    nq, nk = -(-seq_q // block_q), -(-seq_k // block_k)
+    rim_q, rim_k = seq_q % block_q != 0, seq_k % block_k != 0
+    alike = _alike_blocks(causal, seq_q, seq_k, block_q, block_k, grain,
+                          crop_q)
+    if alike is None:
+        q0, k0 = i * block_q, j * block_k
+        live, diag, kc, qc = _tile_kind(causal, q0, q0 + block_q, k0,
+                                        k0 + block_k, seq_q, seq_k)
+        edge = diag | kc | (qc & crop_q)
+        return [(live & edge,
+                 ((0, 0, (causal, rim_k, rim_q and crop_q)),),
+                 (block_q, block_k), (q0, k0)),
+                (live & jnp.logical_not(edge),
+                 ((0, 0, (False, False, False)),),
+                 (block_q, block_k), (q0, k0))]
+    grain = (math.gcd(block_q, grain), math.gcd(block_k, grain))
+    cases = []
+    for (rel, last_q, last_k), (tiles, (a, b)) in alike.items():
+        if not tiles:
+            continue
+        conds = []
+        if causal:
+            conds.append(i > j if rel > 0 else i == j if rel == 0 else i < j)
+        if rim_q:
+            conds.append(i == nq - 1 if last_q else i != nq - 1)
+        if rim_k:
+            conds.append(j == nk - 1 if last_k else j != nk - 1)
+        cases.append((functools.reduce(jnp.logical_and, conds)
+                      if conds else True,
+                      tiles, grain, (a * block_q, b * block_k)))
+    return cases
+
+
+def _tile_groups(tiles, by):
+    """Merge a block's live tiles into the matmuls a kernel runs:
+    ``[(a0, a1, ((b0, b1, flags), ...))]``, ``a`` along axis ``by`` (0:
+    q tiles, the forward's and ``dq``'s accumulator rows; 1: k tiles,
+    ``dkv``'s) and ``b`` along the other.  Neighbours along ``b`` with
+    equal flags are one piece, neighbours along ``a`` with equal pieces
+    one group: an interior block is one group of one piece, the
+    whole-block body less its mask."""
+    lines = {}
+    for tile in tiles:
+        a, b, flags = tile[by], tile[1 - by], tile[2]
+        pieces = lines.setdefault(a, [])
+        if pieces and pieces[-1][1:] == (b, flags):
+            pieces[-1] = (pieces[-1][0], b + 1, flags)
+        else:
+            pieces.append((b, b + 1, flags))
+    groups = []
+    for a in sorted(lines):
+        pieces = tuple(lines[a])
+        if groups and groups[-1][1:] == (a, pieces):
+            groups[-1] = (groups[-1][0], a + 1, pieces)
+        else:
+            groups.append((a, a + 1, pieces))
+    return groups
+
+
+def _piece_mask(shape, q0, k0, flags, seq_q, seq_k):
+    """The mask of one piece whose first score is ``(q0, k0)``: only the
+    comparisons its flags name, ``None`` for an interior piece."""
+    diag, k_crop, q_crop = flags
+    if diag or q_crop:
+        qpos = q0 + lax.broadcasted_iota(jnp.int32, shape, 0)
+    if diag or k_crop:
+        kpos = k0 + lax.broadcasted_iota(jnp.int32, shape, 1)
+    terms = []
+    if diag:
+        terms.append(qpos >= kpos)
+    if k_crop:
+        terms.append(kpos < seq_k)
+    if q_crop:
+        terms.append(qpos < seq_q)
+    return functools.reduce(jnp.logical_and, terms) if terms else None
+
+
+def _visit(cases, body):
+    """Run ``body(tiles, grain, origin)`` for the case the step is in."""
+    for pred, *case in cases:
+        if pred is True:
+            body(*case)
+        else:
+            pl.when(pred)(functools.partial(body, *case))
+
+
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_NN = (((1,), (0,)), ((), ()))      # a @ b
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
+
+
+# --------------------------------------------------------------------------
 # pallas forward kernel
 # --------------------------------------------------------------------------
 
 def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                    acc_ref, m_ref, l_ref, *,
-                   sm_scale, causal, block_q, block_k, seq_k):
+                   sm_scale, causal, block_q, block_k, seq_q, seq_k):
     i = pl.program_id(1)
     j = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -94,36 +338,45 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q_start = i * block_q
-    k_start = j * block_k
+    def body(tiles, grain, origin):
+        (gq, gk), (q0, k0) = grain, origin
+        # one online-softmax update a group of q rows, over all the live
+        # keys the block holds for them
+        for r0, r1, pieces in _tile_groups(tiles, by=0):
+            rows = slice(r0 * gq, r1 * gq)
+            q = q_ref[0, rows, :]
+            scores = []
+            for c0, c1, flags in pieces:
+                cols = slice(c0 * gk, c1 * gk)
+                s = lax.dot_general(q, k_ref[0, cols, :], _NT,
+                                    preferred_element_type=jnp.float32
+                                    ) * sm_scale
+                mask = _piece_mask(s.shape, q0 + r0 * gq, k0 + c0 * gk,
+                                   flags, seq_q, seq_k)
+                if mask is not None:
+                    s = jnp.where(mask, s, _NEG_INF)
+                scores.append((cols, s))
+            m_prev = m_ref[rows, :1]
+            m_cur = m_prev
+            for _, s in scores:
+                m_cur = jnp.maximum(m_cur, s.max(axis=1, keepdims=True))
+            corr = jnp.exp(m_prev - m_cur)
+            l_new = l_ref[rows, :1] * corr
+            acc = acc_ref[rows, :] * corr
+            for cols, s in scores:
+                p = jnp.exp(s - m_cur)
+                l_new = l_new + p.sum(axis=1, keepdims=True)
+                acc = acc + lax.dot_general(
+                    p.astype(v_ref.dtype), v_ref[0, cols, :], _NN,
+                    preferred_element_type=jnp.float32)
+            acc_ref[rows, :] = acc
+            lanes = (m_cur.shape[0], m_ref.shape[1])
+            m_ref[rows, :] = jnp.broadcast_to(m_cur, lanes)
+            l_ref[rows, :] = jnp.broadcast_to(l_new, lanes)
 
-    # causal: whole k-block above the diagonal contributes nothing
-    run = (q_start + block_q - 1 >= k_start) if causal else (j >= 0)
-
-    @pl.when(run)
-    def _body():
-        q = q_ref[0]                      # (block_q, d)
-        k = k_ref[0]                      # (block_k, d)
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
-        kpos = k_start + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = kpos < seq_k               # crop padded keys
-        if causal:
-            qpos = q_start + lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            mask = mask & (qpos >= kpos)
-        s = jnp.where(mask, s, _NEG_INF)
-
-        m_prev = m_ref[:, :1]             # (block_q, 1)
-        m_cur = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        corr = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur)            # (block_q, block_k)
-        l_new = l_ref[:, :1] * corr + p.sum(axis=1, keepdims=True)
-        pv = lax.dot_general(p.astype(v_ref.dtype), v_ref[0],
-                             (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * corr + pv
-        m_ref[...] = jnp.broadcast_to(m_cur, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+    _visit(_block_cases(i, j, causal=causal, seq_q=seq_q, seq_k=seq_k,
+                        block_q=block_q, block_k=block_k,
+                        grain=_FWD_TILE, crop_q=False), body)
 
     @pl.when(j == nk - 1)
     def _finish():
@@ -141,6 +394,15 @@ def _ceil_to(x, m):
 
 def _fa_forward_pallas(q, k, v, causal, sm_scale, block_q, block_k):
     """q,k,v: (BH, S, D) → (out (BH, Sq, D), lse (BH, Sq))."""
+    return _fa_forward_jit(q, k, v, causal, sm_scale, block_q, block_k,
+                           jax.default_backend() != "tpu")
+
+
+# Jitted on everything but the arrays: the layers of a model share ONE
+# trace of a kernel's unrolled body (traced anew for each of 24 layers
+# the three bodies cost a warm set-up 2.5 s: PERF.md, PR 34).
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
+def _fa_forward_jit(q, k, v, causal, sm_scale, block_q, block_k, interpret):
     bh, seq_q, d = q.shape
     seq_k = k.shape[1]
     block_q = min(block_q, _ceil_to(seq_q, 128))
@@ -157,19 +419,23 @@ def _fa_forward_pallas(q, k, v, causal, sm_scale, block_q, block_k):
 
     kernel = functools.partial(
         _fa_fwd_kernel, sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_k=block_k, seq_k=seq_k)
+        block_q=block_q, block_k=block_k, seq_q=seq_q, seq_k=seq_k)
     scratch_shapes = [
         pltpu.VMEM((block_q, d), jnp.float32),
         pltpu.VMEM((block_q, 128), jnp.float32),
         pltpu.VMEM((block_q, 128), jnp.float32),
     ]
+
+    def kv_block(b, i, j):
+        return (b, _last_live_k(i, j, causal, block_q, block_k), 0)
+
     out, lse = pl.pallas_call(
         kernel,
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d), kv_block),
+            pl.BlockSpec((1, block_k, d), kv_block),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -182,7 +448,7 @@ def _fa_forward_pallas(q, k, v, causal, sm_scale, block_q, block_k):
                                  jnp.float32),
         ],
         scratch_shapes=scratch_shapes,
-        interpret=jax.default_backend() != "tpu",
+        interpret=interpret,
         name="mxtpu_flash_fwd",
     )(q, k, v)
     lse = lse[..., 0]
@@ -250,7 +516,9 @@ def _fa_backward(causal, sm_scale, block_q, res, do):
 # one accumulates dq per q-block (k innermost).  Unlike the scan
 # fallback above, the (block, block) score/probability recomputations
 # never leave VMEM, so backward HBM traffic drops from O(S_q * S_k)
-# temps to the O(S * D) operand streams.
+# temps to the O(S * D) operand streams.  Both walk the forward's
+# schedule: dkv by groups of k tiles (a group's dk and dv rows are
+# written once a block), dq by groups of q rows.
 # --------------------------------------------------------------------------
 
 def _fa_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -266,38 +534,43 @@ def _fa_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    q_start = i * block_q
-    k_start = j * block_k
-    run = (q_start + block_q - 1 >= k_start) if causal else (i >= 0)
+    def body(tiles, grain, origin):
+        (gq, gk), (q0, k0) = grain, origin
+        for c0, c1, pieces in _tile_groups(tiles, by=1):
+            cols = slice(c0 * gk, c1 * gk)
+            k = k_ref[0, cols, :]
+            vf = v_ref[0, cols, :].astype(jnp.float32)
+            dk = dk_acc[cols, :]
+            dv = dv_acc[cols, :]
+            for r0, r1, flags in pieces:
+                rows = slice(r0 * gq, r1 * gq)
+                q = q_ref[0, rows, :]
+                dof = do_ref[0, rows, :].astype(jnp.float32)
+                lse = lse_ref[0, rows, :][:, :1]    # lane 0 of broadcast
+                delta = delta_ref[0, rows, :][:, :1]
+                s = lax.dot_general(q, k, _NT,
+                                    preferred_element_type=jnp.float32
+                                    ) * sm_scale
+                p = jnp.exp(s - lse)
+                mask = _piece_mask(s.shape, q0 + r0 * gq, k0 + c0 * gk,
+                                   flags, seq_q, seq_k)
+                if mask is not None:
+                    p = jnp.where(mask, p, 0.0)
+                # dv_j += P^T dO ;  dP = dO V^T ;  dS = P*(dP - delta)*scale
+                dv = dv + lax.dot_general(
+                    p, dof, _TN, preferred_element_type=jnp.float32)
+                dp = lax.dot_general(dof, vf, _NT,
+                                     preferred_element_type=jnp.float32)
+                ds = p * (dp - delta) * sm_scale
+                dk = dk + lax.dot_general(
+                    ds, q.astype(jnp.float32), _TN,
+                    preferred_element_type=jnp.float32)
+            dk_acc[cols, :] = dk
+            dv_acc[cols, :] = dv
 
-    @pl.when(run)
-    def _body():
-        q = q_ref[0]                  # (block_q, d)
-        k = k_ref[0]                  # (block_k, d)
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0][:, :1]       # (block_q, 1): lane-0 of broadcast
-        delta = delta_ref[0][:, :1]
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
-        qpos = q_start + lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        kpos = k_start + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = (qpos < seq_q) & (kpos < seq_k)
-        if causal:
-            mask = mask & (qpos >= kpos)
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
-        dof = do.astype(jnp.float32)
-        # dv_j += P^T dO ;  dP = dO V^T ;  dS = P*(dP - delta)*scale
-        dv_acc[...] = dv_acc[...] + lax.dot_general(
-            p, dof, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = lax.dot_general(dof, v.astype(jnp.float32),
-                             (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * sm_scale
-        dk_acc[...] = dk_acc[...] + lax.dot_general(
-            ds, q.astype(jnp.float32), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _visit(_block_cases(i, j, causal=causal, seq_q=seq_q, seq_k=seq_k,
+                        block_q=block_q, block_k=block_k,
+                        grain=_BWD_TILE), body)
 
     @pl.when(i == nq - 1)
     def _finish():
@@ -316,34 +589,38 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    q_start = i * block_q
-    k_start = j * block_k
-    run = (q_start + block_q - 1 >= k_start) if causal else (j >= 0)
+    def body(tiles, grain, origin):
+        (gq, gk), (q0, k0) = grain, origin
+        for r0, r1, pieces in _tile_groups(tiles, by=0):
+            rows = slice(r0 * gq, r1 * gq)
+            q = q_ref[0, rows, :]
+            dof = do_ref[0, rows, :].astype(jnp.float32)
+            lse = lse_ref[0, rows, :][:, :1]        # lane 0 of broadcast
+            delta = delta_ref[0, rows, :][:, :1]
+            dq = dq_acc[rows, :]
+            for c0, c1, flags in pieces:
+                cols = slice(c0 * gk, c1 * gk)
+                k = k_ref[0, cols, :]
+                s = lax.dot_general(q, k, _NT,
+                                    preferred_element_type=jnp.float32
+                                    ) * sm_scale
+                p = jnp.exp(s - lse)
+                mask = _piece_mask(s.shape, q0 + r0 * gq, k0 + c0 * gk,
+                                   flags, seq_q, seq_k)
+                if mask is not None:
+                    p = jnp.where(mask, p, 0.0)
+                dp = lax.dot_general(
+                    dof, v_ref[0, cols, :].astype(jnp.float32), _NT,
+                    preferred_element_type=jnp.float32)
+                ds = p * (dp - delta) * sm_scale
+                dq = dq + lax.dot_general(
+                    ds, k.astype(jnp.float32), _NN,
+                    preferred_element_type=jnp.float32)
+            dq_acc[rows, :] = dq
 
-    @pl.when(run)
-    def _body():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0][:, :1]       # (block_q, 1): lane-0 of broadcast
-        delta = delta_ref[0][:, :1]
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
-        qpos = q_start + lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        kpos = k_start + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = (qpos < seq_q) & (kpos < seq_k)
-        if causal:
-            mask = mask & (qpos >= kpos)
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
-        dof = do.astype(jnp.float32)
-        dp = lax.dot_general(dof, v.astype(jnp.float32),
-                             (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * sm_scale
-        dq_acc[...] = dq_acc[...] + lax.dot_general(
-            ds, k.astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _visit(_block_cases(i, j, causal=causal, seq_q=seq_q, seq_k=seq_k,
+                        block_q=block_q, block_k=block_k,
+                        grain=_BWD_TILE), body)
 
     @pl.when(j == nk - 1)
     def _finish():
@@ -355,6 +632,13 @@ def _fa_backward_pallas(causal, sm_scale, block_q, block_k, res, do,
     """``delta`` may be precomputed (rowsum(do*out), shape (BH, Sq)) —
     ring attention hoists it out of its per-step loop since do/out are
     loop-invariant there."""
+    return _fa_backward_jit(causal, sm_scale, block_q, block_k, res, do,
+                            delta, jax.default_backend() != "tpu")
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 7))
+def _fa_backward_jit(causal, sm_scale, block_q, block_k, res, do, delta,
+                     interpret):
     q, k, v, out, lse = res
     bh, seq_q, d = q.shape
     seq_k = k.shape[1]
@@ -380,8 +664,6 @@ def _fa_backward_pallas(causal, sm_scale, block_q, block_k, res, do,
 
     common = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
                   block_k=block_k, seq_q=seq_q, seq_k=seq_k)
-    interp = jax.default_backend() != "tpu"
-
     # per-row vectors cross the boundary lane-broadcast (see _LSE_LANES)
     lse = jnp.broadcast_to(lse[..., None], lse.shape + (_LSE_LANES,))
     delta = jnp.broadcast_to(delta[..., None],
@@ -404,10 +686,17 @@ def _fa_backward_pallas(causal, sm_scale, block_q, block_k, res, do,
                          lambda b, x, y: (b, sel_q(x, y), 0)),
         ]
 
+    # a dead step of a causal grid fetches nothing: its index maps stay
+    # on the row's nearest live block
+    first_q = functools.partial(_first_live_q, causal=causal,
+                                block_q=block_q, block_k=block_k, nq=nq)
+    last_k = functools.partial(_last_live_k, causal=causal,
+                               block_q=block_q, block_k=block_k)
+
     dk, dv = pl.pallas_call(
         functools.partial(_fa_bwd_dkdv_kernel, **common),
         grid=(bh, nk, nq),            # q innermost: dk/dv scratch lives
-        in_specs=qi_kj(lambda j, i: i, lambda j, i: j),
+        in_specs=qi_kj(first_q, lambda j, i: j),
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
@@ -420,20 +709,20 @@ def _fa_backward_pallas(causal, sm_scale, block_q, block_k, res, do,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        interpret=interp,
+        interpret=interpret,
         name="mxtpu_flash_dkv",
     )(q, k, v, do, lse, delta)
 
     dq = pl.pallas_call(
         functools.partial(_fa_bwd_dq_kernel, **common),
         grid=(bh, nq, nk),            # k innermost: dq scratch lives
-        in_specs=qi_kj(lambda i, j: i, lambda i, j: j),
+        in_specs=qi_kj(lambda i, j: i, last_k),
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)],
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interp,
+        interpret=interpret,
         name="mxtpu_flash_dq",
     )(q, k, v, do, lse, delta)[0]
 
@@ -476,11 +765,14 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, res, do):
 _flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _flash_block_default(which, fallback=512):
+def _flash_block_default(which):
     """Parse one MXNET_TPU_FLASH_BLOCK_Q/_K override (invalid/non-
-    positive values fall back).  Only consulted when the env override
+    positive values fall back to the registrant's default).  Only
+    consulted when the env override
     is actually set — the default path resolves block sizes through the
     kernel registry (``_resolve_flash_blocks``), once per shape."""
+    fallback = _kernels.get_kernel("flash_attention").default_config[
+        f"block_{which.lower()}"]
     try:
         v = int(os.environ.get(f"MXNET_TPU_FLASH_BLOCK_{which}",
                                fallback))
@@ -548,11 +840,14 @@ def _flash_make_args(case):
 
 
 _kernels.register_kernel(_kernels.KernelSpec(
-    "flash_attention", version=1,
+    "flash_attention", version=2,       # 2: the live-tile schedule
     run=_flash_kernel_run, fallback=_flash_kernel_fallback,
-    config_space={"block_q": (128, 256, 512),
-                  "block_k": (128, 256, 512)},
-    default_config={"block_q": 512, "block_k": 512},
+    config_space={"block_q": (128, 256, 512, 1024),
+                  "block_k": (128, 256, 512, 1024)},
+    # swept on a v5e at (64, 1024, 64) bfloat16 causal under the
+    # schedule (PERF.md, PR 34); a call shorter than a block runs one
+    # block of its own length
+    default_config={"block_q": 1024, "block_k": 1024},
     signature=_flash_signature, make_args=_flash_make_args,
     tune_grid=({"bh": 4, "sq": 128, "sk": 128, "d": 64, "causal": False},
                {"bh": 2, "sq": 256, "sk": 256, "d": 64, "causal": True}),

@@ -79,6 +79,9 @@ def capture_specs(monkeypatch):
 
     import mxnet_tpu.ops.attention as att
     monkeypatch.setattr(att.pl, "pallas_call", spy)
+    # the kernels' wrappers are jitted: a trace kept from an earlier test
+    # of the same shapes would never reach the spy
+    jax.clear_caches()
     return calls
 
 

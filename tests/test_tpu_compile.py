@@ -74,6 +74,39 @@ def test_flash_attention_compiles(v5e, with_bwd):
     assert text.count("tpu_custom_call") >= (3 if with_bwd else 1)
 
 
+@pytest.mark.parametrize("with_bwd", [False, True], ids=["fwd", "fwd_bwd"])
+def test_flash_attention_compiles_at_the_training_cell(v5e, with_bwd):
+    """``gpt2_train``: 4 x 16 heads, 1024 positions, heads of 64,
+    bfloat16, causal, at the blocks the registry resolves by default.
+    The kernels' bodies are unrolled over the live tiles of a block
+    (ops/attention.py, "The schedule"); each still compiles to ONE Mosaic
+    call, under the name ``chipbench/layer_metrics/flash_*_time_share``
+    match."""
+    import json
+    import re
+    from mxnet_tpu import kernels
+    from mxnet_tpu.ops.attention import _flash_attention
+    cfg = kernels.get_kernel("flash_attention").default_config
+
+    def loss(q, k, v):
+        out = _flash_attention(q, k, v, True, D ** -0.5, cfg["block_q"],
+                               cfg["block_k"])
+        return out.astype(jnp.float32).sum()
+
+    qkv = ((64, 1024, D), "bfloat16")
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)) if with_bwd else loss,
+                    v5e, qkv, qkv, qkv)
+    calls = re.findall(r'^\s*(?:ROOT )?%(\S+) = .*custom_call_target='
+                       r'"tpu_custom_call"', text, re.M)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for kernel in ("fwd", "dkv", "dq") if with_bwd else ("fwd",):
+        with open(os.path.join(root, "chipbench", "layer_metrics",
+                               f"flash_{kernel}_time_share.json")) as f:
+            match = json.load(f)["args"]["match"]
+        assert sum(match in c for c in calls) == 1, (match, calls)
+    assert len(calls) == (3 if with_bwd else 1), calls
+
+
 @pytest.mark.parametrize("page_size", [16, 128])
 def test_paged_attention_compiles(v5e, page_size):
     from mxnet_tpu.ops.paged_attention import _paged_attention_pallas
