@@ -35,14 +35,23 @@ and every row runs against it.  ``R == 1`` is multi-head attention,
 one row.
 
 What is done with a block comes in two bodies under the one walker,
-chosen by the head's width, a shape.  Heads narrower than a lane tile
-stay folded into the lane axis (:func:`_folded_body`): a ``(block_k,
-Hkv*D)`` tile is multiplied on the vector unit once per row, and 0/1
-matmuls reduce and broadcast by head.  Heads of a whole number of lane
-tiles (``D % 128 == 0``, :func:`_lanes_body`): a KV head's ``D`` lanes
-are an aligned slice of the block, so the scores of its ``R`` query
-heads are one small matmul against that slice and the values another,
-on the MXU in the pool's dtype.
+chosen by the heads' shape.  Multi-head attention with heads narrower
+than a lane tile stays folded into the lane axis
+(:func:`_folded_body`): a ``(block_k, Hkv*D)`` tile is multiplied on
+the vector unit once per row, and 0/1 matmuls reduce and broadcast by
+head.  Heads of a whole number of lane tiles (``D % 128 == 0``,
+:func:`_lanes_body`): a KV head's ``D`` lanes are an aligned slice of
+the block, so the scores of its ``R`` query heads are one small matmul
+against that slice and the values another, on the MXU in the pool's
+dtype.  GROUPED-QUERY heads narrower than a tile (``R > 1``, ``128 %
+D == 0``) take the same body with the ``128 // D`` KV heads of a lane
+tile PACKED as one: every query head is a row of its tile with zeros in
+its neighbours' lanes, so a row's scores are its own KV head's and
+nothing else, and of a row's ``128`` output lanes its own ``D`` are
+kept (:func:`_pack_queries`, :func:`_unpack_outputs`).  At 4 query
+heads over a KV head of 64 the tile's 8 rows are exactly a sublane
+tile, where the folded form multiplies every block four times on the
+vector unit (PERF.md section 6, PR 35).
 
 A copy moves whole lane tiles: on a TPU a pool whose ``Hkv*D`` is no
 multiple of 128 is gathered by XLA instead (the oracle below).
@@ -364,6 +373,37 @@ def _block_rows(block_k, page_size, pages):
     return page_size if block_k % 8 else block_k
 
 
+def packs_heads(hq: int, h: int, d: int) -> bool:
+    """Whether ``hq`` query heads over ``h`` K/V heads of ``d`` lanes
+    are packed: grouped-query heads that share a lane tile, whole tiles
+    of them."""
+    return hq > h and d < 128 and 128 % d == 0 and h % (128 // d) == 0
+
+
+def _pack_queries(q, h: int, rep: int, d: int):
+    """Grouped-query heads narrower than a lane tile, ``pack = 128 //
+    d`` KV heads a tile: ``q (slots, h * rep, d)`` as ``(slots, pack *
+    rep, h * d)``, row ``i * rep + r`` holding, in every tile ``t``, the
+    query head ``(t * pack + i) * rep + r`` in the lanes of KV head
+    ``t * pack + i`` and zeros in the tile's other lanes."""
+    pack = 128 // d
+    eye = jnp.eye(pack, dtype=q.dtype)
+    qh = q.reshape(q.shape[0], h // pack, pack, rep, d)
+    return jnp.einsum("stird,ij->sirtjd", qh, eye).reshape(
+        q.shape[0], pack * rep, h * d)
+
+
+def _unpack_outputs(out, h: int, rep: int, d: int):
+    """:func:`_pack_queries` undone for the outputs ``(slots, pack *
+    rep, h * d)``: of a row's 128 lanes a tile, the ``d`` of its own KV
+    head: ``(slots, h * rep, d)``."""
+    pack = 128 // d
+    eye = jnp.eye(pack, dtype=out.dtype)
+    oh = out.reshape(out.shape[0], pack, rep, h // pack, pack, d)
+    return jnp.einsum("sirtjd,ij->stird", oh, eye).reshape(
+        out.shape[0], h * rep, d)
+
+
 def _paged_attention_pallas(q, k_pool, v_pool, tables, lengths,
                             sm_scale, block_k):
     s_, hq, d = q.shape
@@ -380,15 +420,22 @@ def _paged_attention_pallas(q, k_pool, v_pool, tables, lengths,
         return paged_attention_reference(q, k_pool, v_pool, tables,
                                          lengths, sm_scale=sm_scale)
     block_k = _block_rows(block_k, page_size, p_)
-    # query head g*rep + r -> row r, KV head g's lanes
-    q = q.reshape(s_, h, rep, d).swapaxes(1, 2).reshape(s_, rep, hd)
-    if d % 128 == 0:
-        rows = -(-rep // 8) * 8
-        q = jnp.pad(q, ((0, 0), (0, rows - rep), (0, 0)))
+    packed = packs_heads(hq, h, d)
+    used = rep * (128 // d) if packed else rep
+    if packed:
+        q = _pack_queries(q, h, rep, d)
+    else:
+        # query head g*rep + r -> row r, KV head g's lanes
+        q = q.reshape(s_, h, rep, d).swapaxes(1, 2).reshape(s_, rep, hd)
+    if packed or d % 128 == 0:
+        # what the body takes for a head: a KV head, or a packed tile
+        heads, width = (hd // 128, 128) if packed else (h, d)
+        rows = -(-used // 8) * 8
+        q = jnp.pad(q, ((0, 0), (0, rows - used), (0, 0)))
         body = functools.partial(_lanes_body, sm_scale=float(sm_scale),
-                                 heads=h, d=d)
+                                 heads=heads, d=width)
         consts, const_specs = (), []
-        stats = (h, rows, 128)
+        stats = (heads, rows, 128)
     else:
         rows = rep
         body = functools.partial(_folded_body, sm_scale=float(sm_scale),
@@ -426,6 +473,8 @@ def _paged_attention_pallas(q, k_pool, v_pool, tables, lengths,
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32), q,
       k_pool.reshape(num_pages, page_size, hd),
       v_pool.reshape(num_pages, page_size, hd), *consts)
+    if packed:
+        return _unpack_outputs(out[:, :used], h, rep, d)
     return out[:, :rep].reshape(s_, rep, h, d).swapaxes(1, 2).reshape(
         s_, hq, d)
 

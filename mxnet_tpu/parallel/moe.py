@@ -16,7 +16,11 @@ families (:func:`route_topk`, :func:`held_experts`): a chip that holds
 ``held`` of the layer's experts routes every row over ALL of them at the
 published top-k and computes the part of the result its own experts
 give.  No capacity, no dropped token, no exchange: what the absent
-experts would add is another chip's to compute.
+experts would add is another chip's to compute.  A chip that holds the
+WHOLE layer keeps its experts stacked, three arrays a layer
+(:func:`stacked_experts`): the same sum and the same time a step as the
+loop of 2-D products, a tenth of its compile time (PERF.md section 6,
+PR 35).
 """
 from __future__ import annotations
 
@@ -30,7 +34,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from .. import telemetry
 
 __all__ = ["switch_moe", "moe_expert_sharding", "route_topk",
-           "held_experts"]
+           "held_experts", "stacked_experts"]
 
 
 def switch_moe(x, gate_w, w1, b1, w2, b2, capacity_factor: float = 1.25,
@@ -110,11 +114,17 @@ def moe_expert_sharding(mesh: Mesh, axis_name: str = "ep"):
 # -- dropless top-k over a share of the experts ---------------------------------
 
 def route_topk(scores, top_k: int, *, n_group: int = 1, topk_group: int = 1,
-               normalize: bool = True, scale: float = 1.0):
+               normalize: bool = True, scale: float = 1.0, bias=None,
+               eps: float = 1e-20):
     """Select ``top_k`` experts a row from ``scores (rows, experts)``
     (float32, already through their sigmoid): ``(index (rows, top_k),
     weight (rows, top_k))``, the weight an expert's own score, divided
-    by the selected scores' sum (``normalize``) and times ``scale``.
+    by the selected scores' sum plus ``eps`` (``normalize``) and times
+    ``scale``.
+
+    ``bias (experts,)`` is a SELECTION bias (the load-balancing buffer
+    of the families that train without an auxiliary loss): the experts
+    are chosen by ``scores + bias``, and weighed by ``scores`` alone.
 
     ``n_group > 1`` is the family's group-limited form of the same
     function: the experts in ``n_group`` equal groups, a group scored by
@@ -122,18 +132,18 @@ def route_topk(scores, top_k: int, *, n_group: int = 1, topk_group: int = 1,
     kept and the selection made inside them.  ``n_group == 1`` is the
     plain top-k over all experts."""
     rows, experts = scores.shape
-    pick = scores
+    pick = scores if bias is None else scores + bias
     if n_group > 1:
         per = experts // n_group
-        grouped = scores.reshape(rows, n_group, per)
+        grouped = pick.reshape(rows, n_group, per)
         rank = jax.lax.top_k(grouped, 2)[0].sum(axis=-1)    # (rows, groups)
         kept = jax.lax.top_k(rank, topk_group)[1]           # (rows, kept)
         open_ = (kept[:, :, None] == jnp.arange(n_group)).any(axis=1)
-        pick = jnp.where(jnp.repeat(open_, per, axis=1), scores, -1.0)
+        pick = jnp.where(jnp.repeat(open_, per, axis=1), pick, -jnp.inf)
     index = jax.lax.top_k(pick, top_k)[1]
     weight = jnp.take_along_axis(scores, index, axis=1)
     if normalize:
-        weight = weight / (weight.sum(axis=-1, keepdims=True) + 1e-20)
+        weight = weight / (weight.sum(axis=-1, keepdims=True) + eps)
     return index, weight * scale
 
 
@@ -167,9 +177,44 @@ def held_experts(h, index, weight, experts, first: int, valid=None):
         y = y + w_j[:, None] * jnp.dot(
             a, w_down, preferred_element_type=jnp.float32)
         got.append((here.any(axis=-1) & live).sum())
-    got = jnp.stack(got).astype(jnp.float32)                # (held,)
-    counters = {"local_pairs": got.sum(),
-                "pairs": live.sum().astype(jnp.float32) * index.shape[1],
-                "rows_mean": got.mean(), "rows_max": got.max(),
-                "idle": (got == 0).sum().astype(jnp.float32)}
-    return y, counters
+    return y, _counters(jnp.stack(got), live, index.shape[1])
+
+
+def _counters(got, live, top_k: int) -> dict:
+    """The experts' counters from ``got (experts,)``, the live rows each
+    expert held here was given."""
+    got = got.astype(jnp.float32)
+    return {"local_pairs": got.sum(),
+            "pairs": live.sum().astype(jnp.float32) * top_k,
+            "rows_mean": got.mean(), "rows_max": got.max(),
+            "idle": (got == 0).sum().astype(jnp.float32)}
+
+
+def stacked_experts(h, index, weight, w_gate, w_up, w_down, valid=None):
+    """:func:`held_experts` for a layer held WHOLE, its experts stacked:
+    ``w_gate``/``w_up (experts, hidden, width)``, ``w_down (experts,
+    width, hidden)``, expert ``e`` at ``e``.  ``(y (rows, hidden)
+    float32, counters)``, the same sum and the same counters.
+
+    Every expert is computed for every row, as there, in three batched
+    products and not ``3 x experts`` of two dimensions: on the chip a
+    step takes the same time (the weights' stream bounds both to 256
+    rows) and an executable compiles in a tenth of it, which at 32
+    experts in 14 layers is what a start pays (PERF.md section 6, PR
+    35; rows sorted by expert through ``lax.ragged_dot`` took twice the
+    time).  A row's routing weight, 0 where it was not routed to the
+    expert, scales the gated activation in float32 before its one
+    rounding to the weights' dtype; the down-projection sums over
+    experts and width at once, in float32."""
+    rows, experts = h.shape[0], w_gate.shape[0]
+    live = jnp.ones((rows,), bool) if valid is None else valid
+    here = index[:, :, None] == jnp.arange(experts)       # (rows, top_k, e)
+    w_e = jnp.where(here, weight[:, :, None], 0.0).sum(axis=1)  # (rows, e)
+    gate = jnp.einsum("rd,edf->erf", h, w_gate)
+    up = jnp.einsum("rd,edf->erf", h, w_up)
+    a = (jax.nn.silu(gate).astype(jnp.float32) * up.astype(jnp.float32)
+         * w_e.T[:, :, None]).astype(h.dtype)
+    y = jnp.einsum("erf,efd->rd", a, w_down,
+                   preferred_element_type=jnp.float32)
+    got = (here.any(axis=1) & live[:, None]).sum(axis=0)    # (experts,)
+    return y, _counters(got, live, index.shape[1])

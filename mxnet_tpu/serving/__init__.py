@@ -21,11 +21,11 @@ from .engine import (InferenceEngine, BadRequestError, QueueFullError,
 from .batcher import DynamicBatcher
 from .server import ServingServer
 from . import decode
-from .decode import (AXK1, DecodeEngine, DecodeModel, DecodeScheduler,
+from .decode import (AXK1, LFM2, DecodeEngine, DecodeModel, DecodeScheduler,
                      FalconH1, OutOfPagesError, PagedKVCache)
 
 __all__ = ["InferenceEngine", "DynamicBatcher", "ServingServer",
            "BadRequestError", "QueueFullError", "RequestTimeoutError",
            "ServingClosedError", "serving_enabled", "slo", "decode",
            "DecodeEngine", "DecodeModel", "DecodeScheduler", "FalconH1",
-           "AXK1", "OutOfPagesError", "PagedKVCache"]
+           "AXK1", "LFM2", "OutOfPagesError", "PagedKVCache"]

@@ -12,6 +12,9 @@ cache with optional speculative decode.
   query attention beside Mamba-2 heads, with per-slot recurrent state;
 - :mod:`axk1` — a third: latent attention over a latent page, and a
   share of sigmoid-routed experts beside a shared one;
+- :mod:`lfm2` — a fourth: layers of different kinds (a gated short
+  convolution with a per-slot tail, or grouped-query attention over
+  pages) under a cache laid out by layer, and a routed layer held whole;
 - :mod:`scheduler` — the continuous batcher (``DecodeScheduler``):
   per-step admission/eviction, chunked prefill, speculative accept.
 
@@ -22,8 +25,9 @@ from .engine import DecodeEngine, DecodePlaneModel
 from .decode_model import DecodeModel
 from .falcon_h1 import FalconH1
 from .axk1 import AXK1
+from .lfm2 import LFM2
 from .scheduler import DecodeScheduler
 
 __all__ = ["PageAllocator", "PagedKVCache", "OutOfPagesError",
-           "DecodePlaneModel", "DecodeModel", "FalconH1", "AXK1", "DecodeEngine",
-           "DecodeScheduler"]
+           "DecodePlaneModel", "DecodeModel", "FalconH1", "AXK1", "LFM2",
+           "DecodeEngine", "DecodeScheduler"]

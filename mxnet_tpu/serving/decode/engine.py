@@ -30,10 +30,11 @@ use and reused forever (the fixed-shape-executable invariant):
 - ``state_reset`` — a model's recurrent state zeroed for one slot.
 
 The engine is handed its model (:class:`DecodePlaneModel`; the target
-and the draft alike): the parameters as a pytree, the kind of paged
-buffers its layers keep (K and V by the geometry of its K/V heads, or a
-latent page by its row's lanes), the kinds of per-slot recurrent state
-its layers keep beside them, and the traced cores.  A decode core may
+and the draft alike): the parameters as a pytree, its cache BY LAYER
+(which paged buffers a layer keeps: K and V by the geometry of its K/V
+heads, a latent page by its row's lanes, or none; and which kinds of
+per-slot recurrent state it keeps beside them, or none), and the traced
+cores.  A decode core may
 hand back, beside its tokens, a small dict of scalar counters (an
 expert layer's routing, say): the engine knows them by name only, reads
 them in the transfer that reads the turn's tokens, and keeps their last
@@ -42,7 +43,8 @@ and write, the executables and their donation; it knows nothing of a
 layer, and nothing of how a token's K/V reaches a pool or a query
 attends over it: that is ``paged_kv``'s, under it the
 ``paged_attention`` and ``rope`` kernel registrants'.  The models are
-``decode_model.DecodeModel``, ``falcon_h1.FalconH1`` and ``axk1.AXK1``.  A model with
+``decode_model.DecodeModel``, ``falcon_h1.FalconH1``, ``axk1.AXK1`` and
+``lfm2.LFM2``.  A model with
 recurrent state cannot be a speculation's target: a rejected draft
 would need the state from before it, and nothing snapshots it.
 """
@@ -61,7 +63,7 @@ import jax.numpy as jnp
 
 from ... import telemetry, tracing
 from ...log import get_logger
-from .paged_kv import PagedKVCache
+from .paged_kv import PagedKVCache, uniform_layout
 
 __all__ = ["DecodePlaneModel", "DecodeEngine"]
 
@@ -85,14 +87,18 @@ class DecodePlaneModel:
     """What :class:`DecodeEngine` asks of a model.
 
     Attributes: ``params`` (a pytree of device arrays, the first
-    argument of every executable), ``vocab_size``, ``n_layers``,
-    ``page_widths``: the lanes of a row of each paged buffer a layer
-    keeps, ``(num_pages, page_size, lanes)`` each (by default K and V,
-    ``kv_heads * head_dim`` lanes; a model with a latent page gives its
-    one width), and ``state_spec``: the kinds of per-slot recurrent
-    state every layer keeps beside them, ``(name, shape of one slot,
-    dtype)`` each, in the order the layer's buffers follow the paged
-    ones in ``pool[layer]``.  Empty: none.
+    argument of every executable), ``vocab_size``, ``n_layers``, and
+    ``cache_layout``: what each layer keeps in the cache,
+    ``(page_widths, state_spec)`` a layer.  ``page_widths`` are the
+    lanes of a row of each paged buffer the layer keeps, ``(num_pages,
+    page_size, lanes)`` each (K and V: two of ``kv_heads * head_dim``
+    lanes; a latent page: its one width; a layer that attends over no
+    cache: none, and it is given no page memory); ``state_spec`` the
+    kinds of per-slot recurrent state it keeps beside them, ``(name,
+    shape of one slot, dtype)`` each, in the order the layer's buffers
+    follow the paged ones in ``pool[layer]``.  Empty: none.  A model
+    whose layers are all of one kind states ``page_widths`` and
+    ``state_spec`` once and inherits the layout that repeats them.
 
     The traced cores take the cache's ``pool`` and return its
     successor; each buffer has one writer and no reader of its old
@@ -106,6 +112,11 @@ class DecodePlaneModel:
     @property
     def page_widths(self) -> tuple:
         return (self.kv_heads * self.head_dim,) * 2
+
+    @property
+    def cache_layout(self) -> tuple:
+        return uniform_layout(self.n_layers, self.page_widths,
+                              self.state_spec)
 
     def fingerprint(self) -> tuple:
         """Everything the cores bake into an executable besides the
@@ -249,11 +260,12 @@ class DecodeEngine:
                  prefill_floor: int = 16):
         self.model = model
         self.draft = draft_model
-        if model.state_spec:
+        kinds = {n for _, spec in model.cache_layout for n, _, _ in spec}
+        if kinds:
             if draft_model is not None or spec_k:
                 raise ValueError(
                     f"{type(model).__name__} keeps recurrent state "
-                    f"({', '.join(n for n, _, _ in model.state_spec)}) that "
+                    f"({', '.join(sorted(kinds))}) that "
                     f"a rejected draft token would already have advanced; "
                     f"speculative decode (draft_model / spec_k) needs state "
                     f"snapshots, which the decode plane does not have")
@@ -275,18 +287,15 @@ class DecodeEngine:
             if draft_model.vocab_size != model.vocab_size:
                 raise ValueError("draft/target vocab sizes differ")
         self.cache = PagedKVCache(
-            layers=model.n_layers, num_pages=self.num_pages,
-            page_size=self.page_size, page_widths=model.page_widths,
-            max_slots=self.max_slots, pages_per_slot=pages_per_slot,
-            dtype=model.params["embed"].dtype,
-            state_spec=model.state_spec)
+            layout=model.cache_layout, num_pages=self.num_pages,
+            page_size=self.page_size, max_slots=self.max_slots,
+            pages_per_slot=pages_per_slot,
+            dtype=model.params["embed"].dtype)
         self.draft_cache = None
         if draft_model is not None:
             self.draft_cache = PagedKVCache(
-                layers=draft_model.n_layers, num_pages=self.num_pages,
-                page_size=self.page_size,
-                page_widths=draft_model.page_widths,
-                max_slots=self.max_slots,
+                layout=draft_model.cache_layout, num_pages=self.num_pages,
+                page_size=self.page_size, max_slots=self.max_slots,
                 pages_per_slot=self.cache.pages_per_slot,
                 dtype=draft_model.params["embed"].dtype)
         self._exec: Dict[str, Any] = {}
@@ -414,18 +423,16 @@ class DecodeEngine:
 
     def _state(self):
         """The recurrent-state buffers of every layer, without the
-        paged ones."""
-        paged = len(self.cache.page_widths)
-        return tuple(layer[paged:] for layer in self.cache.pool)
+        paged ones (a layer that keeps none: an empty tuple)."""
+        return self.cache.split()[1]
 
     def _reset_state(self, slot: int) -> None:
         """Zero ``slot``'s rows of every state buffer, in place."""
+        paged, state = self.cache.split()
         state = self._call(
-            "state_reset", (self._state(), jnp.asarray(slot, jnp.int32)),
+            "state_reset", (state, jnp.asarray(slot, jnp.int32)),
             donate=(0,))
-        paged = len(self.cache.page_widths)
-        self.cache.pool = tuple(layer[:paged] + st for layer, st
-                                in zip(self.cache.pool, state))
+        self.cache.pool = tuple(pg + st for pg, st in zip(paged, state))
 
     def _tables(self, cache) -> jnp.ndarray:
         return jnp.asarray(cache.tables, jnp.int32)
@@ -468,7 +475,7 @@ class DecodeEngine:
         args = (mdl.params, cache.pool, jnp.asarray(padded),
                 jnp.asarray(start, jnp.int32), jnp.asarray(n, jnp.int32),
                 jnp.asarray(cache.tables[slot], jnp.int32))
-        if mdl.state_spec:
+        if cache.state_layers:
             args += (jnp.asarray(slot, jnp.int32),)
         return args
 
@@ -504,7 +511,7 @@ class DecodeEngine:
                 onp.zeros((3 + self.cache.pages_per_slot,), onp.int32)),
                 donate=(0,))
             keys += ["decode", "state_edit"]
-        if self.model.state_spec:
+        if self.cache.state_layers:
             self._get_exec("state_reset",
                            (self._state(), jnp.asarray(0, jnp.int32)),
                            donate=(0,))
@@ -619,7 +626,7 @@ class DecodeEngine:
 
     def acquire_slot(self, slot: int, tokens: int) -> None:
         self.cache.acquire(slot, tokens)
-        if self.cache.state_spec:
+        if self.cache.state_layers:
             # whoever held the slot last left its state behind
             with tracing.span("decode.state_reset", slot=slot):
                 self._reset_state(slot)
@@ -655,6 +662,9 @@ class DecodeEngine:
                 "spec_k": self.spec_k if self.spec_enabled else 0,
                 "page_bytes": self.cache.page_bytes,
                 "state_bytes": self.cache.state_bytes,
+                # layers that hold pages, and layers that hold state
+                "page_layers": self.cache.page_layers,
+                "state_layers": self.cache.state_layers,
                 "state_slots_live": self.cache.state_slots_live(),
                 "state_resets": self.cache.state_resets,
                 "kv_live_share": (self._kv_live_sum / self._decode_steps

@@ -65,7 +65,8 @@ from jax import lax
 from ...ops.ssm import ssm_chunk_scan, ssm_scan_reference, ssm_update
 from .decode_model import rms_norm
 from .engine import DecodePlaneModel
-from .paged_kv import chunk_attention, dense_attention, slot_attention
+from .paged_kv import (chunk_attention, chunk_conv, dense_attention,
+                       dense_conv, slot_attention, slot_conv)
 
 __all__ = ["FalconH1"]
 
@@ -321,9 +322,8 @@ class FalconH1(DecodePlaneModel):
         for (kbuf, vbuf, sbuf, cbuf), lp in zip(pool, params["layers"]):
 
             def mix(xbc, dt, sbuf=sbuf, cbuf=cbuf, lp=lp):
-                taps = jnp.concatenate([cbuf, xbc[:, None, :]], axis=1)
-                conv = (taps * lp["conv_w"]).sum(axis=1) + lp["conv_b"]
-                cbuf = jnp.where(active[:, None, None], taps[:, 1:], cbuf)
+                conv, cbuf = slot_conv(cbuf, xbc, lp["conv_w"], active,
+                                       lp["conv_b"])
                 xs, b, c = self._split(jax.nn.silu(conv))
                 sbuf, y = ssm_update(sbuf, xs, dt, -jnp.exp(lp["a_log"]),
                                      b, c, lp["d"], active)
@@ -354,16 +354,8 @@ class FalconH1(DecodePlaneModel):
         for (kbuf, vbuf, sbuf, cbuf), lp in zip(pool, params["layers"]):
 
             def mix(xbc, dt, sbuf=sbuf, cbuf=cbuf, lp=lp):
-                # the slot's tail, then the chunk: the convolution sees
-                # the tokens before the chunk, and the new tail is the
-                # last rows before the padding, old tail included when
-                # the chunk is shorter than it
-                taps = jnp.concatenate([cbuf[slot], xbc], axis=0)
-                n_tail = self.d_conv - 1
-                conv = sum(taps[j:j + b_] * lp["conv_w"][j]
-                           for j in range(self.d_conv)) + lp["conv_b"]
-                tail = lax.dynamic_slice_in_dim(taps, chunk_len, n_tail, 0)
-                cbuf = lax.dynamic_update_index_in_dim(cbuf, tail, slot, 0)
+                conv, cbuf = chunk_conv(cbuf, xbc, lp["conv_w"], slot,
+                                        chunk_len, lp["conv_b"])
                 xs, b, c = self._split(jax.nn.silu(conv))
                 # a padded row neither decays the state nor adds to it
                 dt = jnp.where(valid[:, None], dt, 0.0)
@@ -391,11 +383,7 @@ class FalconH1(DecodePlaneModel):
         for lp in params["layers"]:
 
             def mix(xbc, dt, lp=lp):
-                taps = jnp.concatenate(
-                    [jnp.zeros((self.d_conv - 1, self.conv_width),
-                               xbc.dtype), xbc], axis=0)
-                conv = sum(taps[j:j + t_] * lp["conv_w"][j]
-                           for j in range(self.d_conv)) + lp["conv_b"]
+                conv = dense_conv(xbc, lp["conv_w"], lp["conv_b"])
                 xs, b, c = self._split(jax.nn.silu(conv))
                 zero = jnp.zeros((self.ssm_heads, self.ssm_head_dim,
                                   self.d_state), jnp.float32)

@@ -1,19 +1,25 @@
 """Paged KV cache: pre-allocated device pool + host page allocator, and
 what an executable does with a pool it was handed.
 
-The device side is ONE pytree per engine, ``pool[layer] = (k, v,
-*state)``: a buffer of its own for every layer's K and for its V, each
+The device side is ONE pytree per engine, ``pool[layer] = (*paged,
+*state)``, laid out BY LAYER: the model says of each layer which paged
+buffers and which per-slot state it keeps (``layout[layer] =
+(page_widths, state_spec)``; :func:`uniform_layout` for a model whose
+layers are all of one kind), and a layer gets exactly those.  A layer
+that keeps K and V has a buffer of its own for each,
 ``(num_pages, page_size, heads * head_dim)`` (``heads`` the KV heads —
 fewer than the query heads under grouped-query attention — folded into
 the lane axis, the layout the ``paged_attention`` kernel reads without
-a relayout on the TPU), allocated once at construction and threaded,
+a relayout on the TPU); a layer that attends over nothing (a
+convolution, a recurrence) keeps no paged buffer and costs no page
+memory.  All of it is allocated once at construction and threaded,
 donated, through every compiled decode/prefill executable — sequence
 state never changes a shape.
 
-A model whose layers also carry recurrent state (a state-space
-layer's state matrix, a convolution's tail) names each kind in
-``state_spec`` and gets, after K and V, one more buffer per kind and
-layer, ``(max_slots, *shape)``: addressed by the slot itself, not
+A layer that carries recurrent state (a state-space layer's state
+matrix, a convolution's tail) names each kind in its ``state_spec``
+and gets, after its paged buffers, one more buffer per kind,
+``(max_slots, *shape)``: addressed by the slot itself, not
 through pages, its size does not grow with the sequence.  It lives
 and dies with the slot: :meth:`PagedKVCache.acquire` counts the slot
 as live (the engine zeroes its rows then, on the device),
@@ -33,13 +39,15 @@ writes for inactive slots / padded prefill rows are directed there and
 dropped by XLA (``mode="drop"``), so masking never needs a branch.
 
 A layer's paged buffers come in one of two kinds, and the model says
-which (``DecodePlaneModel.page_widths``, the lanes of a row of each):
+which (the layer's ``page_widths``, the lanes of a row of each):
 K and V, two buffers of ``heads * head_dim`` lanes, or a LATENT page,
 one buffer whose row ``[c_kv | k_rope | zeros]`` is the key of every
 query head and, in its first ``rank`` lanes, every head's value
 (multi-head latent attention; :func:`latent_width` pads the row to
 whole lane tiles, which the kernel's copies move).  Pages, tables, the
-sentinel and the allocator are the same for both.
+sentinel and the allocator are the same for both, and ONE table a slot
+serves every layer that keeps pages: a page id names the same rows of
+each of their buffers.
 
 The traced side (the second half of this module) is the only code
 besides the ``paged_attention`` kernel that knows any of the above.  A
@@ -69,15 +77,20 @@ from jax import lax
 
 from ... import telemetry
 from ...base import MXNetError
-from ...ops.paged_attention import latent_attention, paged_attention
+from ...ops.paged_attention import (latent_attention, paged_attention,
+                                    packs_heads)
 from ...ops.rope import rope, rope_reference, rope_table
 
 __all__ = ["PageAllocator", "PagedKVCache", "OutOfPagesError",
+           "uniform_layout", "slot_conv", "chunk_conv", "dense_conv",
            "slot_attention", "chunk_attention", "window_attention",
            "dense_attention", "latent_width", "latent_slot_attention",
            "latent_chunk_attention", "latent_dense_attention"]
 
 _NEG_INF = -1e30
+# a prefill chunk gathers a slot's whole table for one softmax up to
+# this many positions, and walks the slot's live pages beyond it
+_GATHER_ROWS = 2048
 
 
 class OutOfPagesError(MXNetError):
@@ -114,58 +127,80 @@ class PageAllocator:
             self._free.extend(pages)
 
 
+def uniform_layout(layers: int, page_widths: Sequence[int],
+                   state_spec: Sequence[Tuple[str, tuple, str]] = ()):
+    """The layout of a model whose ``layers`` layers all keep the same
+    paged buffers and the same per-slot state."""
+    return ((tuple(page_widths), tuple(state_spec)),) * int(layers)
+
+
 class PagedKVCache:
     """One engine's KV state: device pool + slot page tables.
 
     ``pool`` is the device state, a tuple over layers of ``(*paged,
     *state)`` buffers; an executable that was given it returns its
-    successor, which the engine stores back.  ``page_widths`` gives the
-    lanes of a row of each paged buffer of a layer: K and V are two of
-    ``kv_heads * head_dim``, a latent page is one.  ``state_spec`` lists
-    the kinds of per-slot recurrent state a layer holds, ``(name, shape
-    of one slot, dtype)`` each; empty for a model that has none.
+    successor, which the engine stores back.  ``layout`` says what each
+    layer keeps, ``(page_widths, state_spec)`` a layer: ``page_widths``
+    the lanes of a row of each paged buffer (K and V are two of
+    ``kv_heads * head_dim``, a latent page is one, a layer that attends
+    over no cache has none), ``state_spec`` the kinds of per-slot
+    recurrent state, ``(name, shape of one slot, dtype)`` each (empty:
+    none).  Only what a layer names is allocated.
 
     ``pages_per_slot`` bounds a single slot's table width (the traced
     table shape); a slot's token capacity is
     ``pages_per_slot * page_size``."""
 
-    def __init__(self, *, layers: int, num_pages: int, page_size: int,
-                 max_slots: int, page_widths: Sequence[int],
-                 pages_per_slot: Optional[int] = None,
-                 dtype="float32",
-                 state_spec: Sequence[Tuple[str, tuple, str]] = ()):
-        self.layers = int(layers)
+    def __init__(self, *, layout, num_pages: int, page_size: int,
+                 max_slots: int, pages_per_slot: Optional[int] = None,
+                 dtype="float32"):
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
-        self.page_widths = tuple(int(w) for w in page_widths)
         self.max_slots = int(max_slots)
         self.pages_per_slot = int(
             pages_per_slot if pages_per_slot is not None
             else max(1, num_pages // max(1, max_slots)))
-        self.state_spec = tuple((str(n), tuple(int(d) for d in sh), str(dt))
-                                for n, sh, dt in state_spec)
+        self.layout = tuple(
+            (tuple(int(w) for w in widths),
+             tuple((str(n), tuple(int(d) for d in sh), str(dt))
+                   for n, sh, dt in spec))
+            for widths, spec in layout)
+        self.layers = len(self.layout)
         self.pool = tuple(
             tuple(jnp.zeros((self.num_pages, self.page_size, w), dtype=dtype)
-                  for w in self.page_widths)
+                  for w in widths)
             + tuple(jnp.zeros((self.max_slots,) + sh, dtype=dt)
-                    for _, sh, dt in self.state_spec)
-            for _ in range(self.layers))
+                    for _, sh, dt in spec)
+            for widths, spec in self.layout)
         self.state_resets = 0
-        paged = len(self.page_widths)
+        # how many of a layer's buffers are paged (the rest are state),
+        # and how many layers hold any of either kind
+        self.paged = tuple(len(widths) for widths, _ in self.layout)
+        self.page_layers = sum(1 for widths, _ in self.layout if widths)
+        self.state_layers = sum(1 for _, spec in self.layout if spec)
 
         def nbytes(bufs):
             return sum(buf.size * buf.dtype.itemsize for buf in bufs)
 
         # bytes on the device, all layers: the pages, and the recurrent
         # state of every slot
-        self.page_bytes = sum(nbytes(layer[:paged]) for layer in self.pool)
-        self.state_bytes = sum(nbytes(layer[paged:]) for layer in self.pool)
+        self.page_bytes = sum(nbytes(layer[:n])
+                              for layer, n in zip(self.pool, self.paged))
+        self.state_bytes = sum(nbytes(layer[n:])
+                               for layer, n in zip(self.pool, self.paged))
         self.allocator = PageAllocator(self.num_pages)
         # traced inputs: page-table rows + a scratch row of zeros for
         # freed slots (page 0 ids are fine — masked by length 0)
         self.tables = onp.zeros((self.max_slots, self.pages_per_slot),
                                 onp.int32)
         self._slot_pages: Dict[int, List[int]] = {}
+
+    def split(self):
+        """``(paged, state)`` of the pool: each a tuple over layers of
+        that layer's buffers of the one kind, empty where the layer
+        keeps none."""
+        return (tuple(layer[:n] for layer, n in zip(self.pool, self.paged)),
+                tuple(layer[n:] for layer, n in zip(self.pool, self.paged)))
 
     @property
     def slot_capacity(self) -> int:
@@ -177,7 +212,7 @@ class PagedKVCache:
 
     def state_slots_live(self) -> int:
         """Slots whose recurrent state belongs to a request."""
-        return len(self._slot_pages) if self.state_spec else 0
+        return len(self._slot_pages) if self.state_layers else 0
 
     def pages_for(self, tokens: int) -> int:
         return -(-int(tokens) // self.page_size)
@@ -199,7 +234,7 @@ class PagedKVCache:
         row[:need] = pages
         self.tables[slot] = row
         telemetry.gauge("decode.pages_used").set(self.pages_used())
-        if self.state_spec:
+        if self.state_layers:
             self.state_resets += 1
             telemetry.counter("decode.state_resets").inc()
             telemetry.gauge("decode.state_slots_live").set(
@@ -214,7 +249,7 @@ class PagedKVCache:
         self.allocator.free(pages)
         self.tables[slot] = 0
         telemetry.gauge("decode.pages_used").set(self.pages_used())
-        if self.state_spec:
+        if self.state_layers:
             telemetry.gauge("decode.state_slots_live").set(
                 self.state_slots_live())
         return len(pages)
@@ -224,6 +259,50 @@ class PagedKVCache:
 
 
 # -- the traced side -----------------------------------------------------------
+
+# A convolution's tail: per-slot state, addressed by the slot.  A causal
+# depthwise convolution of ``taps`` positions reads, beside the rows it
+# is given, the ``taps - 1`` rows before them: a layer keeps those as a
+# state buffer ``(slots, taps - 1, width)``.  ``w (taps, width)``, tap
+# ``j`` weighing the row ``taps - 1 - j`` positions back; ``bias
+# (width,)`` or None.
+
+def slot_conv(tail, x, w, active, bias=None):
+    """Decode: one row a slot, ``x (slots, width)`` behind the slot's
+    tail ``tail (slots, taps - 1, width)``: ``(the convolution's row
+    (slots, width), the tail's successor)``.  An inactive slot's tail
+    stays as it is."""
+    taps = jnp.concatenate([tail, x[:, None, :]], axis=1)
+    conv = (taps * w).sum(axis=1)
+    if bias is not None:
+        conv = conv + bias
+    return conv, jnp.where(active[:, None, None], taps[:, 1:], tail)
+
+
+def chunk_conv(tail, x, w, slot, chunk_len, bias=None):
+    """Prefill: ``x (bucket, width)`` rows of ONE slot, the first
+    ``chunk_len`` (traced) of them real, behind that slot's row of
+    ``tail``: the convolution sees the tokens before the chunk, and the
+    new tail is the last ``taps - 1`` rows before the padding, rows of
+    the old tail among them where the chunk is shorter than it."""
+    n_tail = tail.shape[1]
+    taps = jnp.concatenate([tail[slot], x], axis=0)
+    conv = sum(taps[j:j + x.shape[0]] * w[j] for j in range(n_tail + 1))
+    if bias is not None:
+        conv = conv + bias
+    last = lax.dynamic_slice_in_dim(taps, chunk_len, n_tail, 0)
+    return conv, lax.dynamic_update_index_in_dim(tail, last, slot, 0)
+
+
+def dense_conv(x, w, bias=None):
+    """The oracles': the whole sequence ``x (T, width)`` from a zero
+    tail, no state."""
+    n_tail = w.shape[0] - 1
+    taps = jnp.concatenate(
+        [jnp.zeros((n_tail, x.shape[1]), x.dtype), x], axis=0)
+    conv = sum(taps[j:j + x.shape[0]] * w[j] for j in range(n_tail + 1))
+    return conv if bias is None else conv + bias
+
 
 def _rotate_write(q, k, v, kbuf, vbuf, pos, page, offset, rope_base):
     """Rotate q and k at ``pos`` and scatter this step's K/V rows into
@@ -244,14 +323,28 @@ def _rotate_write(q, k, v, kbuf, vbuf, pos, page, offset, rope_base):
     return q, kbuf, vbuf
 
 
+# rows of a block where grouped-query heads are packed into lane tiles
+# (``ops/paged_attention.py``): eight query rows a tile make a block's
+# matmuls small beside its fixed cost.  On the chip at 32 query heads
+# over 8 K/V heads of 64, pages of 128: 0.813 / 0.736 / 0.604 ms a call
+# at blocks of 128 / 256 / 512 rows over 120 live slots of 1,036 rows,
+# 1.375 / 1.222 / 0.893 over 192 of 1,139 (PERF.md section 6, PR 35)
+_PACKED_BLOCK_ROWS = 512
+
+
 def _kernel(q, kbuf, vbuf, tables, lengths):
     """One query a slot over its ``lengths`` rows.  A page of 128 rows
     or more is walked 128 rows a block (half a page a block cost 159 us
-    a call against 103: PERF.md section 6, PR 30, finding 2); how many
-    smaller pages make a block is the kernel registry's choice (by
-    default 64 rows, four pages of 16)."""
-    return paged_attention(q, kbuf, vbuf, tables, lengths,
-                           block_k=128 if kbuf.shape[1] >= 128 else None)
+    a call against 103: PERF.md section 6, PR 30, finding 2), or
+    ``_PACKED_BLOCK_ROWS`` where the heads are packed; how many smaller
+    pages make a block is the kernel registry's choice (by default 64
+    rows, four pages of 16)."""
+    heads, d = q.shape[-2:]
+    block_k = None
+    if kbuf.shape[1] >= 128:
+        packed = packs_heads(heads, kbuf.shape[-1] // d, d)
+        block_k = _PACKED_BLOCK_ROWS if packed else 128
+    return paged_attention(q, kbuf, vbuf, tables, lengths, block_k=block_k)
 
 
 def slot_attention(pool, positions, tables, active, *, rope_base):
@@ -306,9 +399,16 @@ def chunk_attention(pool, start, chunk_len, table, bucket: int, *,
     (traced) of them a prompt's positions from ``start`` on, the rest
     padding that writes nothing; ``table (pages_per_slot,)`` is the
     slot's page row.  The chunk attends its causal prefix, earlier
-    chunks included, over the slot's gathered pages: the chunk itself
-    was just written, so one mask covers intra- and cross-chunk keys.
-    Float32 softmax; each K/V head serves its ``rep`` query heads."""
+    chunks included, over the slot's pages: the chunk itself was just
+    written, so one mask covers intra- and cross-chunk keys.  Float32
+    softmax; each K/V head serves its ``rep`` query heads.  A table of
+    up to ``_GATHER_ROWS`` positions is gathered whole for one softmax;
+    a longer one is walked a page at a time over the slot's LIVE pages
+    only (``start + chunk_len`` rows, a traced count) under an online
+    softmax: the whole-table form's scores, ``(bucket, heads, table
+    positions)`` in float32, are what a chunk's attention costs
+    whatever the prompt's length (134 MB a layer at 4,096 positions and
+    256 rows: PERF.md section 6, PR 35)."""
     num_pages, ps = pool[0][0].shape[:2]
     pos = start + jnp.arange(bucket, dtype=jnp.int32)
     valid = jnp.arange(bucket) < chunk_len
@@ -321,6 +421,10 @@ def chunk_attention(pool, start, chunk_len, table, bucket: int, *,
         (heads, hd), kvh = q.shape[1:], k.shape[1]
         q, kbuf, vbuf = _rotate_write(q, k, v, kbuf, vbuf, pos, page,
                                       offset, rope_base)
+        if rows > _GATHER_ROWS:
+            qg = q.reshape(bucket, kvh, heads // kvh, hd).astype(jnp.float32)
+            o = _walk_live_pages(qg, kbuf, vbuf, table, pos, total)
+            return o.reshape(bucket, heads, hd), (kbuf, vbuf)
         kctx = kbuf[table].reshape(rows, kvh, hd)
         vctx = vbuf[table].reshape(rows, kvh, hd)
         qg = q.reshape(bucket, kvh, heads // kvh, hd).astype(jnp.float32)
@@ -337,6 +441,36 @@ def chunk_attention(pool, start, chunk_len, table, bucket: int, *,
         return o.reshape(bucket, heads, hd), (kbuf, vbuf)
 
     return attend
+
+
+def _walk_live_pages(qg, kbuf, vbuf, table, pos, total):
+    """Causal attention of the rotated queries ``qg (bucket, kv heads,
+    rep, head_dim)`` float32 at positions ``pos`` over the first
+    ``total`` rows of ONE slot's pages, a page an iteration under an
+    online softmax: ``(bucket, kv heads, rep, head_dim)`` float32."""
+    bucket, kvh, reps, hd = qg.shape
+    ps = kbuf.shape[1]
+    sm_scale = 1.0 / (hd ** 0.5)
+
+    def one_page(i, carry):
+        m_prev, l, acc = carry
+        k = kbuf[table[i]].reshape(ps, kvh, hd).astype(jnp.float32)
+        v = vbuf[table[i]].reshape(ps, kvh, hd).astype(jnp.float32)
+        s = jnp.einsum("bgrd,kgd->bgrk", qg, k) * sm_scale
+        kpos = i * ps + lax.broadcasted_iota(jnp.int32, s.shape, 3)
+        mask = (kpos <= pos[:, None, None, None]) & (kpos < total)
+        s = jnp.where(mask, s, _NEG_INF)
+        m_cur = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        corr = jnp.exp(m_prev - m_cur)
+        p = jnp.where(mask, jnp.exp(s - m_cur), 0.0)
+        return (m_cur, l * corr + p.sum(axis=-1, keepdims=True),
+                acc * corr + jnp.einsum("bgrk,kgd->bgrd", p, v))
+
+    init = (jnp.full((bucket, kvh, reps, 1), _NEG_INF, jnp.float32),
+            jnp.zeros((bucket, kvh, reps, 1), jnp.float32),
+            jnp.zeros((bucket, kvh, reps, hd), jnp.float32))
+    _, l, acc = lax.fori_loop(0, (total + ps - 1) // ps, one_page, init)
+    return acc / jnp.where(l == 0.0, 1.0, l)
 
 
 def dense_attention(length: int, *, rope_base):

@@ -41,7 +41,8 @@ from mxnet_tpu.serving import (BadRequestError, DecodeEngine, DecodeModel,
                                RequestTimeoutError, ServingClosedError,
                                ServingServer, slo)
 from mxnet_tpu.serving.decode import OutOfPagesError
-from mxnet_tpu.serving.decode.paged_kv import PageAllocator, PagedKVCache
+from mxnet_tpu.serving.decode.paged_kv import (PageAllocator, PagedKVCache,
+                                               uniform_layout)
 
 VOCAB = 48
 REPO = pathlib.Path(__file__).resolve().parent.parent
@@ -118,8 +119,8 @@ def test_page_allocator_recycle_and_exhaustion():
 def test_paged_kv_slot_acquire_release():
     # pool deliberately smaller than max_slots * pages_per_slot so a
     # full-budget acquire can exhaust the free list
-    c = PagedKVCache(layers=2, num_pages=6, page_size=4, max_slots=2,
-                     pages_per_slot=4, page_widths=(16, 16))
+    c = PagedKVCache(layout=uniform_layout(2, (16, 16)), num_pages=6,
+                     page_size=4, max_slots=2, pages_per_slot=4)
     assert c.slot_capacity == 4 * 4     # pages_per_slot * page_size
     c.acquire(0, 9)                     # 9 tokens → 3 pages
     assert c.pages_used() == 3

@@ -439,7 +439,7 @@ def test_a_slots_next_tenant_is_dispatched_behind_the_stray_step(
 def test_decode_model_keeps_no_state_and_its_counters_read_zero():
     eng = DecodeEngine(DecodeModel(48, dim=32, n_heads=4, n_layers=2),
                        max_slots=2, num_pages=8, page_size=8)
-    assert eng.cache.state_spec == () and len(eng.cache.pool[0]) == 2
+    assert eng.cache.state_layers == 0 and len(eng.cache.pool[0]) == 2
     eng.acquire_slot(0, 8)
     st = eng.stats()
     assert (st["state_bytes"], st["state_slots_live"],

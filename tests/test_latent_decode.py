@@ -641,9 +641,9 @@ def test_a_latent_page_is_one_buffer_a_layer_and_counts_its_bytes(models):
     assert eng.cache.pool[0][0].shape == (24, 8, 128)
     assert eng.stats()["page_bytes"] == 3 * 24 * 8 * 128 * 4
     assert eng.stats()["state_bytes"] == 0
-    kv = PagedKVCache(layers=2, num_pages=6, page_size=4, max_slots=2,
-                      page_widths=(16, 16))
-    assert kv.page_widths == (16, 16) and kv.page_bytes == 2 * 2 * 6 * 4 * 64
+    kv = PagedKVCache(layout=paged_kv.uniform_layout(2, (16, 16)),
+                      num_pages=6, page_size=4, max_slots=2)
+    assert kv.paged == (2, 2) and kv.page_bytes == 2 * 2 * 6 * 4 * 64
     plain = DecodeEngine(DecodeModel(48, dim=32, n_heads=4, n_layers=2),
                          max_slots=2, num_pages=8, page_size=8)
     assert plain.stats()["counters"] == {} and plain.counters == {}

@@ -173,6 +173,27 @@ def test_paged_attention_grouped_query_compiles(v5e, block_k):
 
 
 @pytest.mark.parametrize("block_k", [128, 512])
+def test_paged_attention_packs_narrow_grouped_query_heads(v5e, block_k):
+    """``lfm2_decode_reasoning``: 32 query heads over pools of 8 KV heads
+    of 64 (512 lanes), 192 slots x 32 pages of 128: two KV heads share a
+    lane tile and their eight query heads are its eight rows, on the
+    MXU; no 0/1 head-membership operand (the folded form's) is left.  A
+    block of one page, and of four (``paged_kv._PACKED_BLOCK_ROWS``)."""
+    from mxnet_tpu.ops.paged_attention import _paged_attention_pallas
+    pool = ((3072, 128, 8 * 64), "bfloat16")
+    text = _compile(
+        lambda q, k, v, t, l: _paged_attention_pallas(
+            q, k, v, t, l, 64 ** -0.5, block_k),
+        v5e, ((192, 32, 64), "bfloat16"), pool, pool,
+        ((192, 32), "int32"), ((192,), "int32"))
+    call = [line for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(call) == 1 and "mxtpu_paged_attention" in call[0]
+    # operands: tables, lengths, the packed queries (192, 8, 512), K, V
+    assert "bf16[192,8,512]" in call[0] and "f32[8,512]" not in call[0]
+
+
+@pytest.mark.parametrize("block_k", [128, 512])
 def test_latent_attention_compiles_at_the_reasoning_cell(v5e, block_k):
     # axk1_decode_reasoning: 128 slots x 32 pages of 128 rows, 64 heads
     # over one row of 640 lanes ([512 | 64 | 64 of padding]); a block is
@@ -439,6 +460,85 @@ def test_latent_executables_update_the_cache_in_place(v5e, key, temp_mb):
         assert all("mxtpu_latent_attention" in c for c in calls)
     else:       # a chunk walks the slot's live pages in XLA
         assert not calls
+
+
+# lfm2_decode_reasoning: a cache laid out by layer.  The first 4 of its 16
+# layers, one whole period at the published widths (shapes only): two
+# dense convolution layers, a routed attention layer, a routed
+# convolution layer, all 32 experts stacked.  K and V pages in the one
+# attention layer, 3072 pages x 128 x 512 lanes bf16 (403 MB a buffer);
+# a tail of 192 slots x 2 x 2048 float32 (3.1 MB) in the other three.
+# All sixteen layers, compiled here by hand (PERF.md section 4, PR 35):
+# arguments 14.326 GB of which the cache's 3.259 GB is aliased;
+# temporaries 51.8 MB (decode), 18.4 MB (prefill b256), 17.1 MB (b32); a
+# chunk that gathered its slot's whole table of 4,096 positions for one
+# softmax kept 457.2 MB (b256).
+
+@pytest.mark.parametrize("key,temp_mb", [("decode", 64), ("prefill_b256", 32),
+                                         ("prefill_b32", 32)])
+def test_mixed_layer_executables_update_the_cache_in_place(v5e, key,
+                                                           temp_mb):
+    """Pages for the attention layer alone and the other layers' tails
+    are the arguments beside the weights; all of them are aliased to
+    the outputs whole; the temporaries hold no copy of a page buffer;
+    the decode step's attention is the named kernel, once an attention
+    layer."""
+    import json
+    import re
+    from mxnet_tpu.serving import LFM2
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chipbench")
+    with open(os.path.join(bench, "configs", "lfm2_8b_a1b.json")) as f:
+        cfg = json.load(f)
+    cfg = dict(cfg, num_hidden_layers=4, layer_types=cfg["layer_types"][:4])
+    with open(os.path.join(bench, "workloads",
+                           "lfm2_decode_reasoning.json")) as f:
+        geo = json.load(f)["engine"]
+    mdl = LFM2(cfg, abstract=True)
+    slots, pps = geo["max_slots"], geo["pages_per_slot"]
+
+    def spec(shape, dtype="bfloat16"):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype),
+                                    sharding=v5e)
+
+    params = jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype),
+                                    mdl.params)
+    # the pool as the cache builds it from the model's layout
+    pool = tuple(
+        tuple(spec((geo["num_pages"], geo["page_size"], w)) for w in widths)
+        + tuple(spec((slots,) + tuple(sh), dt) for _, sh, dt in state)
+        for widths, state in mdl.cache_layout)
+    assert [len(layer) for layer in pool] == [1, 1, 2, 1]
+    nbytes = lambda a: a.size * a.dtype.itemsize           # noqa: E731
+    page_buf, tail = nbytes(pool[2][0]), nbytes(pool[0][0])
+    assert (page_buf, tail) == (3072 * 128 * 512 * 2, 192 * 2 * 2048 * 4)
+    cache = 2 * page_buf + 3 * tail
+    weights = sum(nbytes(a) for a in jax.tree_util.tree_leaves(params))
+    if key == "decode":
+        lowered = _lower_chained_decode(mdl, params, pool,
+                                        _resident(spec, slots, pps))
+    else:
+        bucket = int(key.rsplit("b", 1)[1])
+        lowered = jax.jit(lambda *a: mdl.prefill_core(*a),
+                          donate_argnums=(1,)).lower(
+            params, pool, spec((bucket,), "int32"), spec((), "int32"),
+            spec((), "int32"), spec((pps,), "int32"), spec((), "int32"))
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    # arguments: the weights, the cache, and a few small arrays
+    assert 0 <= mem.argument_size_in_bytes - weights - cache < 2 ** 16
+    state = mem.alias_size_in_bytes - cache
+    assert 0 <= state < 2 ** 16 and (state > 0) == (key == "decode")
+    assert mem.temp_size_in_bytes < temp_mb * 1e6, mem.temp_size_in_bytes
+    assert mem.temp_size_in_bytes < page_buf
+    calls = re.findall(r'^\s*(?:ROOT )?%(\S+) = .*custom_call_target='
+                       r'"tpu_custom_call"', compiled.as_text(), re.M)
+    assert all("mxtpu_" in c for c in calls), calls
+    # the one attention layer: rope on q and on k, then the paged
+    # kernel in the decode step; a chunk gathers its pages in XLA
+    assert sum("mxtpu_paged_attention" in c for c in calls) \
+        == (1 if key == "decode" else 0)
+    assert sum("mxtpu_rope" in c for c in calls) == 2
 
 
 # -- the chained decode executable at the two decode cells' whole geometry ----
