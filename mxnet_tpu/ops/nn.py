@@ -10,6 +10,7 @@ matmuls; everything is rank-polymorphic over 1D/2D/3D spatial dims
 """
 from __future__ import annotations
 
+import functools
 import os
 from typing import Sequence, Tuple
 
@@ -373,6 +374,65 @@ def _softmax_output(data, label, *, grad_scale=1.0, ignore_label=-1.0,
 
 # -- normalization (parity: batch_norm.cc, layer_norm.cc, group_norm.cc) ---
 
+def _channel_shape(x, axis):
+    shape = [1] * x.ndim
+    shape[axis] = -1
+    return shape
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _batch_norm_train(x, gamma, beta, eps, axis, fix_gamma):
+    return _batch_norm_train_fwd(x, gamma, beta, eps, axis, fix_gamma)[0]
+
+
+def _batch_norm_train_fwd(x, gamma, beta, eps, axis, fix_gamma):
+    acc = jnp.promote_types(x.dtype, jnp.float32)
+    red = tuple(i for i in range(x.ndim) if i != axis)
+    shape = _channel_shape(x, axis)
+    n = x.size // x.shape[axis]
+    xf = x.astype(acc)
+    # neither sum needs the other: XLA makes them one multi-output reduce
+    # inside the fusion that writes x (a convolution), and nothing reads x
+    # again for its statistics.  (An explicit variadic lax.reduce does not
+    # fuse into a convolution and costs the pass back.)
+    mean = jnp.sum(xf, axis=red) / n
+    var = jnp.maximum(jnp.sum(xf * xf, axis=red) / n - mean * mean, 0)
+    inv = lax.rsqrt(var + eps)
+    scale = inv if fix_gamma else inv * gamma.astype(acc)
+    shift = beta.astype(acc) - mean * scale
+    out = xf * scale.reshape(shape) + shift.reshape(shape)
+    out = out.astype(jnp.result_type(x, gamma, beta))
+    return ((out, mean.astype(x.dtype), var.astype(x.dtype)),
+            (x, gamma, beta, mean, inv))
+
+
+def _batch_norm_train_bwd(eps, axis, fix_gamma, res, cts):
+    x, gamma, beta, mean, inv = res
+    dy, dmean, dvar = cts
+    acc = mean.dtype
+    red = tuple(i for i in range(x.ndim) if i != axis)
+    shape = _channel_shape(x, axis)
+    n = x.size // x.shape[axis]
+    xc = x.astype(acc) - mean.reshape(shape)
+    dyf = dy.astype(acc)
+    # dbeta and dgamma / inv: again one multi-output reduce, over (dy, x)
+    dbeta = jnp.sum(dyf, axis=red)
+    dyxc = jnp.sum(dyf * xc, axis=red)
+    scale = inv if fix_gamma else inv * gamma.astype(acc)
+    # dx = scale (dy - dbeta/n - xhat sum(dy xhat)/n), plus what the
+    # returned mean and var owe x: dmean/n + 2 dvar (x - mean)/n
+    k_x = (2 * dvar.astype(acc) - scale * inv * inv * dyxc) / n
+    k_0 = (dmean.astype(acc) - scale * dbeta) / n
+    dx = dyf * scale.reshape(shape) + xc * k_x.reshape(shape) \
+        + k_0.reshape(shape)
+    dgamma = jnp.zeros_like(gamma) if fix_gamma \
+        else (dyxc * inv).astype(gamma.dtype)
+    return dx.astype(x.dtype), dgamma, dbeta.astype(beta.dtype)
+
+
+_batch_norm_train.defvjp(_batch_norm_train_fwd, _batch_norm_train_bwd)
+
+
 @register("BatchNorm", aliases=("batch_norm",), multi_out=True)
 def _batch_norm(x, gamma, beta, moving_mean, moving_var, *, eps=1e-3,
                 momentum=0.9, fix_gamma=True, use_global_stats=False,
@@ -380,16 +440,36 @@ def _batch_norm(x, gamma, beta, moving_mean, moving_var, *, eps=1e-3,
                 use_batch_stats=False, **_ignored):
     """Returns (out, mean, var): mean/var are the stats used, so the Gluon
     layer can fold them into moving averages (the reference mutates aux
-    states inside the kernel, src/operator/nn/batch_norm.cc)."""
-    red = tuple(i for i in range(x.ndim) if i != axis)
+    states inside the kernel, src/operator/nn/batch_norm.cc).
+
+    With batch statistics (training) the op reads ``x`` once on the way
+    in and ``x`` and ``dy`` once on the way back, because a training step
+    of a convolutional net is bound by the bytes of its activations:
+
+    - one pass: ``sum(x)`` and ``sum(x*x)`` come out of one reduce, with
+      float32 accumulators (the input's dtype where that is wider);
+      ``mean = s1/n``, ``var = max(s2/n - mean^2, 0)``, biased.  ``var`` of
+      the centred values would need ``mean`` first, a second read.
+    - ``gamma*rsqrt(var+eps)`` and ``beta - mean*scale`` fold into one
+      per-channel multiply-add in the accumulator's dtype, cast at the end
+      (``x - mean`` is never rounded to bfloat16).
+    - the gradient is derived by hand (``jax.custom_vjp``): residuals
+      ``x, mean, inv``; ``dbeta = sum(dy)`` and ``dgamma = sum(dy*xhat)``
+      in one reduce; ``dx = scale*(dy - dbeta/n - xhat*dgamma/n)``.
+      Autodiff of ``mean((x - mean(x))^2)`` keeps a term that is zero and
+      costs a read of ``x``, and runs each sum as a pass of its own.
+    - the cotangents of the returned ``mean`` and ``var`` are honoured
+      (``dx += dmean/n + 2 dvar (x - mean)/n``, in the same pass).
+
+    ``axis`` may be negative.  Inference and ``use_global_stats`` keep the
+    plain expression over the moving statistics.
+    """
+    axis = axis % x.ndim
     if use_batch_stats and not use_global_stats:
-        mean = jnp.mean(x, axis=red)
-        var = jnp.var(x, axis=red)
-    else:
-        mean, var = moving_mean, moving_var
+        return _batch_norm_train(x, gamma, beta, eps, axis, bool(fix_gamma))
+    mean, var = moving_mean, moving_var
     g = jnp.ones_like(gamma) if fix_gamma else gamma
-    shape = [1] * x.ndim
-    shape[axis] = -1
+    shape = _channel_shape(x, axis)
     inv = lax.rsqrt(var + eps)
     out = (x - mean.reshape(shape)) * (inv * g).reshape(shape) + beta.reshape(shape)
     return out, mean, var

@@ -99,6 +99,196 @@ def test_batchnorm():
     assert_almost_equal(m, bm, rtol=1e-4)
 
 
+# -- BatchNorm's training path: one pass, float32 sums, its own gradient.
+#    The formulation it replaced stays as the reference
+#    (batch_norm_two_pass.py).
+
+def _bn_two_pass(x, gamma, beta, *, eps, axis, fix_gamma):
+    from batch_norm_two_pass import two_pass_batch_norm
+    return two_pass_batch_norm(x, gamma, beta, eps, axis, fix_gamma)
+
+
+def _bn_op(x, gamma, beta, **kw):
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.nn import _batch_norm
+    c = gamma.shape[0]
+    return _batch_norm(x, gamma, beta, jnp.zeros(c, x.dtype),
+                       jnp.ones(c, x.dtype), use_batch_stats=True, **kw)
+
+
+def _bn_case(axis, c=16, seed=0):
+    import jax
+    k = jax.random.split(jax.random.PRNGKey(seed), 6)
+    shape = (8, c, 14, 14) if axis == 1 else (8, 14, 14, c)
+    x = jax.random.normal(k[0], shape) * 1.5 + 0.7
+    gamma = jax.random.uniform(k[1], (c,)) + 0.5
+    beta = jax.random.normal(k[2], (c,))
+    # cotangents of out, mean and var
+    w = (jax.random.normal(k[3], shape), jax.random.normal(k[4], (c,)),
+         jax.random.normal(k[5], (c,)))
+    return x, gamma, beta, w
+
+
+def _bn_all(fn, args, w):
+    """(out, mean, var, dx, dgamma, dbeta) of ``fn`` in float32, the
+    gradients those of ``sum(w_o out) + sum(w_m mean) + sum(w_v var)``."""
+    import jax
+    import jax.numpy as jnp
+
+    def loss(x, gamma, beta):
+        return sum((o.astype(jnp.float32) * wi).sum()
+                   for o, wi in zip(fn(x, gamma, beta), w))
+
+    outs = list(fn(*args)) + list(jax.grad(loss, (0, 1, 2))(*args))
+    return [o.astype(jnp.float32) for o in outs]
+
+
+def _scaled_errs(got, ref):
+    import jax.numpy as jnp
+    return [float(jnp.abs(g - r).max() / jnp.maximum(jnp.abs(r).max(), 1e-30))
+            for g, r in zip(got, ref)]
+
+
+@pytest.mark.parametrize("fix_gamma", [False, True])
+@pytest.mark.parametrize("axis", [1, -1])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_batchnorm_train_matches_two_pass_reference(dtype, axis, fix_gamma):
+    """Output, returned mean and var, dx, dgamma and dbeta against the
+    two-pass formulation evaluated in float32 on the same (rounded)
+    inputs: float32 to 1e-5 of each reference's largest value, bfloat16
+    no worse than the two-pass formulation itself does in bfloat16."""
+    import jax.numpy as jnp
+    x, gamma, beta, w = _bn_case(axis)
+    kw = dict(eps=1e-5, axis=axis, fix_gamma=fix_gamma)
+    low = [a.astype(dtype) for a in (x, gamma, beta)]
+    ref = _bn_all(lambda *a: _bn_two_pass(*a, **kw),
+                  [a.astype(jnp.float32) for a in low], w)
+    got_raw = _bn_op(*low, **kw)
+    assert [o.dtype for o in got_raw] == [jnp.dtype(dtype)] * 3
+    assert got_raw[1].shape == got_raw[2].shape == gamma.shape
+    got = _bn_all(lambda *a: _bn_op(*a, **kw), low, w)
+    errs = _scaled_errs(got, ref)
+    if dtype == "float32":
+        assert max(errs) < 1e-5, errs
+    else:
+        old = _scaled_errs(
+            _bn_all(lambda *a: _bn_two_pass(*a, **kw), low, w), ref)
+        # one bfloat16 rounding of the result itself is 2**-9
+        for name, new_e, old_e in zip(
+                ("out", "mean", "var", "dx", "dgamma", "dbeta"), errs, old):
+            assert new_e <= max(old_e, 2.0 ** -8), (name, new_e, old_e)
+    if fix_gamma:
+        assert float(jnp.abs(got[4]).max()) == 0.0
+    assert float(got[2].min()) >= 0.0
+
+
+def test_batchnorm_one_pass_variance_survives_a_large_mean():
+    """A channel whose mean is 30 times its deviation: s2/n - mean^2
+    cancels five digits of float32's seven; a constant channel cancels
+    all of them and must not come out negative."""
+    import jax
+    import jax.numpy as jnp
+    x = jax.random.normal(jax.random.PRNGKey(3), (8, 4, 14, 14))
+    x = x * jnp.array([1.0, 0.1, 2.0, 0.0]).reshape(1, 4, 1, 1) \
+        + jnp.array([30.0, -3.0, 0.5, 3.3]).reshape(1, 4, 1, 1)
+    out, mean, var = _bn_op(x, jnp.ones(4), jnp.zeros(4), eps=1e-5, axis=1,
+                            fix_gamma=False)
+    ref = onp.asarray(x, "float64").var(axis=(0, 2, 3))
+    got = onp.asarray(var, "float64")
+    assert (got >= 0).all()
+    onp.testing.assert_allclose(got[:3], ref[:3], rtol=1e-3)
+    assert got[3] <= 1e-5
+    assert bool(jnp.isfinite(out).all())
+    onp.testing.assert_allclose(
+        onp.asarray(out[:, :3]).std(axis=(0, 2, 3)), 1.0, rtol=2e-3)
+
+
+@pytest.mark.parametrize("which", ["mean", "var"])
+def test_batchnorm_grad_through_returned_statistics(which):
+    """``output_mean_var`` users differentiate the returned statistics:
+    d mean / dx = 1/n and d var / dx = 2 (x - mean) / n."""
+    import jax
+    import jax.numpy as jnp
+    x, gamma, beta, _ = _bn_case(1, c=4, seed=1)
+    pick = 1 if which == "mean" else 2
+    kw = dict(eps=1e-5, axis=1, fix_gamma=False)
+    wv = jnp.arange(1.0, 5.0)
+
+    def loss(fn):
+        return lambda x_: (fn(x_, gamma, beta, **kw)[pick] * wv).sum()
+
+    got = jax.grad(loss(_bn_op))(x)
+    ref = jax.grad(loss(_bn_two_pass))(x)
+    n = x.size // 4
+    mean = x.mean(axis=(0, 2, 3)).reshape(1, 4, 1, 1)
+    by_hand = wv.reshape(1, 4, 1, 1) * (
+        jnp.ones_like(x) / n if which == "mean" else 2 * (x - mean) / n)
+    scale = float(jnp.abs(ref).max())
+    assert float(jnp.abs(got - ref).max()) < 1e-5 * scale
+    assert float(jnp.abs(got - by_hand).max()) < 1e-5 * scale
+
+
+def test_batchnorm_fix_gamma_gives_no_gamma_gradient():
+    x = nd.array(onp.random.randn(4, 3, 5, 5).astype("float32"))
+    gamma = nd.array(onp.random.rand(3).astype("float32") + 0.5)
+    beta = nd.array(onp.random.randn(3).astype("float32"))
+    for a in (x, gamma, beta):
+        a.attach_grad()
+    with autograd.record():
+        out, _, _ = nd.BatchNorm(x, gamma, beta, nd.zeros((3,)),
+                                 nd.ones((3,)), fix_gamma=True,
+                                 use_batch_stats=True)
+        loss = (out * out * out).sum()
+    loss.backward()
+    assert (gamma.grad.asnumpy() == 0).all()
+    assert onp.abs(beta.grad.asnumpy()).max() > 0
+    assert onp.abs(x.grad.asnumpy()).max() > 0
+    # gamma is ignored on the way in as well
+    assert_almost_equal(out.asnumpy().std(axis=(0, 2, 3)), onp.ones(3),
+                        rtol=1e-2)
+
+
+@pytest.mark.parametrize("axis", [1, -1])
+def test_batchnorm_global_stats_path_is_unchanged(axis):
+    """Inference and ``use_global_stats`` keep the old expression, bit
+    for bit."""
+    import jax.numpy as jnp
+    from jax import lax
+    from mxnet_tpu.ops.nn import _batch_norm
+    x, gamma, beta, _ = _bn_case(axis)
+    mm = jnp.linspace(-1.0, 1.0, 16)
+    mv = jnp.linspace(0.5, 2.0, 16)
+    shape = [1] * x.ndim
+    shape[axis] = -1
+    for fix_gamma in (False, True):
+        g = jnp.ones_like(gamma) if fix_gamma else gamma
+        want = (x - mm.reshape(shape)) * (
+            lax.rsqrt(mv + 1e-3) * g).reshape(shape) + beta.reshape(shape)
+        for flags in (dict(use_batch_stats=False),
+                      dict(use_batch_stats=True, use_global_stats=True)):
+            out, mean, var = _batch_norm(x, gamma, beta, mm, mv, eps=1e-3,
+                                         axis=axis, fix_gamma=fix_gamma,
+                                         **flags)
+            assert onp.array_equal(onp.asarray(out), onp.asarray(want))
+            assert mean is mm and var is mv
+
+
+def test_gluon_batchnorm_last_axis_keeps_per_channel_statistics():
+    from mxnet_tpu.gluon import nn
+    bn = nn.BatchNorm(axis=-1, in_channels=3)
+    bn.initialize()
+    x = onp.random.randn(4, 5, 5, 3).astype("float32") * [1.0, 2.0, 3.0] \
+        + [0.0, 5.0, -5.0]
+    with autograd.record():
+        out = bn(nd.array(x.astype("float32")))
+    assert_almost_equal(out.asnumpy().mean(axis=(0, 1, 2)), onp.zeros(3),
+                        atol=1e-4)
+    assert_almost_equal(out.asnumpy().std(axis=(0, 1, 2)), onp.ones(3),
+                        rtol=1e-3)
+    assert_almost_equal(bn.running_mean.data().asnumpy(),
+                        0.1 * x.mean(axis=(0, 1, 2)), rtol=1e-3, atol=1e-5)
+
+
 def test_layernorm():
     x = onp.random.randn(4, 10).astype("float32")
     g = onp.ones(10, "float32")

@@ -668,3 +668,99 @@ def test_custom_call_carries_the_kernels_name(v5e, kernel):
     calls = re.findall(r'^\s*(?:ROOT )?%(\S+) = .*custom_call_target='
                        r'"tpu_custom_call"', _TEXTS[family], re.M)
     assert all("mxtpu_" in c for c in calls), calls
+
+
+# -- BatchNorm's training pass inside a compiled block -----------------------
+# A ResNet step is bound by the bytes of its activations (PERF.md §5), so
+# what BatchNorm costs is how often it makes the program read a conv
+# output.  The op's one-pass statistics ride in the fusion that writes the
+# conv output and its hand-derived gradient takes one reduce over (dy, x);
+# the two-pass formulation it replaced (batch_norm_two_pass.py) reads the
+# conv output again for the variance and again in the backward.
+
+def _load_step_bytes():
+    import importlib.util
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "step_bytes", os.path.join(root, "tools", "step_bytes.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _compile_bottleneck(sharding, batch, wrt_input):
+    """``value_and_grad`` of one ``BottleneckV1(256, 1)`` in train mode on
+    ``bf16[batch, 256, 56, 56]`` (ResNet-50's first stage), traced as
+    ``SPMDTrainer`` traces a net."""
+    import numpy as onp
+    from mxnet_tpu import autograd as ag
+    from mxnet_tpu.gluon.block import _TraceContext, _trace_scope
+    from mxnet_tpu.gluon.model_zoo.vision.resnet import BottleneckV1
+    from mxnet_tpu.ndarray import NDArray
+    blk = BottleneckV1(256, 1)
+    blk.initialize()
+    blk(NDArray(onp.zeros((1, 256, 8, 8), onp.float32)))
+    params = list(blk.collect_params().values())
+
+    def loss(p_list, x):
+        saved = [p._data for p in params]
+        tc = _TraceContext(jax.random.PRNGKey(0))
+        try:
+            for p, a in zip(params, p_list):
+                p._data = NDArray(a.astype(jnp.bfloat16))
+            with _trace_scope(tc), ag.pause(train_mode=True):
+                out = blk.forward(NDArray(x))
+            return (out._data.astype(jnp.float32).mean(),
+                    tuple(v for _, v in tc.aux))
+        finally:
+            for p, s in zip(params, saved):
+                p._data = s
+
+    args = ([jax.ShapeDtypeStruct(p.data().shape, jnp.float32,
+                                  sharding=sharding) for p in params],
+            jax.ShapeDtypeStruct((batch, 256, 56, 56), jnp.bfloat16,
+                                 sharding=sharding))
+    fn = jax.value_and_grad(loss, argnums=(0, 1) if wrt_input else 0,
+                            has_aux=True)
+    return jax.jit(fn).lower(*args).compile()
+
+
+@pytest.mark.parametrize("batch,wrt_input", [(32, False), (64, True)],
+                         ids=["params", "params_and_input"])
+def test_batch_norm_reads_a_conv_output_once(v5e, monkeypatch, batch,
+                                             wrt_input):
+    """No entry fusion reads one conv output only to emit per-channel
+    vectors, forward or backward, and XLA counts at least 12% fewer bytes
+    than for the same block on the two-pass reference (which has such
+    passes: the detector is shown to find them)."""
+    from batch_norm_two_pass import two_pass_batch_norm
+    from mxnet_tpu.ops import nn as nn_ops, registry
+    sb = _load_step_bytes()
+
+    def bytes_and_passes(compiled):
+        out, _text = sb.report(compiled, top=0)
+        return out["bytes_accessed"], out["statistics_only_passes"]
+
+    def forget_traces():
+        # the registry keeps one jitted partial per (op, params), and a
+        # jit nested in a trace replays its cached body: drop them, so
+        # that each block below traces the function then in place
+        op = registry.get("BatchNorm")
+        op._partials.clear()
+        op._jits.clear()
+
+    try:
+        forget_traces()
+        new_bytes, new_passes = bytes_and_passes(
+            _compile_bottleneck(v5e, batch, wrt_input))
+        monkeypatch.setattr(nn_ops, "_batch_norm_train",
+                            two_pass_batch_norm)
+        forget_traces()
+        old_bytes, old_passes = bytes_and_passes(
+            _compile_bottleneck(v5e, batch, wrt_input))
+    finally:
+        monkeypatch.undo()
+        forget_traces()
+    assert old_passes, "the reference block shows no statistics-only pass"
+    assert new_passes == []
+    assert new_bytes <= 0.88 * old_bytes, (new_bytes, old_bytes)
