@@ -51,6 +51,7 @@ would need the state from before it, and nothing snapshots it.
 from __future__ import annotations
 
 import collections
+import contextlib
 import functools
 import os
 import time
@@ -242,6 +243,29 @@ def _means(sums: Dict[str, list]) -> Dict[str, float]:
     return {name: total / n for name, (total, n) in sums.items()}
 
 
+_CLOCK_STATES = ("empty", "host", "sync")
+
+
+def _sched_stats(sums: Dict[str, float]) -> dict:
+    """The turn clock's seconds by state, the turns, and each state's
+    share of the three states' sum."""
+    out = {f"{st}_s": sums.get(f"{st}_s", 0.0) for st in _CLOCK_STATES}
+    total = sum(out.values())
+    out["turns"] = int(sums.get("turns", 0))
+    for st in _CLOCK_STATES:
+        out[f"{st}_share"] = out[f"{st}_s"] / total if total else 0.0
+    return out
+
+
+def _request_stats(sums: Dict[str, float]) -> dict:
+    """The requests whose first token was committed, and the means of
+    the two waits that make up their time to it."""
+    n = int(sums.get("count", 0))
+    return {"count": n,
+            **{f"{name}_mean": sums.get(name, 0.0) / n if n else 0.0
+               for name in ("queue_wait_ms", "prefill_wait_ms")}}
+
+
 class DecodeEngine:
     """Owns the model(s), the paged KV pools, and the compiled
     executables.  All knobs default from the environment:
@@ -323,6 +347,13 @@ class DecodeEngine:
         self.counters: Dict[str, float] = {}
         self._life: Dict[str, list] = {}
         self._traced: Dict[str, list] = {}
+        # what the scheduler books beside them (`book`): running sums by
+        # kind and name, over the engine's life and under a capture
+        self._booked: Dict[str, Dict[str, float]] = {}
+        self._booked_traced: Dict[str, Dict[str, float]] = {}
+        # seconds the host has been blocked in a read, over the engine's
+        # life: the scheduler takes its turn's part as a difference
+        self.sync_s = 0.0
         # share of the page table under live context in the last decode
         # step (what the paged kernel walks), and its sum over the steps;
         # whether that step was dispatched with the one before it unread
@@ -558,7 +589,7 @@ class DecodeEngine:
                 tokens, counters, traced = self._in_flight.popleft()
                 if tokens is nxt:
                     break
-        with tracing.span("decode.sync"):
+        with self._blocked():
             nxt_host, firsts_host, counters = jax.device_get(
                 (nxt, firsts, counters))
         if counters:
@@ -567,6 +598,29 @@ class DecodeEngine:
             if traced:
                 _add(self._traced, self.counters)
         return nxt_host, firsts_host
+
+    @contextlib.contextmanager
+    def _blocked(self):
+        """The host waits for the device: the span ``decode.sync``, and
+        its seconds onto ``sync_s``."""
+        with tracing.span("decode.sync"):
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                self.sync_s += time.perf_counter() - t0
+
+    def book(self, kind: str, traced: bool, **values: float) -> None:
+        """What the scheduler reports into the engine's accounting,
+        beside ``counters``: ``values`` onto the running sums of
+        ``kind`` (``"sched"``: the turn clock's ``empty_s``, ``host_s``,
+        ``sync_s`` and ``turns``; ``"requests"``: ``count`` and a
+        request's two waits at its first token), over the engine's life
+        and, where ``traced``, over what a profiler capture covered."""
+        for booked in (self._booked, self._booked_traced)[:1 + traced]:
+            sums = booked.setdefault(kind, {})
+            for name, value in values.items():
+                sums[name] = sums.get(name, 0.0) + value
 
     def _count_live(self, positions, active, traced=False):
         """Pages holding an active slot's context (its pending token's
@@ -600,7 +654,7 @@ class DecodeEngine:
             vargs = (self.model.params, self.cache.pool, window, pos,
                      self._tables(self.cache), act)
         self.cache.pool, greedy, accepted = self._call("verify", vargs)
-        with tracing.span("decode.sync"):
+        with self._blocked():
             return onp.asarray(greedy), onp.asarray(accepted)
 
     def prefill_chunk_step(self, slot: int, chunk, start: int):
@@ -677,12 +731,22 @@ class DecodeEngine:
                     self._live_tokens_sum / self._decode_steps
                     if self._decode_steps else 0.0),
                 "counters": _means(self._life),
+                # the scheduler thread's time by state (nothing to run /
+                # its own work / blocked in a read) and the waits of the
+                # requests whose first token was committed, as booked
+                "sched": _sched_stats(self._booked.get("sched", {})),
+                "requests": _request_stats(
+                    self._booked.get("requests", {})),
                 "traced": {
                     "decode_steps": self._traced_steps,
                     "live_tokens_mean": (
                         self._traced_tokens_sum / self._traced_steps
                         if self._traced_steps else 0.0),
-                    "counters": _means(self._traced)},
+                    "counters": _means(self._traced),
+                    "sched": _sched_stats(
+                        self._booked_traced.get("sched", {})),
+                    "requests": _request_stats(
+                        self._booked_traced.get("requests", {}))},
                 "chained_share": (self._chained_steps / self._decode_steps
                                   if self._decode_steps else 0.0),
                 "state_edits": self.state_edits}

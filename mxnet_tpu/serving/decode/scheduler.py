@@ -43,6 +43,20 @@ or manually):
    plus ``serving.request`` span closure and SLO request feed (TTFT +
    latency) for finished slots.
 
+The scheduler accounts for its own time and for each request's waits,
+always, into the engine's accounting (``engine.book``; ``stats()`` here
+and ``engine.stats()`` show the figures).  *The turn clock*: the
+thread's wall time is booked to exactly one of three states: ``empty``
+(``_loop`` found nothing to run and waits for a request: the span
+``decode.empty``), ``sync`` (blocked in the turn's read of the turn
+before: ``decode.sync``) and ``host`` (everything else the thread does:
+the rest of ``decode.step``).  *A request's waits*, taken when its first
+token reaches the host: ``queue_wait_ms`` from ``submit`` to admission
+(no slot, or no pages) and ``prefill_wait_ms`` from admission to that
+token (its chunks' turns and the read one turn later); they sum to its
+``ttft_ms``.  The step record carries ``sync_ms`` beside ``step_ms`` and
+``queue_wait_ms`` beside ``ttft_ms``.
+
 What the one-turn delay rests on: every executable of the engine runs
 on one device stream, in the order dispatched.  That a request ends by
 its count is known before its last token is: its slot is switched off
@@ -100,6 +114,11 @@ class _Request:
         self.slot = None
         self.dispatched = 0      # tokens whose step has been dispatched
 
+    def queue_ms(self, now: float) -> float:
+        """From ``submit`` to admission; still queued: to ``now``."""
+        admit = self.t_admit if self.t_admit is not None else now
+        return round((admit - self.t_submit) * 1e3, 3)
+
 
 class DecodeScheduler:
     """Continuous batcher over a :class:`DecodeEngine`.
@@ -135,6 +154,10 @@ class DecodeScheduler:
         self._spec_accepted = 0
         self._last_compiles = engine.compiles
         self._last_edits = engine.state_edits
+        self._last_sync = engine.sync_s
+        # whether a profiler capture ran when the turn at hand began:
+        # what it books belongs to that capture's trace
+        self._traced = False
         if start:
             self.start()
 
@@ -272,7 +295,7 @@ class DecodeScheduler:
         entry = {
             "id": r.rid, "ok": ok, "kind": "generate",
             "latency_ms": round((now - r.t_submit) * 1e3, 3),
-            "queue_ms": round(((r.t_admit or now) - r.t_submit) * 1e3, 3),
+            "queue_ms": r.queue_ms(now),
             "ts": round(time.time(), 3)}
         if r.ttft_ms is not None:
             entry["ttft_ms"] = r.ttft_ms
@@ -297,10 +320,23 @@ class DecodeScheduler:
         """One scheduler turn: expire → admit → dispatch (prefill,
         decode) → commit the turn before → account.  Returns the decode
         extras dict it recorded."""
+        return self._turn(time.perf_counter())[0]
+
+    def _turn(self, since: float) -> tuple:
+        """``step()`` for a caller that keeps the turn clock: the time
+        from ``since`` to the turn's end is booked to ``host`` but for
+        what the turn spent blocked in its read, which is ``sync``.
+        Returns the step record and the clock's reading at that end."""
+        traced = tracing.capturing()
         with self._step_lock, tracing.span("decode.step") as turn:
+            self._traced = traced
             extra = self._step_locked()
             turn.annotate(slots_active=extra["slots_active"])
-            return extra
+        now = time.perf_counter()
+        sync_s = extra["sync_ms"] / 1e3
+        self.engine.book("sched", traced, host_s=now - since - sync_s,
+                         sync_s=sync_s, turns=1)
+        return extra, now
 
     def _step_locked(self) -> dict:
         # every phase is a tracing span (ring and, while a profiler
@@ -312,9 +348,10 @@ class DecodeScheduler:
         token = telemetry.begin_step()
         now = time.perf_counter()
         # the step record, filled in as the phases go: `tokens`,
-        # `completed` and `ttft_ms` where tokens are committed
+        # `completed`, `ttft_ms` and `queue_wait_ms` where tokens are
+        # committed
         extra = {"tokens": 0, "prefill_tokens": 0, "completed": 0,
-                 "ttft_ms": []}
+                 "ttft_ms": [], "queue_wait_ms": []}
 
         with tracing.span("decode.expire"):
             evictions = self._expire(now)
@@ -337,7 +374,9 @@ class DecodeScheduler:
             edits = eng.state_edits - self._last_edits
             self._last_edits = eng.state_edits
             if not extra["ttft_ms"]:
-                del extra["ttft_ms"]
+                del extra["ttft_ms"], extra["queue_wait_ms"]
+            sync_s, self._last_sync = (eng.sync_s - self._last_sync,
+                                       eng.sync_s)
             extra.update({
                 "slots_active": active,
                 "max_slots": eng.max_slots,
@@ -357,6 +396,8 @@ class DecodeScheduler:
                 "state_edits": edits,
                 "counters": dict(eng.counters),
                 "step_ms": round((time.perf_counter() - t_step) * 1e3, 3),
+                # of it, blocked in the read of the turn before
+                "sync_ms": round(sync_s * 1e3, 3),
             })
             telemetry.end_step(token, "serving.DecodeScheduler",
                                extra={"decode": extra})
@@ -485,8 +526,14 @@ class DecodeScheduler:
         for (r, _), tok in zip(firsts, toks):
             if r.future.done():     # evicted with its chunk in flight
                 continue
-            r.ttft_ms = round((time.perf_counter() - r.t_submit) * 1e3, 3)
+            now = time.perf_counter()
+            r.ttft_ms = round((now - r.t_submit) * 1e3, 3)
+            queue_ms = r.queue_ms(now)
             extra["ttft_ms"].append(r.ttft_ms)
+            extra["queue_wait_ms"].append(queue_ms)
+            self.engine.book("requests", self._traced, count=1,
+                             queue_wait_ms=queue_ms,
+                             prefill_wait_ms=r.ttft_ms - queue_ms)
             extra["tokens"] += 1
             extra["completed"] += self._commit(r, int(tok))
 
@@ -547,18 +594,33 @@ class DecodeScheduler:
 
     def _loop(self):
         idle_wait = _getenv_float("MXNET_DECODE_IDLE_WAIT_S", 0.005)
+        # the turn clock: everything this thread does from here on is
+        # booked, up to `clock`, to one of its three states
+        clock = time.perf_counter()
         while True:
             with self._cv:
                 has_work = self._has_work()
                 if self._closed and not (self._drain and has_work):
                     break
                 if not has_work:
-                    self._cv.wait(idle_wait)
+                    traced = tracing.capturing()
+                    with tracing.span("decode.empty"):
+                        self._cv.wait(idle_wait)
+                    now = time.perf_counter()
+                    self.engine.book("sched", traced, empty_s=now - clock)
+                    clock = now
                     continue
-            self.step()
+            clock = self._turn(clock)[1]
 
     def stats(self) -> dict:
+        booked = self.engine.stats()
         return {
+            # the turn clock and the requests' waits, as the engine's
+            # accounting has them (life; "traced": under a capture)
+            "sched": booked["sched"],
+            "requests": booked["requests"],
+            "traced": {k: booked["traced"][k]
+                       for k in ("sched", "requests")},
             "queue_depth": self.pending(),
             "slots_active": self.active(),
             "max_slots": self.engine.max_slots,

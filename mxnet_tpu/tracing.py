@@ -50,7 +50,8 @@ see docs/ARCHITECTURE.md "Tracing & diagnostics" for the full table):
 - ``compile.*`` — jit compile sites (eager op / cached step / serving)
 - ``comm.*``    — kvstore collectives, tagged ``payload_nbytes``
 - ``serving.*`` — request lifecycle: enqueue→coalesce→dispatch→reply
-- ``decode.*``  — one scheduler turn of the decode plane and its phases
+- ``decode.*``  — the decode plane's scheduler: a turn, its phases, and
+  the wait between turns when nothing is to run (``decode.empty``)
 """
 from __future__ import annotations
 

@@ -771,6 +771,188 @@ def test_a_turn_under_a_capture_reaches_the_host_plane(
     assert tracing._completed_events() == []
 
 
+# -- the turn clock, and a request's waits ------------------------------------
+
+def test_a_requests_waits_sum_to_its_first_answer(model):
+    """One slot, three requests at once: the second and third wait in
+    the queue no less than the service of those before them, and every
+    request's ``queue_wait_ms + prefill_wait_ms`` is its ``ttft_ms``."""
+    eng = _engine(model, max_slots=1)
+    sch = _sched(eng)
+    futs = [sch.submit(p, max_new_tokens=5) for p in _prompts(3, seed=4)]
+    t0 = time.perf_counter()
+    records, done_at = [], {}
+    while sch._has_work():
+        records.append(sch.step())
+        for i, f in enumerate(futs):
+            if f.done() and i not in done_at:
+                done_at[i] = time.perf_counter()
+    ttft = [t for r in records for t in r.get("ttft_ms", ())]
+    queue = [q for r in records for q in r.get("queue_wait_ms", ())]
+    assert len(ttft) == len(queue) == 3
+    for r in records:       # the two lists go together, in one order
+        assert len(r.get("queue_wait_ms", ())) == len(r.get("ttft_ms", ()))
+    assert queue[0] < ttft[0]
+    for i in (1, 2):
+        # admitted only once the one before it had left the slot
+        assert queue[i] >= (done_at[i - 1] - t0) * 1e3
+        assert queue[i] > queue[i - 1]
+    st = eng.stats()["requests"]
+    assert st["count"] == 3
+    assert st["queue_wait_ms_mean"] == pytest.approx(sum(queue) / 3)
+    assert st["queue_wait_ms_mean"] + st["prefill_wait_ms_mean"] == \
+        pytest.approx(sum(ttft) / 3, abs=1e-6)
+    assert sch.stats()["requests"] == st
+    assert eng.stats()["traced"]["requests"]["count"] == 0
+    sch.close(drain=True)
+
+
+def test_observe_and_the_step_record_share_one_queue_wait(model):
+    """What ``/requestz`` shows as a request's ``queue_ms`` is the
+    ``queue_wait_ms`` of the step record: one expression, two stamps."""
+    slo.declare(latency_ms=1e9)
+    slo.clear_ring()
+    sch = _sched(_engine(model, max_slots=1))
+    futs = [sch.submit(p, max_new_tokens=2) for p in _prompts(2, seed=6)]
+    records = []
+    while sch._has_work():
+        records.append(sch.step())
+    assert all(f.done() for f in futs)
+    queue = [q for r in records for q in r.get("queue_wait_ms", ())]
+    seen = sorted(e["queue_ms"] for e in slo.requestz()["slowest"]
+                  if e.get("kind") == "generate")
+    assert seen == sorted(queue) and len(seen) == 2
+    sch.close(drain=True)
+
+
+def test_turn_clock_books_every_moment_of_its_thread(model):
+    """``empty_s + host_s + sync_s`` is the scheduler thread's elapsed
+    time, over a run with an idle stretch in it; the shares are of that
+    sum."""
+    eng = _engine(model)
+    eng.warmup([8, 16])
+    sch = _sched(eng)
+    life = {}
+    loop = sch._loop
+
+    def timed():
+        life["lo"] = time.perf_counter()
+        loop()
+        life["hi"] = time.perf_counter()
+
+    sch._loop = timed
+    sch.start()
+    first, second = _prompts(2, seed=5)
+    assert len(sch.submit(first, max_new_tokens=6).result(60)) == 6
+    time.sleep(0.08)                    # nobody asks
+    assert len(sch.submit(second, max_new_tokens=6).result(60)) == 6
+    sch.close(drain=True)
+    assert not sch._thread.is_alive()
+    st = eng.stats()["sched"]
+    booked = st["empty_s"] + st["host_s"] + st["sync_s"]
+    assert booked == pytest.approx(life["hi"] - life["lo"], abs=1e-3)
+    assert st["empty_s"] >= 0.07 and st["sync_s"] > 0 and st["host_s"] > 0
+    assert st["turns"] >= 12            # two requests of six tokens
+    assert st["empty_share"] + st["host_share"] + st["sync_share"] == \
+        pytest.approx(1.0)
+    assert st["empty_share"] == pytest.approx(st["empty_s"] / booked)
+    assert sch.stats()["sched"] == st
+
+
+def test_traced_clock_and_waits_count_only_under_a_capture(model,
+                                                           monkeypatch):
+    """``stats()["traced"]`` holds the turns begun while a capture ran
+    and the requests whose first token such a turn committed: the rule
+    ``traced.decode_steps`` follows."""
+    eng = _engine(model, max_slots=2)
+    sch = _sched(eng)
+    futs = [sch.submit(p, max_new_tokens=4) for p in _prompts(3, seed=8)]
+    on = {"now": False}
+    monkeypatch.setattr(tracing, "capturing", lambda: on["now"])
+    turns = under = firsts_under = 0
+    sync_under = 0.0
+    while sch._has_work():
+        # the capture covers the second to the fourth turn: the first
+        # two requests' first tokens, not the third's
+        on["now"] = 1 <= turns <= 3
+        rec = sch.step()
+        turns += 1
+        if on["now"]:
+            under += 1
+            firsts_under += len(rec.get("ttft_ms", ()))
+            sync_under += rec["sync_ms"]
+    assert all(f.done() for f in futs)
+    st = eng.stats()
+    assert st["sched"]["turns"] == turns and st["requests"]["count"] == 3
+    traced = st["traced"]
+    assert traced["sched"]["turns"] == under == 3
+    assert traced["requests"]["count"] == firsts_under == 2
+    assert traced["sched"]["empty_s"] == 0.0
+    assert traced["sched"]["sync_s"] == pytest.approx(sync_under / 1e3,
+                                                      abs=1e-5)
+    assert 0 < traced["sched"]["host_s"] < st["sched"]["host_s"]
+    assert traced["sched"]["host_share"] + traced["sched"]["sync_share"] \
+        == pytest.approx(1.0)
+    sch.close(drain=True)
+
+
+@pytest.mark.parametrize("kind", ["plain", "spec", "hybrid"])
+def test_step_record_carries_the_waits_and_the_sync(warm_engines, warm,
+                                                    kind):
+    """``sync_ms`` beside ``step_ms`` in every record (the read of the
+    turn before: none in a turn that found nothing in flight) and
+    ``queue_wait_ms`` wherever ``ttft_ms`` is."""
+    eng = warm("hybrid")[1] if kind == "hybrid" else warm_engines[kind][0]
+    sch = _sched(eng)
+    futs = [sch.submit(p, max_new_tokens=4) for p in _prompts(2, seed=12)]
+    records = _steps_until(sch, lambda: not sch._has_work())
+    assert all(len(f.result(0)) == 4 for f in futs)
+    for rec in records:
+        assert 0.0 <= rec["sync_ms"] <= rec["step_ms"]
+        assert ("queue_wait_ms" in rec) == ("ttft_ms" in rec)
+        for q, t in zip(rec.get("queue_wait_ms", ()), rec.get("ttft_ms", ())):
+            assert 0.0 <= q <= t
+    assert sum(len(r.get("queue_wait_ms", ())) for r in records) == 2
+    assert any(r["sync_ms"] > 0 for r in records)
+    # the first chained turn has nothing to read; a speculative turn
+    # reads its own tokens
+    assert (records[0]["sync_ms"] == 0.0) == (kind != "spec")
+    # each a difference of the engine's running total, rounded to a us
+    assert sum(r["sync_ms"] for r in records) / 1e3 <= \
+        eng.sync_s + 1e-6 * len(records)
+    sch.close(drain=True)
+
+
+def test_an_empty_server_waits_under_a_span_on_the_host_plane(
+        model, _traced, xplane_capture):
+    """Under a real profiler capture the wait between turns, when
+    nothing is to run, is ``mxtpu.decode.empty`` on the scheduler's own
+    line of ``/host:CPU``, beside its turns; and the clock's traced
+    figures count it."""
+    eng = _engine(model)
+    eng.warmup([8, 16])
+    sch = _sched(eng, start=True)
+    with xplane_capture() as found:
+        time.sleep(0.03)
+        assert len(sch.submit(_prompts(1, seed=3)[0],
+                              max_new_tokens=3).result(60)) == 3
+        time.sleep(0.03)
+    sch.close(drain=True)
+    empty = [e for e in found if e["name"] == "mxtpu.decode.empty"]
+    steps = [e for e in found if e["name"] == "mxtpu.decode.step"]
+    assert len(empty) >= 4 and len(steps) >= 3
+    assert {e["plane"] for e in empty} == {"/host:CPU"}
+    assert {e["line"] for e in empty} == {e["line"] for e in steps}
+    for e in empty:         # between turns, never inside one
+        assert not any(s["lo"] < e["hi"] and e["lo"] < s["hi"]
+                       for s in steps)
+    traced = eng.stats()["traced"]
+    assert traced["sched"]["empty_s"] >= 0.04
+    assert traced["sched"]["turns"] >= len(steps) - 1
+    assert traced["requests"]["count"] == 1
+    assert tracing._completed_events() == []
+
+
 @pytest.fixture(scope="module")
 def warm_engines(model, draft):
     plain = _engine(model)

@@ -694,8 +694,11 @@ def test_counters_ride_with_the_tokens(models, _clean):
     # held 3 of 48 at top-2: a sixteenth of the pairs when routing is even
     assert 0.0 < st["counters"]["moe_expert_rows_mean"] < 2.0
     # no profiler capture ran: nothing belongs to a trace
-    assert st["traced"] == {"decode_steps": 0, "live_tokens_mean": 0.0,
-                            "counters": {}}
+    traced = st["traced"]
+    assert (traced["decode_steps"], traced["live_tokens_mean"],
+            traced["counters"]) == (0, 0.0, {})
+    assert traced["sched"]["turns"] == traced["requests"]["count"] == 0
+    assert st["sched"]["turns"] == turns and st["requests"]["count"] == 2
 
 
 def test_the_steps_under_a_capture_are_counted_apart(models, monkeypatch,
