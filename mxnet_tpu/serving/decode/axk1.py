@@ -59,8 +59,9 @@ from jax import lax
 from ...ops.rope import yarn_frequencies
 from ...parallel.moe import held_experts, route_topk
 from .engine import DecodePlaneModel
-from .paged_kv import (latent_chunk_attention, latent_dense_attention,
-                       latent_slot_attention, latent_width)
+from .paged_kv import (last_rows, latent_chunk_attention,
+                       latent_dense_attention, latent_slot_attention,
+                       latent_width)
 
 __all__ = ["AXK1"]
 
@@ -325,23 +326,21 @@ class AXK1(DecodePlaneModel):
         pool, x, mean = self._layers(params, pool, tokens, attend, active)
         return pool, self._logits(params, x), self._counters(mean)
 
-    # -- prefill: one chunk of one slot ------------------------------------------
+    # -- prefill: lanes, each one chunk of one slot -------------------------------
 
-    def prefill_core(self, params, pool, tokens, start, chunk_len, table):
+    def prefill_core(self, params, pool, tokens, start, chunk_len, tables):
         pool, logits = self.prefill_logits(params, pool, tokens, start,
-                                           chunk_len, table)
-        return pool, jnp.argmax(logits).astype(jnp.int32)
+                                           chunk_len, tables)
+        return pool, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    def prefill_logits(self, params, pool, tokens, start, chunk_len, table):
-        """One chunk up to the logits ``(vocab,)`` after its last valid
-        token."""
+    def prefill_logits(self, params, pool, tokens, start, chunk_len, tables):
+        """A dispatch of chunks up to the logits ``(lanes, vocab)``
+        after each lane's last valid token."""
         attend = latent_chunk_attention(
-            pool, start, chunk_len, table, tokens.shape[0],
+            pool, start, chunk_len, tables, tokens.shape[1],
             inv_freq=self.inv_freq, sm_scale=self.sm_scale)
-        pool, x, _ = self._layers(params, pool, tokens, attend)
-        last = lax.dynamic_index_in_dim(x, jnp.maximum(chunk_len - 1, 0),
-                                        axis=0, keepdims=False)
-        return pool, self._logits(params, last)
+        pool, x, _ = self._layers(params, pool, tokens.reshape(-1), attend)
+        return pool, self._logits(params, last_rows(x, chunk_len))
 
     # -- dense: the whole sequence, no cache (the in-program oracle) -------------
 

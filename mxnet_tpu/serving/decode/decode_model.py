@@ -18,8 +18,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from .engine import DecodePlaneModel
-from .paged_kv import (chunk_attention, dense_attention, slot_attention,
-                       window_attention)
+from .paged_kv import (chunk_attention, dense_attention, last_rows,
+                       slot_attention, window_attention)
 
 __all__ = ["DecodeModel", "rms_norm"]
 
@@ -119,15 +119,15 @@ class DecodeModel(DecodePlaneModel):
         logits = x @ params["embed"].T
         return pool, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    def prefill_core(self, params, pool, tokens, start, chunk_len, table):
-        """The token is meaningful only after a prompt's final chunk."""
-        pool, x = self._layers(params, pool, tokens, chunk_attention(
-            pool, start, chunk_len, table, tokens.shape[0],
-            rope_base=self.rope_base))
-        last = lax.dynamic_index_in_dim(x, jnp.maximum(chunk_len - 1, 0),
-                                        axis=0, keepdims=False)
-        logits = last @ params["embed"].T
-        return pool, jnp.argmax(logits).astype(jnp.int32)
+    def prefill_core(self, params, pool, tokens, start, chunk_len, tables):
+        """A lane's token is meaningful only after a prompt's final
+        chunk."""
+        pool, x = self._layers(
+            params, pool, tokens.reshape(-1), chunk_attention(
+                pool, start, chunk_len, tables, tokens.shape[1],
+                rope_base=self.rope_base))
+        logits = last_rows(x, chunk_len) @ params["embed"].T
+        return pool, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
     def verify_core(self, params, pool, tokens, base_pos, tables, active):
         pool, x = self._layers(params, pool, tokens, window_attention(

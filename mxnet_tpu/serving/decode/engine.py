@@ -16,10 +16,14 @@ use and reused forever (the fixed-shape-executable invariant):
   before it and a steady turn uploads nothing;
 - ``state_edit`` — one row of the resident state rewritten, when a slot
   changes hands: the only way the host touches that state;
-- ``prefill_b<n>`` — one prompt chunk for one slot, chunk length padded
-  into pow2 sequence buckets (chunked prefill: long prompts are fed
-  bucket-by-bucket so running decodes aren't stalled behind one long
-  prompt);
+- ``prefill_b<n>`` — a prefill dispatch of ``n`` rows in LANES, each
+  lane the next prompt chunk of one slot, so that every slot filling in
+  a turn is served by ONE pass over the weights.  One lane: the chunk's
+  length padded into pow2 sequence buckets (chunked prefill: long
+  prompts are fed bucket-by-bucket so running decodes aren't stalled
+  behind one long prompt).  More: a pow2 of lanes, each the full chunk's
+  bucket, ``n`` their product, up to ``_PREFILL_ROWS`` rows (two lanes
+  at a chunk of 128);
 - ``draft``/``verify``, ``draft_prefill_b<n>`` — the speculative path:
   the draft model proposes ``k`` tokens per slot (its own paged KV
   pool, same page geometry, shared page tables), then the target model
@@ -84,6 +88,17 @@ def _pow2(n: int, floor: int) -> int:
     return b
 
 
+# rows a prefill dispatch holds at most: a chunk is bound by the weights'
+# stream, so a second chunk in the same pass is nearly free until the
+# rows' operations take as long as the stream does: on a v5e 240
+# operations a weight byte, which bfloat16 weights reach at 240 rows.
+# Past that a lane costs its operations (falcon_h1_34b on the chip: 11.8
+# ms one lane of 128, 13.5 two, 21.0 four) and every multi-lane
+# executable costs a warm start a second or more (PERF.md section 6,
+# PR 38)
+_PREFILL_ROWS = 256
+
+
 class DecodePlaneModel:
     """What :class:`DecodeEngine` asks of a model.
 
@@ -130,11 +145,21 @@ class DecodePlaneModel:
         dict of scalars by name (the same names every step)."""
         raise NotImplementedError
 
-    def prefill_core(self, params, pool, tokens, start, chunk_len, table,
+    def prefill_core(self, params, pool, tokens, start, chunk_len, tables,
                      *slot):
-        """One prompt chunk of one slot: ``(pool, next token)``.  The
-        slot's index follows ``table`` for a model with recurrent
-        state, which is addressed by it."""
+        """A prefill dispatch in lanes, each the next prompt chunk of
+        one slot: ``tokens (lanes, bucket)``, of which lane ``i``'s
+        first ``chunk_len[i]`` are a prompt's positions ``start[i]`` on,
+        written through the slot's page row ``tables[i]``; ``(pool,
+        next token per lane)``.  The slots' indices ``slot (lanes,)``
+        follow ``tables`` for a model with recurrent state, which is
+        addressed by them.  A lane of length 0 is padding: its pages
+        are the sentinel's, its slot index lies past the state
+        buffers, it writes nothing and its token means nothing.  The
+        weights are read once for all lanes: projections, MLPs, experts
+        and norms see ``lanes * bucket`` rows; only what is a slot's by
+        nature (attention over its pages, a recurrence from its state)
+        goes lane by lane.  A slot rides in one lane at most."""
         raise NotImplementedError
 
     def verify_core(self, params, pool, tokens, base_pos, tables, active):
@@ -222,6 +247,21 @@ def _verify_core(mdl: DecodePlaneModel, params, pool, tokens, base_pos,
     return pool, greedy, accepted
 
 
+def _prefill_core(mdl: DecodePlaneModel, stateful: bool, width: int, params,
+                  pool, staged):
+    """The model's prefill over what the host staged in ONE int32 array
+    (one upload a dispatch), a row a lane: ``[tokens (bucket) | start |
+    chunk_len | slot | page row (width)]``.  ``(pool, the lanes' next
+    tokens)``, the tokens as scalars of their own: a slot's first token
+    goes into the resident decode state without leaving the device."""
+    bucket = staged.shape[1] - 3 - width
+    start, chunk_len, slot = (staged[:, bucket + i] for i in range(3))
+    pool, tokens = mdl.prefill_core(
+        params, pool, staged[:, :bucket], start, chunk_len,
+        staged[:, bucket + 3:], *((slot,) if stateful else ()))
+    return pool, tuple(tokens[i] for i in range(staged.shape[0]))
+
+
 def _state_reset_core(state, slot):
     """Zero one slot's rows of every layer's recurrent-state buffers
     (``state[layer]`` is ``pool[layer]`` without its paged buffers)."""
@@ -254,6 +294,16 @@ def _sched_stats(sums: Dict[str, float]) -> dict:
     out["turns"] = int(sums.get("turns", 0))
     for st in _CLOCK_STATES:
         out[f"{st}_share"] = out[f"{st}_s"] / total if total else 0.0
+    return out
+
+
+def _prefill_stats(sums: Dict[str, float]) -> dict:
+    """Prefill dispatches (``runs``), the chunks they carried, the rows
+    they computed (padding included), and chunks a run."""
+    out = {name: int(sums.get(name, 0))
+           for name in ("runs", "chunks", "rows")}
+    out["chunks_per_run"] = (out["chunks"] / out["runs"] if out["runs"]
+                             else 0.0)
     return out
 
 
@@ -382,6 +432,21 @@ class DecodeEngine:
     def prefill_bucket(self, n: int) -> int:
         return min(_pow2(n, self.prefill_floor), self.prefill_chunk)
 
+    @property
+    def prefill_runs(self) -> int:
+        """Prefill dispatches over the engine's life (the scheduler
+        takes its turn's part as a difference)."""
+        return int(self._booked.get("prefill", {}).get("runs", 0))
+
+    @property
+    def prefill_lanes(self) -> int:
+        """The most chunks one prefill dispatch carries: as many full
+        chunks as ``_PREFILL_ROWS`` rows hold, no more than there are
+        slots, a power of two."""
+        most = max(1, min(_PREFILL_ROWS // self.prefill_chunk,
+                          self.max_slots))
+        return 1 << (most.bit_length() - 1)
+
     # -- compiled-executable plumbing ---------------------------------------
 
     @staticmethod
@@ -414,8 +479,11 @@ class DecodeEngine:
                  "verify": functools.partial(_verify_core, self.model)}
         if key in named:
             return named[key]
-        mdl = self.draft if key.startswith("draft_") else self.model
-        return mdl.prefill_core
+        draft = key.startswith("draft_")
+        return functools.partial(
+            _prefill_core, self.draft if draft else self.model,
+            bool((self.draft_cache if draft else self.cache).state_layers),
+            self.cache.pages_per_slot)
 
     def _get_exec(self, key: str, args, donate=(1,)):
         """Load-or-compile one executable WITHOUT running it.  Order:
@@ -498,24 +566,35 @@ class DecodeEngine:
             self._edit_state(slot, self._no_token, 0, False,
                              onp.zeros_like(self.cache.tables[slot]))
 
-    @staticmethod
-    def _prefill_args(mdl, cache, padded, start: int, n: int, slot: int):
-        """What a prefill executable of ``mdl`` over ``cache`` is called
-        with for ``n`` tokens in ``padded`` at ``start`` of ``slot``;
-        the slot's index last, for a model whose state it addresses."""
-        args = (mdl.params, cache.pool, jnp.asarray(padded),
-                jnp.asarray(start, jnp.int32), jnp.asarray(n, jnp.int32),
-                jnp.asarray(cache.tables[slot], jnp.int32))
-        if cache.state_layers:
-            args += (jnp.asarray(slot, jnp.int32),)
-        return args
+    def _prefill_shape(self, chunks: int, longest: int):
+        """``(lanes, bucket)`` of the dispatch that carries ``chunks``
+        chunks, the longest of ``longest`` tokens: one chunk in its own
+        pow2 bucket; several in a pow2 of lanes of the full chunk's."""
+        if chunks == 1:
+            return 1, self.prefill_bucket(longest)
+        return _pow2(chunks, 1), self.prefill_chunk
+
+    def _stage(self, cache, group, lanes: int, bucket: int):
+        """What ``_prefill_core`` unpacks, built on the host: a row a
+        lane of ``group``'s ``(slot, chunk, start)``; the lanes left
+        over are padding (length 0, each a slot index of its own past
+        the buffers: every index of a scatter stays distinct)."""
+        staged = onp.zeros((lanes, bucket + 3 + cache.pages_per_slot),
+                           onp.int32)
+        staged[:, bucket + 2] = self.max_slots + onp.arange(lanes)
+        for row, (slot, chunk, start) in zip(staged, group):
+            row[:len(chunk)] = chunk
+            row[bucket:bucket + 3] = start, len(chunk), slot
+            row[bucket + 3:] = cache.tables[slot]
+        return staged
 
     def warmup(self, prefill_lengths: Sequence[int] = (1,)) -> List[str]:
         """Materialize every executable this engine will dispatch —
         decode and its state's edit (draft and verify instead under
-        speculation) and one prefill per bucket covering
-        ``prefill_lengths`` — WITHOUT running any of them.  Against a
-        populated artifact store each one deserializes (``compiles``
+        speculation), one one-lane prefill per bucket covering
+        ``prefill_lengths`` and every multi-lane prefill a turn with
+        several filling slots dispatches — WITHOUT running any of
+        them.  Against a populated artifact store each one deserializes (``compiles``
         stays 0); otherwise this pays the compiles ahead of traffic.  Also prefetches the kernel-autotune cache.
         Returns the exec keys materialized."""
         from ... import kernels
@@ -547,16 +626,24 @@ class DecodeEngine:
                            (self._state(), jnp.asarray(0, jnp.int32)),
                            donate=(0,))
             keys.append("state_reset")
-        for bucket in sorted({self.prefill_bucket(int(n))
-                              for n in prefill_lengths}):
-            padded = onp.zeros((bucket,), onp.int32)
-            self._get_exec(f"prefill_b{bucket}", self._prefill_args(
-                self.model, self.cache, padded, 0, 1, 0))
-            keys.append(f"prefill_b{bucket}")
+        # every prefill shape a turn can dispatch: one lane in each
+        # bucket asked for, and every pow2 of lanes of the full chunk
+        shapes = [(1, bucket) for bucket in sorted(
+            {self.prefill_bucket(int(n)) for n in prefill_lengths})]
+        lanes = 2
+        while lanes <= self.prefill_lanes:
+            shapes.append((lanes, self.prefill_chunk))
+            lanes *= 2
+        for lanes, bucket in shapes:
+            key = f"prefill_b{lanes * bucket}"
+            self._get_exec(key, (self.model.params, self.cache.pool,
+                                 self._stage(self.cache, (), lanes, bucket)))
+            keys.append(key)
             if self.draft_cache is not None:
-                self._get_exec(f"draft_prefill_b{bucket}", self._prefill_args(
-                    self.draft, self.draft_cache, padded, 0, 1, 0))
-                keys.append(f"draft_prefill_b{bucket}")
+                self._get_exec("draft_" + key, (
+                    self.draft.params, self.draft_cache.pool,
+                    self._stage(self.draft_cache, (), lanes, bucket)))
+                keys.append("draft_" + key)
         return keys
 
     # -- device steps --------------------------------------------------------
@@ -615,8 +702,10 @@ class DecodeEngine:
         beside ``counters``: ``values`` onto the running sums of
         ``kind`` (``"sched"``: the turn clock's ``empty_s``, ``host_s``,
         ``sync_s`` and ``turns``; ``"requests"``: ``count`` and a
-        request's two waits at its first token), over the engine's life
-        and, where ``traced``, over what a profiler capture covered."""
+        request's two waits at its first token; the engine's own
+        ``"prefill"``: ``runs``, ``chunks``, ``rows``), over the
+        engine's life and, where ``traced``, over what a profiler
+        capture covered."""
         for booked in (self._booked, self._booked_traced)[:1 + traced]:
             sums = booked.setdefault(kind, {})
             for name, value in values.items():
@@ -657,24 +746,37 @@ class DecodeEngine:
         with self._blocked():
             return onp.asarray(greedy), onp.asarray(accepted)
 
-    def prefill_chunk_step(self, slot: int, chunk, start: int):
-        """Feed one prompt chunk for ``slot`` (padded into its pow2
-        bucket); returns the greedy next token after the chunk, on the
-        device and not waited for."""
-        with tracing.span("decode.stage"):
-            bucket = self.prefill_bucket(len(chunk))
-            padded = onp.zeros((bucket,), onp.int32)
-            padded[:len(chunk)] = chunk
-            args = self._prefill_args(self.model, self.cache, padded, start,
-                                      len(chunk), slot)
-        self.cache.pool, nxt = self._call(f"prefill_b{bucket}", args)
-        if self.draft_cache is not None:
-            with tracing.span("decode.stage"):
-                dargs = self._prefill_args(self.draft, self.draft_cache,
-                                           padded, start, len(chunk), slot)
-            self.draft_cache.pool, _ = self._call(
-                f"draft_prefill_b{bucket}", dargs)
-        return nxt
+    def prefill_chunks(self, chunks):
+        """Feed the next prompt chunk of every filling slot: ``chunks``
+        is ``(slot, tokens, start)`` a slot, a slot once.  Up to
+        ``prefill_lanes`` of them ride in one dispatch, one pass over
+        the weights (more: further dispatches).  Returns the greedy next
+        token after each chunk, in ``chunks``' order, on the device and
+        not waited for."""
+        out = []
+        for i in range(0, len(chunks), self.prefill_lanes):
+            group = chunks[i:i + self.prefill_lanes]
+            lanes, bucket = self._prefill_shape(
+                len(group), max(len(chunk) for _, chunk, _ in group))
+            key = f"prefill_b{lanes * bucket}"
+            with tracing.span("decode.prefill", lanes=lanes,
+                              chunks=len(group),
+                              tokens=sum(len(c) for _, c, _ in group)):
+                with tracing.span("decode.stage"):
+                    staged = self._stage(self.cache, group, lanes, bucket)
+                self.cache.pool, tokens = self._call(
+                    key, (self.model.params, self.cache.pool, staged))
+                if self.draft_cache is not None:
+                    with tracing.span("decode.stage"):
+                        staged = self._stage(self.draft_cache, group, lanes,
+                                             bucket)
+                    self.draft_cache.pool, _ = self._call(
+                        "draft_" + key, (self.draft.params,
+                                         self.draft_cache.pool, staged))
+            self.book("prefill", tracing.capturing(), runs=1,
+                      chunks=len(group), rows=lanes * bucket)
+            out += tokens[:len(group)]
+        return out
 
     # -- slot page lifecycle -------------------------------------------------
 
@@ -737,6 +839,8 @@ class DecodeEngine:
                 "sched": _sched_stats(self._booked.get("sched", {})),
                 "requests": _request_stats(
                     self._booked.get("requests", {})),
+                # prefill dispatches and the chunks that rode in them
+                "prefill": _prefill_stats(self._booked.get("prefill", {})),
                 "traced": {
                     "decode_steps": self._traced_steps,
                     "live_tokens_mean": (
@@ -746,7 +850,9 @@ class DecodeEngine:
                     "sched": _sched_stats(
                         self._booked_traced.get("sched", {})),
                     "requests": _request_stats(
-                        self._booked_traced.get("requests", {}))},
+                        self._booked_traced.get("requests", {})),
+                    "prefill": _prefill_stats(
+                        self._booked_traced.get("prefill", {}))},
                 "chained_share": (self._chained_steps / self._decode_steps
                                   if self._decode_steps else 0.0),
                 "state_edits": self.state_edits}

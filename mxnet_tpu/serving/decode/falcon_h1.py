@@ -40,7 +40,8 @@ how the mixer's convolution and recurrence reach their state (the
   the state-space state are rows of the layer's two state buffers,
   ``(slots, conv-1, conv width)`` and ``(slots, heads, head dim, state
   size)``, the latter updated in place by ``ssm_update``;
-- prefill: one chunk of one slot.  The chunk attends over the slot's
+- prefill: lanes, each one chunk of one slot, every projection one
+  product over all their rows.  A chunk attends over its slot's
   gathered pages; the recurrence runs in the chunked form from the
   slot's state (``prefill_chunk`` is ``mamba_chunk_size``, so a chunk of
   the prompt is a chunk of the scan) and leaves it for the next chunk
@@ -66,7 +67,8 @@ from ...ops.ssm import ssm_chunk_scan, ssm_scan_reference, ssm_update
 from .decode_model import rms_norm
 from .engine import DecodePlaneModel
 from .paged_kv import (chunk_attention, chunk_conv, dense_attention,
-                       dense_conv, slot_attention, slot_conv)
+                       dense_conv, last_rows, slot_attention, slot_conv,
+                       slot_rows)
 
 __all__ = ["FalconH1"]
 
@@ -333,43 +335,50 @@ class FalconH1(DecodePlaneModel):
             out.append(kv + state)
         return tuple(out), self._logits(params, x)
 
-    # -- prefill: one chunk of one slot ------------------------------------------
+    # -- prefill: lanes, each one chunk of one slot -------------------------------
 
-    def prefill_core(self, params, pool, tokens, start, chunk_len, table,
+    def prefill_core(self, params, pool, tokens, start, chunk_len, tables,
                      slot):
         pool, logits = self.prefill_logits(params, pool, tokens, start,
-                                           chunk_len, table, slot)
-        return pool, jnp.argmax(logits).astype(jnp.int32)
+                                           chunk_len, tables, slot)
+        return pool, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    def prefill_logits(self, params, pool, tokens, start, chunk_len, table,
+    def prefill_logits(self, params, pool, tokens, start, chunk_len, tables,
                        slot):
-        """One chunk up to the logits ``(vocab,)`` after its last valid
-        token."""
-        b_ = tokens.shape[0]
-        attend = chunk_attention(pool, start, chunk_len, table, b_,
+        """A dispatch of chunks up to the logits ``(lanes, vocab)``
+        after each lane's last valid token."""
+        lanes, b_ = tokens.shape
+        attend = chunk_attention(pool, start, chunk_len, tables, b_,
                                  rope_base=self.rope_base)
-        valid = jnp.arange(b_) < chunk_len
-        x = self._embed(params, tokens)
+        valid = (jnp.arange(b_)[None, :] < chunk_len[:, None]).reshape(-1)
+        x = self._embed(params, tokens.reshape(-1))
+
+        def by_lane(rows):
+            return rows.reshape((lanes, b_) + rows.shape[1:])
+
         out = []
         for (kbuf, vbuf, sbuf, cbuf), lp in zip(pool, params["layers"]):
 
             def mix(xbc, dt, sbuf=sbuf, cbuf=cbuf, lp=lp):
                 conv, cbuf = chunk_conv(cbuf, xbc, lp["conv_w"], slot,
                                         chunk_len, lp["conv_b"])
-                xs, b, c = self._split(jax.nn.silu(conv))
+                xs, b, c = map(by_lane, self._split(jax.nn.silu(conv)))
                 # a padded row neither decays the state nor adds to it
-                dt = jnp.where(valid[:, None], dt, 0.0)
-                state, y = ssm_chunk_scan(
-                    sbuf[slot], xs, dt, -jnp.exp(lp["a_log"]), b, c,
-                    lp["d"])
-                sbuf = lax.dynamic_update_index_in_dim(sbuf, state, slot, 0)
-                return y, (sbuf, cbuf)
+                dt = by_lane(jnp.where(valid[:, None], dt, 0.0))
+                # the recurrence is a slot's own: a lane at a time from
+                # the slot's state, and the states back in one scatter
+                # (a padding lane's slot is past the buffer: dropped)
+                before = slot_rows(sbuf, slot)
+                state, y = zip(*(ssm_chunk_scan(
+                    before[i], xs[i], dt[i], -jnp.exp(lp["a_log"]), b[i],
+                    c[i], lp["d"]) for i in range(lanes)))
+                sbuf = sbuf.at[slot].set(jnp.stack(state), mode="drop")
+                return jnp.concatenate(y), (sbuf, cbuf)
 
             x, kv, state = self._block(lp, x, (kbuf, vbuf), attend, mix)
             out.append(kv + state)
-        last = lax.dynamic_index_in_dim(x, jnp.maximum(chunk_len - 1, 0),
-                                        axis=0, keepdims=False)
-        return tuple(out), self._logits(params, last)
+        return tuple(out), self._logits(params,
+                                        last_rows(x, chunk_len))
 
     # -- dense: the whole sequence, no cache (the in-program oracle) -------------
 

@@ -57,7 +57,7 @@ from jax import lax
 from ...parallel.moe import route_topk, stacked_experts
 from .engine import DecodePlaneModel
 from .paged_kv import (chunk_attention, chunk_conv, dense_attention,
-                       dense_conv, slot_attention, slot_conv)
+                       dense_conv, last_rows, slot_attention, slot_conv)
 
 __all__ = ["LFM2"]
 
@@ -359,29 +359,28 @@ class LFM2(DecodePlaneModel):
                                      active)
         return pool, self._logits(params, x), self._counters(seen)
 
-    # -- prefill: one chunk of one slot ------------------------------------------
+    # -- prefill: lanes, each one chunk of one slot -------------------------------
 
-    def prefill_core(self, params, pool, tokens, start, chunk_len, table,
+    def prefill_core(self, params, pool, tokens, start, chunk_len, tables,
                      slot):
         pool, logits = self.prefill_logits(params, pool, tokens, start,
-                                           chunk_len, table, slot)
-        return pool, jnp.argmax(logits).astype(jnp.int32)
+                                           chunk_len, tables, slot)
+        return pool, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    def prefill_logits(self, params, pool, tokens, start, chunk_len, table,
+    def prefill_logits(self, params, pool, tokens, start, chunk_len, tables,
                        slot):
-        """One chunk up to the logits ``(vocab,)`` after its last valid
-        token."""
-        attend = chunk_attention(self._paged(pool), start, chunk_len, table,
-                                 tokens.shape[0], rope_base=self.rope_base)
+        """A dispatch of chunks up to the logits ``(lanes, vocab)``
+        after each lane's last valid token."""
+        attend = chunk_attention(self._paged(pool), start, chunk_len, tables,
+                                 tokens.shape[1], rope_base=self.rope_base)
 
         def conv(g, w, tail):
             y, tail = chunk_conv(tail, g, w, slot, chunk_len)
             return y, (tail,)
 
-        pool, x, _ = self._layers(params, pool, tokens, attend, conv)
-        last = lax.dynamic_index_in_dim(x, jnp.maximum(chunk_len - 1, 0),
-                                        axis=0, keepdims=False)
-        return pool, self._logits(params, last)
+        pool, x, _ = self._layers(params, pool, tokens.reshape(-1), attend,
+                                  conv)
+        return pool, self._logits(params, last_rows(x, chunk_len))
 
     # -- dense: the whole sequence, no cache (the in-program oracle) -------------
 

@@ -53,7 +53,8 @@ The traced side (the second half of this module) is the only code
 besides the ``paged_attention`` kernel that knows any of the above.  A
 model's core builds ONE attention for the executable it is traced into
 (:func:`slot_attention` for decode, :func:`chunk_attention` for
-prefill, :func:`window_attention` for verify) from the positions and
+prefill, whose rows are lanes, several slots' chunks in one dispatch,
+:func:`window_attention` for verify) from the positions and
 page tables the executable was handed, and calls it in every layer as
 ``attend(q, k, v, kbuf, vbuf)``: the projected heads before rotation
 and the layer's own buffers in, the attention output ``(..., query
@@ -82,10 +83,11 @@ from ...ops.paged_attention import (latent_attention, paged_attention,
 from ...ops.rope import rope, rope_reference, rope_table
 
 __all__ = ["PageAllocator", "PagedKVCache", "OutOfPagesError",
-           "uniform_layout", "slot_conv", "chunk_conv", "dense_conv",
-           "slot_attention", "chunk_attention", "window_attention",
-           "dense_attention", "latent_width", "latent_slot_attention",
-           "latent_chunk_attention", "latent_dense_attention"]
+           "uniform_layout", "slot_conv", "slot_rows", "chunk_conv",
+           "dense_conv", "slot_attention", "chunk_attention", "last_rows",
+           "window_attention", "dense_attention", "latent_width",
+           "latent_slot_attention", "latent_chunk_attention",
+           "latent_dense_attention"]
 
 _NEG_INF = -1e30
 # a prefill chunk gathers a slot's whole table for one softmax up to
@@ -279,19 +281,36 @@ def slot_conv(tail, x, w, active, bias=None):
     return conv, jnp.where(active[:, None, None], taps[:, 1:], tail)
 
 
+def slot_rows(buf, slot):
+    """The rows ``slot (lanes,)`` of a per-slot state buffer, ``(lanes,
+    *one slot's shape)``, a slice a lane: a gather over the buffer
+    would have the TPU compiler copy the buffer whole first.  An index
+    past the buffer (a padding lane's) reads the last slot's rows."""
+    return jnp.stack([lax.dynamic_index_in_dim(buf, slot[i], 0, False)
+                      for i in range(slot.shape[0])])
+
+
 def chunk_conv(tail, x, w, slot, chunk_len, bias=None):
-    """Prefill: ``x (bucket, width)`` rows of ONE slot, the first
-    ``chunk_len`` (traced) of them real, behind that slot's row of
+    """Prefill: ``x (lanes * bucket, width)`` rows, ``bucket`` a lane,
+    lane ``i`` the rows of slot ``slot[i]`` with the first
+    ``chunk_len[i]`` (traced) of them real, behind that slot's row of
     ``tail``: the convolution sees the tokens before the chunk, and the
     new tail is the last ``taps - 1`` rows before the padding, rows of
-    the old tail among them where the chunk is shorter than it."""
-    n_tail = tail.shape[1]
-    taps = jnp.concatenate([tail[slot], x], axis=0)
-    conv = sum(taps[j:j + x.shape[0]] * w[j] for j in range(n_tail + 1))
+    the old tail among them where the chunk is shorter than it.  The
+    tails go back in one scatter (a slot rides once in a dispatch); a
+    padding lane carries a slot index past the buffer and its tail is
+    dropped."""
+    lanes, n_tail = slot.shape[0], tail.shape[1]
+    xl = x.reshape(lanes, -1, x.shape[-1])
+    bucket = xl.shape[1]
+    taps = jnp.concatenate([slot_rows(tail, slot), xl], axis=1)
+    conv = sum(taps[:, j:j + bucket] * w[j] for j in range(n_tail + 1))
     if bias is not None:
         conv = conv + bias
-    last = lax.dynamic_slice_in_dim(taps, chunk_len, n_tail, 0)
-    return conv, lax.dynamic_update_index_in_dim(tail, last, slot, 0)
+    last = jnp.stack([lax.dynamic_slice_in_dim(taps[i], chunk_len[i],
+                                               n_tail, 0)
+                      for i in range(lanes)])
+    return conv.reshape(x.shape), tail.at[slot].set(last, mode="drop")
 
 
 def dense_conv(x, w, bias=None):
@@ -393,69 +412,110 @@ def window_attention(pool, base_pos, width: int, tables, active, *,
     return attend
 
 
-def chunk_attention(pool, start, chunk_len, table, bucket: int, *,
+def _chunk_rows(pool, start, chunk_len, tables, bucket: int):
+    """Where the rows of a prefill dispatch go: lane ``i`` holds
+    ``bucket`` rows, the first ``chunk_len[i]`` of them positions
+    ``start[i]`` on of the slot whose page row is ``tables[i]``.
+    ``(positions (lanes, bucket), page and offset of every row, flat,
+    the padding's page the sentinel, rows live a lane (lanes,))``."""
+    num_pages, ps = pool[0][0].shape[:2]
+    pos = start[:, None] + jnp.arange(bucket, dtype=jnp.int32)[None, :]
+    valid = jnp.arange(bucket)[None, :] < chunk_len[:, None]
+    pagerow = jnp.take_along_axis(tables, pos // ps, axis=1, mode="clip")
+    page = jnp.where(valid, pagerow, num_pages).astype(jnp.int32)
+    return pos, page.reshape(-1), (pos % ps).reshape(-1), start + chunk_len
+
+
+def last_rows(x, chunk_len):
+    """Of a prefill dispatch's rows ``x (lanes * bucket, dim)`` each
+    lane's last valid one, ``(lanes, dim)``: the row a head reads."""
+    lanes = chunk_len.shape[0]
+    xl = x.reshape(lanes, -1, x.shape[-1])
+    at = jnp.maximum(chunk_len - 1, 0)
+    return jnp.stack([lax.dynamic_index_in_dim(xl[i], at[i], 0, False)
+                      for i in range(lanes)])
+
+
+def chunk_attention(pool, start, chunk_len, tables, bucket: int, *,
                     rope_base):
-    """Prefill: ``bucket`` rows of ONE slot, the first ``chunk_len``
-    (traced) of them a prompt's positions from ``start`` on, the rest
-    padding that writes nothing; ``table (pages_per_slot,)`` is the
-    slot's page row.  The chunk attends its causal prefix, earlier
-    chunks included, over the slot's pages: the chunk itself was just
-    written, so one mask covers intra- and cross-chunk keys.  Float32
-    softmax; each K/V head serves its ``rep`` query heads.  A table of
-    up to ``_GATHER_ROWS`` positions is gathered whole for one softmax;
-    a longer one is walked a page at a time over the slot's LIVE pages
-    only (``start + chunk_len`` rows, a traced count) under an online
-    softmax: the whole-table form's scores, ``(bucket, heads, table
-    positions)`` in float32, are what a chunk's attention costs
+    """Prefill: ``lanes * bucket`` rows, ``bucket`` a lane, lane ``i``
+    the next chunk of ONE slot: its first ``chunk_len[i]`` (traced)
+    rows a prompt's positions from ``start[i]`` on, the rest padding
+    that writes nothing (a lane of length 0 is all padding);
+    ``tables (lanes, pages_per_slot)`` the slots' page rows.  Every
+    lane's K and V rows are rotated and written in one scatter a
+    buffer; then each lane attends its own causal prefix, earlier
+    chunks included, over its own slot's pages: the chunk itself was
+    just written, so one mask covers intra- and cross-chunk keys.
+    Float32 softmax; each K/V head serves its ``rep`` query heads.  A
+    table of up to ``_GATHER_ROWS`` positions is gathered whole for one
+    softmax; a longer one is walked a page at a time over the slot's
+    LIVE pages only (``start + chunk_len`` rows, a traced count) under
+    an online softmax: the whole-table form's scores, ``(bucket, heads,
+    table positions)`` in float32, are what a chunk's attention costs
     whatever the prompt's length (134 MB a layer at 4,096 positions and
     256 rows: PERF.md section 6, PR 35)."""
-    num_pages, ps = pool[0][0].shape[:2]
-    pos = start + jnp.arange(bucket, dtype=jnp.int32)
-    valid = jnp.arange(bucket) < chunk_len
-    total = start + chunk_len
-    page = jnp.where(valid, table[pos // ps], num_pages).astype(jnp.int32)
-    offset = pos % ps
-    rows = table.shape[0] * ps
+    lanes = tables.shape[0]
+    pos, page, offset, total = _chunk_rows(pool, start, chunk_len, tables,
+                                           bucket)
+    one_slot = (_walk_live_pages
+                if tables.shape[1] * pool[0][0].shape[1] > _GATHER_ROWS
+                else _gather_pages)
 
     def attend(q, k, v, kbuf, vbuf):
         (heads, hd), kvh = q.shape[1:], k.shape[1]
-        q, kbuf, vbuf = _rotate_write(q, k, v, kbuf, vbuf, pos, page,
-                                      offset, rope_base)
-        if rows > _GATHER_ROWS:
-            qg = q.reshape(bucket, kvh, heads // kvh, hd).astype(jnp.float32)
-            o = _walk_live_pages(qg, kbuf, vbuf, table, pos, total)
-            return o.reshape(bucket, heads, hd), (kbuf, vbuf)
-        kctx = kbuf[table].reshape(rows, kvh, hd)
-        vctx = vbuf[table].reshape(rows, kvh, hd)
-        qg = q.reshape(bucket, kvh, heads // kvh, hd).astype(jnp.float32)
-        s = jnp.einsum("bgrd,kgd->bgrk", qg,
-                       kctx.astype(jnp.float32)) * (1.0 / (hd ** 0.5))
-        kpos = lax.broadcasted_iota(jnp.int32, s.shape, 3)
-        mask = (kpos <= pos[:, None, None, None]) & (kpos < total)
-        s = jnp.where(mask, s, _NEG_INF)
-        m = s.max(axis=-1, keepdims=True)
-        pr = jnp.where(mask, jnp.exp(s - m), 0.0)
-        l = pr.sum(axis=-1, keepdims=True)
-        l = jnp.where(l == 0.0, 1.0, l)
-        o = jnp.einsum("bgrk,kgd->bgrd", pr / l, vctx.astype(jnp.float32))
-        return o.reshape(bucket, heads, hd), (kbuf, vbuf)
+        q, kbuf, vbuf = _rotate_write(q, k, v, kbuf, vbuf, pos.reshape(-1),
+                                      page, offset, rope_base)
+        qg = q.reshape(lanes, bucket, kvh, heads // kvh, hd).astype(
+            jnp.float32)
+        o = [one_slot(kbuf, vbuf, qg[i], tables[i], pos[i], total[i])
+             for i in range(lanes)]
+        return (jnp.concatenate(o).reshape(lanes * bucket, heads, hd),
+                (kbuf, vbuf))
 
     return attend
 
 
-def _walk_live_pages(qg, kbuf, vbuf, table, pos, total):
+def _gather_pages(kbuf, vbuf, qg, table, pos, total):
+    """Causal attention of the rotated queries ``qg (bucket, kv heads,
+    rep, head_dim)`` float32 at positions ``pos`` over the first
+    ``total`` rows of ONE slot's pages, the slot's whole table gathered
+    for one softmax: ``(bucket, kv heads, rep, head_dim)`` float32."""
+    kvh, hd = qg.shape[1], qg.shape[3]
+    kctx = kbuf[table].reshape(-1, kvh, hd)
+    vctx = vbuf[table].reshape(-1, kvh, hd)
+    s = jnp.einsum("bgrd,kgd->bgrk", qg,
+                   kctx.astype(jnp.float32)) * (1.0 / (hd ** 0.5))
+    kpos = lax.broadcasted_iota(jnp.int32, s.shape, 3)
+    mask = (kpos <= pos[:, None, None, None]) & (kpos < total)
+    s = jnp.where(mask, s, _NEG_INF)
+    m = s.max(axis=-1, keepdims=True)
+    pr = jnp.where(mask, jnp.exp(s - m), 0.0)
+    l = pr.sum(axis=-1, keepdims=True)
+    l = jnp.where(l == 0.0, 1.0, l)
+    return jnp.einsum("bgrk,kgd->bgrd", pr / l, vctx.astype(jnp.float32))
+
+
+def _walk_live_pages(kbuf, vbuf, qg, table, pos, total):
     """Causal attention of the rotated queries ``qg (bucket, kv heads,
     rep, head_dim)`` float32 at positions ``pos`` over the first
     ``total`` rows of ONE slot's pages, a page an iteration under an
-    online softmax: ``(bucket, kv heads, rep, head_dim)`` float32."""
+    online softmax: ``(bucket, kv heads, rep, head_dim)`` float32.
+    The slot's pages are gathered out of the buffers first (4 MB each
+    at 4,096 positions of 512 lanes; the scores were what the
+    whole-table form cost) and the loop reads those: two lanes' loops
+    reading the page buffers themselves had the TPU compiler copy each
+    buffer into the layout its product prefers, 806 MB a layer at
+    ``lfm2_decode_reasoning``'s geometry (PERF.md section 6, PR 38)."""
     bucket, kvh, reps, hd = qg.shape
     ps = kbuf.shape[1]
     sm_scale = 1.0 / (hd ** 0.5)
+    kctx, vctx = kbuf[table], vbuf[table]
 
     def one_page(i, carry):
         m_prev, l, acc = carry
-        k = kbuf[table[i]].reshape(ps, kvh, hd).astype(jnp.float32)
-        v = vbuf[table[i]].reshape(ps, kvh, hd).astype(jnp.float32)
+        k = kctx[i].reshape(ps, kvh, hd).astype(jnp.float32)
+        v = vctx[i].reshape(ps, kvh, hd).astype(jnp.float32)
         s = jnp.einsum("bgrd,kgd->bgrk", qg, k) * sm_scale
         kpos = i * ps + lax.broadcasted_iota(jnp.int32, s.shape, 3)
         mask = (kpos <= pos[:, None, None, None]) & (kpos < total)
@@ -555,50 +615,57 @@ def latent_slot_attention(pool, positions, tables, active, *, inv_freq,
     return attend
 
 
-def latent_chunk_attention(pool, start, chunk_len, table, bucket: int, *,
+def latent_chunk_attention(pool, start, chunk_len, tables, bucket: int, *,
                            inv_freq, sm_scale):
-    """Prefill over a latent page: ``bucket`` rows of ONE slot, as
-    :func:`chunk_attention`'s.  The absorbed form again, walked a page
-    at a time over the slot's LIVE pages only (``start + chunk_len``
-    rows, a traced count) under an online softmax: the plain form would
-    up-project every gathered row to ``heads x (nope + v)`` and a
-    slot's table spans positions a prompt never reaches, where this
-    reads what the decode step will read and keeps a page's scores,
-    ``(bucket, heads, page_size)``, as its largest temporary."""
-    num_pages, ps = pool[0][0].shape[:2]
-    pos = start + jnp.arange(bucket, dtype=jnp.int32)
-    valid = jnp.arange(bucket) < chunk_len
-    total = start + chunk_len
-    page = jnp.where(valid, table[pos // ps], num_pages).astype(jnp.int32)
-    offset = pos % ps
+    """Prefill over a latent page: ``lanes * bucket`` rows, ``bucket``
+    a lane and a lane ONE slot's next chunk, as
+    :func:`chunk_attention`'s.  The absorbed form again (the
+    absorption, the rows' one scatter and the way back through ``W_v``
+    are one product each over every lane's rows), each lane walked a
+    page at a time over its slot's LIVE pages only (``start +
+    chunk_len`` rows, a traced count) under an online softmax: the
+    plain form would up-project every gathered row to ``heads x (nope +
+    v)`` and a slot's table spans positions a prompt never reaches,
+    where this reads what the decode step will read and keeps a page's
+    scores, ``(bucket, heads, page_size)``, as its largest temporary."""
+    lanes, ps = tables.shape[0], pool[0][0].shape[1]
+    pos, page, offset, total = _chunk_rows(pool, start, chunk_len, tables,
+                                           bucket)
 
     def attend(q_nope, q_rope, c_kv, k_rope, w_kvb, buf):
         rank, heads = c_kv.shape[-1], q_nope.shape[1]
         q, w_v, buf = _latent_write(q_nope, q_rope, c_kv, k_rope, w_kvb,
-                                    buf, pos, page, offset, inv_freq)
+                                    buf, pos.reshape(-1), page, offset,
+                                    inv_freq)
 
-        def one_page(i, carry):
-            m_prev, l, acc = carry
-            rows = buf[table[i]]                              # (ps, width)
-            s = jnp.einsum("bhw,kw->bhk", q, rows,
-                           preferred_element_type=jnp.float32) * sm_scale
-            kpos = i * ps + lax.broadcasted_iota(jnp.int32, s.shape, 2)
-            mask = (kpos <= pos[:, None, None]) & (kpos < total)
-            s = jnp.where(mask, s, _NEG_INF)
-            m_cur = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-            corr = jnp.exp(m_prev - m_cur)
-            p = jnp.where(mask, jnp.exp(s - m_cur), 0.0)
-            pv = jnp.einsum("bhk,kr->bhr", p.astype(rows.dtype),
-                            rows[:, :rank],
-                            preferred_element_type=jnp.float32)
-            return (m_cur, l * corr + p.sum(axis=-1, keepdims=True),
-                    acc * corr + pv)
+        def one_slot(q, table, pos, total):
+            def one_page(i, carry):
+                m_prev, l, acc = carry
+                rows = buf[table[i]]                          # (ps, width)
+                s = jnp.einsum("bhw,kw->bhk", q, rows,
+                               preferred_element_type=jnp.float32) * sm_scale
+                kpos = i * ps + lax.broadcasted_iota(jnp.int32, s.shape, 2)
+                mask = (kpos <= pos[:, None, None]) & (kpos < total)
+                s = jnp.where(mask, s, _NEG_INF)
+                m_cur = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+                corr = jnp.exp(m_prev - m_cur)
+                p = jnp.where(mask, jnp.exp(s - m_cur), 0.0)
+                pv = jnp.einsum("bhk,kr->bhr", p.astype(rows.dtype),
+                                rows[:, :rank],
+                                preferred_element_type=jnp.float32)
+                return (m_cur, l * corr + p.sum(axis=-1, keepdims=True),
+                        acc * corr + pv)
 
-        init = (jnp.full((bucket, heads, 1), _NEG_INF, jnp.float32),
-                jnp.zeros((bucket, heads, 1), jnp.float32),
-                jnp.zeros((bucket, heads, rank), jnp.float32))
-        _, l, acc = lax.fori_loop(0, (total + ps - 1) // ps, one_page, init)
-        o = (acc / jnp.where(l == 0.0, 1.0, l)).astype(q.dtype)
+            init = (jnp.full((bucket, heads, 1), _NEG_INF, jnp.float32),
+                    jnp.zeros((bucket, heads, 1), jnp.float32),
+                    jnp.zeros((bucket, heads, rank), jnp.float32))
+            _, l, acc = lax.fori_loop(0, (total + ps - 1) // ps, one_page,
+                                      init)
+            return (acc / jnp.where(l == 0.0, 1.0, l)).astype(q.dtype)
+
+        ql = q.reshape((lanes, bucket) + q.shape[1:])
+        o = jnp.concatenate([one_slot(ql[i], tables[i], pos[i], total[i])
+                             for i in range(lanes)])
         return jnp.einsum("bhr,rhd->bhd", o, w_v), (buf,)
 
     return attend

@@ -26,10 +26,13 @@ or manually):
 2. admit — free slots pull from the queue when the page budget
    (prompt + max_new [+ spec window]) fits; pages are acquired in full
    at admission so generation can never run out mid-flight;
-3. prefill — each admitted slot is dispatched ONE pow2-bucketed prompt
-   chunk (chunked prefill: long prompts interleave with running decodes
-   instead of stalling them); the final chunk's token stays on the
-   device, as the slot's row of the engine's resident decode state;
+3. prefill — each admitted slot is given ONE prompt chunk a turn
+   (chunked prefill: long prompts interleave with running decodes
+   instead of stalling them), and the chunks of all filling slots go to
+   the engine together: up to ``engine.prefill_lanes`` ride as lanes of
+   one dispatch, one pass over the weights; the final chunk's token
+   stays on the device, as the slot's row of the engine's resident
+   decode state;
 4. decode — one batched token step over every decoding slot is
    dispatched from that state, and nothing of it is waited for;
 5. commit — the ONE blocking read of the turn, and it is of the turn
@@ -350,8 +353,8 @@ class DecodeScheduler:
         # the step record, filled in as the phases go: `tokens`,
         # `completed`, `ttft_ms` and `queue_wait_ms` where tokens are
         # committed
-        extra = {"tokens": 0, "prefill_tokens": 0, "completed": 0,
-                 "ttft_ms": [], "queue_wait_ms": []}
+        extra = {"tokens": 0, "prefill_tokens": 0, "prefill_runs": 0,
+                 "completed": 0, "ttft_ms": [], "queue_wait_ms": []}
 
         with tracing.span("decode.expire"):
             evictions = self._expire(now)
@@ -463,24 +466,29 @@ class DecodeScheduler:
         return admitted
 
     def _prefill(self, extra: dict) -> list:
-        """Phase 3: one chunk per prefilling slot, dispatched and not
-        waited for.  Returns ``(request, its first token on the
+        """Phase 3: the next chunk of every prefilling slot, handed to
+        the engine together (several ride in one dispatch), dispatched
+        and not waited for.  Returns ``(request, its first token on the
         device)`` for every prompt whose last chunk this was."""
         eng = self.engine
+        filling = [r for r in self._slots
+                   if r is not None and r.prefilled < len(r.prompt)]
+        if not filling:
+            return []
+        chunks = [(r.slot, r.prompt[r.prefilled:
+                                    r.prefilled + eng.prefill_chunk],
+                   r.prefilled) for r in filling]
+        runs = eng.prefill_runs
+        toks = eng.prefill_chunks(chunks)
+        extra["prefill_runs"] = eng.prefill_runs - runs
         firsts = []
-        for s, r in enumerate(self._slots):
-            if r is None or r.prefilled >= len(r.prompt):
-                continue
-            chunk = r.prompt[r.prefilled:
-                             r.prefilled + eng.prefill_chunk]
-            with tracing.span("decode.prefill", request_id=r.rid,
-                              slot=s, tokens=len(chunk)):
-                tok = eng.prefill_chunk_step(s, chunk, r.prefilled)
+        for r, (_, chunk, _), tok in zip(filling, chunks, toks):
             r.prefilled += len(chunk)
             extra["prefill_tokens"] += len(chunk)
-            telemetry.counter("decode.prefill_tokens").inc(len(chunk))
             if r.prefilled >= len(r.prompt):
                 firsts.append((r, tok))
+        telemetry.counter("decode.prefill_tokens").inc(
+            extra["prefill_tokens"])
         return firsts
 
     def _chained_turn(self, firsts: list, extra: dict) -> int:

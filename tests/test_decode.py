@@ -231,9 +231,11 @@ def _core_call(eng, core):
     if core == "verify":
         return mdl.verify_core, (jnp.zeros((slots, 3), jnp.int32), ints,
                                  tables, act)
-    slot = (jnp.int32(0),) if mdl.state_spec else ()
-    return mdl.prefill_core, (jnp.zeros((8,), jnp.int32), jnp.int32(0),
-                              jnp.int32(5), tables[0]) + slot
+    slot = (jnp.zeros((1,), jnp.int32),) if mdl.state_spec else ()
+    return mdl.prefill_core, (jnp.zeros((1, 8), jnp.int32),
+                              jnp.zeros((1,), jnp.int32),
+                              jnp.full((1,), 5, jnp.int32),
+                              tables[:1]) + slot
 
 
 @pytest.mark.parametrize("family,core", [
@@ -244,14 +246,13 @@ def test_cores_touch_a_layer_buffer_only_by_its_scatter(model, family, core):
     it or sets it back: per layer the only equations as large as a
     buffer are the two scatters of the paged format's one write and,
     for a model with recurrent state, the update of the state-space
-    state (the kernel's aliased output in a decode step, the slot's
-    rows set back after a chunk).  (A slice of buffer size is a 201 MB
+    state (the kernel's aliased output in a decode step, the lanes'
+    slots' rows set back in one scatter after a prefill dispatch).  (A slice of buffer size is a 201 MB
     copy in front of the Mosaic call on the chip.)"""
     if family == "hybrid":
         # 8 slots x (4 heads x 16 x 16) of state, as large as a K/V buffer
         model, kw = _tiny_hybrid(), dict(max_slots=8, num_pages=32)
-        state = {"decode": ["pallas_call"],
-                 "prefill": ["dynamic_update_slice"]}[core]
+        state = {"decode": ["pallas_call"], "prefill": ["scatter"]}[core]
     else:
         kw, state = {}, []
     # a fraction of the pool per slot table, so no gather is as large
@@ -317,7 +318,7 @@ def test_pool_is_one_k_and_one_v_buffer_per_layer(model, draft, spec):
     prompt = _prompts(1, lo=5, hi=5, seed=11)[0]
     eng.acquire_slot(0, 8)      # holds page 0 and stays inactive
     eng.acquire_slot(1, len(prompt) + 4)
-    tok = eng.prefill_chunk_step(1, prompt, 0)
+    tok, = eng.prefill_chunks([(1, prompt, 0)])
     if spec:
         tokens = onp.zeros((eng.max_slots,), onp.int32)
         pos = onp.zeros((eng.max_slots,), onp.int32)
@@ -421,15 +422,19 @@ def test_eos_stops_generation(model):
 
 
 def test_warm_admissions_never_recompile(model):
-    """The fixed-shape contract: after the first wave compiles the
-    prefill bucket + decode executables, a second wave with staggered
-    admissions (requests joining mid-flight) adds zero compiles, and
-    every page returns to the free list."""
+    """The fixed-shape contract: after warm-up has compiled the
+    prefill bucket, the multi-lane prefills and the decode executables,
+    neither a first wave (three slots filling in one turn: a dispatch
+    of two lanes and one of one) nor a second with staggered admissions
+    (requests joining mid-flight: one lane, then two) adds a compile, and every
+    page returns to the free list."""
     eng = _engine(model)
     sch = _sched(eng)
     prompts = _prompts(6, lo=3, hi=8, seed=5)   # one pow2 bucket
-    _gen(sch, prompts[:3], max_new=6)
+    eng.warmup([8])
     warm = eng.compiles
+    _gen(sch, prompts[:3], max_new=6)
+    assert eng.compiles == warm
     assert warm > 0 and eng.cache.pages_used() == 0
     futs = [sch.submit(prompts[3], max_new_tokens=6)]
     sch.step()                          # admit + begin while others queue
@@ -439,6 +444,49 @@ def test_warm_admissions_never_recompile(model):
         model.greedy_reference(p, 6) for p in prompts[3:]]
     assert eng.compiles == warm         # steady state: 0 new compiles
     assert eng.cache.pages_used() == 0
+    sch.close(drain=True)
+
+
+def test_a_burst_fills_its_slots_in_one_prefill_dispatch(model, monkeypatch):
+    """Warmed as the benchmark's drivers warm an engine (every one-lane
+    bucket from the floor to the chunk, nothing said of lanes), a burst
+    that fills four slots in one turn is ONE prefill dispatch of four
+    lanes and compiles nothing; the turn after carries the two prompts
+    that have a chunk left as two lanes, the last turn one; the step
+    record counts the dispatches beside the tokens, and
+    ``stats()["prefill"]`` and its traced twin count runs, chunks and
+    rows (the twin: what was dispatched under a capture)."""
+    eng = _engine(model, prefill_chunk=16, prefill_floor=8)
+    assert eng.warmup([8, 16]) == ["decode", "state_edit", "prefill_b8",
+                                   "prefill_b16", "prefill_b32",
+                                   "prefill_b64"]
+    compiled = eng.compiles
+    on = {"now": False}
+    monkeypatch.setattr(tracing, "capturing", lambda: on["now"])
+    sch = _sched(eng)
+    assert eng.prefill_lanes == 4           # 256 // 16, and four slots
+    rs = onp.random.RandomState(21)
+    prompts = [[int(t) for t in rs.randint(0, VOCAB, size=n)]
+               for n in (5, 20, 16, 37)]
+    futs = [sch.submit(p, max_new_tokens=3) for p in prompts]
+    turns = [sch.step()]
+    on["now"] = True            # the capture covers the second turn
+    turns.append(sch.step())
+    on["now"] = False
+    turns.append(sch.step())
+    assert [(t["prefill_runs"], t["prefill_tokens"]) for t in turns] == [
+        (1, 5 + 16 + 16 + 16), (1, 4 + 16), (1, 5)]
+    _run(sch)
+    assert [f.result(0) for f in futs] == [
+        model.greedy_reference(p, 3) for p in prompts]
+    assert eng.compiles == compiled
+    stats = eng.stats()
+    assert stats["prefill"] == {"runs": 3, "chunks": 7,
+                                "rows": 4 * 16 + 2 * 16 + 8,
+                                "chunks_per_run": 7 / 3}
+    assert stats["traced"]["prefill"] == {"runs": 1, "chunks": 2,
+                                          "rows": 32, "chunks_per_run": 2.0}
+    assert _engine(model).stats()["prefill"]["chunks_per_run"] == 0.0
     sch.close(drain=True)
 
 
@@ -696,8 +744,8 @@ def _mixed_turn(model, **kw):
 
 def test_one_turn_leaves_every_phase_span_nested(model, _traced):
     """One ``step()`` under ``tracing.enable()``: the eight names, each
-    phase inside ``decode.step``, one ``decode.prefill`` per chunk with
-    its staging inside, and ONE ``decode.sync``: the turn's only wait,
+    phase inside ``decode.step``, one ``decode.prefill`` per dispatch
+    with its staging inside, and ONE ``decode.sync``: the turn's only wait,
     for the turn before."""
     sch = _mixed_turn(model)
     tracing.enable()
@@ -727,9 +775,8 @@ def test_one_turn_leaves_every_phase_span_nested(model, _traced):
     assert admit["dur"] == 0 and parent(admit) == "decode.admit_phase"
     # one chunk, one prefill span (a `with`, no record_span beside it)
     prefill, = [e for e in evs if e["name"] == "decode.prefill"]
-    assert prefill["args"]["slot"] == 1
+    assert prefill["args"]["lanes"] == 1
     assert prefill["args"]["tokens"] >= 4
-    assert "request_id" in prefill["args"]
     one = {e["name"]: e["args"] for e in evs}
     assert one["decode.admit_phase"]["admitted"] == 1
     # the slot that just prefilled its only chunk decodes in this turn too
@@ -767,7 +814,7 @@ def test_a_turn_under_a_capture_reaches_the_host_plane(
         "mxtpu." + n for n in PHASES + ("decode.step", "decode.stage",
                                         "decode.sync")}
     prefill, = [e for e in found if e["name"] == "mxtpu.decode.prefill"]
-    assert prefill["stats"]["slot"] == 1
+    assert prefill["stats"]["lanes"] == 1
     assert tracing._completed_events() == []
 
 
@@ -965,32 +1012,45 @@ def _trace_patterns():
     """The executables a per-layer metric reads, by the pattern its file
     under ``chipbench/layer_metrics/`` gives the trace reader."""
     out = {}
-    for name in ("decode_exec_ms_p50", "prefill_exec_ms_p50"):
+    for name in ("decode_exec_ms_p50", "prefill_exec_ms_p50",
+                 "prefill_runs_per_step"):
         with open(REPO / "chipbench" / "layer_metrics" / f"{name}.json") as f:
-            out[name] = json.load(f)["args"]["pattern"]
-    return out
+            out[name] = json.load(f)["args"]
+    # a prefill run a decode run: the two executables' own patterns
+    runs = out.pop("prefill_runs_per_step")
+    assert (runs["pattern"], runs["per"]) == (
+        out["prefill_exec_ms_p50"]["pattern"],
+        out["decode_exec_ms_p50"]["pattern"])
+    return {name: args["pattern"] for name, args in out.items()}
 
 
 @pytest.mark.parametrize("kind,key,metric", [
     ("plain", "decode", "decode_exec_ms_p50"),
     ("plain", "state_edit", None),
     ("plain", "prefill_b16", "prefill_exec_ms_p50"),
+    # the multi-lane prefill: two lanes of the full chunk (128)
+    ("plain", "prefill_b256", "prefill_exec_ms_p50"),
     ("spec", "draft", None),
     ("spec", "verify", None),
     ("spec", "prefill_b16", "prefill_exec_ms_p50"),
-    ("spec", "draft_prefill_b16", None)])
+    ("spec", "draft_prefill_b16", None),
+    ("spec", "prefill_b256", "prefill_exec_ms_p50"),
+    ("spec", "draft_prefill_b256", None)])
 def test_executable_carries_its_key_as_its_name(warm_engines, kind, key,
                                                 metric):
     """A device trace shows an executable as its module's name: each of
     the engine's is ``jit_mxtpu_<key>``, not ``jit__lambda_``, warm-up
     materialises all a turn can dispatch (the chained turn's pair, or
-    the speculative turn's), and the benchmark's patterns match the
-    decode and prefill executables and nothing else."""
+    the speculative turn's, and beside the one-lane prefill buckets
+    every multi-lane prefill, named by its rows, lanes x the full
+    chunk, which no one-lane bucket can reach), and the benchmark's
+    patterns match the decode and prefill executables and nothing
+    else: ``prefill_runs_per_step`` reads the same pattern."""
     eng, keys = warm_engines[kind]
     assert sorted(eng._exec) == sorted(keys) == sorted(
-        {"plain": ["decode", "state_edit", "prefill_b16"],
-         "spec": ["draft", "verify", "prefill_b16",
-                  "draft_prefill_b16"]}[kind])
+        {"plain": ["decode", "state_edit", "prefill_b16", "prefill_b256"],
+         "spec": ["draft", "verify", "prefill_b16", "draft_prefill_b16",
+                  "prefill_b256", "draft_prefill_b256"]}[kind])
     text = eng._exec[key].as_text()
     assert f"HloModule jit_mxtpu_{key}," in text
     assert "lambda" not in text.split("\n", 1)[0]

@@ -116,13 +116,14 @@ class _Through:
             chunk = prompt[start:start + CHUNK]
             padded = onp.zeros((eng.prefill_bucket(len(chunk)),), onp.int32)
             padded[:len(chunk)] = chunk
+            # one lane: the lanes == 1 case of the one prefill path
             eng.cache.pool, logits = self.prefill(
-                self.model.params, eng.cache.pool, jnp.asarray(padded),
-                jnp.asarray(start, jnp.int32),
-                jnp.asarray(len(chunk), jnp.int32),
-                jnp.asarray(eng.cache.tables[slot], jnp.int32),
-                jnp.asarray(slot, jnp.int32))
-        return onp.asarray(logits, onp.float32)
+                self.model.params, eng.cache.pool, jnp.asarray(padded)[None],
+                jnp.asarray([start], jnp.int32),
+                jnp.asarray([len(chunk)], jnp.int32),
+                jnp.asarray(eng.cache.tables[slot], jnp.int32)[None],
+                jnp.asarray([slot], jnp.int32))
+        return onp.asarray(logits[0], onp.float32)
 
     def step(self, feed):
         """One decode step; ``feed`` maps slot -> (token, position).
@@ -344,8 +345,10 @@ def test_scheduler_matches_the_dense_oracle_and_never_recompiles(
         models, _clean):
     model, _ = models("float32", "ones")
     eng = _engine(model, max_slots=2)
+    # two slots: one multi-lane executable, two lanes of the full chunk
     assert eng.warmup([8, CHUNK]) == ["decode", "state_edit", "state_reset",
-                                      "prefill_b8", "prefill_b16"]
+                                      "prefill_b8", "prefill_b16",
+                                      "prefill_b32"]
     compiled = eng.compiles
 
     class Sink:
@@ -629,7 +632,8 @@ def test_chunk_attention_grouped_is_multi_head_over_repeated_heads(
                                            jnp.float32), rep, axis=1)
                     for _ in range(2))
             attend = chunk_attention(
-                (kv,), jnp.int32(at), jnp.int32(n), table, bucket,
+                (kv,), jnp.asarray([at], jnp.int32),
+                jnp.asarray([n], jnp.int32), table[None], bucket,
                 rope_base=10000.0)
             out, kv = attend(q, k, v, *kv)
         return onp.asarray(out)[:chunk_len]

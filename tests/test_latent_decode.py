@@ -162,13 +162,14 @@ class _Through:
             chunk = prompt[start:start + CHUNK]
             padded = onp.zeros((eng.prefill_bucket(len(chunk)),), onp.int32)
             padded[:len(chunk)] = chunk
+            # one lane: the lanes == 1 case of the one prefill path
             (eng.cache.pool, logits), picks = self._prefill(
-                self.model.params, eng.cache.pool, jnp.asarray(padded),
-                jnp.asarray(start, jnp.int32),
-                jnp.asarray(len(chunk), jnp.int32),
-                jnp.asarray(eng.cache.tables[slot], jnp.int32))
+                self.model.params, eng.cache.pool, jnp.asarray(padded)[None],
+                jnp.asarray([start], jnp.int32),
+                jnp.asarray([len(chunk)], jnp.int32),
+                jnp.asarray(eng.cache.tables[slot], jnp.int32)[None])
             self._keep(picks, [(i, start + i) for i in range(len(chunk))])
-        return onp.asarray(logits, onp.float32)
+        return onp.asarray(logits[0], onp.float32)
 
     def step(self, slot, token, position):
         n = self.eng.max_slots
@@ -467,7 +468,7 @@ def test_the_absorbed_form_is_the_plain_one(models):
     pool = ((jnp.zeros((6, 8, model.width), jnp.float32),),)
     table = jnp.asarray([4, 1, 3, 0], jnp.int32)
     got, (buf,) = paged_kv.latent_chunk_attention(
-        pool, jnp.asarray(0), jnp.asarray(t_), table, 32, **kw)(
+        pool, jnp.asarray([0]), jnp.asarray([t_]), table[None], 32, **kw)(
         *(jnp.pad(a, ((0, 32 - t_),) + ((0, 0),) * (a.ndim - 1))
           for a in (q_nope, q_rope, c_kv, k_rope)), w_kvb, pool[0][0])
     assert float(jnp.abs(got[:t_] - want).max()) < 2e-5
@@ -736,8 +737,9 @@ def test_scheduler_matches_the_dense_oracle_and_never_recompiles(models,
     dispatched and read at once, by hand) give the same tokens."""
     model, _ = models("float32")
     eng = _engine(model, max_slots=2)
+    # two slots: one multi-lane executable, two lanes of the full chunk
     assert eng.warmup([8, CHUNK]) == ["decode", "state_edit", "prefill_b8",
-                                      "prefill_b16"]
+                                      "prefill_b16", "prefill_b32"]
     compiled = eng.compiles
     sch = DecodeScheduler(eng, start=False)
     prompts = [_tokens(n, seed=n) for n in (3, 16, 23, 40, 9)]
@@ -753,7 +755,7 @@ def test_scheduler_matches_the_dense_oracle_and_never_recompiles(models,
         sync.acquire_slot(0, len(p) + 5)
         tok = None
         for start in range(0, len(p), CHUNK):
-            tok = sync.prefill_chunk_step(0, p[start:start + CHUNK], start)
+            tok, = sync.prefill_chunks([(0, p[start:start + CHUNK], start)])
         sync.activate_slot(0, tok, len(p))
         out = [int(tok)]
         for _ in range(4):

@@ -166,14 +166,15 @@ class _Through:
             piece = prompt[start:start + chunk]
             padded = onp.zeros((eng.prefill_bucket(len(piece)),), onp.int32)
             padded[:len(piece)] = piece
+            # one lane: the lanes == 1 case of the one prefill path
             (eng.cache.pool, logits), picks = self._prefill(
-                self.model.params, eng.cache.pool, jnp.asarray(padded),
-                jnp.asarray(start, jnp.int32),
-                jnp.asarray(len(piece), jnp.int32),
-                jnp.asarray(eng.cache.tables[slot], jnp.int32),
-                jnp.asarray(slot, jnp.int32))
+                self.model.params, eng.cache.pool, jnp.asarray(padded)[None],
+                jnp.asarray([start], jnp.int32),
+                jnp.asarray([len(piece)], jnp.int32),
+                jnp.asarray(eng.cache.tables[slot], jnp.int32)[None],
+                jnp.asarray([slot], jnp.int32))
             self._keep(picks, [(i, start + i) for i in range(len(piece))])
-        return onp.asarray(logits, onp.float32)
+        return onp.asarray(logits[0], onp.float32)
 
     def step(self, slot, token, position):
         n = self.eng.max_slots
@@ -334,9 +335,10 @@ def test_a_long_table_is_walked_by_its_live_pages(models, ref, monkeypatch,
     assert worst <= TOL["float32"] and widest <= MARGIN["float32"], worst
     model, _ = models("float32")
     eng = _engine(model)
-    args = (model.params, eng.cache.pool, jnp.zeros((8,), jnp.int32),
-            jnp.int32(0), jnp.int32(5),
-            jnp.asarray(eng.cache.tables[0], jnp.int32), jnp.int32(0))
+    args = (model.params, eng.cache.pool, jnp.zeros((1, 8), jnp.int32),
+            jnp.zeros((1,), jnp.int32), jnp.full((1,), 5, jnp.int32),
+            jnp.asarray(eng.cache.tables[:1], jnp.int32),
+            jnp.zeros((1,), jnp.int32))
     assert "while" in str(jax.make_jaxpr(model.prefill_logits)(*args))
     monkeypatch.setattr(paged_kv, "_GATHER_ROWS", 2048)
     assert "while" not in str(jax.make_jaxpr(model.prefill_logits)(*args))
@@ -560,7 +562,7 @@ def test_the_three_convolutions_are_one_convolution():
         x = onp.zeros((bucket, 6), onp.float32)
         x[:n] = g[start:start + n]
         y, tail = paged_kv.chunk_conv(tail, jnp.asarray(x), jnp.asarray(w),
-                                      jnp.asarray(2), jnp.asarray(n))
+                                      jnp.asarray([2]), jnp.asarray([n]))
         got.append(onp.asarray(y)[:n])
         start += n
     for t in (11, 12):
@@ -819,8 +821,10 @@ def test_scheduler_matches_the_dense_oracle_and_never_recompiles(models,
     dispatched and read at once, by hand) give the same tokens."""
     model, _ = models("float32")
     eng = _engine(model, max_slots=2)
+    # two slots: one multi-lane executable, two lanes of the full chunk
     assert eng.warmup([8, CHUNK]) == ["decode", "state_edit", "state_reset",
-                                      "prefill_b8", "prefill_b16"]
+                                      "prefill_b8", "prefill_b16",
+                                      "prefill_b32"]
     compiled = eng.compiles
     sch = DecodeScheduler(eng, start=False)
     prompts = [_tokens(n, seed=n) for n in (1, 16, 17, 40, 9)]
@@ -836,7 +840,7 @@ def test_scheduler_matches_the_dense_oracle_and_never_recompiles(models,
         sync.acquire_slot(0, len(p) + 5)
         tok = None
         for start in range(0, len(p), CHUNK):
-            tok = sync.prefill_chunk_step(0, p[start:start + CHUNK], start)
+            tok, = sync.prefill_chunks([(0, p[start:start + CHUNK], start)])
         sync.activate_slot(0, tok, len(p))
         out = [int(tok)]
         for _ in range(4):

@@ -300,12 +300,28 @@ def _lower_chained_decode(mdl, params, pool, state):
                    donate_argnums=(1, 2)).lower(params, pool, state)
 
 
-@pytest.mark.parametrize("key", ["decode", "prefill_b128"])
+def _lower_prefill(mdl, params, pool, spec, pps, key, chunk):
+    """``prefill_b<rows>`` as ``DecodeEngine`` compiles it on a TPU: the
+    model's lane-form core inside the engine's wrapper, which unpacks
+    the one staged array, the pool donated.  More rows than the chunk
+    are lanes of the full chunk; fewer are one lane's bucket."""
+    from mxnet_tpu.serving.decode import engine as E
+    rows = int(key.rsplit("b", 1)[1])
+    lanes = max(1, rows // chunk)
+    stateful = any(state for _, state in mdl.cache_layout)
+    return jax.jit(lambda *a: E._prefill_core(mdl, stateful, pps, *a),
+                   donate_argnums=(1,)).lower(
+        params, pool, spec((lanes, rows // lanes + 3 + pps), "int32"))
+
+
+@pytest.mark.parametrize("key", ["decode", "prefill_b128", "prefill_b512"])
 def test_decode_executables_update_the_pool_in_place(v5e, key):
     """``memory_analysis`` of the compiled executable: the whole pool is
     aliased to the outputs, and the temporaries hold no copy of even one
     layer buffer (a slice in front of the Mosaic call, or a buffer set
-    back after the scatter, is 201 MB each)."""
+    back after the scatter, is 201 MB each).  ``prefill_b512`` is the
+    multi-lane executable of a paged-only model: four slots' chunks of
+    128 in one dispatch."""
     from mxnet_tpu.serving import DecodeModel
     layers, slots, pps = 2, 96, 64
     mdl = DecodeModel(512, dim=1024, n_heads=16, n_layers=layers,
@@ -323,10 +339,7 @@ def test_decode_executables_update_the_pool_in_place(v5e, key):
         lowered = _lower_chained_decode(mdl, params, pool,
                                         _resident(spec, slots, pps))
     else:
-        lowered = jax.jit(lambda *a: mdl.prefill_core(*a),
-                          donate_argnums=(1,)).lower(
-            params, pool, spec((128,), "int32"), spec((), "int32"),
-            spec((), "int32"), spec((pps,), "int32"))
+        lowered = _lower_prefill(mdl, params, pool, spec, pps, key, 128)
     mem = lowered.compile().memory_analysis()
     one = buf.size * buf.dtype.itemsize          # 201 MB
     state = mem.alias_size_in_bytes - 2 * layers * one
@@ -343,11 +356,17 @@ def test_decode_executables_update_the_pool_in_place(v5e, key):
 # parameters are shapes: nothing of their 4.4 GB is allocated).
 
 @pytest.mark.parametrize("key", ["decode", "prefill_b128", "prefill_b16",
+                                 "prefill_b256", "prefill_b512",
                                  "state_reset"])
 def test_hybrid_executables_update_kv_and_state_in_place(v5e, key):
     """K/V and both state buffers are aliased to the outputs, and the
     temporaries stay under one state-space buffer (403 MB): no
-    executable holds a copy of a buffer it was donated."""
+    executable holds a copy of a buffer it was donated, the multi-lane
+    prefill executables (two and four slots' chunks of 128 in one
+    dispatch: their states read a slice a lane, written back in one
+    scatter) no more than the one-lane ones.  A gather of the lanes'
+    states had the TPU compiler copy the 403 MB buffer whole, a layer
+    (PERF.md section 6, PR 38)."""
     import json
     from mxnet_tpu.serving import FalconH1
     from mxnet_tpu.serving.decode import engine as E
@@ -380,18 +399,27 @@ def test_hybrid_executables_update_kv_and_state_in_place(v5e, key):
             mdl, params, pool, _resident(spec, FH_SLOTS, FH_PAGES))
     else:
         donated = pool
-        bucket = int(key.rsplit("b", 1)[1])
-        lowered = jax.jit(lambda *a: mdl.prefill_core(*a),
-                          donate_argnums=(1,)).lower(
-            params, pool, spec((bucket,), "int32"), spec((), "int32"),
-            spec((), "int32"), spec((FH_PAGES,), "int32"),
-            spec((), "int32"))
+        lowered = _lower_prefill(mdl, params, pool, spec, FH_PAGES, key, 128)
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
     state = mem.alias_size_in_bytes - sum(
         nbytes(b) for layer in donated for b in layer)
     assert 0 <= state < 2 ** 16 and (state > 0) == (key == "decode")
     assert mem.temp_size_in_bytes < nbytes(ssm_buf), mem.temp_size_in_bytes
+    if key.startswith("prefill"):
+        # nor a quarter of one: 17.8 MB (b128), 11.2 (b16), 33.9 (b256),
+        # 73.0 (b512) at these two layers and the whole vocabulary
+        assert mem.temp_size_in_bytes < 96e6, mem.temp_size_in_bytes
+        # ONE product a weight matrix whatever the lanes (nine a layer:
+        # q, k, v, o, the mixer's in and out, the MLP's three), six a
+        # lane and layer for what is a slot's own (two of its attention,
+        # four of its chunked scan), and the head: a product over the
+        # lanes' last rows, a multiply-reduce for one row
+        import re
+        lanes = max(1, int(key.rsplit("b", 1)[1]) // 128)
+        products = re.findall(r"= \S+ (?:convolution|dot)\(",
+                              compiled.as_text())
+        assert len(products) == 2 * (9 + 6 * lanes) + (lanes > 1)
     if key == "decode":
         import re
         calls = re.findall(r'^\s*(?:ROOT )?%(\S+) = .*custom_call_target='
@@ -407,10 +435,12 @@ def test_hybrid_executables_update_kv_and_state_in_place(v5e, key):
 # vocabulary's slice (shapes only).  All seven layers, compiled here by
 # hand (PERF.md section 4, PR 33): arguments 14.380 GB of which the
 # pool's 4.698 GB is aliased; temporaries 99.6 MB (decode), 152.0 MB
-# (prefill b256), 65.0 MB (b32).
+# (prefill b256), 65.0 MB (b32).  ``prefill_b512`` is two lanes of 256
+# (PR 38): 161.2 MB at these two layers against 103.8 at one lane.
 
 @pytest.mark.parametrize("key,temp_mb", [("decode", 64), ("prefill_b256", 140),
-                                         ("prefill_b32", 48)])
+                                         ("prefill_b32", 48),
+                                         ("prefill_b512", 280)])
 def test_latent_executables_update_the_cache_in_place(v5e, key, temp_mb):
     """The latent pages are aliased to the outputs whole, the
     temporaries hold no copy of a layer's buffer and stay near what
@@ -443,11 +473,8 @@ def test_latent_executables_update_the_cache_in_place(v5e, key, temp_mb):
         lowered = _lower_chained_decode(mdl, params, pool,
                                         _resident(spec, slots, pps))
     else:
-        bucket = int(key.rsplit("b", 1)[1])
-        lowered = jax.jit(lambda *a: mdl.prefill_core(*a),
-                          donate_argnums=(1,)).lower(
-            params, pool, spec((bucket,), "int32"), spec((), "int32"),
-            spec((), "int32"), spec((pps,), "int32"))
+        lowered = _lower_prefill(mdl, params, pool, spec, pps, key,
+                                 geo["prefill_chunk"])
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
     state = mem.alias_size_in_bytes - 2 * one
@@ -472,10 +499,14 @@ def test_latent_executables_update_the_cache_in_place(v5e, key, temp_mb):
 # arguments 14.326 GB of which the cache's 3.259 GB is aliased;
 # temporaries 51.8 MB (decode), 18.4 MB (prefill b256), 17.1 MB (b32); a
 # chunk that gathered its slot's whole table of 4,096 positions for one
-# softmax kept 457.2 MB (b256).
+# softmax kept 457.2 MB (b256).  ``prefill_b512`` is two lanes of 256
+# (PR 38): 64.1 MB at these four layers (5.8 at one lane), the stacked
+# experts' rows; while two lanes' walks read the page buffers themselves
+# the compiler copied K and V into another layout, 818 MB.
 
 @pytest.mark.parametrize("key,temp_mb", [("decode", 64), ("prefill_b256", 32),
-                                         ("prefill_b32", 32)])
+                                         ("prefill_b32", 32),
+                                         ("prefill_b512", 96)])
 def test_mixed_layer_executables_update_the_cache_in_place(v5e, key,
                                                            temp_mb):
     """Pages for the attention layer alone and the other layers' tails
@@ -518,11 +549,8 @@ def test_mixed_layer_executables_update_the_cache_in_place(v5e, key,
         lowered = _lower_chained_decode(mdl, params, pool,
                                         _resident(spec, slots, pps))
     else:
-        bucket = int(key.rsplit("b", 1)[1])
-        lowered = jax.jit(lambda *a: mdl.prefill_core(*a),
-                          donate_argnums=(1,)).lower(
-            params, pool, spec((bucket,), "int32"), spec((), "int32"),
-            spec((), "int32"), spec((pps,), "int32"), spec((), "int32"))
+        lowered = _lower_prefill(mdl, params, pool, spec, pps, key,
+                                 geo["prefill_chunk"])
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
     # arguments: the weights, the cache, and a few small arrays
