@@ -266,7 +266,6 @@ def _run_leg(leg, art):
     return json.loads(line[len("RESULT "):])
 
 
-@pytest.mark.timeout(600)
 def test_cross_process_zero_compile(tmp_path, monkeypatch):
     """The ISSUE acceptance gate end to end: a cold process pays every
     compile and commits the executables; a warm process — serving

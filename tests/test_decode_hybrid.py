@@ -99,14 +99,21 @@ def _tokens(n, seed, vocab=128):
             onp.random.RandomState(seed).randint(0, vocab, size=n)]
 
 
+_JITTED = {}        # model -> its two cores under jit, and the model
+
+
 class _Through:
     """Drives an engine's cache by hand, keeping the logits the engine's
     own executables reduce to a token."""
 
     def __init__(self, model, eng):
         self.model, self.eng = model, eng
-        self.decode = jax.jit(model.decode_logits)
-        self.prefill = jax.jit(model.prefill_logits)
+        # one jit a model for the whole file: a new one would trace the
+        # interpreted kernels and compile again for every engine
+        if id(model) not in _JITTED:
+            _JITTED[id(model)] = (jax.jit(model.decode_logits),
+                                  jax.jit(model.prefill_logits), model)
+        self.decode, self.prefill, _ = _JITTED[id(model)]
 
     def feed_prompt(self, slot, prompt):
         """Chunked prefill of ``prompt``; the logits after its last

@@ -62,7 +62,10 @@ def _tiny_cifar(n=32):
 
 def test_transformer_lm_example(monkeypatch, capsys):
     m = _load("gluon/transformer_lm.py", "tlm_example")
-    monkeypatch.setattr(sys, "argv", ["transformer_lm.py", "--steps", "30",
+    # a step of the interpreted flash kernel on all eight devices takes
+    # a second and more in a loaded run; 12 of 12 tokens match after 10
+    # steps and after 20
+    monkeypatch.setattr(sys, "argv", ["transformer_lm.py", "--steps", "15",
                                       "--batch-size", "16",
                                       "--seq-len", "16", "--units", "32",
                                       "--layers", "1"])
@@ -217,7 +220,11 @@ def test_lstm_crf_example():
     """CRF forward-algorithm NLL trains; Viterbi decode is accurate on
     the transition-structured task (parity: example/gluon/lstm_crf)."""
     m = _load("gluon/lstm_crf.py", "lstm_crf_example")
-    net, losses = m.train(iters=80, verbose=False)
+    # every eager step traces and lowers the LSTM's scans anew, half a
+    # second a step and more in a loaded run: a fifth of the steps at
+    # five times the rate.  Seeds 0-2: the loss falls to 0.06-0.10 of
+    # its start and the accuracy reads 0.93-0.95
+    net, losses = m.train(iters=16, lr=0.05, verbose=False)
     assert losses[-1] < losses[0] * 0.3, (losses[0], losses[-1])
     rng = onp.random.RandomState(9)
     words, tags = m.synth_data(rng, 128)

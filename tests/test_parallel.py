@@ -1,5 +1,8 @@
 """Mesh/SPMD tests on the 8-device virtual CPU mesh (parity:
 tests/python/gpu/test_device.py + multi-device kvstore tests)."""
+import importlib.util
+import os
+
 import numpy as onp
 import pytest
 
@@ -84,13 +87,51 @@ def test_spmd_tensor_parallel_shard():
     assert l2 < l1 + 1.0  # trains without error; loss roughly sane
 
 
-def test_graft_dryrun_multichip():
-    import importlib.util
+def _graft_entry():
     spec = importlib.util.spec_from_file_location(
-        "__graft_entry__", "/root/repo/__graft_entry__.py")
+        "__graft_entry__", os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "__graft_entry__.py"))
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.slow
+def test_graft_dryrun_multichip():
+    """Second tier: the driver's entry as the driver calls it, ResNet-50
+    built twice and six hundred eager operators compiled on the way to
+    its two SPMD steps (95 s alone, 105 s in the tier-1 run).
+    ``test_graft_dryrun_multichip_every_section`` walks the same
+    sections in tier-1 with a ResNet of one stage."""
+    _graft_entry().dryrun_multichip(8)
+
+
+def test_graft_dryrun_multichip_every_section(monkeypatch, capsys):
+    """Every section of ``dryrun_multichip`` on the 8-device mesh (dp4 x
+    tp2, ZeRO-3 over dp8, the three pipeline schedules over pp4 x dp2,
+    ring and Ulysses attention over sp4, a transformer over dp4 x tp2,
+    experts over ep4, the 3-D capstone), each held to its 1-device
+    numbers by the entry's own asserts, with the classifier a ResNet of
+    one stage of one basic block (24 s alone; ResNet-18 42 s, ResNet-50
+    95 s)."""
+    from mxnet_tpu.gluon.model_zoo.vision.resnet import (BasicBlockV1,
+                                                         ResNetV1)
+    mod = _graft_entry()
+
+    def one_stage(classes=10, thumbnail=True):
+        net = ResNetV1(BasicBlockV1, [1], [8, 8], classes=classes,
+                       thumbnail=thumbnail)
+        net.initialize(init=mx.initializer.Xavier())
+        return net
+
+    monkeypatch.setattr(mod, "_build_resnet", one_stage)
     mod.dryrun_multichip(8)
+    out = capsys.readouterr().out
+    for section in ("mesh=dp4xtp2", "zero3-fsdp dp8", "gpipe",
+                    "interleaved-gpipe", "true-1f1b", "ring-attention",
+                    "ring-FLASH", "ulysses", "transformer dp4xtp2",
+                    "switch-moe", "3D capstone"):
+        assert section in out, section
 
 
 def test_kvstore_local_pushpull():

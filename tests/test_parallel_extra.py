@@ -33,14 +33,22 @@ def _sequential(params, x):
     return x
 
 
+def _jitted(schedule, stage_fn, mesh, **kw):
+    """``schedule`` as ONE compiled program of (params, x).  Called
+    eagerly a ``shard_map`` dispatches its body primitive by primitive
+    to every device: minutes for an unrolled schedule on 8x4 arrays."""
+    return jax.jit(lambda params, x: schedule(stage_fn, params, x, mesh,
+                                              **kw))
+
+
 def test_gpipe_forward_matches_sequential():
     S, H, B, M = 4, 8, 16, 4
     rng = onp.random.RandomState(0)
     mesh = make_mesh({"pp": S})
     params = _stacked_params(rng, S, H)
     x = jnp.asarray(rng.randn(B, H).astype(onp.float32))
-    got = pipeline_forward(_stage_fn, params, x, mesh, n_microbatches=M,
-                           batch_axis_name=None)
+    got = _jitted(pipeline_forward, _stage_fn, mesh, n_microbatches=M,
+                  batch_axis_name=None)(params, x)
     ref = _sequential(params, x)
     onp.testing.assert_allclose(onp.asarray(got), onp.asarray(ref),
                                 rtol=1e-5, atol=1e-5)
@@ -64,8 +72,8 @@ def test_gpipe_training_step_matches_sequential_grads():
     def seq_loss(p):
         return jnp.mean((_sequential(p, x) - y) ** 2)
 
-    l_pp, g_pp = jax.value_and_grad(pp_loss)(params)
-    l_seq, g_seq = jax.value_and_grad(seq_loss)(params)
+    l_pp, g_pp = jax.jit(jax.value_and_grad(pp_loss))(params)
+    l_seq, g_seq = jax.jit(jax.value_and_grad(seq_loss))(params)
     onp.testing.assert_allclose(float(l_pp), float(l_seq), rtol=1e-5)
     for a, b in zip(g_pp, g_seq):
         onp.testing.assert_allclose(onp.asarray(a), onp.asarray(b),
@@ -79,7 +87,8 @@ def test_gpipe_dp_x_pp():
     mesh = make_mesh({"dp": 2, "pp": S})
     params = _stacked_params(rng, S, H)
     x = jnp.asarray(rng.randn(B, H).astype(onp.float32))
-    got = pipeline_forward(_stage_fn, params, x, mesh, n_microbatches=M)
+    got = _jitted(pipeline_forward, _stage_fn, mesh,
+                  n_microbatches=M)(params, x)
     ref = _sequential(params, x)
     onp.testing.assert_allclose(onp.asarray(got), onp.asarray(ref),
                                 rtol=1e-5, atol=1e-5)
@@ -105,8 +114,8 @@ def test_ring_attention_inside_training_step():
         o = jax.nn.softmax(s, axis=-1) @ v
         return jnp.mean((o @ w) ** 2)
 
-    l_r, g_r = jax.value_and_grad(ring_loss)(wo)
-    l_d, g_d = jax.value_and_grad(dense_loss)(wo)
+    l_r, g_r = jax.jit(jax.value_and_grad(ring_loss))(wo)
+    l_d, g_d = jax.jit(jax.value_and_grad(dense_loss))(wo)
     onp.testing.assert_allclose(float(l_r), float(l_d), rtol=1e-4)
     onp.testing.assert_allclose(onp.asarray(g_r), onp.asarray(g_d),
                                 rtol=1e-3, atol=1e-5)
@@ -266,10 +275,10 @@ def test_ulysses_matches_dense():
             o = jax.nn.softmax(s, axis=-1) @ v
             return jnp.mean((o @ w) ** 2)
 
-        l_u, (gq_u, gw_u) = jax.value_and_grad(
-            uly_loss, argnums=(0, 1))(q, wo)
-        l_d, (gq_d, gw_d) = jax.value_and_grad(
-            dense_loss, argnums=(0, 1))(q, wo)
+        l_u, (gq_u, gw_u) = jax.jit(jax.value_and_grad(
+            uly_loss, argnums=(0, 1)))(q, wo)
+        l_d, (gq_d, gw_d) = jax.jit(jax.value_and_grad(
+            dense_loss, argnums=(0, 1)))(q, wo)
         onp.testing.assert_allclose(float(l_u), float(l_d), rtol=1e-4)
         onp.testing.assert_allclose(onp.asarray(gq_u),
                                     onp.asarray(gq_d),
@@ -361,8 +370,8 @@ def test_interleaved_forward_matches_sequential():
     mesh = make_mesh({"pp": S})
     params = _layer_stack(rng, S * V, H)
     x = jnp.asarray(rng.randn(B, H).astype(onp.float32))
-    got = pipeline_forward_interleaved(_stage_fn, params, x, mesh,
-                                n_microbatches=M, batch_axis_name=None)
+    got = _jitted(pipeline_forward_interleaved, _stage_fn, mesh,
+                  n_microbatches=M, batch_axis_name=None)(params, x)
     ref = _sequential(params, x)
     onp.testing.assert_allclose(onp.asarray(got), onp.asarray(ref),
                                 rtol=1e-5, atol=1e-5)
@@ -399,8 +408,8 @@ def test_interleaved_matches_gpipe_numerics_and_grads():
                                     batch_axis_name=None)
         return jnp.mean((out - y) ** 2)
 
-    l_g, g_g = jax.value_and_grad(gpipe_loss)(gpipe_params)
-    l_f, g_f = jax.value_and_grad(inter_loss)(layers)
+    l_g, g_g = jax.jit(jax.value_and_grad(gpipe_loss))(gpipe_params)
+    l_f, g_f = jax.jit(jax.value_and_grad(inter_loss))(layers)
     onp.testing.assert_allclose(float(l_f), float(l_g), rtol=1e-5)
     for a, b in zip(g_f, g_g):
         onp.testing.assert_allclose(
@@ -476,8 +485,8 @@ def test_interleaved_dp_x_pp():
     mesh = make_mesh({"dp": 2, "pp": S})
     layers = _layer_stack(rng, S * V, H)
     x = jnp.asarray(rng.randn(B, H).astype(onp.float32))
-    got = pipeline_forward_interleaved(_stage_fn, layers, x, mesh,
-                                n_microbatches=M)
+    got = _jitted(pipeline_forward_interleaved, _stage_fn, mesh,
+                  n_microbatches=M)(layers, x)
     ref = _sequential(layers, x)
     onp.testing.assert_allclose(onp.asarray(got), onp.asarray(ref),
                                 rtol=1e-5, atol=1e-5)
@@ -501,7 +510,7 @@ def _seq_value_and_grad(params, x, t, M):
                 h = _stage_fn(jax.tree.map(lambda a: a[s], p), h)
             return _mse(h, tm)
         return jnp.mean(jax.vmap(one)(xmb, tmb))
-    return jax.value_and_grad(loss)(params)
+    return jax.jit(jax.value_and_grad(loss))(params)
 
 
 def test_true_1f1b_matches_sequential_deep_microbatching():
@@ -514,9 +523,9 @@ def test_true_1f1b_matches_sequential_deep_microbatching():
     params = _layer_stack(rng, S, H)
     x = jnp.asarray(rng.randn(B, H).astype(onp.float32))
     t = jnp.asarray(rng.randn(B, H).astype(onp.float32))
-    loss, grads = pipeline_value_and_grad_1f1b(
-        _stage_fn, _mse, params, x, t, mesh, n_microbatches=M,
-        batch_axis_name=None)
+    loss, grads = jax.jit(lambda p, xx, tt: pipeline_value_and_grad_1f1b(
+        _stage_fn, _mse, p, xx, tt, mesh, n_microbatches=M,
+        batch_axis_name=None))(params, x, t)
     lref, gref = _seq_value_and_grad(params, x, t, M)
     onp.testing.assert_allclose(float(loss), float(lref), rtol=1e-6)
     for g, gr in zip(grads, gref):
@@ -532,8 +541,8 @@ def test_true_1f1b_dp_x_pp_matches_sequential():
     params = _layer_stack(rng, S, H)
     x = jnp.asarray(rng.randn(B, H).astype(onp.float32))
     t = jnp.asarray(rng.randn(B, H).astype(onp.float32))
-    loss, grads = pipeline_value_and_grad_1f1b(
-        _stage_fn, _mse, params, x, t, mesh, n_microbatches=M)
+    loss, grads = jax.jit(lambda p, xx, tt: pipeline_value_and_grad_1f1b(
+        _stage_fn, _mse, p, xx, tt, mesh, n_microbatches=M))(params, x, t)
     # dp shards see B/2 rows each with M microbatches; the reference is
     # the mean over both shards of the per-shard microbatched loss
     l0, g0 = _seq_value_and_grad(params, x[:B // 2], t[:B // 2], M)
@@ -602,8 +611,8 @@ def test_pipeline_forward_1f1b_alias_warns():
     layers = _layer_stack(rng, S * V, H)
     x = jnp.asarray(rng.randn(B, H).astype(onp.float32))
     with pytest.warns(DeprecationWarning, match="interleaved"):
-        got = pipeline_forward_1f1b(_stage_fn, layers, x, mesh,
-                                    n_microbatches=M, batch_axis_name=None)
+        got = _jitted(pipeline_forward_1f1b, _stage_fn, mesh,
+                      n_microbatches=M, batch_axis_name=None)(layers, x)
     ref = _sequential(layers, x)
     onp.testing.assert_allclose(onp.asarray(got), onp.asarray(ref),
                                 rtol=1e-5, atol=1e-5)

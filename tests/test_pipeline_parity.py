@@ -55,11 +55,13 @@ def test_gpipe_interleaved_1f1b_value_and_grad_parity():
                                            batch_axis_name=None)
         return _mse(out, t)
 
-    l_g, g_g = jax.value_and_grad(gpipe_loss)(params)
-    l_i, g_i = jax.value_and_grad(inter_loss)(params)
-    l_f, g_f = pipeline_value_and_grad_1f1b(
-        _stage_fn, _mse, params, x, t, mesh, n_microbatches=M,
-        batch_axis_name=None)
+    # each schedule as ONE compiled program: called eagerly, a shard_map
+    # dispatches its body primitive by primitive to every device
+    l_g, g_g = jax.jit(jax.value_and_grad(gpipe_loss))(params)
+    l_i, g_i = jax.jit(jax.value_and_grad(inter_loss))(params)
+    l_f, g_f = jax.jit(lambda p: pipeline_value_and_grad_1f1b(
+        _stage_fn, _mse, p, x, t, mesh, n_microbatches=M,
+        batch_axis_name=None))(params)
 
     for name, (l, g) in (("interleaved", (l_i, g_i)),
                          ("1f1b", (l_f, g_f))):
@@ -85,9 +87,9 @@ def test_schedules_agree_under_dp_x_pp():
         out = pipeline_forward(_stage_fn, p, x, mesh, n_microbatches=M)
         return _mse(out, t)
 
-    l_g, g_g = jax.value_and_grad(gpipe_loss)(params)
-    l_f, g_f = pipeline_value_and_grad_1f1b(
-        _stage_fn, _mse, params, x, t, mesh, n_microbatches=M)
+    l_g, g_g = jax.jit(jax.value_and_grad(gpipe_loss))(params)
+    l_f, g_f = jax.jit(lambda p: pipeline_value_and_grad_1f1b(
+        _stage_fn, _mse, p, x, t, mesh, n_microbatches=M))(params)
     onp.testing.assert_allclose(float(l_f), float(l_g), rtol=1e-6)
     for a, b in zip(g_f, g_g):
         onp.testing.assert_allclose(onp.asarray(a), onp.asarray(b),
@@ -105,7 +107,8 @@ def test_ring_attention_matches_reference_on_composed_mesh():
     q = jnp.asarray(rng.randn(B, H, S, D).astype(onp.float32))
     k = jnp.asarray(rng.randn(B, H, S, D).astype(onp.float32))
     v = jnp.asarray(rng.randn(B, H, S, D).astype(onp.float32))
-    got = ring_self_attention(q, k, v, plan.mesh)
+    got = jax.jit(lambda q, k, v: ring_self_attention(
+        q, k, v, plan.mesh))(q, k, v)
     want = attention_reference(q, k, v)
     onp.testing.assert_allclose(onp.asarray(got), onp.asarray(want),
                                 rtol=1e-5, atol=1e-6)
@@ -121,7 +124,8 @@ def test_ulysses_attention_matches_reference_on_composed_mesh():
     k = jnp.asarray(rng.randn(B, H, S, D).astype(onp.float32))
     v = jnp.asarray(rng.randn(B, H, S, D).astype(onp.float32))
     for causal in (False, True):
-        got = ulysses_self_attention(q, k, v, plan.mesh, causal=causal)
+        got = jax.jit(lambda q, k, v: ulysses_self_attention(
+            q, k, v, plan.mesh, causal=causal))(q, k, v)
         want = attention_reference(q, k, v, causal=causal)
         onp.testing.assert_allclose(onp.asarray(got),
                                     onp.asarray(want),

@@ -122,7 +122,7 @@ def test_device_op_table_totals_match_step_time(tmp_path):
         return sum(r["total_us"] for r in table.values()) / 1e6
 
     table = {}
-    deadline = time.perf_counter() + 5.0
+    deadline = time.perf_counter() + 30.0       # a loaded host flushes late
     while time.perf_counter() < deadline:
         table = profiler.device_op_table()
         if table and total_s_of(table) > 0.3 * wall_s:

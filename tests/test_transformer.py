@@ -303,7 +303,10 @@ def test_vision_transformer_trains():
     tr = SPMDTrainer(vit, gluon.loss.SoftmaxCrossEntropyLoss(),
                      optimizer="adam",
                      optimizer_params={"learning_rate": 1e-3},
-                     mesh=make_mesh({"dp": -1}))
+                     # two devices: a step of the interpreted flash
+                     # kernel takes 0.4 s there and 1.7 s on all eight,
+                     # which share this host's cores
+                     mesh=make_mesh({"dp": 2}))
     first = last = None
     for epoch in range(8):
         for i in range(0, 64, 16):
