@@ -406,13 +406,23 @@ def _unpack_outputs(out, h: int, rep: int, d: int):
 
 def _paged_attention_pallas(q, k_pool, v_pool, tables, lengths,
                             sm_scale, block_k):
+    return _paged_attention_jit(q, k_pool, v_pool, tables, lengths,
+                                float(sm_scale), int(block_k),
+                                jax.default_backend() == "tpu")
+
+
+# Jitted on everything but the arrays: the layers of a step, and every
+# executable that attends over the same grid of slots, share ONE trace
+# of the kernel (PERF.md, PR 40).
+@functools.partial(jax.jit, static_argnums=(5, 6, 7))
+def _paged_attention_jit(q, k_pool, v_pool, tables, lengths, sm_scale,
+                         block_k, on_tpu):
     s_, hq, d = q.shape
     num_pages, page_size = k_pool.shape[:2]
     p_ = tables.shape[1]
     h = _kv_heads(q, k_pool)
     rep = hq // h
     hd = h * d
-    on_tpu = jax.default_backend() == "tpu"
     if hd % 128 and on_tpu:
         # Mosaic copies whole lane tiles: a page of a pool narrower than
         # one, or of one and a part, is no source of a copy.  Such a pool

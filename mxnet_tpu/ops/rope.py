@@ -144,12 +144,20 @@ def _max_block_r(h, d):
 
 def _rope_pallas(x, positions, base, block_r):
     """x (R, H, D), positions (R,) → rotated (R, H, D)."""
+    return _rope_jit(x, jnp.asarray(positions), float(base), int(block_r),
+                     jax.default_backend() != "tpu")
+
+
+# Jitted on everything but the arrays: the layers of a step share ONE
+# trace of the kernel (PERF.md, PR 40).
+@functools.partial(jax.jit, static_argnums=(2, 3, 4))
+def _rope_jit(x, positions, base, block_r, interpret):
     r, h, d = x.shape
     if d % 2:
         raise ValueError(f"rope requires an even head_dim, got {d}")
     block_r = max(1, min(block_r, _ceil_to(r, 8), _max_block_r(h, d)))
     pad = _ceil_to(r, block_r) - r
-    pos = jnp.asarray(positions).astype(jnp.float32)
+    pos = positions.astype(jnp.float32)
     if pad:
         x = jnp.pad(x, ((0, pad), (0, 0), (0, 0)))
         pos = jnp.pad(pos, (0, pad))
@@ -163,7 +171,7 @@ def _rope_pallas(x, positions, base, block_r):
         ],
         out_specs=pl.BlockSpec((block_r, h, d), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        interpret=jax.default_backend() != "tpu",
+        interpret=interpret,
         name="mxtpu_rope",
     )(x, pos)
     return out[:r] if pad else out

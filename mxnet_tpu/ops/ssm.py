@@ -168,6 +168,16 @@ def _ssm_kernel(idx_ref, n_ref, s_ref, dx_ref, dec_ref, b_ref, c_ref,
 def _ssm_update_pallas(state, x, dt, a, b, c, active, block_h):
     """The kernel's part: ``(state, y)`` without the ``d * x`` skip;
     ``y`` rows of inactive slots are whatever the output buffer held."""
+    return _ssm_update_jit(state, x, dt, a, b, c, active, int(block_h),
+                           jax.default_backend() != "tpu")
+
+
+# Jitted on everything but the arrays: the layers of a step, and every
+# executable that updates the same grid of slots, share ONE trace of the
+# kernel (traced anew at each call site, the kernels cost a fused
+# Falcon-H1 turn's warm set-up seconds: PERF.md, PR 40).
+@functools.partial(jax.jit, static_argnums=(7, 8))
+def _ssm_update_jit(state, x, dt, a, b, c, active, block_h, interpret):
     f32 = jnp.float32
     s_, h, p, n = state.shape
     g = b.shape[1]
@@ -220,7 +230,7 @@ def _ssm_update_pallas(state, x, dt, a, b, c, active, block_h):
                    jax.ShapeDtypeStruct((s_, h, p), f32)],
         # operands count the two prefetched scalars: the state is the third
         input_output_aliases={2: 0},
-        interpret=jax.default_backend() != "tpu",
+        interpret=interpret,
         name="mxtpu_ssm_update",
     )(idx.astype(jnp.int32), n_active.reshape(1), state, dx, dec,
       b.astype(f32).reshape(s_, g, 1, n), c.astype(f32).reshape(s_, g, 1, n))

@@ -16,6 +16,11 @@ use and reused forever (the fixed-shape-executable invariant):
   before it and a steady turn uploads nothing;
 - ``state_edit`` — one row of the resident state rewritten, when a slot
   changes hands: the only way the host touches that state;
+- ``decode_fill_b<n>`` — ``decode`` with a prefill dispatch's lanes
+  inside it (``n`` rows of them, a pow2 of lanes of the full chunk's
+  bucket, up to ``_PREFILL_ROWS``), for a model that offers
+  ``turn_core``: a turn that has slots decoding and slots filling reads
+  the weights ONCE for both;
 - ``prefill_b<n>`` — a prefill dispatch of ``n`` rows in LANES, each
   lane the next prompt chunk of one slot, so that every slot filling in
   a turn is served by ONE pass over the weights.  One lane: the chunk's
@@ -162,6 +167,19 @@ class DecodePlaneModel:
         goes lane by lane.  A slot rides in one lane at most."""
         raise NotImplementedError
 
+    def turn_core(self, params, pool, tokens, positions, tables, active,
+                  lane_tokens, start, chunk_len, lane_tables, slot):
+        """Optional: what lets a decode step carry a prefill dispatch.
+        ``decode_core``'s step over the slots (its first six arguments)
+        and ``prefill_core``'s lanes (the rest: ``lane_tokens (lanes,
+        bucket)``, ``start``, ``chunk_len``, ``lane_tables``, ``slot`` by
+        lane, ``slot`` past the buffers on a padding lane) in ONE pass
+        over the weights: ``(pool, next token per slot, next token per
+        lane)``, the model's counters after them as ``decode_core``
+        hands them.  A lane's slot is not active: the two kinds of row
+        touch disjoint rows of every buffer."""
+        raise NotImplementedError
+
     def verify_core(self, params, pool, tokens, base_pos, tables, active):
         """Optional: what lets the model be a speculation's target.
         A window ``tokens (slots, k+1)`` at positions ``base_pos`` on:
@@ -191,6 +209,14 @@ class DecodePlaneModel:
 
 # -- what the engine itself traces -----------------------------------------------
 
+def _advance(state, nxt):
+    """The resident state the next turn starts from: every active slot's
+    token replaced by the one it just emitted, its position advanced."""
+    tokens, positions, active, tables = state
+    return (jnp.where(active, nxt, tokens),
+            positions + active.astype(positions.dtype), active, tables)
+
+
 def _chained_decode_core(mdl: DecodePlaneModel, params, pool, state):
     """The model's decode step over the resident ``state = (tokens,
     positions, active, tables)`` and the state the next turn starts
@@ -201,9 +227,34 @@ def _chained_decode_core(mdl: DecodePlaneModel, params, pool, state):
     tokens, positions, active, tables = state
     pool, nxt, *counters = mdl.decode_core(params, pool, tokens, positions,
                                            tables, active)
-    state = (jnp.where(active, nxt, tokens),
-             positions + active.astype(positions.dtype), active, tables)
-    return pool, state, nxt, (counters[0] if counters else {})
+    return pool, _advance(state, nxt), nxt, (counters[0] if counters else {})
+
+
+def _unstage(staged, width: int):
+    """What the host staged for a dispatch of lanes in ONE int32 array
+    (one upload), a row a lane: ``[tokens (bucket) | start | chunk_len |
+    slot | page row (width)]``; ``(tokens (lanes, bucket), start,
+    chunk_len, slot, page rows)``."""
+    bucket = staged.shape[1] - 3 - width
+    start, chunk_len, slot = (staged[:, bucket + i] for i in range(3))
+    return staged[:, :bucket], start, chunk_len, slot, staged[:, bucket + 3:]
+
+
+def _turn_core(mdl: DecodePlaneModel, width: int, params, pool, state,
+               staged):
+    """``_chained_decode_core`` with a prefill dispatch's lanes inside
+    it, as ``_prefill_core`` stages them: ``(pool, state, next token per
+    slot, counters, the lanes' next tokens)``, the last as scalars of
+    their own (a slot's first token goes into the resident state
+    without leaving the device)."""
+    lane_tokens, start, chunk_len, slot, lane_tables = _unstage(staged, width)
+    tokens, positions, active, tables = state
+    pool, nxt, firsts, *counters = mdl.turn_core(
+        params, pool, tokens, positions, tables, active, lane_tokens, start,
+        chunk_len, lane_tables, slot)
+    return (pool, _advance(state, nxt), nxt,
+            (counters[0] if counters else {}),
+            tuple(firsts[i] for i in range(staged.shape[0])))
 
 
 def _state_edit_core(state, token, patch):
@@ -249,16 +300,13 @@ def _verify_core(mdl: DecodePlaneModel, params, pool, tokens, base_pos,
 
 def _prefill_core(mdl: DecodePlaneModel, stateful: bool, width: int, params,
                   pool, staged):
-    """The model's prefill over what the host staged in ONE int32 array
-    (one upload a dispatch), a row a lane: ``[tokens (bucket) | start |
-    chunk_len | slot | page row (width)]``.  ``(pool, the lanes' next
-    tokens)``, the tokens as scalars of their own: a slot's first token
-    goes into the resident decode state without leaving the device."""
-    bucket = staged.shape[1] - 3 - width
-    start, chunk_len, slot = (staged[:, bucket + i] for i in range(3))
-    pool, tokens = mdl.prefill_core(
-        params, pool, staged[:, :bucket], start, chunk_len,
-        staged[:, bucket + 3:], *((slot,) if stateful else ()))
+    """The model's prefill over what the host staged (``_unstage``).
+    ``(pool, the lanes' next tokens)``, the tokens as scalars of their
+    own: a slot's first token goes into the resident decode state
+    without leaving the device."""
+    tokens, start, chunk_len, slot, tables = _unstage(staged, width)
+    pool, tokens = mdl.prefill_core(params, pool, tokens, start, chunk_len,
+                                    tables, *((slot,) if stateful else ()))
     return pool, tuple(tokens[i] for i in range(staged.shape[0]))
 
 
@@ -299,11 +347,13 @@ def _sched_stats(sums: Dict[str, float]) -> dict:
 
 def _prefill_stats(sums: Dict[str, float]) -> dict:
     """Prefill dispatches (``runs``), the chunks they carried, the rows
-    they computed (padding included), and chunks a run."""
+    they computed (padding included), those that rode inside a decode
+    step (``fused``), chunks a run and the fused share of the runs."""
     out = {name: int(sums.get(name, 0))
-           for name in ("runs", "chunks", "rows")}
-    out["chunks_per_run"] = (out["chunks"] / out["runs"] if out["runs"]
-                             else 0.0)
+           for name in ("runs", "chunks", "rows", "fused")}
+    runs = out["runs"]
+    out["chunks_per_run"] = out["chunks"] / runs if runs else 0.0
+    out["fused_share"] = out["fused"] / runs if runs else 0.0
     return out
 
 
@@ -439,6 +489,13 @@ class DecodeEngine:
         return int(self._booked.get("prefill", {}).get("runs", 0))
 
     @property
+    def fuses(self) -> bool:
+        """Whether a decode step can carry a prefill dispatch's lanes:
+        the model offers ``turn_core``."""
+        return (type(self.model).turn_core is not DecodePlaneModel.turn_core
+                and not self.spec_enabled)
+
+    @property
     def prefill_lanes(self) -> int:
         """The most chunks one prefill dispatch carries: as many full
         chunks as ``_PREFILL_ROWS`` rows hold, no more than there are
@@ -479,6 +536,9 @@ class DecodeEngine:
                  "verify": functools.partial(_verify_core, self.model)}
         if key in named:
             return named[key]
+        if key.startswith("decode_fill_"):
+            return functools.partial(_turn_core, self.model,
+                                     self.cache.pages_per_slot)
         draft = key.startswith("draft_")
         return functools.partial(
             _prefill_core, self.draft if draft else self.model,
@@ -574,6 +634,11 @@ class DecodeEngine:
             return 1, self.prefill_bucket(longest)
         return _pow2(chunks, 1), self.prefill_chunk
 
+    def _fill_key(self, lanes: int) -> str:
+        """The decode step that carries ``lanes`` lanes, each the full
+        chunk's bucket (a short chunk is padded to it)."""
+        return f"decode_fill_b{lanes * self.prefill_chunk}"
+
     def _stage(self, cache, group, lanes: int, bucket: int):
         """What ``_prefill_core`` unpacks, built on the host: a row a
         lane of ``group``'s ``(slot, chunk, start)``; the lanes left
@@ -591,7 +656,8 @@ class DecodeEngine:
     def warmup(self, prefill_lengths: Sequence[int] = (1,)) -> List[str]:
         """Materialize every executable this engine will dispatch —
         decode and its state's edit (draft and verify instead under
-        speculation), one one-lane prefill per bucket covering
+        speculation), every decode step with lanes inside it where the
+        model offers them, one one-lane prefill per bucket covering
         ``prefill_lengths`` and every multi-lane prefill a turn with
         several filling slots dispatches — WITHOUT running any of
         them.  Against a populated artifact store each one deserializes (``compiles``
@@ -621,6 +687,15 @@ class DecodeEngine:
                 onp.zeros((3 + self.cache.pages_per_slot,), onp.int32)),
                 donate=(0,))
             keys += ["decode", "state_edit"]
+            lanes = 1
+            while self.fuses and lanes <= self.prefill_lanes:
+                key = self._fill_key(lanes)
+                self._get_exec(key, (
+                    self.model.params, self.cache.pool, self._resident,
+                    self._stage(self.cache, (), lanes, self.prefill_chunk)),
+                    donate=(1, 2))
+                keys.append(key)
+                lanes *= 2
         if self.cache.state_layers:
             self._get_exec("state_reset",
                            (self._state(), jnp.asarray(0, jnp.int32)),
@@ -648,21 +723,45 @@ class DecodeEngine:
 
     # -- device steps --------------------------------------------------------
 
-    def decode_step(self):
+    def decode_step(self, chunks=()):
         """Dispatch one non-speculative engine step over the full slot
-        grid, from the resident state and into it.  Returns the next
-        token per slot, on the device and not waited for: :meth:`read`
-        it after the next turn's dispatch."""
+        grid, from the resident state and into it.  ``chunks`` (what
+        :meth:`prefill_chunks` takes, up to ``prefill_lanes`` of them,
+        their slots not decoding) ride inside the step as lanes of
+        ``decode_fill_b<rows>``, one pass over the weights for both: a
+        model that offers ``turn_core`` only (:attr:`fuses`).  Returns
+        the next token per slot and the chunks' next tokens in their
+        order, on the device and not waited for: :meth:`read` them after
+        the next turn's dispatch."""
         traced = tracing.capturing()
         self._count_live(self._positions, self._active, traced)
         self.chained = int(bool(self._in_flight))
         self._chained_steps += self.chained
-        self.cache.pool, self._resident, nxt, counters = self._call(
-            "decode", (self.model.params, self.cache.pool, self._resident),
-            donate=(1, 2))
+        args = (self.model.params, self.cache.pool, self._resident)
+        firsts = ()
+        if chunks:
+            slots = [slot for slot, _, _ in chunks]
+            if (not self.fuses or len(chunks) > self.prefill_lanes
+                    or self._active[slots].any()):
+                raise ValueError(
+                    f"a decode step of {type(self.model).__name__} carries "
+                    f"no chunk of a decoding slot and at most "
+                    f"{self.prefill_lanes if self.fuses else 0} chunks")
+            lanes = _pow2(len(chunks), 1)
+            with tracing.span("decode.stage"):
+                staged = self._stage(self.cache, chunks, lanes,
+                                     self.prefill_chunk)
+            (self.cache.pool, self._resident, nxt, counters,
+             firsts) = self._call(self._fill_key(lanes), args + (staged,),
+                                  donate=(1, 2))
+            self.book("prefill", traced, runs=1, chunks=len(chunks),
+                      rows=lanes * self.prefill_chunk, fused=1)
+        else:
+            self.cache.pool, self._resident, nxt, counters = self._call(
+                "decode", args, donate=(1, 2))
         self._positions += self._active
         self._in_flight.append((nxt, counters, traced))
-        return nxt
+        return nxt, list(firsts[:len(chunks)])
 
     def read(self, nxt, firsts):
         """The turn's one blocking read: a ``decode_step``'s tokens
@@ -703,7 +802,8 @@ class DecodeEngine:
         ``kind`` (``"sched"``: the turn clock's ``empty_s``, ``host_s``,
         ``sync_s`` and ``turns``; ``"requests"``: ``count`` and a
         request's two waits at its first token; the engine's own
-        ``"prefill"``: ``runs``, ``chunks``, ``rows``), over the
+        ``"prefill"``: ``runs``, ``chunks``, ``rows``, and ``fused``, the
+        runs that rode inside a decode step), over the
         engine's life and, where ``traced``, over what a profiler
         capture covered."""
         for booked in (self._booked, self._booked_traced)[:1 + traced]:
@@ -839,7 +939,8 @@ class DecodeEngine:
                 "sched": _sched_stats(self._booked.get("sched", {})),
                 "requests": _request_stats(
                     self._booked.get("requests", {})),
-                # prefill dispatches and the chunks that rode in them
+                # prefill dispatches, the chunks that rode in them, and
+                # those that rode inside a decode step
                 "prefill": _prefill_stats(self._booked.get("prefill", {})),
                 "traced": {
                     "decode_steps": self._traced_steps,

@@ -29,7 +29,7 @@ silu(z))`` over the groups with a learned weight; ``y @ W_out``.
 ``MLP``: ``(up(h) * silu(gate(h) * mlp_multipliers[0])) @ W_down *
 mlp_multipliers[1]``.
 
-One block function (:meth:`FalconH1._block`) serves the three paths;
+One block function (:meth:`FalconH1._block`) serves the four paths;
 what differs between them is handed to it: how attention reaches its
 keys and values (built by ``paged_kv``, which alone knows a page), and
 how the mixer's convolution and recurrence reach their state (the
@@ -46,6 +46,11 @@ how the mixer's convolution and recurrence reach their state (the
   slot's state (``prefill_chunk`` is ``mamba_chunk_size``, so a chunk of
   the prompt is a chunk of the scan) and leaves it for the next chunk
   or the first decode step;
+- a turn: the decode step with a prefill dispatch's lanes inside it,
+  the slots' rows and the lanes' one matrix through every projection,
+  MLP, norm and the head, attention and the mixer's state each by its
+  own path (a filling slot does not decode: the rows are disjoint), so
+  the weights stream once for both;
 - dense: the whole sequence with no cache, causal attention and the
   recurrence over time: the in-program oracle the cached paths are
   pinned to.
@@ -68,9 +73,14 @@ from .decode_model import rms_norm
 from .engine import DecodePlaneModel
 from .paged_kv import (chunk_attention, chunk_conv, dense_attention,
                        dense_conv, last_rows, slot_attention, slot_conv,
-                       slot_rows)
+                       slot_rows, turn_attention)
 
 __all__ = ["FalconH1"]
+
+# a lane's chunked scan, jitted on its own: the lanes and layers of an
+# executable, and every executable with lanes of one bucket, share ONE
+# trace of it (PERF.md, PR 40)
+_chunk_scan = jax.jit(ssm_chunk_scan)
 
 # the keys of config.json the arithmetic reads
 _KEYS = ("vocab_size", "hidden_size", "num_hidden_layers",
@@ -308,6 +318,59 @@ class FalconH1(DecodePlaneModel):
         return _scaled(x @ params["head"],
                        self.config["lm_head_multiplier"])
 
+    # -- the mixer's state, by the kind of row ------------------------------------
+
+    def _slot_mix(self, active, lp, xbc, dt, sbuf, cbuf):
+        """Decode: one row a slot, the convolution's tail and the state
+        rows of the slot, ``ssm_update`` in place; ``(y, (sbuf,
+        cbuf))``."""
+        conv, cbuf = slot_conv(cbuf, xbc, lp["conv_w"], active, lp["conv_b"])
+        xs, b, c = self._split(jax.nn.silu(conv))
+        sbuf, y = ssm_update(sbuf, xs, dt, -jnp.exp(lp["a_log"]), b, c,
+                             lp["d"], active)
+        return y, (sbuf, cbuf)
+
+    @staticmethod
+    def _valid(chunk_len, bucket: int):
+        """Which of a dispatch's ``lanes * bucket`` rows are a prompt's."""
+        return (jnp.arange(bucket)[None, :] < chunk_len[:, None]).reshape(-1)
+
+    def _lane_mix(self, slot, chunk_len, valid, lp, xbc, dt, sbuf, cbuf):
+        """Prefill: lanes of one slot's chunk each, the chunked scan from
+        the slot's state; ``(y, (sbuf, cbuf))``.  ``valid``: which rows
+        are a prompt's (``_valid``)."""
+        lanes = slot.shape[0]
+
+        def by_lane(rows):
+            return rows.reshape((lanes, -1) + rows.shape[1:])
+
+        conv, cbuf = chunk_conv(cbuf, xbc, lp["conv_w"], slot, chunk_len,
+                                lp["conv_b"])
+        xs, b, c = map(by_lane, self._split(jax.nn.silu(conv)))
+        # a padded row neither decays the state nor adds to it
+        dt = by_lane(jnp.where(valid[:, None], dt, 0.0))
+        # the recurrence is a slot's own: a lane at a time from the
+        # slot's state, and the states back in one scatter (a padding
+        # lane's slot is past the buffer: dropped)
+        before = slot_rows(sbuf, slot)
+        state, y = zip(*(_chunk_scan(
+            before[i], xs[i], dt[i], -jnp.exp(lp["a_log"]), b[i], c[i],
+            lp["d"]) for i in range(lanes)))
+        sbuf = sbuf.at[slot].set(jnp.stack(state), mode="drop")
+        return jnp.concatenate(y), (sbuf, cbuf)
+
+    def _cached(self, params, pool, x, attend, mix):
+        """Every layer over rows ``x`` with its cached buffers, the
+        mixer's state through ``mix(lp, xbc, dt, sbuf=, cbuf=)``:
+        ``(pool, x)``."""
+        out = []
+        for (kbuf, vbuf, sbuf, cbuf), lp in zip(pool, params["layers"]):
+            x, kv, state = self._block(
+                lp, x, (kbuf, vbuf), attend,
+                functools.partial(mix, lp, sbuf=sbuf, cbuf=cbuf))
+            out.append(kv + state)
+        return tuple(out), x
+
     # -- decode: one token a slot ------------------------------------------------
 
     def decode_core(self, params, pool, tokens, positions, tables, active):
@@ -319,21 +382,10 @@ class FalconH1(DecodePlaneModel):
         """The decode step up to its logits ``(slots, vocab)``."""
         attend = slot_attention(pool, positions, tables, active,
                                 rope_base=self.rope_base)
-        x = self._embed(params, tokens)
-        out = []
-        for (kbuf, vbuf, sbuf, cbuf), lp in zip(pool, params["layers"]):
-
-            def mix(xbc, dt, sbuf=sbuf, cbuf=cbuf, lp=lp):
-                conv, cbuf = slot_conv(cbuf, xbc, lp["conv_w"], active,
-                                       lp["conv_b"])
-                xs, b, c = self._split(jax.nn.silu(conv))
-                sbuf, y = ssm_update(sbuf, xs, dt, -jnp.exp(lp["a_log"]),
-                                     b, c, lp["d"], active)
-                return y, (sbuf, cbuf)
-
-            x, kv, state = self._block(lp, x, (kbuf, vbuf), attend, mix)
-            out.append(kv + state)
-        return tuple(out), self._logits(params, x)
+        pool, x = self._cached(params, pool, self._embed(params, tokens),
+                               attend, functools.partial(self._slot_mix,
+                                                         active))
+        return pool, self._logits(params, x)
 
     # -- prefill: lanes, each one chunk of one slot -------------------------------
 
@@ -347,38 +399,54 @@ class FalconH1(DecodePlaneModel):
                        slot):
         """A dispatch of chunks up to the logits ``(lanes, vocab)``
         after each lane's last valid token."""
-        lanes, b_ = tokens.shape
-        attend = chunk_attention(pool, start, chunk_len, tables, b_,
-                                 rope_base=self.rope_base)
-        valid = (jnp.arange(b_)[None, :] < chunk_len[:, None]).reshape(-1)
-        x = self._embed(params, tokens.reshape(-1))
+        attend = chunk_attention(pool, start, chunk_len, tables,
+                                 tokens.shape[1], rope_base=self.rope_base)
+        valid = self._valid(chunk_len, tokens.shape[1])
+        pool, x = self._cached(
+            params, pool, self._embed(params, tokens.reshape(-1)), attend,
+            functools.partial(self._lane_mix, slot, chunk_len, valid))
+        return pool, self._logits(params, last_rows(x, chunk_len))
 
-        def by_lane(rows):
-            return rows.reshape((lanes, b_) + rows.shape[1:])
+    # -- a turn: the decode step with a dispatch's lanes inside it ----------------
 
-        out = []
-        for (kbuf, vbuf, sbuf, cbuf), lp in zip(pool, params["layers"]):
+    def turn_core(self, params, pool, tokens, positions, tables, active,
+                  lane_tokens, start, chunk_len, lane_tables, slot):
+        pool, logits = self.turn_logits(params, pool, tokens, positions,
+                                        tables, active, lane_tokens, start,
+                                        chunk_len, lane_tables, slot)
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return pool, nxt[:tokens.shape[0]], nxt[tokens.shape[0]:]
 
-            def mix(xbc, dt, sbuf=sbuf, cbuf=cbuf, lp=lp):
-                conv, cbuf = chunk_conv(cbuf, xbc, lp["conv_w"], slot,
-                                        chunk_len, lp["conv_b"])
-                xs, b, c = map(by_lane, self._split(jax.nn.silu(conv)))
-                # a padded row neither decays the state nor adds to it
-                dt = by_lane(jnp.where(valid[:, None], dt, 0.0))
-                # the recurrence is a slot's own: a lane at a time from
-                # the slot's state, and the states back in one scatter
-                # (a padding lane's slot is past the buffer: dropped)
-                before = slot_rows(sbuf, slot)
-                state, y = zip(*(ssm_chunk_scan(
-                    before[i], xs[i], dt[i], -jnp.exp(lp["a_log"]), b[i],
-                    c[i], lp["d"]) for i in range(lanes)))
-                sbuf = sbuf.at[slot].set(jnp.stack(state), mode="drop")
-                return jnp.concatenate(y), (sbuf, cbuf)
+    def turn_logits(self, params, pool, tokens, positions, tables, active,
+                    lane_tokens, start, chunk_len, lane_tables, slot):
+        """The decode step and a dispatch of lanes in ONE pass over the
+        weights: every projection, MLP and norm over the slots' rows and
+        then the lanes' as one matrix, attention and the mixer's state
+        split at ``slots`` by kind (a filling slot does not decode: the
+        two touch disjoint rows of every buffer, and the lanes' reads
+        and scatters follow the decode rows' update of the same buffer,
+        never a copy of it).  The logits ``(slots + lanes, vocab)``: a
+        slot's, then a lane's after its last valid token, one head for
+        both."""
+        n = tokens.shape[0]
+        attend = turn_attention(pool, positions, tables, active, start,
+                                chunk_len, lane_tables, lane_tokens.shape[1],
+                                rope_base=self.rope_base)
+        valid = self._valid(chunk_len, lane_tokens.shape[1])
 
-            x, kv, state = self._block(lp, x, (kbuf, vbuf), attend, mix)
-            out.append(kv + state)
-        return tuple(out), self._logits(params,
-                                        last_rows(x, chunk_len))
+        def mix(lp, xbc, dt, sbuf, cbuf):
+            y_slots, (sbuf, cbuf) = self._slot_mix(active, lp, xbc[:n],
+                                                   dt[:n], sbuf, cbuf)
+            y_lanes, (sbuf, cbuf) = self._lane_mix(slot, chunk_len, valid,
+                                                   lp, xbc[n:], dt[n:],
+                                                   sbuf, cbuf)
+            return jnp.concatenate([y_slots, y_lanes]), (sbuf, cbuf)
+
+        x = self._embed(params, jnp.concatenate([tokens,
+                                                 lane_tokens.reshape(-1)]))
+        pool, x = self._cached(params, pool, x, attend, mix)
+        return pool, self._logits(params, jnp.concatenate(
+            [x[:n], last_rows(x[n:], chunk_len)]))
 
     # -- dense: the whole sequence, no cache (the in-program oracle) -------------
 

@@ -54,6 +54,7 @@ besides the ``paged_attention`` kernel that knows any of the above.  A
 model's core builds ONE attention for the executable it is traced into
 (:func:`slot_attention` for decode, :func:`chunk_attention` for
 prefill, whose rows are lanes, several slots' chunks in one dispatch,
+:func:`turn_attention` for a decode step that carries such lanes,
 :func:`window_attention` for verify) from the positions and
 page tables the executable was handed, and calls it in every layer as
 ``attend(q, k, v, kbuf, vbuf)``: the projected heads before rotation
@@ -85,8 +86,8 @@ from ...ops.rope import rope, rope_reference, rope_table
 __all__ = ["PageAllocator", "PagedKVCache", "OutOfPagesError",
            "uniform_layout", "slot_conv", "slot_rows", "chunk_conv",
            "dense_conv", "slot_attention", "chunk_attention", "last_rows",
-           "window_attention", "dense_attention", "latent_width",
-           "latent_slot_attention", "latent_chunk_attention",
+           "turn_attention", "window_attention", "dense_attention",
+           "latent_width", "latent_slot_attention", "latent_chunk_attention",
            "latent_dense_attention"]
 
 _NEG_INF = -1e30
@@ -366,17 +367,24 @@ def _kernel(q, kbuf, vbuf, tables, lengths):
     return paged_attention(q, kbuf, vbuf, tables, lengths, block_k=block_k)
 
 
-def slot_attention(pool, positions, tables, active, *, rope_base):
-    """Decode: one token per slot at ``positions (slots,)``, written
-    there through ``tables (slots, pages_per_slot)``, attending over
-    the slot's ``positions + 1`` rows.  An inactive slot writes nothing
-    and yields zeros."""
+def _slot_places(pool, positions, tables, active):
+    """Where a decode step's rows go, one a slot: ``(rows live a slot
+    (its position's included), page, offset)``, an inactive slot's page
+    the sentinel."""
     num_pages, ps = pool[0][0].shape[:2]
     lengths = jnp.where(active, positions + 1, 0).astype(jnp.int32)
     pagerow = jnp.take_along_axis(
         tables, (positions // ps)[:, None], axis=1)[:, 0]
     page = jnp.where(active, pagerow, num_pages).astype(jnp.int32)
-    offset = positions % ps
+    return lengths, page, positions % ps
+
+
+def slot_attention(pool, positions, tables, active, *, rope_base):
+    """Decode: one token per slot at ``positions (slots,)``, written
+    there through ``tables (slots, pages_per_slot)``, attending over
+    the slot's ``positions + 1`` rows.  An inactive slot writes nothing
+    and yields zeros."""
+    lengths, page, offset = _slot_places(pool, positions, tables, active)
 
     def attend(q, k, v, kbuf, vbuf):
         q, kbuf, vbuf = _rotate_write(q, k, v, kbuf, vbuf, positions, page,
@@ -455,22 +463,58 @@ def chunk_attention(pool, start, chunk_len, tables, bucket: int, *,
     table positions)`` in float32, are what a chunk's attention costs
     whatever the prompt's length (134 MB a layer at 4,096 positions and
     256 rows: PERF.md section 6, PR 35)."""
-    lanes = tables.shape[0]
     pos, page, offset, total = _chunk_rows(pool, start, chunk_len, tables,
                                            bucket)
-    one_slot = (_walk_live_pages
-                if tables.shape[1] * pool[0][0].shape[1] > _GATHER_ROWS
-                else _gather_pages)
 
     def attend(q, k, v, kbuf, vbuf):
-        (heads, hd), kvh = q.shape[1:], k.shape[1]
         q, kbuf, vbuf = _rotate_write(q, k, v, kbuf, vbuf, pos.reshape(-1),
                                       page, offset, rope_base)
-        qg = q.reshape(lanes, bucket, kvh, heads // kvh, hd).astype(
-            jnp.float32)
-        o = [one_slot(kbuf, vbuf, qg[i], tables[i], pos[i], total[i])
-             for i in range(lanes)]
-        return (jnp.concatenate(o).reshape(lanes * bucket, heads, hd),
+        return (_lanes_attend(q, k.shape[1], kbuf, vbuf, tables, pos, total),
+                (kbuf, vbuf))
+
+    return attend
+
+
+def _lanes_attend(q, kvh, kbuf, vbuf, tables, pos, total):
+    """Each lane's rotated queries ``q (lanes * bucket, heads, hd)`` over
+    its own causal prefix, through its slot's page row ``tables[i]``,
+    once the rows are written: ``(lanes * bucket, heads, hd)`` float32."""
+    (lanes, bucket), (heads, hd) = pos.shape, q.shape[1:]
+    one_slot = (_walk_live_pages
+                if tables.shape[1] * kbuf.shape[1] > _GATHER_ROWS
+                else _gather_pages)
+    qg = q.reshape(lanes, bucket, kvh, heads // kvh, hd).astype(jnp.float32)
+    o = [one_slot(kbuf, vbuf, qg[i], tables[i], pos[i], total[i])
+         for i in range(lanes)]
+    return jnp.concatenate(o).reshape(lanes * bucket, heads, hd)
+
+
+def turn_attention(pool, positions, tables, active, start, chunk_len,
+                   lane_tables, bucket: int, *, rope_base):
+    """A decode step with a dispatch's lanes inside it: ``slots`` rows
+    (``positions.shape[0]``, one a slot, as :func:`slot_attention`'s),
+    then ``lanes * bucket`` rows (as :func:`chunk_attention`'s, their
+    slots' page rows ``lane_tables``).  Every row is rotated and written
+    in ONE scatter a buffer first; then the slots' queries go through
+    the paged kernel and the lanes' over their gathered pages, both
+    reading the buffer that scatter left (a read of a buffer before a
+    write into it had the TPU compiler copy the buffer whole).  A
+    filling slot does not decode: the rows land on disjoint pages."""
+    slots = positions.shape[0]
+    lengths, page, offset = _slot_places(pool, positions, tables, active)
+    pos, lane_page, lane_offset, total = _chunk_rows(
+        pool, start, chunk_len, lane_tables, bucket)
+    at = jnp.concatenate([positions, pos.reshape(-1)])
+    page = jnp.concatenate([page, lane_page])
+    offset = jnp.concatenate([offset, lane_offset])
+
+    def attend(q, k, v, kbuf, vbuf):
+        q, kbuf, vbuf = _rotate_write(q, k, v, kbuf, vbuf, at, page, offset,
+                                      rope_base)
+        o_slots = _kernel(q[:slots], kbuf, vbuf, tables, lengths)
+        o_lanes = _lanes_attend(q[slots:], k.shape[1], kbuf, vbuf,
+                                lane_tables, pos, total)
+        return (jnp.concatenate([o_slots.astype(o_lanes.dtype), o_lanes]),
                 (kbuf, vbuf))
 
     return attend

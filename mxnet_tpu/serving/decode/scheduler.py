@@ -34,7 +34,12 @@ or manually):
    stays on the device, as the slot's row of the engine's resident
    decode state;
 4. decode — one batched token step over every decoding slot is
-   dispatched from that state, and nothing of it is waited for;
+   dispatched from that state, and nothing of it is waited for.  Where
+   the engine ``fuses`` (its model offers ``turn_core``) and a slot
+   decodes, the first ``prefill_lanes`` chunks of phase 3 are not
+   dispatched there but ride inside this step, one pass over the
+   weights for both; a slot whose last chunk rode is switched on behind
+   the step, and decodes from the next turn;
 5. commit — the ONE blocking read of the turn, and it is of the turn
    before: its tokens (prompts' first tokens among them: TTFT) reach
    their requests, finished requests leave their slots.  The device
@@ -354,7 +359,8 @@ class DecodeScheduler:
         # `completed`, `ttft_ms` and `queue_wait_ms` where tokens are
         # committed
         extra = {"tokens": 0, "prefill_tokens": 0, "prefill_runs": 0,
-                 "completed": 0, "ttft_ms": [], "queue_wait_ms": []}
+                 "prefill_fused": 0, "completed": 0, "ttft_ms": [],
+                 "queue_wait_ms": []}
 
         with tracing.span("decode.expire"):
             evictions = self._expire(now)
@@ -362,9 +368,11 @@ class DecodeScheduler:
         with tracing.span("decode.admit_phase") as sp:
             sp.annotate(admitted=self._admit(now))
 
-        firsts = self._prefill(extra)
-        decoding = (self._spec_turn if eng.spec_enabled
-                    else self._chained_turn)(firsts, extra)
+        fill = self._fill()
+        if eng.spec_enabled:
+            decoding = self._spec_turn(self._prefill(fill, extra), extra)
+        else:
+            decoding = self._chained_turn(fill, extra)
 
         # 6. account
         with tracing.span("decode.account"):
@@ -465,55 +473,92 @@ class DecodeScheduler:
             self._gauge_q.set(len(self._q))
         return admitted
 
-    def _prefill(self, extra: dict) -> list:
-        """Phase 3: the next chunk of every prefilling slot, handed to
-        the engine together (several ride in one dispatch), dispatched
-        and not waited for.  Returns ``(request, its first token on the
-        device)`` for every prompt whose last chunk this was."""
+    def _fill(self) -> list:
+        """Phase 3's work: ``(request, (slot, its next chunk, start))``
+        for every prefilling slot, in the slots' order."""
         eng = self.engine
-        filling = [r for r in self._slots
-                   if r is not None and r.prefilled < len(r.prompt)]
-        if not filling:
+        return [(r, (r.slot, r.prompt[r.prefilled:
+                                      r.prefilled + eng.prefill_chunk],
+                     r.prefilled))
+                for r in self._slots
+                if r is not None and r.prefilled < len(r.prompt)]
+
+    def _prefill(self, fill: list, extra: dict) -> list:
+        """Phase 3: ``fill``'s chunks handed to the engine together
+        (several ride in one dispatch), dispatched and not waited for.
+        Returns ``(request, its first token on the device)`` for every
+        prompt whose last chunk this was."""
+        if not fill:
             return []
-        chunks = [(r.slot, r.prompt[r.prefilled:
-                                    r.prefilled + eng.prefill_chunk],
-                   r.prefilled) for r in filling]
-        runs = eng.prefill_runs
-        toks = eng.prefill_chunks(chunks)
-        extra["prefill_runs"] = eng.prefill_runs - runs
-        firsts = []
-        for r, (_, chunk, _), tok in zip(filling, chunks, toks):
+        runs = self.engine.prefill_runs
+        toks = self.engine.prefill_chunks([chunk for _, chunk in fill])
+        return self._filled(fill, toks, runs, extra)
+
+    def _filled(self, fill: list, toks: list, runs: int,
+                extra: dict) -> list:
+        """``fill``'s chunks were dispatched, with ``toks`` their next
+        tokens and ``runs`` the engine's prefill runs before: account
+        for them, and return ``(request, first token)`` of every prompt
+        whose last chunk this was."""
+        extra["prefill_runs"] += self.engine.prefill_runs - runs
+        firsts, tokens = [], 0
+        for (r, (_, chunk, _)), tok in zip(fill, toks):
             r.prefilled += len(chunk)
-            extra["prefill_tokens"] += len(chunk)
+            tokens += len(chunk)
             if r.prefilled >= len(r.prompt):
                 firsts.append((r, tok))
-        telemetry.counter("decode.prefill_tokens").inc(
-            extra["prefill_tokens"])
+        extra["prefill_tokens"] += tokens
+        telemetry.counter("decode.prefill_tokens").inc(tokens)
         return firsts
 
-    def _chained_turn(self, firsts: list, extra: dict) -> int:
-        """Phases 4 and 5: switch the slots of ``firsts`` on, dispatch
-        one batched token step from the engine's resident state, and
-        only then read the turn before and hand its tokens to their
-        requests.  Returns the number of slots that decode."""
-        eng = self.engine
+    def _activate(self, firsts: list) -> None:
+        """Switch the slots of ``firsts`` on: the token stays on the
+        device, as the slot's row of the resident state; a request of
+        one token needs no step."""
         for r, tok in firsts:
-            # the token stays on the device, as the slot's row of the
-            # resident state; a request of one token needs no step
             r.dispatched = 1
             if r.max_new > 1:
-                eng.activate_slot(r.slot, tok, len(r.prompt))
-        decoding = [r for r in self._slots
-                    if r is not None and 0 < r.dispatched < r.max_new]
+                self.engine.activate_slot(r.slot, tok, len(r.prompt))
+
+    def _decoding(self) -> list:
+        """The requests whose slot takes the next decode step."""
+        return [r for r in self._slots
+                if r is not None and 0 < r.dispatched < r.max_new]
+
+    def _chained_turn(self, fill: list, extra: dict) -> int:
+        """Phases 3 to 5: dispatch ``fill``'s chunks, switch on the
+        slots whose prompt they finish, dispatch one batched token step
+        from the engine's resident state, and only then read the turn
+        before and hand its tokens to their requests.  Where the engine
+        fuses and a slot decodes, up to ``prefill_lanes`` of the chunks
+        ride inside the step instead, and the slots whose prompt they
+        finish are switched on behind it.  Returns the number of slots
+        that decode."""
+        eng = self.engine
+        ride = (fill[:eng.prefill_lanes] if eng.fuses and self._decoding()
+                else [])
+        firsts = self._prefill(fill[len(ride):], extra)
+        self._activate(firsts)
+        decoding = self._decoding()
         nxt = None
         if decoding:
-            with tracing.span("decode.decode", decoding=len(decoding)):
-                nxt = eng.decode_step()
+            runs = eng.prefill_runs
+            # a step that carries chunks has a span of its own: readers
+            # pair `decode.decode` with the `decode` executable's runs
+            with tracing.span("decode.decode_fill" if ride
+                              else "decode.decode", decoding=len(decoding),
+                              lanes=len(ride)):
+                nxt, toks = eng.decode_step([chunk for _, chunk in ride])
                 for r in decoding:
                     r.dispatched += 1
                     if r.dispatched == r.max_new:
                         # its last token is in flight: no step after this
                         eng.deactivate_slot(r.slot)
+            if ride:
+                extra["prefill_fused"] += 1
+                ridden = self._filled(ride, toks, runs, extra)
+                self._activate(ridden)
+                firsts += ridden
         before, self._inflight = self._inflight, (
             (nxt, decoding, firsts) if decoding or firsts else None)
         if before is not None:

@@ -252,7 +252,10 @@ def test_cores_touch_a_layer_buffer_only_by_its_scatter(model, family, core):
     if family == "hybrid":
         # 8 slots x (4 heads x 16 x 16) of state, as large as a K/V buffer
         model, kw = _tiny_hybrid(), dict(max_slots=8, num_pages=32)
-        state = {"decode": ["pallas_call"], "prefill": ["scatter"]}[core]
+        # the kernel's call, and the jit around it on its static
+        # arguments (PR 40), in a decode step
+        state = {"decode": ["jit", "pallas_call"],
+                 "prefill": ["scatter"]}[core]
     else:
         kw, state = {}, []
     # a fraction of the pool per slot table, so no gather is as large
@@ -482,10 +485,12 @@ def test_a_burst_fills_its_slots_in_one_prefill_dispatch(model, monkeypatch):
     assert eng.compiles == compiled
     stats = eng.stats()
     assert stats["prefill"] == {"runs": 3, "chunks": 7,
-                                "rows": 4 * 16 + 2 * 16 + 8,
-                                "chunks_per_run": 7 / 3}
+                                "rows": 4 * 16 + 2 * 16 + 8, "fused": 0,
+                                "chunks_per_run": 7 / 3, "fused_share": 0.0}
     assert stats["traced"]["prefill"] == {"runs": 1, "chunks": 2,
-                                          "rows": 32, "chunks_per_run": 2.0}
+                                          "rows": 32, "fused": 0,
+                                          "chunks_per_run": 2.0,
+                                          "fused_share": 0.0}
     assert _engine(model).stats()["prefill"]["chunks_per_run"] == 0.0
     sch.close(drain=True)
 
@@ -1004,8 +1009,11 @@ def test_an_empty_server_waits_under_a_span_on_the_host_plane(
 def warm_engines(model, draft):
     plain = _engine(model)
     spec = _engine(model, num_pages=64, draft_model=draft, spec_k=3)
+    # a model that offers turn_core, at the Falcon cells' chunk of 128
+    fused = _engine(_tiny_hybrid(), prefill_chunk=128)
     return {"plain": (plain, plain.warmup([8])),
-            "spec": (spec, spec.warmup([8]))}
+            "spec": (spec, spec.warmup([8])),
+            "fused": (fused, fused.warmup([8]))}
 
 
 def _trace_patterns():
@@ -1035,7 +1043,11 @@ def _trace_patterns():
     ("spec", "prefill_b16", "prefill_exec_ms_p50"),
     ("spec", "draft_prefill_b16", None),
     ("spec", "prefill_b256", "prefill_exec_ms_p50"),
-    ("spec", "draft_prefill_b256", None)])
+    ("spec", "draft_prefill_b256", None),
+    # the decode step with one and two lanes of 128 inside it
+    ("fused", "decode_fill_b128", None),
+    ("fused", "decode_fill_b256", None),
+    ("fused", "decode", "decode_exec_ms_p50")])
 def test_executable_carries_its_key_as_its_name(warm_engines, kind, key,
                                                 metric):
     """A device trace shows an executable as its module's name: each of
@@ -1043,14 +1055,21 @@ def test_executable_carries_its_key_as_its_name(warm_engines, kind, key,
     materialises all a turn can dispatch (the chained turn's pair, or
     the speculative turn's, and beside the one-lane prefill buckets
     every multi-lane prefill, named by its rows, lanes x the full
-    chunk, which no one-lane bucket can reach), and the benchmark's
-    patterns match the decode and prefill executables and nothing
-    else: ``prefill_runs_per_step`` reads the same pattern."""
+    chunk, which no one-lane bucket can reach; where the model offers
+    ``turn_core``, the decode step with one and two lanes inside it,
+    named by the lanes' rows), and the benchmark's patterns match the
+    decode and prefill executables and nothing else:
+    ``prefill_runs_per_step`` reads the same pattern, and a decode step
+    that carries lanes is neither a decode run nor a prefill run to
+    them."""
     eng, keys = warm_engines[kind]
     assert sorted(eng._exec) == sorted(keys) == sorted(
         {"plain": ["decode", "state_edit", "prefill_b16", "prefill_b256"],
          "spec": ["draft", "verify", "prefill_b16", "draft_prefill_b16",
-                  "prefill_b256", "draft_prefill_b256"]}[kind])
+                  "prefill_b256", "draft_prefill_b256"],
+         "fused": ["decode", "state_edit", "decode_fill_b128",
+                   "decode_fill_b256", "state_reset", "prefill_b16",
+                   "prefill_b256"]}[kind])
     text = eng._exec[key].as_text()
     assert f"HloModule jit_mxtpu_{key}," in text
     assert "lambda" not in text.split("\n", 1)[0]

@@ -352,10 +352,12 @@ def test_scheduler_matches_the_dense_oracle_and_never_recompiles(
         models, _clean):
     model, _ = models("float32", "ones")
     eng = _engine(model, max_slots=2)
-    # two slots: one multi-lane executable, two lanes of the full chunk
-    assert eng.warmup([8, CHUNK]) == ["decode", "state_edit", "state_reset",
-                                      "prefill_b8", "prefill_b16",
-                                      "prefill_b32"]
+    # two slots: one multi-lane executable, two lanes of the full chunk,
+    # and the decode step with one and two such lanes inside it
+    assert eng.warmup([8, CHUNK]) == ["decode", "state_edit",
+                                      "decode_fill_b16", "decode_fill_b32",
+                                      "state_reset", "prefill_b8",
+                                      "prefill_b16", "prefill_b32"]
     compiled = eng.compiles
 
     class Sink:

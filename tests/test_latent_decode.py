@@ -759,7 +759,7 @@ def test_scheduler_matches_the_dense_oracle_and_never_recompiles(models,
         sync.activate_slot(0, tok, len(p))
         out = [int(tok)]
         for _ in range(4):
-            nxt, _ = sync.read(sync.decode_step(), [])
+            nxt, _ = sync.read(*sync.decode_step())
             out.append(int(nxt[0]))
         sync.release_slot(0)
         assert out == f.result(0)

@@ -314,6 +314,18 @@ def _lower_prefill(mdl, params, pool, spec, pps, key, chunk):
         params, pool, spec((lanes, rows // lanes + 3 + pps), "int32"))
 
 
+def _lower_turn(mdl, params, pool, state, spec, pps, key, chunk):
+    """``decode_fill_b<rows>`` as ``DecodeEngine`` compiles it on a TPU:
+    the model's turn core inside the engine's wrapper, the decode step's
+    resident state and lanes of the full chunk staged as a prefill
+    dispatch stages them, pool and state donated."""
+    from mxnet_tpu.serving.decode import engine as E
+    lanes = int(key.rsplit("b", 1)[1]) // chunk
+    return jax.jit(lambda *a: E._turn_core(mdl, pps, *a),
+                   donate_argnums=(1, 2)).lower(
+        params, pool, state, spec((lanes, chunk + 3 + pps), "int32"))
+
+
 @pytest.mark.parametrize("key", ["decode", "prefill_b128", "prefill_b512"])
 def test_decode_executables_update_the_pool_in_place(v5e, key):
     """``memory_analysis`` of the compiled executable: the whole pool is
@@ -357,6 +369,7 @@ def test_decode_executables_update_the_pool_in_place(v5e, key):
 
 @pytest.mark.parametrize("key", ["decode", "prefill_b128", "prefill_b16",
                                  "prefill_b256", "prefill_b512",
+                                 "decode_fill_b128", "decode_fill_b256",
                                  "state_reset"])
 def test_hybrid_executables_update_kv_and_state_in_place(v5e, key):
     """K/V and both state buffers are aliased to the outputs, and the
@@ -366,7 +379,11 @@ def test_hybrid_executables_update_kv_and_state_in_place(v5e, key):
     dispatch: their states read a slice a lane, written back in one
     scatter) no more than the one-lane ones.  A gather of the lanes'
     states had the TPU compiler copy the 403 MB buffer whole, a layer
-    (PERF.md section 6, PR 38)."""
+    (PERF.md section 6, PR 38).  The decode step with one or two lanes
+    inside it (``decode_fill``) reads the lanes' states out of what the
+    state update left and scatters them back into it: no copy either,
+    and ONE product a weight matrix for the slots' rows and the lanes'
+    together."""
     import json
     from mxnet_tpu.serving import FalconH1
     from mxnet_tpu.serving.decode import engine as E
@@ -397,6 +414,11 @@ def test_hybrid_executables_update_kv_and_state_in_place(v5e, key):
         donated = pool
         lowered = _lower_chained_decode(
             mdl, params, pool, _resident(spec, FH_SLOTS, FH_PAGES))
+    elif key.startswith("decode_fill"):
+        donated = pool
+        lowered = _lower_turn(mdl, params, pool,
+                              _resident(spec, FH_SLOTS, FH_PAGES), spec,
+                              FH_PAGES, key, 128)
     else:
         donated = pool
         lowered = _lower_prefill(mdl, params, pool, spec, FH_PAGES, key, 128)
@@ -404,8 +426,25 @@ def test_hybrid_executables_update_kv_and_state_in_place(v5e, key):
     mem = compiled.memory_analysis()
     state = mem.alias_size_in_bytes - sum(
         nbytes(b) for layer in donated for b in layer)
-    assert 0 <= state < 2 ** 16 and (state > 0) == (key == "decode")
+    assert 0 <= state < 2 ** 16
+    assert (state > 0) == key.startswith("decode")
     assert mem.temp_size_in_bytes < nbytes(ssm_buf), mem.temp_size_in_bytes
+    if key.startswith("decode_fill"):
+        import re
+        # no copy of a state buffer, nor of anything near one: 39.6 MB
+        # (decode) and 17.7 (prefill_b256) at the cell's six layers
+        assert mem.temp_size_in_bytes < 100e6, mem.temp_size_in_bytes
+        lanes = int(key.rsplit("b", 1)[1]) // 128
+        text = compiled.as_text()
+        products = re.findall(r"= \S+ (?:convolution|dot)\(", text)
+        # nine weight matrices a layer for all rows, six a lane and layer
+        # for what is a slot's own, and one head over slots and lanes
+        assert len(products) == 2 * (9 + 6 * lanes) + 1, len(products)
+        calls = re.findall(r'^\s*(?:ROOT )?%(\S+) = .*custom_call_target='
+                           r'"tpu_custom_call"', text, re.M)
+        # per layer: the decode rows' state update and paged attention
+        assert sum("mxtpu_ssm_update" in c for c in calls) == 2
+        assert sum("mxtpu_paged_attention" in c for c in calls) == 2
     if key.startswith("prefill"):
         # nor a quarter of one: 17.8 MB (b128), 11.2 (b16), 33.9 (b256),
         # 73.0 (b512) at these two layers and the whole vocabulary
