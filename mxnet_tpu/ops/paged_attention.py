@@ -12,19 +12,24 @@ lengths (the fixed-shape-executable invariant, docs/ARCHITECTURE.md
 "Decode serving").
 
 The Pallas kernel's work follows the live lengths, not the table's
-size.  Its grid is one step a slot; the page table and the lengths are
-scalar-prefetched; the pools stay whole operands in HBM.  Inside a
-slot's step (:func:`_pa_walker`) a loop runs over the slot's
-``cdiv(length, block_k)`` live blocks and no further: each block's
-pages are copied from ``pool[table[slot, page]]`` into one of two VMEM
-buffers while the block before it is worked on, and a slot's last block
-starts the first block of the next live slot, so the copies do not
-drain at a slot's end.  An idle slot (length 0) costs an empty grid
-step: no copy, no arithmetic, exact zeros out, matching the oracle.  A
-block is ``block_k`` rows: several whole pages (``block_k //
-page_size``, one copy a page) or a part of one page; online-softmax
-float32 accumulators live in VMEM scratch across a slot's blocks, and
-the tail block's rows past the length are masked.
+size.  Its grid is one step a LIVE slot: the wrapper lists the slots of
+a length in slot order and counts them, both are scalar-prefetched
+beside the page table and the lengths, and the count bounds the grid (a
+dynamic bound), so an idle slot costs no grid step at all; a call with
+no live slot takes one step that walks nothing.  The pools stay whole
+operands in HBM.  Inside a slot's step (:func:`_pa_walker`) a loop runs
+over the slot's ``cdiv(length, block_k)`` live blocks and no further:
+each block's pages are copied from ``pool[table[slot, page]]`` into one
+of two VMEM buffers while the block before it is worked on, and a
+slot's last block starts the first block of the next live slot on the
+list, so the copies do not drain at a slot's end.  The output is one
+VMEM block for the whole call, zeroed at the first step and copied out
+after the last, so the idle slots' rows, which no step visits, are
+exact zeros, matching the oracle.  A block is ``block_k`` rows: several
+whole pages (``block_k // page_size``, one copy a page) or a part of
+one page; online-softmax float32 accumulators live in VMEM scratch
+across a slot's blocks, and the tail block's rows past the length are
+masked.
 
 Grouped-query attention: ``q`` may carry ``R`` times the pool's KV
 heads (query head ``h`` reads KV head ``h // R``).  The pool is sized
@@ -173,13 +178,10 @@ def _folded_body(q_ref, seg_ref, o_ref, acc_ref, m_ref, l_ref, *,
             pv = (per_head(p, False) * v).sum(axis=0, keepdims=True)
             acc_ref[row] = acc_ref[row] * per_head(corr, False) + pv
 
-    def finish():
-        for r in range(rep):
-            row = slice(r, r + 1)
-            l = l_ref[row]
-            l = jnp.where(l == 0.0, 1.0, l)
-            o_ref[0, row] = (acc_ref[row]
-                             / per_head(l, False)).astype(o_ref.dtype)
+    def finish(slot):
+        l = jnp.where(l_ref[...] == 0.0, 1.0, l_ref[...])
+        o_ref[slot] = (acc_ref[...]
+                       / per_head(l, False)).astype(o_ref.dtype)
 
     return block, finish
 
@@ -219,12 +221,13 @@ def _lanes_body(q_ref, o_ref, acc_ref, m_ref, l_ref, *, sm_scale, heads, d):
                                  preferred_element_type=jnp.float32)
             acc_ref[:, lanes] = acc_ref[:, lanes] * corr[:, :1] + pv
 
-    def finish():
+    def finish(slot):
         for g in range(heads):
             lanes = slice(g * d, (g + 1) * d)
             l = l_ref[g][:, :1]
             l = jnp.where(l == 0.0, 1.0, l)
-            o_ref[0, :, lanes] = (acc_ref[:, lanes] / l).astype(o_ref.dtype)
+            o_ref[slot, :, lanes] = (acc_ref[:, lanes]
+                                     / l).astype(o_ref.dtype)
 
     return block, finish
 
@@ -260,24 +263,28 @@ def _latent_body(q_ref, o_ref, acc_ref, m_ref, l_ref, *, sm_scale, rank):
                              preferred_element_type=jnp.float32)
         acc_ref[...] = acc_ref[...] * corr[:, :1] + pv
 
-    def finish():
+    def finish(slot):
         l = l_ref[...][:, :1]
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[slot] = (acc_ref[...] / l).astype(o_ref.dtype)
 
     return block, finish
 
 
-def _pa_walker(tbl_ref, len_ref, q_ref, *refs, body, block_k, pools=2):
-    """One grid step a slot; inside it a loop over the slot's live
+def _pa_walker(tbl_ref, len_ref, live_ref, count_ref, q_ref, *refs, body,
+               block_k, pools=2):
+    """One grid step a live slot: the grid is bounded by ``count_ref[0]``
+    and step ``i`` works on slot ``live_ref[i]`` (the slots of a length,
+    in slot order).  Inside a step a loop runs over the slot's live
     blocks alone, each copied from the ``pools`` paged buffers (K and V;
     one for a latent cache; whole operands, in HBM) into one of two
     VMEM buffers a pool while the block before it is worked on.
-    The last block of a slot starts the first block of the next live
-    slot, so idle slots in between cost an empty grid step and the copy
-    engine does not drain at a slot's end.  ``at_ref`` carries that
-    hand-over from step to step: the buffer the slot's first block is
-    in, and whether its copy is already in flight.
+    The last block of a slot starts the first block of the next slot on
+    the list, ``live_ref[i + 1]``, so the copy engine does not drain at
+    a slot's end.  ``at_ref`` carries that hand-over from step to step:
+    the buffer the slot's first block is in.  ``o_ref`` is the whole
+    output, zeroed at the first step; a slot's ``finish`` writes its
+    rows, so an idle slot, which has no step, keeps zeros.
 
     A block is ``block_k`` rows: whole pages (one copy a page) or a
     part of one page.  Its rows past the length are masked by the body;
@@ -289,10 +296,13 @@ def _pa_walker(tbl_ref, len_ref, q_ref, *refs, body, block_k, pools=2):
     slots, pages = tbl_ref.shape
     page_size = hbm[0].shape[1]
     rows = min(block_k, page_size)                # rows of one copy
-    block, finish = body(q_ref, *consts, o_ref, acc_ref, m_ref, l_ref)
-    s_i = pl.program_id(0)
+    i = pl.program_id(0)
+    s_i = live_ref[i]
     length = len_ref[s_i]
     n = pl.cdiv(length, block_k)
+    later = i + 1 < count_ref[0]                  # a live slot follows
+    then = live_ref[jnp.minimum(i + 1, slots - 1)]
+    block, finish = body(q_ref, *consts, o_ref, acc_ref, m_ref, l_ref)
 
     def copies(slot, blk, buf):
         made = []
@@ -316,49 +326,66 @@ def _pa_walker(tbl_ref, len_ref, q_ref, *refs, body, block_k, pools=2):
         for copy in copies(slot, blk, buf):
             copy.start()
 
-    def next_live(slot):
-        return lax.while_loop(
-            lambda j: (j < slots) & (len_ref[jnp.minimum(j, slots - 1)] == 0),
-            lambda j: j + 1, slot + 1)
-
-    @pl.when(s_i == 0)
-    def _reset():
-        at_ref[0] = 0
-        at_ref[1] = 0
-
-    @pl.when(length == 0)
-    def _idle():
+    @pl.when(i == 0)                  # the first live slot of the call
+    def _first():
         o_ref[...] = jnp.zeros_like(o_ref)
+        at_ref[0] = 0
 
-    @pl.when(length > 0)
-    def _live():
-        first = at_ref[0]
-
-        @pl.when(at_ref[1] == 0)      # the first live slot of the call
+        @pl.when(n > 0)               # else no slot is live
         def _():
-            start(s_i, 0, first)
+            start(s_i, 0, 0)
 
-        then = next_live(s_i)
-        at_ref[1] = (then < slots).astype(jnp.int32)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
 
-        def step(i, buf):
-            more = i + 1 < n          # else: the next live slot's first
+    def step(j, buf):
+        more = j + 1 < n              # else: the next live slot's first
 
-            @pl.when(more | (then < slots))
-            def _():
-                start(jnp.where(more, s_i, then), jnp.where(more, i + 1, 0),
-                      1 - buf)
+        @pl.when(more | later)
+        def _():
+            start(jnp.where(more, s_i, then), jnp.where(more, j + 1, 0),
+                  1 - buf)
 
-            for copy in copies(s_i, i, buf):
-                copy.wait()
-            block(*(b.at[buf] for b in bufs), i * block_k, length)
-            return 1 - buf
+        for copy in copies(s_i, j, buf):
+            copy.wait()
+        block(*(b.at[buf] for b in bufs), j * block_k, length)
+        return 1 - buf
 
-        at_ref[0] = lax.fori_loop(0, n, step, first)
-        finish()
+    at_ref[0] = lax.fori_loop(0, n, step, at_ref[0])
+    finish(s_i)
+
+
+def _walk(kernel, lengths, tables, q, *operands, specs, out_shape,
+          scratch, name, on_tpu):
+    """``kernel`` over the live slots of ``lengths``, one grid step each,
+    and one step where none is live (it zeroes the output).  The slots
+    of a length are listed in slot order, padded with slot 0, and
+    counted; both are scalar-prefetched after the table and the
+    lengths.  The output is one VMEM block for the whole call,
+    single-buffered: it is copied out once, after the last step."""
+    live = jnp.nonzero(lengths > 0, size=lengths.shape[0],
+                       fill_value=0)[0].astype(jnp.int32)
+    count = (lengths > 0).sum(dtype=jnp.int32).reshape(1)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(jnp.maximum(count[0], 1),),
+        in_specs=[pl.BlockSpec((1, *q.shape[1:]),
+                               lambda i, tbl, ln, live, n: (live[i], 0, 0)),
+                  *specs],
+        out_specs=pl.BlockSpec(out_shape.shape, lambda *_: (0, 0, 0),
+                               pipeline_mode=pl.Buffered(1)),
+        scratch_shapes=scratch,
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=not on_tpu,
+        name=name,
+    )(tables, lengths, live, count, q, *operands)
 
 
 def _block_rows(block_k, page_size, pages):
@@ -453,36 +480,26 @@ def _paged_attention_jit(q, k_pool, v_pool, tables, lengths, sm_scale,
         seg = (jnp.arange(hd, dtype=jnp.int32)[None, :] // d
                == jnp.arange(h, dtype=jnp.int32)[:, None])
         consts = (seg.astype(jnp.float32),)
-        const_specs = [pl.BlockSpec((h, hd), lambda s, tbl, ln: (0, 0))]
+        const_specs = [pl.BlockSpec((h, hd), lambda *_: (0, 0))]
         stats = (rep, h)
-    per_slot = pl.BlockSpec((1, rows, hd), lambda s, tbl, ln: (s, 0, 0))
     whole = pl.BlockSpec(memory_space=pl.ANY)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(s_,),
-        in_specs=[per_slot, whole, whole, *const_specs],
-        out_specs=per_slot,
-        scratch_shapes=[
+    out = _walk(
+        functools.partial(_pa_walker, body=body, block_k=block_k),
+        lengths.astype(jnp.int32), tables.astype(jnp.int32), q,
+        k_pool.reshape(num_pages, page_size, hd),
+        v_pool.reshape(num_pages, page_size, hd), *consts,
+        specs=[whole, whole, *const_specs],
+        out_shape=jax.ShapeDtypeStruct((s_, rows, hd), q.dtype),
+        scratch=[
             pltpu.VMEM((rows, hd), jnp.float32),
             pltpu.VMEM(stats, jnp.float32),
             pltpu.VMEM(stats, jnp.float32),
             pltpu.VMEM((2, block_k, hd), k_pool.dtype),
             pltpu.VMEM((2, block_k, hd), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.SMEM((2,), jnp.int32),
+            pltpu.SMEM((1,), jnp.int32),
         ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_pa_walker, body=body, block_k=block_k),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s_, rows, hd), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=not on_tpu,
-        name="mxtpu_paged_attention",
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), q,
-      k_pool.reshape(num_pages, page_size, hd),
-      v_pool.reshape(num_pages, page_size, hd), *consts)
+        name="mxtpu_paged_attention", on_tpu=on_tpu)
     if packed:
         return _unpack_outputs(out[:, :used], h, rep, d)
     return out[:, :rep].reshape(s_, rep, h, d).swapaxes(1, 2).reshape(
@@ -542,7 +559,7 @@ def _paged_make_args(case):
 
 
 _kernels.register_kernel(_kernels.KernelSpec(
-    "paged_attention", version=3,       # 3: the walker; a block may span pages
+    "paged_attention", version=4,       # 4: the grid walks the live slots
     run=_paged_kernel_run, fallback=_paged_kernel_fallback,
     config_space={"block_k": (16, 32, 64, 128)},
     default_config={"block_k": 64},
@@ -652,30 +669,20 @@ def _latent_attention_pallas(q, pool, tables, lengths, rank, sm_scale,
     q = jnp.pad(q, ((0, 0), (0, rows - h), (0, 0)))
     body = functools.partial(_latent_body, sm_scale=float(sm_scale),
                              rank=rank)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(s_,),
-        in_specs=[pl.BlockSpec((1, rows, w), lambda s, tbl, ln: (s, 0, 0)),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((1, rows, rank), lambda s, tbl, ln: (s, 0, 0)),
-        scratch_shapes=[
+    out = _walk(
+        functools.partial(_pa_walker, body=body, block_k=block_k, pools=1),
+        lengths.astype(jnp.int32), tables.astype(jnp.int32), q, pool,
+        specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_shape=jax.ShapeDtypeStruct((s_, rows, rank), q.dtype),
+        scratch=[
             pltpu.VMEM((rows, rank), jnp.float32),
             pltpu.VMEM((rows, 128), jnp.float32),
             pltpu.VMEM((rows, 128), jnp.float32),
             pltpu.VMEM((2, block_k, w), pool.dtype),
             pltpu.SemaphoreType.DMA((1, 2)),
-            pltpu.SMEM((2,), jnp.int32),
+            pltpu.SMEM((1,), jnp.int32),
         ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_pa_walker, body=body, block_k=block_k, pools=1),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s_, rows, rank), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=not on_tpu,
-        name="mxtpu_latent_attention",
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), q, pool)
+        name="mxtpu_latent_attention", on_tpu=on_tpu)
     return out[:, :h]
 
 
@@ -713,7 +720,7 @@ def _latent_make_args(case):
 
 
 _kernels.register_kernel(_kernels.KernelSpec(
-    "latent_attention", version=1,
+    "latent_attention", version=2,      # 2: the grid walks the live slots
     run=_latent_kernel_run, fallback=latent_attention_reference,
     config_space={"block_k": (128, 256, 512)},
     default_config={"block_k": 512},

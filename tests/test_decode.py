@@ -157,26 +157,53 @@ _PA_CASES = {
     "live_last": (16, 4, 32, [0, 0, 0, 0, 37]),
     "live_alternating": (16, 4, 32, [0, 9, 0, 64, 0, 33, 0]),
 }
+# A lightly loaded server: 96 slots, one or two of them live, through
+# each body: (query heads, KV heads, head width) of multi-head attention
+# folded into the lanes, grouped-query heads of a lane tile, and
+# grouped-query heads packed two KV heads a tile.  A case without heads
+# runs 2 heads of 8, folded.
+SERVER_SLOTS = 96
+_PA_SERVER = {"one_middle": {47: 37}, "one_first": {0: 64},
+              "one_last": {95: 17}, "two_apart": {3: 50, 91: 33}}
+_PA_BODIES = {"folded": (4, 4, 64), "lanes": (4, 2, 128),
+              "packed": (8, 2, 64)}
+
+
+def _server_lengths(live):
+    return [live.get(s, 0) for s in range(SERVER_SLOTS)]
+
+
+_PA_CASES.update({
+    f"server_{name}_{body}": (16, 4, 32, _server_lengths(live), heads)
+    for name, live in _PA_SERVER.items()
+    for body, heads in _PA_BODIES.items()})
+
+
+def _pa_args(ps, p_, lengths, heads=(2, 2, 8)):
+    """Random queries and pools for ``lengths``; the page tables are a
+    random permutation of the pool."""
+    rs = onp.random.RandomState(3)
+    (h, kvh, d), s_ = heads, len(lengths)
+    pages = s_ * p_ + 3
+    q = jnp.asarray(rs.randn(s_, h, d), jnp.float32)
+    kp = jnp.asarray(rs.randn(pages, ps, kvh, d), jnp.float32)
+    vp = jnp.asarray(rs.randn(pages, ps, kvh, d), jnp.float32)
+    tables = jnp.asarray(
+        rs.permutation(pages)[:s_ * p_].reshape(s_, p_), jnp.int32)
+    return q, kp, vp, tables, jnp.asarray(lengths, jnp.int32)
 
 
 @pytest.mark.parametrize("case", sorted(_PA_CASES))
 def test_paged_attention_ragged_parity_vs_oracle(case):
     """Kernel vs gather-oracle over ragged lengths, including an
     inactive (length-0) slot, through the public entry point; the page
-    tables are a random permutation of the pool."""
+    tables are a random permutation of the pool.  Every idle slot's
+    output is exact zeros, though the kernel walks the live slots
+    alone."""
     from mxnet_tpu.ops.paged_attention import (paged_attention,
                                                paged_attention_reference)
-    import jax.numpy as jnp
-    ps, p_, block_k, lengths = _PA_CASES[case]
-    rs = onp.random.RandomState(3)
-    s_, h, d = len(lengths), 2, 8
-    pages = s_ * p_ + 3
-    q = jnp.asarray(rs.randn(s_, h, d), jnp.float32)
-    kp = jnp.asarray(rs.randn(pages, ps, h, d), jnp.float32)
-    vp = jnp.asarray(rs.randn(pages, ps, h, d), jnp.float32)
-    tables = jnp.asarray(
-        rs.permutation(pages)[:s_ * p_].reshape(s_, p_), jnp.int32)
-    lengths = jnp.asarray(lengths, jnp.int32)
+    ps, p_, block_k, lengths, *heads = _PA_CASES[case]
+    q, kp, vp, tables, lengths = _pa_args(ps, p_, lengths, *heads)
     out = paged_attention(q, kp, vp, tables, lengths, block_k=block_k)
     ref = paged_attention_reference(q, kp, vp, tables, lengths)
     onp.testing.assert_allclose(onp.asarray(out), onp.asarray(ref),
@@ -184,6 +211,65 @@ def test_paged_attention_ragged_parity_vs_oracle(case):
     idle = onp.asarray(lengths) == 0
     assert not onp.asarray(out)[idle].any()    # length-0 slot → zeros
     assert onp.asarray(out)[~idle].any(axis=(1, 2)).all()
+
+
+def _walk_of(fn, *args):
+    """The one Pallas call of ``fn(*args)`` and the values of its
+    operands (the grid's dynamic bounds first), the jaxpr evaluated up
+    to the call through every jit around it."""
+    from jax.extend.core import ClosedJaxpr, jaxpr_as_fun
+
+    def find(closed, vals):
+        jaxpr = closed.jaxpr
+        for i, eqn in enumerate(jaxpr.eqns):
+            sub = eqn.params.get("jaxpr")
+            if eqn.primitive.name != "pallas_call" and not (
+                    isinstance(sub, ClosedJaxpr) and any(
+                        e.primitive.name == "pallas_call"
+                        for e in _equations(sub.jaxpr))):
+                continue
+            head = ClosedJaxpr(jaxpr.replace(eqns=jaxpr.eqns[:i],
+                                             outvars=list(eqn.invars)),
+                               closed.consts)
+            ins = jaxpr_as_fun(head)(*vals)
+            return (eqn, ins) if eqn.primitive.name == "pallas_call" \
+                else find(sub, ins)
+
+    return find(jax.make_jaxpr(fn)(*args), args)
+
+
+@pytest.mark.parametrize("live", ["two_apart", "none"])
+@pytest.mark.parametrize("body", [*_PA_BODIES, "latent"])
+def test_the_walk_is_bounded_by_the_live_slots(body, live):
+    """The kernel's grid has one dynamic bound, the count of live slots,
+    and its scalar-prefetched slot list names them in slot order: a
+    server with 2 of 96 slots live takes 2 grid steps, an empty one
+    the one step that zeroes the output; never one step a slot of the
+    table."""
+    from mxnet_tpu import kernels
+    from mxnet_tpu.ops.paged_attention import latent_attention, \
+        paged_attention
+    lengths = _server_lengths(_PA_SERVER.get(live, {}))
+    if body == "latent":
+        (q, pool, tables, _), kw = kernels.get_kernel(
+            "latent_attention").make_args(dict(
+                slots=SERVER_SLOTS, pages_per_slot=4, page_size=16, h=4,
+                rank=128, rope=64))
+        args = (q, pool, tables, jnp.asarray(lengths, jnp.int32))
+        eqn, ins = _walk_of(lambda *a: latent_attention(*a, block_k=32,
+                                                        **kw), *args)
+    else:
+        args = _pa_args(16, 4, lengths, _PA_BODIES[body])
+        eqn, ins = _walk_of(lambda *a: paged_attention(*a, block_k=32),
+                            *args)
+    grid = eqn.params["grid_mapping"]
+    assert len(grid.grid) == 1 and grid.num_dynamic_grid_bounds == 1
+    bound, _, _, order, count = (onp.asarray(x) for x in ins[:5])
+    slots = [s for s, n in enumerate(lengths) if n]
+    assert int(count[0]) == len(slots)
+    assert int(bound) == max(len(slots), 1)
+    assert order.shape == (SERVER_SLOTS,)
+    assert order[:len(slots)].tolist() == slots
 
 
 # -- the pool's layout: one whole buffer per layer for K and for V -----------

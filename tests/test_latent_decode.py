@@ -507,6 +507,27 @@ def test_latent_attention_matches_a_written_out_softmax(case, block_k,
                            - want).max() <= tol
 
 
+@pytest.mark.parametrize("live", [{47: 37}, {0: 64}, {95: 17},
+                                  {3: 50, 91: 33}, {}],
+                         ids=["one_middle", "one_first", "one_last",
+                              "two_apart", "all_idle"])
+def test_latent_attention_walks_a_lightly_loaded_server(live):
+    """96 slots, none to two of them live (the walk visits those
+    alone): the live slots' outputs are the oracle's, every idle
+    slot's is exact zeros."""
+    spec = kernels.get_kernel("latent_attention")
+    (q, pool, tables, _), kw = spec.make_args(dict(
+        slots=96, pages_per_slot=4, page_size=16, h=4, rank=128, rope=64))
+    lengths = jnp.asarray([live.get(s, 0) for s in range(96)], jnp.int32)
+    got = onp.asarray(latent_attention(q, pool, tables, lengths,
+                                       block_k=32, **kw))
+    want = onp.asarray(spec.fallback(q, pool, tables, lengths, **kw))
+    onp.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    idle = onp.asarray(lengths) == 0
+    assert not got[idle].any()
+    assert got[~idle].any(axis=(1, 2)).all()
+
+
 def test_yarn_frequencies_and_the_table_rotation(ref):
     sc = _config()["rope_scaling"]
     inv = yarn_frequencies(64, 10000, **sc)

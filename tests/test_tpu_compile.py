@@ -10,6 +10,7 @@ The kernels choose interpret mode from ``jax.default_backend()``; the
 test steers that call, the program has no option for it.
 """
 import os
+import re
 
 import pytest
 
@@ -107,6 +108,20 @@ def test_flash_attention_compiles_at_the_training_cell(v5e, with_bwd):
     assert len(calls) == (3 if with_bwd else 1), calls
 
 
+def _walks_the_live_slots(text, slots, name):
+    """The Mosaic call ``name`` is ONE and takes its grid's bound as its
+    first operand, a scalar (a grid of a fixed size takes none), then
+    the scalar-prefetched table, lengths, the list of live slots and
+    their count (``ops/paged_attention.py``, ``_walk``)."""
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1 and name in calls[0], calls
+    walk = (rf"operand_layout_constraints={{s32\[\], s32\[{slots},\d+\]\S*, "
+            rf"s32\[{slots}\]\S*, s32\[{slots}\]\S*, s32\[1\]")
+    assert re.search(walk, calls[0]), calls[0]
+    return calls[0]
+
+
 @pytest.mark.parametrize("page_size", [16, 128])
 def test_paged_attention_compiles(v5e, page_size):
     from mxnet_tpu.ops.paged_attention import _paged_attention_pallas
@@ -131,7 +146,7 @@ def test_paged_attention_compiles_at_the_decode_cell(v5e, block_k):
             q, k, v, t, l, 64 ** -0.5, block_k),
         v5e, ((96, 16, 64), "bfloat16"), pool, pool,
         ((96, 64), "int32"), ((96,), "int32"))
-    assert text.count("tpu_custom_call") == 1
+    _walks_the_live_slots(text, 96, "mxtpu_paged_attention")
 
 
 def test_a_pool_of_no_whole_lane_tile_is_gathered_by_xla(v5e):
@@ -162,8 +177,10 @@ def test_paged_attention_grouped_query_compiles(v5e, block_k):
     pool = ((FH_SLOTS * FH_PAGES, FH_PAGE, 4 * 128), "bfloat16")
     args = (((FH_SLOTS, 20, 128), "bfloat16"), pool, pool,
             ((FH_SLOTS, FH_PAGES), "int32"), ((FH_SLOTS,), "int32"))
-    _compile(lambda q, k, v, t, l: _paged_attention_pallas(
-        q, k, v, t, l, 128 ** -0.5, block_k), v5e, *args)
+    _walks_the_live_slots(_compile(
+        lambda q, k, v, t, l: _paged_attention_pallas(
+            q, k, v, t, l, 128 ** -0.5, block_k), v5e, *args),
+        FH_SLOTS, "mxtpu_paged_attention")
     # the oracle too: chip_smoke.py runs it in float32 on the chip
     f32 = tuple((shape, "float32" if dt == "bfloat16" else dt)
                 for shape, dt in args)
@@ -186,11 +203,9 @@ def test_paged_attention_packs_narrow_grouped_query_heads(v5e, block_k):
             q, k, v, t, l, 64 ** -0.5, block_k),
         v5e, ((192, 32, 64), "bfloat16"), pool, pool,
         ((192, 32), "int32"), ((192,), "int32"))
-    call = [line for line in text.splitlines()
-            if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(call) == 1 and "mxtpu_paged_attention" in call[0]
-    # operands: tables, lengths, the packed queries (192, 8, 512), K, V
-    assert "bf16[192,8,512]" in call[0] and "f32[8,512]" not in call[0]
+    call = _walks_the_live_slots(text, 192, "mxtpu_paged_attention")
+    # operands: the walk's scalars, the packed queries (192, 8, 512), K, V
+    assert "bf16[192,8,512]" in call and "f32[8,512]" not in call
 
 
 @pytest.mark.parametrize("block_k", [128, 512])
@@ -204,7 +219,7 @@ def test_latent_attention_compiles_at_the_reasoning_cell(v5e, block_k):
                                                     192 ** -0.5, block_k),
         v5e, ((128, 64, 640), "bfloat16"), ((4096, 128, 640), "bfloat16"),
         ((128, 32), "int32"), ((128,), "int32"))
-    assert "mxtpu_latent_attention" in text
+    _walks_the_live_slots(text, 128, "mxtpu_latent_attention")
 
 
 def test_a_latent_row_of_no_whole_lane_tile_is_refused(v5e):
