@@ -73,7 +73,11 @@ head and its first ``rank`` lanes are every head's value, so a block is
 copied once and read for the scores and for the values.  The queries
 come absorbed (``q_nope`` through the key half of the up-projection),
 ``rank + rope`` lanes a head; the output stays in the latent space and
-the caller takes it through the value half.
+the caller takes it through the value half.  At 64 heads a block's two
+products keep the MXU as long as its copy keeps the DMA engine, so this
+body is staged: the walker runs the next block's scores beside this
+block's softmax, over four copy buffers (:func:`_pa_walker`; the other
+bodies, bound by their copies, keep two).
 """
 from __future__ import annotations
 
@@ -91,7 +95,8 @@ from .. import kernels as _kernels
 from .registry import register
 
 __all__ = ["paged_attention", "paged_attention_reference",
-           "latent_attention", "latent_attention_reference"]
+           "latent_attention", "latent_attention_reference",
+           "latent_overlap_share"]
 
 _NEG_INF = -1e30
 
@@ -238,27 +243,39 @@ def _latent_body(q_ref, o_ref, acc_ref, m_ref, l_ref, *, sm_scale, rank):
     block's ``(block_k, width)`` tile is their keys whole and their
     values in its first ``rank`` lanes.  Two matmuls a block on the MXU
     in the pool's dtype with float32 accumulation; the running maximum
-    and sum are kept lane-broadcast, ``(rows, 128)``."""
+    and sum are kept lane-broadcast, ``(rows, 128)``.
 
-    def block(kv_ref, start, length):
+    At 64 heads a block's two products take the MXU as long as its copy
+    takes the DMA engine, so the body is STAGED (``depth`` 4,
+    :func:`_pa_walker`): ``score`` is a block's first product alone,
+    issued while the block before it is in ``update`` (the softmax, the
+    values, the accumulators).  Only the block that holds a slot's last
+    row (``tail``) is masked: every other is whole."""
+
+    def score(q, kv_ref):
         exact = (lax.Precision.HIGHEST if kv_ref.dtype == jnp.float32
                  else lax.Precision.DEFAULT)
-        kv = kv_ref[...]                                      # (block_k, W)
-        q = q_ref[0].astype(kv.dtype)                         # (rows, W)
-        s = lax.dot_general(q, kv, (((1,), (1,)), ((), ())),
-                            precision=exact,
+        s = lax.dot_general(q.astype(kv_ref.dtype), kv_ref[...],
+                            (((1,), (1,)), ((), ())), precision=exact,
                             preferred_element_type=jnp.float32)
-        s = s * sm_scale                                      # (rows, block_k)
-        kpos = start + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = kpos < length
-        s = jnp.where(mask, s, _NEG_INF)
+        return s * sm_scale                                   # (rows, block_k)
+
+    def update(s, kv_ref, start, length, tail):
+        exact = (lax.Precision.HIGHEST if kv_ref.dtype == jnp.float32
+                 else lax.Precision.DEFAULT)
+        if tail:
+            kpos = start + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            mask = kpos < length
+            s = jnp.where(mask, s, _NEG_INF)
         m_prev = m_ref[...]                                   # (rows, 128)
         m_cur = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         corr = jnp.exp(m_prev - m_cur)
-        p = jnp.where(mask, jnp.exp(s - m_cur[:, :1]), 0.0)
+        p = jnp.exp(s - m_cur[:, :1])
+        if tail:
+            p = jnp.where(mask, p, 0.0)
         l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
         m_ref[...] = m_cur
-        pv = lax.dot_general(p.astype(kv.dtype), kv[:, :rank],
+        pv = lax.dot_general(p.astype(kv_ref.dtype), kv_ref[:, :rank],
                              (((1,), (0,)), ((), ())), precision=exact,
                              preferred_element_type=jnp.float32)
         acc_ref[...] = acc_ref[...] * corr[:, :1] + pv
@@ -268,7 +285,13 @@ def _latent_body(q_ref, o_ref, acc_ref, m_ref, l_ref, *, sm_scale, rank):
         l = jnp.where(l == 0.0, 1.0, l)
         o_ref[slot] = (acc_ref[...] / l).astype(o_ref.dtype)
 
-    return block, finish
+    return score, update, finish
+
+
+# four buffers: with three, only one block's copies were in flight while
+# a stage computed: 259 us a call against 236 at axk1_decode_reasoning's
+# geometry on a v5e, and 236 at five (PERF.md section 6)
+_latent_body.depth = 4
 
 
 def _pa_walker(tbl_ref, len_ref, live_ref, count_ref, q_ref, *refs, body,
@@ -277,20 +300,39 @@ def _pa_walker(tbl_ref, len_ref, live_ref, count_ref, q_ref, *refs, body,
     and step ``i`` works on slot ``live_ref[i]`` (the slots of a length,
     in slot order).  Inside a step a loop runs over the slot's live
     blocks alone, each copied from the ``pools`` paged buffers (K and V;
-    one for a latent cache; whole operands, in HBM) into one of two
-    VMEM buffers a pool while the block before it is worked on.
-    The last block of a slot starts the first block of the next slot on
-    the list, ``live_ref[i + 1]``, so the copy engine does not drain at
-    a slot's end.  ``at_ref`` carries that hand-over from step to step:
-    the buffer the slot's first block is in.  ``o_ref`` is the whole
-    output, zeroed at the first step; a slot's ``finish`` writes its
-    rows, so an idle slot, which has no step, keeps zeros.
+    one for a latent cache; whole operands, in HBM) into one of
+    ``depth`` VMEM buffers a pool while the blocks before it are worked
+    on.  Copies run ahead across slots: a slot's last blocks start the
+    first blocks of the next slots on the list, ``live_ref[i + 1]`` and
+    on, so the copy engine does not drain at a slot's end.
+    ``at_ref`` carries that hand-over from step to step: the buffer the
+    slot's first block is in.  ``o_ref`` is the whole output, zeroed at
+    the first step; a slot's ``finish`` writes its rows, so an idle
+    slot, which has no step, keeps zeros.
+
+    The depth is the body's (its ``depth`` attribute, else 2).  A body
+    of depth 2 returns ``(block, finish)``: two buffers, block ``j + 1``
+    copied while ``block`` works on block ``j``.  A STAGED body (depth
+    3 or more) returns ``(score, update, finish)``: ``depth`` buffers,
+    and while ``update`` takes block ``j`` through its softmax and
+    values, ``score`` already runs block ``j + 1``'s first product and
+    the copies of blocks up to ``j + depth - 1`` are in flight (those
+    may belong to the next ``depth - 1`` live slots).  The scores wait
+    in ``s_ref`` for the next stage, and a slot's last stage scores the
+    next slot's first block with that slot's queries, so the stages do
+    not drain at a slot's end either.  For that the staged walk is
+    handed the queries whole, in HBM (``q_ref``), and copies each live
+    slot's once, one step ahead, into one of two buffers (``q_bufs``,
+    slot ``live_ref[i]``'s in buffer ``i % 2``).
 
     A block is ``block_k`` rows: whole pages (one copy a page) or a
     part of one page.  Its rows past the length are masked by the body;
     a page index past the table's width reads the last column, whose
     rows are all masked."""
+    depth = getattr(getattr(body, "func", body), "depth", 2)
     hbm, refs = refs[:pools], refs[pools:]
+    if depth > 2:   # a block's scores, two slots' queries, their copies
+        *refs, s_ref, q_bufs, q_sems = refs
     *consts, o_ref, acc_ref, m_ref, l_ref = refs[:-(pools + 2)]
     *bufs, sems, at_ref = refs[-(pools + 2):]
     slots, pages = tbl_ref.shape
@@ -302,7 +344,7 @@ def _pa_walker(tbl_ref, len_ref, live_ref, count_ref, q_ref, *refs, body,
     n = pl.cdiv(length, block_k)
     later = i + 1 < count_ref[0]                  # a live slot follows
     then = live_ref[jnp.minimum(i + 1, slots - 1)]
-    block, finish = body(q_ref, *consts, o_ref, acc_ref, m_ref, l_ref)
+    *stages, finish = body(q_ref, *consts, o_ref, acc_ref, m_ref, l_ref)
 
     def copies(slot, blk, buf):
         made = []
@@ -326,6 +368,65 @@ def _pa_walker(tbl_ref, len_ref, live_ref, count_ref, q_ref, *refs, body,
         for copy in copies(slot, blk, buf):
             copy.start()
 
+    def wait(slot, blk, buf):
+        for copy in copies(slot, blk, buf):
+            copy.wait()
+
+    if depth > 2:
+        score, update = stages
+
+        def q_copy(slot, buf):
+            return pltpu.make_async_copy(q_ref.at[slot], q_bufs.at[buf],
+                                         q_sems.at[buf])
+
+        mine, theirs = lax.rem(i, 2), lax.rem(i + 1, 2)
+        lead = depth - 1                          # blocks copied ahead
+        # the live slots after this one that a copy can reach, and their
+        # blocks; a slot past the list is padding (never copied)
+        nexts = [(live_ref[jnp.minimum(i + d, slots - 1)],
+                  i + d < count_ref[0]) for d in range(1, lead + 1)]
+        sizes = [pl.cdiv(len_ref[slot], block_k) for slot, _ in nexts]
+
+        def ahead(j):
+            """Slot and block of the walk's block ``j`` counted from this
+            slot's first (``j < n + lead``), and whether it is: this
+            slot's, or one of the next live slots'."""
+            slot, blk, real, rest = s_i, j, j < n, j - n
+            for (nxt, live), size in zip(nexts, sizes):
+                here = (rest >= 0) & (rest < size)
+                slot = jnp.where(here, nxt, slot)
+                blk = jnp.where(here, rest, blk)
+                real = real | (here & live)
+                rest = rest - size
+            return slot, blk, real
+
+        def stage(j, buf, tail):
+            """Block ``j`` (in ``buf``, its scores in ``s_ref``) through
+            ``update`` beside the next block's ``score``; block ``j +
+            lead`` copied.  ``tail``: the slot's last block, whose next
+            is the next slot's first."""
+            slot, blk, real = ahead(j + lead)
+            nxt = lax.rem(buf + 1, depth)
+
+            @pl.when(real)
+            def _():
+                start(slot, blk, lax.rem(buf + lead, depth))
+
+            if tail:
+                @pl.when(later)
+                def _():
+                    wait(then, 0, nxt)
+                    q_copy(then, theirs).wait()
+                q = q_bufs[theirs]
+            else:
+                wait(s_i, j + 1, nxt)
+                q = q_bufs[mine]
+            s_next = score(q, *(b.at[nxt] for b in bufs))
+            update(s_ref[...], *(b.at[buf] for b in bufs), j * block_k,
+                   length, tail)
+            s_ref[...] = s_next
+            return nxt
+
     @pl.when(i == 0)                  # the first live slot of the call
     def _first():
         o_ref[...] = jnp.zeros_like(o_ref)
@@ -334,10 +435,40 @@ def _pa_walker(tbl_ref, len_ref, live_ref, count_ref, q_ref, *refs, body,
         @pl.when(n > 0)               # else no slot is live
         def _():
             start(s_i, 0, 0)
+            if depth > 2:             # its first block scored here
+                q_copy(s_i, 0).start()
+                for j in range(1, lead):
+                    slot, blk, real = ahead(j)
+
+                    @pl.when(real)
+                    def _():
+                        start(slot, blk, j)
+
+                q_copy(s_i, 0).wait()
+                wait(s_i, 0, 0)
+                s_ref[...] = score(q_bufs[0], *(b.at[0] for b in bufs))
+
+    if depth > 2:
+        @pl.when(later)
+        def _():
+            q_copy(then, theirs).start()
 
     acc_ref[...] = jnp.zeros_like(acc_ref)
     m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
+
+    if depth > 2:
+        buf = lax.fori_loop(0, n - 1, lambda j, b: stage(j, b, False),
+                            at_ref[0])
+
+        @pl.when(n > 0)
+        def _():
+            at_ref[0] = stage(n - 1, buf, True)
+
+        finish(s_i)
+        return
+
+    block, = stages
 
     def step(j, buf):
         more = j + 1 < n              # else: the next live slot's first
@@ -356,8 +487,14 @@ def _pa_walker(tbl_ref, len_ref, live_ref, count_ref, q_ref, *refs, body,
     finish(s_i)
 
 
-def _walk(kernel, lengths, tables, q, *operands, specs, out_shape,
-          scratch, name, on_tpu):
+def _slot_rows(q):
+    """The block of ``q`` a grid step reads: its live slot's rows."""
+    return pl.BlockSpec((1, *q.shape[1:]),
+                        lambda i, tbl, ln, live, n: (live[i], 0, 0))
+
+
+def _walk(kernel, lengths, tables, *operands, specs, out_shape, scratch,
+          name, on_tpu):
     """``kernel`` over the live slots of ``lengths``, one grid step each,
     and one step where none is live (it zeroes the output).  The slots
     of a length are listed in slot order, padded with slot 0, and
@@ -370,9 +507,7 @@ def _walk(kernel, lengths, tables, q, *operands, specs, out_shape,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(jnp.maximum(count[0], 1),),
-        in_specs=[pl.BlockSpec((1, *q.shape[1:]),
-                               lambda i, tbl, ln, live, n: (live[i], 0, 0)),
-                  *specs],
+        in_specs=specs,
         out_specs=pl.BlockSpec(out_shape.shape, lambda *_: (0, 0, 0),
                                pipeline_mode=pl.Buffered(1)),
         scratch_shapes=scratch,
@@ -385,7 +520,7 @@ def _walk(kernel, lengths, tables, q, *operands, specs, out_shape,
             dimension_semantics=("arbitrary",)),
         interpret=not on_tpu,
         name=name,
-    )(tables, lengths, live, count, q, *operands)
+    )(tables, lengths, live, count, *operands)
 
 
 def _block_rows(block_k, page_size, pages):
@@ -488,7 +623,7 @@ def _paged_attention_jit(q, k_pool, v_pool, tables, lengths, sm_scale,
         lengths.astype(jnp.int32), tables.astype(jnp.int32), q,
         k_pool.reshape(num_pages, page_size, hd),
         v_pool.reshape(num_pages, page_size, hd), *consts,
-        specs=[whole, whole, *const_specs],
+        specs=[_slot_rows(q), whole, whole, *const_specs],
         out_shape=jax.ShapeDtypeStruct((s_, rows, hd), q.dtype),
         scratch=[
             pltpu.VMEM((rows, hd), jnp.float32),
@@ -654,9 +789,18 @@ def latent_attention_reference(q, pool, tables, lengths, *, rank, sm_scale):
 
 def _latent_attention_pallas(q, pool, tables, lengths, rank, sm_scale,
                              block_k):
+    return _latent_attention_jit(q, pool, tables, lengths, int(rank),
+                                 float(sm_scale), int(block_k),
+                                 jax.default_backend() == "tpu")
+
+
+# Jitted on everything but the arrays, as _paged_attention_jit: the seven
+# layers of a decode step share ONE trace of the staged kernel's body.
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
+def _latent_attention_jit(q, pool, tables, lengths, rank, sm_scale, block_k,
+                          on_tpu):
     s_, h, w = q.shape
     page_size, p_ = pool.shape[1], tables.shape[1]
-    on_tpu = jax.default_backend() == "tpu"
     if on_tpu and (w % 128 or rank % 128):
         # a copy moves whole lane tiles and the values are a lane-aligned
         # slice of the row: serving/decode/paged_kv.py pads its rows so
@@ -669,18 +813,25 @@ def _latent_attention_pallas(q, pool, tables, lengths, rank, sm_scale,
     q = jnp.pad(q, ((0, 0), (0, rows - h), (0, 0)))
     body = functools.partial(_latent_body, sm_scale=float(sm_scale),
                              rank=rank)
+    whole = pl.BlockSpec(memory_space=pl.ANY)
+    depth = _latent_body.depth
     out = _walk(
         functools.partial(_pa_walker, body=body, block_k=block_k, pools=1),
+        # the queries whole: the staged walk copies each slot's by hand
         lengths.astype(jnp.int32), tables.astype(jnp.int32), q, pool,
-        specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        specs=[whole, whole],
         out_shape=jax.ShapeDtypeStruct((s_, rows, rank), q.dtype),
         scratch=[
             pltpu.VMEM((rows, rank), jnp.float32),
             pltpu.VMEM((rows, 128), jnp.float32),
             pltpu.VMEM((rows, 128), jnp.float32),
-            pltpu.VMEM((2, block_k, w), pool.dtype),
-            pltpu.SemaphoreType.DMA((1, 2)),
+            pltpu.VMEM((depth, block_k, w), pool.dtype),
+            pltpu.SemaphoreType.DMA((1, depth)),
             pltpu.SMEM((1,), jnp.int32),
+            # the staged walk's: a block's scores, two slots' queries
+            pltpu.VMEM((rows, block_k), jnp.float32),
+            pltpu.VMEM((2, rows, w), q.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
         ],
         name="mxtpu_latent_attention", on_tpu=on_tpu)
     return out[:, :h]
@@ -720,7 +871,7 @@ def _latent_make_args(case):
 
 
 _kernels.register_kernel(_kernels.KernelSpec(
-    "latent_attention", version=2,      # 2: the grid walks the live slots
+    "latent_attention", version=3,      # 3: the scores are staged
     run=_latent_kernel_run, fallback=latent_attention_reference,
     config_space={"block_k": (128, 256, 512)},
     default_config={"block_k": 512},
@@ -736,6 +887,16 @@ _kernels.register_kernel(_kernels.KernelSpec(
 ))
 
 
+def _latent_block(q, pool, tables, lengths, rank, sm_scale):
+    """The registry's block for this call's signature."""
+    sig, dt = _latent_signature(q, pool, tables, lengths, rank=rank,
+                                sm_scale=sm_scale)
+    return _kernels.resolve(
+        "latent_attention", sig, dt,
+        tune_args=((q, pool, tables, lengths),
+                   {"rank": rank, "sm_scale": sm_scale}))["block_k"]
+
+
 def latent_attention(q, pool, tables, lengths, *, rank, sm_scale,
                      block_k=None):
     """One attention step per slot over a paged latent cache.
@@ -749,14 +910,24 @@ def latent_attention(q, pool, tables, lengths, *, rank, sm_scale,
     caller to up-project.  ``W`` and ``rank`` are multiples of 128 on a
     TPU (else the XLA gather runs)."""
     if block_k is None:
-        sig, dt = _latent_signature(q, pool, tables, lengths, rank=rank,
-                                    sm_scale=sm_scale)
-        block_k = _kernels.resolve(
-            "latent_attention", sig, dt,
-            tune_args=((q, pool, tables, lengths),
-                       {"rank": rank, "sm_scale": sm_scale}))["block_k"]
+        block_k = _latent_block(q, pool, tables, lengths, rank, sm_scale)
     return _latent_attention_pallas(q, pool, tables, lengths, int(rank),
                                     float(sm_scale), int(block_k))
+
+
+def latent_overlap_share(q, pool, tables, lengths, *, rank, sm_scale,
+                         block_k=None):
+    """Of the blocks :func:`latent_attention` walks for these
+    arguments, the share whose scores are issued while another block's
+    softmax runs: every block but the call's first, since the staged
+    walk carries its stages across slots (:func:`_pa_walker`); 0 where
+    no slot is live.  A float32 scalar, counted from ``lengths``."""
+    if block_k is None:
+        block_k = _latent_block(q, pool, tables, lengths, rank, sm_scale)
+    rows = _block_rows(block_k, pool.shape[1], tables.shape[1])
+    blocks = ((lengths + rows - 1) // rows).sum()
+    return jnp.where(blocks > 0, (blocks - 1) / jnp.maximum(blocks, 1),
+                     0.0).astype(jnp.float32)
 
 
 register("latent_attention", aliases=("_npx_latent_attention",))(

@@ -302,7 +302,9 @@ class AXK1(DecodePlaneModel):
         """What a decode step hands back beside its tokens, means over
         the expert layers: the share of routed (row, expert) pairs that
         met an expert held here, the rows a held expert got (mean and
-        largest), the share of held experts that got none."""
+        largest), the share of held experts that got none.  The decode
+        step adds ``latent_overlap_share``, a mean over the layers
+        (``paged_attention.latent_overlap_share``)."""
         if not mean:
             return {}
         return {"moe_local_pair_share":
@@ -324,7 +326,9 @@ class AXK1(DecodePlaneModel):
             pool, positions, tables, active, inv_freq=self.inv_freq,
             sm_scale=self.sm_scale)
         pool, x, mean = self._layers(params, pool, tokens, attend, active)
-        return pool, self._logits(params, x), self._counters(mean)
+        counters = dict(self._counters(mean), latent_overlap_share=jnp.stack(
+            attend.overlap).mean())
+        return pool, self._logits(params, x), counters
 
     # -- prefill: lanes, each one chunk of one slot -------------------------------
 

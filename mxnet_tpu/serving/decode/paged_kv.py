@@ -79,8 +79,8 @@ from jax import lax
 
 from ... import telemetry
 from ...base import MXNetError
-from ...ops.paged_attention import (latent_attention, paged_attention,
-                                    packs_heads)
+from ...ops.paged_attention import (latent_attention, latent_overlap_share,
+                                    packs_heads, paged_attention)
 from ...ops.rope import rope, rope_reference, rope_table
 
 __all__ = ["PageAllocator", "PagedKVCache", "OutOfPagesError",
@@ -641,7 +641,8 @@ def latent_slot_attention(pool, positions, tables, active, *, inv_freq,
     W_k . c_kv + q_rope . k_rope``, the output summed in the latent
     space and taken through ``W_v``): the ``latent_attention`` kernel
     reads each page once for both.  An inactive slot writes nothing and
-    yields zeros."""
+    yields zeros.  ``attend.overlap`` collects, a call a layer, the
+    kernel's ``latent_overlap_share`` (a counter of the decode step)."""
     num_pages, ps = pool[0][0].shape[:2]
     lengths = jnp.where(active, positions + 1, 0).astype(jnp.int32)
     pagerow = jnp.take_along_axis(
@@ -652,10 +653,13 @@ def latent_slot_attention(pool, positions, tables, active, *, inv_freq,
     def attend(q_nope, q_rope, c_kv, k_rope, w_kvb, buf):
         q, w_v, buf = _latent_write(q_nope, q_rope, c_kv, k_rope, w_kvb,
                                     buf, positions, page, offset, inv_freq)
-        o = latent_attention(q, buf, tables, lengths, rank=c_kv.shape[-1],
-                             sm_scale=sm_scale)
+        kw = dict(rank=c_kv.shape[-1], sm_scale=sm_scale)
+        o = latent_attention(q, buf, tables, lengths, **kw)
+        attend.overlap.append(latent_overlap_share(q, buf, tables, lengths,
+                                                   **kw))
         return jnp.einsum("bhr,rhd->bhd", o, w_v), (buf,)
 
+    attend.overlap = []
     return attend
 
 

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 
 import mxnet_tpu as mx  # noqa: F401  (registers ops + kernel specs)
 from mxnet_tpu import kernels, telemetry
+from mxnet_tpu.ops import paged_attention
 from mxnet_tpu.ops.paged_attention import latent_attention
 from mxnet_tpu.ops.rope import rope_reference, rope_table, yarn_frequencies
 from mxnet_tpu.parallel.moe import held_experts, route_topk
@@ -481,14 +482,43 @@ def test_the_absorbed_form_is_the_plain_one(models):
     assert float(jnp.abs(last[0] - want[-1]).max()) < 2e-5
 
 
+# Lengths of six slots in rows of a block ``r``, for the staged walk
+# (ops/paged_attention.py, _pa_walker): its stages cross slots, so what
+# a slot's neighbours hold is what is tested.
+_WALKS = {
+    "blocks_1_2_3_4": lambda r: [r // 2 + 1, r + 3, 2 * r + 5, 3 * r + 1,
+                                 0, 0],
+    "exact_multiples": lambda r: [r, 2 * r, 3 * r, 0, r, 0],
+    "zeros_between": lambda r: [0, r + 1, 0, 0, 2 * r, 0],
+    "one_live": lambda r: [0, 0, 0, 2 * r + 3, 0, 0],
+    "all_live": lambda r: [1, r - 1, r + 1, 2 * r, 3 * r + 2, 7],
+    # slots 1-4 each one block: its only block is scored in the previous
+    # slot's last stage and scores the next slot's first in its own
+    "one_block_hand_overs": lambda r: [2 * r + 1, 5, 3, r, 9, r + 2],
+}
+
+
+def _latent_case(spec, case, block_k, dtype):
+    """The registry's tuning case ``case``, or six slots of up to 2,560
+    rows (pages of 128, the cell's) with the lengths ``_WALKS[case]``
+    gives at this block."""
+    if not isinstance(case, str):
+        return spec.make_args(dict(spec.tune_grid[case], dtype=dtype))
+    pages, page_size = 20, 128
+    (q, pool, tables, _), kw = spec.make_args(dict(
+        slots=6, pages_per_slot=pages, page_size=page_size, h=16, rank=128,
+        rope=64, dtype=dtype))
+    rows = paged_attention._block_rows(block_k, page_size, pages)
+    return (q, pool, tables, jnp.asarray(_WALKS[case](rows), jnp.int32)), kw
+
+
 @pytest.mark.parametrize("dtype,tol", [("float32", 2e-6), ("bfloat16", 2e-2)])
-@pytest.mark.parametrize("case", range(3))
+@pytest.mark.parametrize("case", [0, 1, 2, *_WALKS])
 @pytest.mark.parametrize("block_k", [8, 128, 512])
 def test_latent_attention_matches_a_written_out_softmax(case, block_k,
                                                         dtype, tol):
     spec = kernels.get_kernel("latent_attention")
-    (q, pool, tables, lengths), kw = spec.make_args(
-        dict(spec.tune_grid[case], dtype=dtype))
+    (q, pool, tables, lengths), kw = _latent_case(spec, case, block_k, dtype)
     got = latent_attention(q, pool, tables, lengths, block_k=block_k, **kw)
     fall = spec.fallback(q, pool, tables, lengths, **kw)
     rank = kw["rank"]
@@ -505,6 +535,23 @@ def test_latent_attention_matches_a_written_out_softmax(case, block_k,
         for out in (got, fall):
             assert onp.abs(onp.asarray(out[s], onp.float32)
                            - want).max() <= tol
+
+
+@pytest.mark.parametrize("lengths,want", [
+    ([0, 0, 5, 0], 0.0),                # one slot of one block, no successor
+    ([0, 0, 0, 0], 0.0),                # no slot live
+    ([8, 0, 5, 0], 0.5),                # one block each: the second overlaps
+    ([128, 128, 128, 128], 63 / 64),    # 16 blocks a slot
+])
+def test_latent_overlap_share_counts_the_staged_blocks(lengths, want):
+    """Every block but the call's first has its scores issued beside
+    another block's softmax: (blocks - 1) / blocks, blocks of 8 rows."""
+    spec = kernels.get_kernel("latent_attention")
+    (q, pool, tables, _), kw = spec.make_args(spec.tune_grid[1])
+    lengths = jnp.asarray(lengths, jnp.int32)
+    got = paged_attention.latent_overlap_share(q, pool, tables, lengths,
+                                               block_k=8, **kw)
+    assert got.dtype == jnp.float32 and float(got) == pytest.approx(want)
 
 
 @pytest.mark.parametrize("live", [{47: 37}, {0: 64}, {95: 17},
@@ -702,13 +749,16 @@ def test_counters_ride_with_the_tokens(models, _clean):
         assert f.result(0) == model.greedy_reference(p, 6)
     assert len(reads) <= turns           # never a second read in a turn
     names = {"moe_local_pair_share", "moe_expert_rows_mean",
-             "moe_expert_rows_max", "moe_experts_idle_share"}
+             "moe_expert_rows_max", "moe_experts_idle_share",
+             "latent_overlap_share"}
     decoded = [r for r in Sink.records if r["counters"]]
     assert decoded and all(set(r["counters"]) == names for r in decoded)
     st = eng.stats()
     assert set(st["counters"]) == names
     assert 0.0 <= st["counters"]["moe_local_pair_share"] <= 1.0
     assert st["counters"]["moe_expert_rows_max"] <= 2.0
+    # a slot's context fits one block: two live slots read 1/2, one 0
+    assert 0.0 < st["counters"]["latent_overlap_share"] <= 0.5
     # two slots decode positions 11.. and 20..: 5 steps each, the
     # context one longer a step, over the steps that were dispatched
     assert st["live_tokens_mean"] * eng._decode_steps == pytest.approx(

@@ -210,16 +210,63 @@ def test_paged_attention_packs_narrow_grouped_query_heads(v5e, block_k):
 
 @pytest.mark.parametrize("block_k", [128, 512])
 def test_latent_attention_compiles_at_the_reasoning_cell(v5e, block_k):
-    # axk1_decode_reasoning: 128 slots x 32 pages of 128 rows, 64 heads
-    # over one row of 640 lanes ([512 | 64 | 64 of padding]); a block is
-    # one page or four
+    """``axk1_decode_reasoning``: 128 slots x 32 pages of 128 rows, 64
+    heads over one row of 640 lanes ([512 | 64 | 64 of padding]); a
+    block is one page or four.  The walk is staged: four copy buffers,
+    a block's scores and two slots' queries in VMEM beside the whole
+    output (8.4 MB at 128 slots), inside the scoped limit."""
     from mxnet_tpu.ops.paged_attention import _latent_attention_pallas
-    text = _compile(
-        lambda q, p, t, l: _latent_attention_pallas(q, p, t, l, 512,
-                                                    192 ** -0.5, block_k),
-        v5e, ((128, 64, 640), "bfloat16"), ((4096, 128, 640), "bfloat16"),
-        ((128, 32), "int32"), ((128,), "int32"))
-    _walks_the_live_slots(text, 128, "mxtpu_latent_attention")
+
+    def fn(q, p, t, l):
+        return _latent_attention_pallas(q, p, t, l, 512, 192 ** -0.5,
+                                        block_k)
+
+    specs = (((128, 64, 640), "bfloat16"), ((4096, 128, 640), "bfloat16"),
+             ((128, 32), "int32"), ((128,), "int32"))
+    text = _compile(fn, v5e, *specs)
+    call = _walks_the_live_slots(text, 128, "mxtpu_latent_attention")
+    # operands: the walk's scalars, the queries whole (a slot's are
+    # copied by hand, once), the pool
+    assert call.count("bf16[128,64,640]") == 1, call
+    kernel = str(jax.make_jaxpr(fn)(*[
+        jax.ShapeDtypeStruct(shape, jnp.dtype(dt)) for shape, dt in specs]))
+    for scratch in ("Ref<any>{bf16[128,64,640]}",
+                    f"Ref<vmem>{{bf16[4,{block_k},640]}}",
+                    f"Ref<vmem>{{f32[64,{block_k}]}}",
+                    "Ref<vmem>{bf16[2,64,640]}",
+                    "Ref<semaphore_mem>{dma_sem[1,4]}",
+                    "Ref<semaphore_mem>{dma_sem[2]}"):
+        assert scratch in kernel, scratch
+
+
+def test_the_paged_bodies_lower_as_before_the_latent_pipeline():
+    """The staged walk is the latent body's alone: the folded, lanes and
+    packed bodies keep two buffers, and their kernels at the
+    ``gpt2_decode_chat``, Falcon-H1 and ``lfm2_decode_reasoning``
+    geometries (blocks as ``paged_kv._kernel`` chooses) trace to the
+    very jaxprs they did before it, the Mosaic calls' whole input (the
+    text hashed: it carries no source location)."""
+    import hashlib
+    from mxnet_tpu.ops.paged_attention import _paged_attention_pallas
+    want = {
+        "gpt2": "cdac690ce87388f4ddad1985ff46db5b565beb0209c58bdfd65f07fdc5d2ef95",
+        "falcon": "a837c8f37049b334bd30a81d763f9d357c35bb1d0c30deabeddfc63d80abd162",
+        "lfm2": "c2209ad0f24322ae869deeb660c4769ac580d71a18efd511380699b2b02d603c",
+    }
+    geometry = {"gpt2": ((96, 16, 64), (6144, 16, 1024), (96, 64), 64),
+                "falcon": ((96, 20, 128), (768, 128, 512), (96, 8), 128),
+                "lfm2": ((192, 32, 64), (3072, 128, 512), (192, 32), 512)}
+    got = {}
+    for cell, (q, pool, tables, block_k) in geometry.items():
+        spec = [jax.ShapeDtypeStruct(shape, jnp.dtype(dt)) for shape, dt in (
+            (q, "bfloat16"), (pool, "bfloat16"), (pool, "bfloat16"),
+            (tables, "int32"), (tables[:1], "int32"))]
+        text = str(jax.make_jaxpr(
+            lambda *a: _paged_attention_pallas(*a, q[-1] ** -0.5, block_k))(
+                *spec))
+        assert "interpret=False" in text and "dma_sem[2,2]" in text
+        got[cell] = hashlib.sha256(text.encode()).hexdigest()
+    assert got == want
 
 
 def test_a_latent_row_of_no_whole_lane_tile_is_refused(v5e):
