@@ -54,11 +54,10 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 from ...ops.rope import yarn_frequencies
 from ...parallel.moe import held_experts, route_topk
-from .engine import DecodePlaneModel
+from .decode_model import Float32Stream
 from .paged_kv import (last_rows, latent_chunk_attention,
                        latent_dense_attention, latent_slot_attention,
                        latent_width)
@@ -85,7 +84,7 @@ def _yarn_mscale(factor: float, mscale: float) -> float:
     return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
 
 
-class AXK1(DecodePlaneModel):
+class AXK1(Float32Stream):
     """``AXK1(config)`` with ``config`` the dict of an ``axk1``
     ``config.json`` and the three keys of the share (module docstring).
     ``abstract=True`` gives ``params`` as shapes only."""
@@ -216,24 +215,7 @@ class AXK1(DecodePlaneModel):
                                             i, mat)
                            for i in range(self.n_layers)]}
 
-    def fingerprint(self) -> tuple:
-        return ("axk1", str(self.dtype)) + tuple(
-            tuple(sorted(v.items())) if isinstance(v, dict) else v
-            for v in self.config.values())
-
     # -- the block ---------------------------------------------------------------
-
-    def _norm(self, x, g):
-        """RMSNorm with float32 statistics; the row stays float32."""
-        xf = x.astype(jnp.float32)
-        return xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
-                              + self.eps) * g.astype(jnp.float32)
-
-    def _scores(self, h, w_router):
-        """The router's scores of float32 rows ``h``: float32 all
-        through, the product at the highest precision."""
-        return jax.nn.sigmoid(jnp.dot(h, w_router.astype(jnp.float32),
-                                      precision=lax.Precision.HIGHEST))
 
     def _ffn(self, h, w_gate, w_up, w_down):
         return jnp.dot(jax.nn.silu(h @ w_gate) * (h @ w_up), w_down,
@@ -294,10 +276,6 @@ class AXK1(DecodePlaneModel):
                 for k in (seen[0] if seen else ())}
         return tuple(out), x, mean
 
-    def _logits(self, params, x):
-        return jnp.dot(self._norm(x, params["lnf"]).astype(self.dtype),
-                       params["head"], preferred_element_type=jnp.float32)
-
     def _counters(self, mean) -> dict:
         """What a decode step hands back beside its tokens, means over
         the expert layers: the share of routed (row, expert) pairs that
@@ -315,11 +293,6 @@ class AXK1(DecodePlaneModel):
 
     # -- decode: one token a slot ------------------------------------------------
 
-    def decode_core(self, params, pool, tokens, positions, tables, active):
-        pool, logits, counters = self.decode_logits(
-            params, pool, tokens, positions, tables, active)
-        return pool, jnp.argmax(logits, axis=-1).astype(jnp.int32), counters
-
     def decode_logits(self, params, pool, tokens, positions, tables, active):
         """The decode step up to its logits ``(slots, vocab)``."""
         attend = latent_slot_attention(
@@ -332,14 +305,10 @@ class AXK1(DecodePlaneModel):
 
     # -- prefill: lanes, each one chunk of one slot -------------------------------
 
-    def prefill_core(self, params, pool, tokens, start, chunk_len, tables):
-        pool, logits = self.prefill_logits(params, pool, tokens, start,
-                                           chunk_len, tables)
-        return pool, jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-    def prefill_logits(self, params, pool, tokens, start, chunk_len, tables):
+    def prefill_logits(self, params, pool, tokens, start, chunk_len, tables,
+                       slot):
         """A dispatch of chunks up to the logits ``(lanes, vocab)``
-        after each lane's last valid token."""
+        after each lane's last valid token (no state: ``slot`` unread)."""
         attend = latent_chunk_attention(
             pool, start, chunk_len, tables, tokens.shape[1],
             inv_freq=self.inv_freq, sm_scale=self.sm_scale)
@@ -355,10 +324,3 @@ class AXK1(DecodePlaneModel):
             tokens.shape[0], inv_freq=self.inv_freq, sm_scale=self.sm_scale)
         _, x, _ = self._layers(params, [()] * self.n_layers, tokens, attend)
         return self._logits(params, x)
-
-    @functools.cached_property
-    def _dense_jit(self):
-        return jax.jit(self.dense_logits)
-
-    def _ref_logits_last(self, tokens):
-        return self._dense_jit(self.params, tokens)[-1]

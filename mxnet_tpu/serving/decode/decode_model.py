@@ -2,13 +2,16 @@
 (RMSNorm, rotary positions, no biases, GELU MLP, head tied to the
 embedding) as a plain parameter pytree and ONE block function.
 
-The cores — decode, prefill, verify, and the dense oracle — differ only
+Its logits (decode, prefill, verify, and the dense oracle) differ only
 in the attention they hand the block, and that comes from the paged
 format's own module (``paged_kv``): this file knows no page.
+
+Beside it, what the decode plane's models share of their arithmetic:
+``rms_norm``, and ``Float32Stream`` for a model whose residual stream is
+float32.
 """
 from __future__ import annotations
 
-import functools
 from typing import Any, Dict
 
 import numpy as onp
@@ -21,13 +24,36 @@ from .engine import DecodePlaneModel
 from .paged_kv import (chunk_attention, dense_attention, last_rows,
                        slot_attention, window_attention)
 
-__all__ = ["DecodeModel", "rms_norm"]
+__all__ = ["DecodeModel", "Float32Stream", "rms_norm"]
 
 
 def rms_norm(x, g, eps=1e-6):
     xf = x.astype(jnp.float32)
     scale = lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
     return (xf * scale).astype(x.dtype) * g
+
+
+class Float32Stream(DecodePlaneModel):
+    """A model whose residual stream is float32 (``AXK1``, ``LFM2``):
+    its norms keep float32 statistics and rows, its head multiplies in
+    the model's dtype into float32, its router scores float32 rows."""
+
+    def _norm(self, x, g):
+        """RMSNorm at ``self.eps`` with float32 statistics; the row
+        stays float32."""
+        xf = x.astype(jnp.float32)
+        return xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
+                              + self.eps) * g.astype(jnp.float32)
+
+    def _scores(self, h, w_router):
+        """The router's scores of float32 rows ``h``: float32 all
+        through, the product at the highest precision."""
+        return jax.nn.sigmoid(jnp.dot(h, w_router.astype(jnp.float32),
+                                      precision=lax.Precision.HIGHEST))
+
+    def _logits(self, params, x):
+        return jnp.dot(self._norm(x, params["lnf"]).astype(self.dtype),
+                       params["head"], preferred_element_type=jnp.float32)
 
 
 class DecodeModel(DecodePlaneModel):
@@ -52,6 +78,11 @@ class DecodeModel(DecodePlaneModel):
         self.n_layers = int(n_layers)
         self.head_dim = dim // n_heads
         self.rope_base = float(rope_base)
+        self.dtype = jnp.dtype(dtype)
+        self.config = {"vocab_size": self.vocab_size, "dim": self.dim,
+                       "n_heads": self.n_heads, "n_layers": self.n_layers,
+                       "mlp_ratio": int(mlp_ratio),
+                       "rope_base": self.rope_base}
         rng = onp.random.RandomState(seed)
 
         def mat(*shape, scale):
@@ -76,10 +107,6 @@ class DecodeModel(DecodePlaneModel):
             "layers": layers,
             "lnf": jnp.ones((dim,), dtype=dtype),
         }
-
-    def fingerprint(self) -> tuple:
-        return (self.vocab_size, self.dim, self.n_heads, self.n_layers,
-                self.head_dim, self.rope_base)
 
     # -- the block -------------------------------------------------------------
 
@@ -111,47 +138,33 @@ class DecodeModel(DecodePlaneModel):
             out.append(kv)
         return tuple(out), rms_norm(x, params["lnf"])
 
-    # -- the traced cores --------------------------------------------------------
+    # -- the logits -----------------------------------------------------------
 
-    def decode_core(self, params, pool, tokens, positions, tables, active):
+    def decode_logits(self, params, pool, tokens, positions, tables, active):
         pool, x = self._layers(params, pool, tokens, slot_attention(
             pool, positions, tables, active, rope_base=self.rope_base))
-        logits = x @ params["embed"].T
-        return pool, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return pool, x @ params["embed"].T
 
-    def prefill_core(self, params, pool, tokens, start, chunk_len, tables):
-        """A lane's token is meaningful only after a prompt's final
-        chunk."""
+    def prefill_logits(self, params, pool, tokens, start, chunk_len, tables,
+                       slot):
+        """No state: ``slot`` is not read."""
         pool, x = self._layers(
             params, pool, tokens.reshape(-1), chunk_attention(
                 pool, start, chunk_len, tables, tokens.shape[1],
                 rope_base=self.rope_base))
-        logits = last_rows(x, chunk_len) @ params["embed"].T
-        return pool, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return pool, last_rows(x, chunk_len) @ params["embed"].T
 
-    def verify_core(self, params, pool, tokens, base_pos, tables, active):
+    def verify_logits(self, params, pool, tokens, base_pos, tables, active):
         pool, x = self._layers(params, pool, tokens, window_attention(
             pool, base_pos, tokens.shape[1], tables, active,
             rope_base=self.rope_base))
-        logits = x @ params["embed"].T                    # (S, W, V)
-        return pool, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return pool, x @ params["embed"].T                # (S, W, V)
 
-    # -- dense full-recompute oracle (tests pin the paged path to it) --------
-
-    def _dense_logits_last(self, params, tokens):
-        """Last-position logits of a dense causal forward over the whole
-        sequence — the O(T^2) full-recompute oracle the paged path is
+    def dense_logits(self, params, tokens):
+        """Logits ``(T, vocab)`` of a dense causal forward over the whole
+        sequence: the O(T^2) full-recompute oracle the paged path is
         pinned to."""
         _, x = self._layers(
             params, [()] * self.n_layers, tokens,
             dense_attention(tokens.shape[0], rope_base=self.rope_base))
-        return x[-1] @ params["embed"].T
-
-    @functools.cached_property
-    def _dense_jit(self):
-        # one program per sequence length (the parameters are an
-        # argument, not constants)
-        return jax.jit(self._dense_logits_last)
-
-    def _ref_logits_last(self, tokens):
-        return self._dense_jit(self.params, tokens)
+        return x @ params["embed"].T

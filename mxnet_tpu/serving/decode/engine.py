@@ -18,8 +18,8 @@ use and reused forever (the fixed-shape-executable invariant):
   changes hands: the only way the host touches that state;
 - ``decode_fill_b<n>`` — ``decode`` with a prefill dispatch's lanes
   inside it (``n`` rows of them, a pow2 of lanes of the full chunk's
-  bucket, up to ``_PREFILL_ROWS``), for a model that offers
-  ``turn_core``: a turn that has slots decoding and slots filling reads
+  bucket, up to ``_PREFILL_ROWS``), for a model that supplies
+  ``turn_logits``: a turn that has slots decoding and slots filling reads
   the weights ONCE for both;
 - ``prefill_b<n>`` — a prefill dispatch of ``n`` rows in LANES, each
   lane the next prompt chunk of one slot, so that every slot filling in
@@ -42,12 +42,13 @@ The engine is handed its model (:class:`DecodePlaneModel`; the target
 and the draft alike): the parameters as a pytree, its cache BY LAYER
 (which paged buffers a layer keeps: K and V by the geometry of its K/V
 heads, a latent page by its row's lanes, or none; and which kinds of
-per-slot recurrent state it keeps beside them, or none), and the traced
-cores.  A decode core may
-hand back, beside its tokens, a small dict of scalar counters (an
-expert layer's routing, say): the engine knows them by name only, reads
-them in the transfer that reads the turn's tokens, and keeps their last
-values and running means.  The engine owns the cache the cores read
+per-slot recurrent state it keeps beside them, or none), and its
+arithmetic up to the logits, from which :class:`DecodePlaneModel`
+derives the traced cores.  A decode step may hand back, beside its
+logits, a small dict of scalar counters (an expert layer's routing,
+say): the engine knows them by name only, reads them in the transfer
+that reads the turn's tokens, and keeps their last values and running
+means.  The engine owns the cache the cores read
 and write, the executables and their donation; it knows nothing of a
 layer, and nothing of how a token's K/V reaches a pool or a query
 attends over it: that is ``paged_kv``'s, under it the
@@ -104,29 +105,88 @@ def _pow2(n: int, floor: int) -> int:
 _PREFILL_ROWS = 256
 
 
+def _frozen(value):
+    """``value`` as nested tuples, hashable and in a stable order."""
+    if isinstance(value, dict):
+        return tuple((k, _frozen(v)) for k, v in sorted(value.items()))
+    if isinstance(value, (list, tuple)):
+        return tuple(_frozen(v) for v in value)
+    return value
+
+
+def _greedy(logits):
+    """The greedy token of each row of ``logits (..., vocab)``."""
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
 class DecodePlaneModel:
-    """What :class:`DecodeEngine` asks of a model.
+    """What :class:`DecodeEngine` asks of a model, and what this class
+    derives from it once for every model.
 
-    Attributes: ``params`` (a pytree of device arrays, the first
-    argument of every executable), ``vocab_size``, ``n_layers``, and
-    ``cache_layout``: what each layer keeps in the cache,
-    ``(page_widths, state_spec)`` a layer.  ``page_widths`` are the
-    lanes of a row of each paged buffer the layer keeps, ``(num_pages,
-    page_size, lanes)`` each (K and V: two of ``kv_heads * head_dim``
-    lanes; a latent page: its one width; a layer that attends over no
-    cache: none, and it is given no page memory); ``state_spec`` the
-    kinds of per-slot recurrent state it keeps beside them, ``(name,
-    shape of one slot, dtype)`` each, in the order the layer's buffers
-    follow the paged ones in ``pool[layer]``.  Empty: none.  A model
-    whose layers are all of one kind states ``page_widths`` and
-    ``state_spec`` once and inherits the layout that repeats them.
+    **What a model supplies: its arithmetic up to the logits.**
 
-    The traced cores take the cache's ``pool`` and return its
+    - ``params``, a pytree of device arrays (the first argument of every
+      executable); ``config``, a dict of everything else its arithmetic
+      bakes into a program; ``dtype``, ``vocab_size``, ``n_layers``.
+    - ``cache_layout``: what each layer keeps in the cache,
+      ``(page_widths, state_spec)`` a layer.  ``page_widths`` are the
+      lanes of a row of each paged buffer the layer keeps,
+      ``(num_pages, page_size, lanes)`` each (K and V: two of
+      ``kv_heads * head_dim`` lanes; a latent page: its one width; a
+      layer that attends over no cache: none, and it is given no page
+      memory); ``state_spec`` the kinds of per-slot recurrent state it
+      keeps beside them, ``(name, shape of one slot, dtype)`` each, in
+      the order the layer's buffers follow the paged ones in
+      ``pool[layer]``.  Empty: none.  A model whose layers are all of
+      one kind states ``page_widths`` and ``state_spec`` once and
+      inherits the layout that repeats them.
+    - ``decode_logits(params, pool, tokens, positions, tables,
+      active)``: one token a slot, ``(pool, logits (slots, vocab))``,
+      or ``(pool, logits, counters)`` with ``counters`` a dict of
+      scalars by name (the same names every step).
+    - ``prefill_logits(params, pool, tokens, start, chunk_len, tables,
+      slot)``: a prefill dispatch in lanes, each the next prompt chunk
+      of one slot: ``tokens (lanes, bucket)``, of which lane ``i``'s
+      first ``chunk_len[i]`` are a prompt's positions ``start[i]`` on,
+      written through the slot's page row ``tables[i]``; ``(pool,
+      logits (lanes, vocab))`` after each lane's last valid token.  The
+      slots' indices ``slot (lanes,)`` address a model's recurrent
+      state; a model without any ignores them.  A lane of length 0 is
+      padding: its pages are the sentinel's, its slot index lies past
+      the state buffers, it writes nothing and its logits mean nothing.
+      The weights are read once for all lanes: projections, MLPs,
+      experts and norms see ``lanes * bucket`` rows; only what is a
+      slot's by nature (attention over its pages, a recurrence from its
+      state) goes lane by lane.  A slot rides in one lane at most.
+    - Optionally ``turn_logits``: what lets a decode step carry a
+      prefill dispatch.  ``decode_logits``' step over the slots (its
+      first six arguments) and ``prefill_logits``' lanes (the rest:
+      ``lane_tokens (lanes, bucket)``, ``start``, ``chunk_len``,
+      ``lane_tables``, ``slot``) in ONE pass over the weights: ``(pool,
+      logits (slots + lanes, vocab))``, a slot's row then a lane's, the
+      model's counters after them as ``decode_logits`` hands them.  A
+      lane's slot is not active: the two kinds of row touch disjoint
+      rows of every buffer.
+    - Optionally ``verify_logits``: what lets the model be a
+      speculation's target.  A window ``tokens (slots, k+1)`` at
+      positions ``base_pos`` on: ``(pool, logits (slots, k+1,
+      vocab))``.
+    - ``dense_logits(params, tokens)``: the logits ``(T, vocab)`` of the
+      whole sequence with no cache, the in-program oracle the cached
+      paths are pinned to.
+
+    The logits methods take the cache's ``pool`` and return its
     successor; each buffer has one writer and no reader of its old
-    value left, so a donated buffer is updated in place.  A core builds
-    its attention from ``paged_kv`` (``slot_attention``,
-    ``chunk_attention``, ``window_attention``) and hands it to the
-    model's one block: a model addresses no page itself."""
+    value left, so a donated buffer is updated in place.  They build
+    their attention from ``paged_kv`` (``slot_attention``,
+    ``chunk_attention``, ``window_attention``, ``turn_attention``) and
+    hand it to the model's one block: a model addresses no page itself.
+
+    **What this class derives**, the same for every model: the traced
+    cores the engine compiles (``decode_core``, ``prefill_core``,
+    ``turn_core``, ``verify_core``: the logits' greedy tokens, the
+    model's counters passed on), the jitted dense oracle
+    (``_ref_logits_last``, ``greedy_reference``) and ``fingerprint``."""
 
     state_spec: tuple = ()
 
@@ -141,55 +201,65 @@ class DecodePlaneModel:
 
     def fingerprint(self) -> tuple:
         """Everything the cores bake into an executable besides the
-        shapes of its arguments (the artifact store's key)."""
+        shapes of its arguments (the artifact store's key): the model's
+        class, its dtype and its ``config``."""
+        return ((type(self).__name__, str(self.dtype))
+                + _frozen(self.config))
+
+    # -- what a model may supply ----------------------------------------------
+
+    def turn_logits(self, params, pool, tokens, positions, tables, active,
+                    lane_tokens, start, chunk_len, lane_tables, slot):
         raise NotImplementedError
+
+    def verify_logits(self, params, pool, tokens, base_pos, tables, active):
+        raise NotImplementedError
+
+    # -- what the engine compiles ---------------------------------------------
 
     def decode_core(self, params, pool, tokens, positions, tables, active):
-        """One token per slot: ``(pool, next token per slot)``, or
-        ``(pool, next token per slot, counters)`` with ``counters`` a
-        dict of scalars by name (the same names every step)."""
-        raise NotImplementedError
+        """``(pool, next token per slot)``, the counters after them."""
+        pool, logits, *counters = self.decode_logits(
+            params, pool, tokens, positions, tables, active)
+        return (pool, _greedy(logits), *counters)
 
     def prefill_core(self, params, pool, tokens, start, chunk_len, tables,
-                     *slot):
-        """A prefill dispatch in lanes, each the next prompt chunk of
-        one slot: ``tokens (lanes, bucket)``, of which lane ``i``'s
-        first ``chunk_len[i]`` are a prompt's positions ``start[i]`` on,
-        written through the slot's page row ``tables[i]``; ``(pool,
-        next token per lane)``.  The slots' indices ``slot (lanes,)``
-        follow ``tables`` for a model with recurrent state, which is
-        addressed by them.  A lane of length 0 is padding: its pages
-        are the sentinel's, its slot index lies past the state
-        buffers, it writes nothing and its token means nothing.  The
-        weights are read once for all lanes: projections, MLPs, experts
-        and norms see ``lanes * bucket`` rows; only what is a slot's by
-        nature (attention over its pages, a recurrence from its state)
-        goes lane by lane.  A slot rides in one lane at most."""
-        raise NotImplementedError
+                     slot):
+        """``(pool, next token per lane)``: a lane's token means
+        something only after a prompt's final chunk."""
+        pool, logits = self.prefill_logits(params, pool, tokens, start,
+                                           chunk_len, tables, slot)
+        return pool, _greedy(logits)
 
     def turn_core(self, params, pool, tokens, positions, tables, active,
                   lane_tokens, start, chunk_len, lane_tables, slot):
-        """Optional: what lets a decode step carry a prefill dispatch.
-        ``decode_core``'s step over the slots (its first six arguments)
-        and ``prefill_core``'s lanes (the rest: ``lane_tokens (lanes,
-        bucket)``, ``start``, ``chunk_len``, ``lane_tables``, ``slot`` by
-        lane, ``slot`` past the buffers on a padding lane) in ONE pass
-        over the weights: ``(pool, next token per slot, next token per
-        lane)``, the model's counters after them as ``decode_core``
-        hands them.  A lane's slot is not active: the two kinds of row
-        touch disjoint rows of every buffer."""
-        raise NotImplementedError
+        """``(pool, next token per slot, next token per lane)``, the
+        counters after them."""
+        pool, logits, *counters = self.turn_logits(
+            params, pool, tokens, positions, tables, active, lane_tokens,
+            start, chunk_len, lane_tables, slot)
+        nxt = _greedy(logits)
+        n = tokens.shape[0]
+        return (pool, nxt[:n], nxt[n:], *counters)
 
     def verify_core(self, params, pool, tokens, base_pos, tables, active):
-        """Optional: what lets the model be a speculation's target.
-        A window ``tokens (slots, k+1)`` at positions ``base_pos`` on:
-        ``(pool, the greedy next token at every window position)``."""
-        raise NotImplementedError
+        """``(pool, the greedy next token at every window position)``."""
+        pool, logits = self.verify_logits(params, pool, tokens, base_pos,
+                                          tables, active)
+        return pool, _greedy(logits)
+
+    # -- the oracle -----------------------------------------------------------
+
+    @functools.cached_property
+    def _dense_jit(self):
+        # one program per sequence length (the parameters are an
+        # argument, not constants)
+        return jax.jit(self.dense_logits)
 
     def _ref_logits_last(self, tokens):
         """Last-position logits of the model's dense forward over the
         whole of ``tokens``, no cache: the in-program oracle."""
-        raise NotImplementedError
+        return self._dense_jit(self.params, tokens)[-1]
 
     def greedy_reference(self, prompt, max_new_tokens: int,
                          eos: Optional[int] = None) -> List[int]:
@@ -298,15 +368,14 @@ def _verify_core(mdl: DecodePlaneModel, params, pool, tokens, base_pos,
     return pool, greedy, accepted
 
 
-def _prefill_core(mdl: DecodePlaneModel, stateful: bool, width: int, params,
-                  pool, staged):
+def _prefill_core(mdl: DecodePlaneModel, width: int, params, pool, staged):
     """The model's prefill over what the host staged (``_unstage``).
     ``(pool, the lanes' next tokens)``, the tokens as scalars of their
     own: a slot's first token goes into the resident decode state
     without leaving the device."""
     tokens, start, chunk_len, slot, tables = _unstage(staged, width)
     pool, tokens = mdl.prefill_core(params, pool, tokens, start, chunk_len,
-                                    tables, *((slot,) if stateful else ()))
+                                    tables, slot)
     return pool, tuple(tokens[i] for i in range(staged.shape[0]))
 
 
@@ -491,8 +560,9 @@ class DecodeEngine:
     @property
     def fuses(self) -> bool:
         """Whether a decode step can carry a prefill dispatch's lanes:
-        the model offers ``turn_core``."""
-        return (type(self.model).turn_core is not DecodePlaneModel.turn_core
+        the model supplies ``turn_logits``."""
+        return (type(self.model).turn_logits
+                is not DecodePlaneModel.turn_logits
                 and not self.spec_enabled)
 
     @property
@@ -539,10 +609,9 @@ class DecodeEngine:
         if key.startswith("decode_fill_"):
             return functools.partial(_turn_core, self.model,
                                      self.cache.pages_per_slot)
-        draft = key.startswith("draft_")
         return functools.partial(
-            _prefill_core, self.draft if draft else self.model,
-            bool((self.draft_cache if draft else self.cache).state_layers),
+            _prefill_core,
+            self.draft if key.startswith("draft_") else self.model,
             self.cache.pages_per_slot)
 
     def _get_exec(self, key: str, args, donate=(1,)):
@@ -729,7 +798,7 @@ class DecodeEngine:
         :meth:`prefill_chunks` takes, up to ``prefill_lanes`` of them,
         their slots not decoding) ride inside the step as lanes of
         ``decode_fill_b<rows>``, one pass over the weights for both: a
-        model that offers ``turn_core`` only (:attr:`fuses`).  Returns
+        model that supplies ``turn_logits`` only (:attr:`fuses`).  Returns
         the next token per slot and the chunks' next tokens in their
         order, on the device and not waited for: :meth:`read` them after
         the next turn's dispatch."""

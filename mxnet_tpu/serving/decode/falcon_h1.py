@@ -245,11 +245,6 @@ class FalconH1(DecodePlaneModel):
                 "layers": [layer(jax.random.fold_in(key, i + 1))
                            for i in range(self.n_layers)]}
 
-    def fingerprint(self) -> tuple:
-        return ("falcon_h1", str(self.dtype)) + tuple(
-            tuple(v) if isinstance(v, (list, tuple)) else v
-            for v in self.config.values())
-
     # -- the block ---------------------------------------------------------------
 
     def _block(self, lp, x, kv, attend, mix):
@@ -373,11 +368,6 @@ class FalconH1(DecodePlaneModel):
 
     # -- decode: one token a slot ------------------------------------------------
 
-    def decode_core(self, params, pool, tokens, positions, tables, active):
-        pool, logits = self.decode_logits(params, pool, tokens, positions,
-                                          tables, active)
-        return pool, jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
     def decode_logits(self, params, pool, tokens, positions, tables, active):
         """The decode step up to its logits ``(slots, vocab)``."""
         attend = slot_attention(pool, positions, tables, active,
@@ -388,12 +378,6 @@ class FalconH1(DecodePlaneModel):
         return pool, self._logits(params, x)
 
     # -- prefill: lanes, each one chunk of one slot -------------------------------
-
-    def prefill_core(self, params, pool, tokens, start, chunk_len, tables,
-                     slot):
-        pool, logits = self.prefill_logits(params, pool, tokens, start,
-                                           chunk_len, tables, slot)
-        return pool, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
     def prefill_logits(self, params, pool, tokens, start, chunk_len, tables,
                        slot):
@@ -408,14 +392,6 @@ class FalconH1(DecodePlaneModel):
         return pool, self._logits(params, last_rows(x, chunk_len))
 
     # -- a turn: the decode step with a dispatch's lanes inside it ----------------
-
-    def turn_core(self, params, pool, tokens, positions, tables, active,
-                  lane_tokens, start, chunk_len, lane_tables, slot):
-        pool, logits = self.turn_logits(params, pool, tokens, positions,
-                                        tables, active, lane_tokens, start,
-                                        chunk_len, lane_tables, slot)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return pool, nxt[:tokens.shape[0]], nxt[tokens.shape[0]:]
 
     def turn_logits(self, params, pool, tokens, positions, tables, active,
                     lane_tokens, start, chunk_len, lane_tables, slot):
@@ -470,10 +446,3 @@ class FalconH1(DecodePlaneModel):
 
             x, _, _ = self._block(lp, x, (), attend, mix)
         return self._logits(params, x)
-
-    @functools.cached_property
-    def _dense_jit(self):
-        return jax.jit(self.dense_logits)
-
-    def _ref_logits_last(self, tokens):
-        return self._dense_jit(self.params, tokens)[-1]

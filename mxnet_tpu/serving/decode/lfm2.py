@@ -55,7 +55,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ...parallel.moe import route_topk, stacked_experts
-from .engine import DecodePlaneModel
+from .decode_model import Float32Stream
 from .paged_kv import (chunk_attention, chunk_conv, dense_attention,
                        dense_conv, last_rows, slot_attention, slot_conv)
 
@@ -105,7 +105,7 @@ def _dot_wide(a, w):
     return out[:a.shape[0]] + out[a.shape[0]:]
 
 
-class LFM2(DecodePlaneModel):
+class LFM2(Float32Stream):
     """``LFM2(config)`` with ``config`` the dict of an ``lfm2_moe``
     ``config.json``.  ``abstract=True`` gives ``params`` as shapes only
     (for compiling without the weights)."""
@@ -236,24 +236,7 @@ class LFM2(DecodePlaneModel):
                                             i, mat, small)
                            for i in range(self.n_layers)]}
 
-    def fingerprint(self) -> tuple:
-        return ("lfm2", str(self.dtype)) + tuple(
-            tuple(v) if isinstance(v, (list, tuple)) else v
-            for v in self.config.values())
-
     # -- the block ---------------------------------------------------------------
-
-    def _norm(self, x, g):
-        """RMSNorm with float32 statistics; the row stays float32."""
-        xf = x.astype(jnp.float32)
-        return xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
-                              + self.eps) * g.astype(jnp.float32)
-
-    def _scores(self, h, w_router):
-        """The router's scores of float32 rows ``h``: float32 all
-        through, the product at the highest precision."""
-        return jax.nn.sigmoid(jnp.dot(h, w_router.astype(jnp.float32),
-                                      precision=lax.Precision.HIGHEST))
 
     def _block(self, lp, x, cache, attend, conv, valid=None):
         """One layer over rows ``x (rows, dim)``, float32; the layer's
@@ -316,10 +299,6 @@ class LFM2(DecodePlaneModel):
         return tuple(layer for layer, kind in zip(pool, self.kinds)
                      if kind != "conv")
 
-    def _logits(self, params, x):
-        return jnp.dot(self._norm(x, params["lnf"]).astype(self.dtype),
-                       params["head"], preferred_element_type=jnp.float32)
-
     def _counters(self, seen) -> dict:
         """What a decode step hands back beside its tokens, means over
         the expert layers: the rows (decoding slots) an expert got, mean
@@ -341,11 +320,6 @@ class LFM2(DecodePlaneModel):
 
     # -- decode: one token a slot ------------------------------------------------
 
-    def decode_core(self, params, pool, tokens, positions, tables, active):
-        pool, logits, counters = self.decode_logits(
-            params, pool, tokens, positions, tables, active)
-        return pool, jnp.argmax(logits, axis=-1).astype(jnp.int32), counters
-
     def decode_logits(self, params, pool, tokens, positions, tables, active):
         """The decode step up to its logits ``(slots, vocab)``."""
         attend = slot_attention(self._paged(pool), positions, tables, active,
@@ -360,12 +334,6 @@ class LFM2(DecodePlaneModel):
         return pool, self._logits(params, x), self._counters(seen)
 
     # -- prefill: lanes, each one chunk of one slot -------------------------------
-
-    def prefill_core(self, params, pool, tokens, start, chunk_len, tables,
-                     slot):
-        pool, logits = self.prefill_logits(params, pool, tokens, start,
-                                           chunk_len, tables, slot)
-        return pool, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
     def prefill_logits(self, params, pool, tokens, start, chunk_len, tables,
                        slot):
@@ -393,10 +361,3 @@ class LFM2(DecodePlaneModel):
             params, [()] * self.n_layers, tokens, attend,
             lambda g, w: (dense_conv(g, w), ()))
         return self._logits(params, x)
-
-    @functools.cached_property
-    def _dense_jit(self):
-        return jax.jit(self.dense_logits)
-
-    def _ref_logits_last(self, tokens):
-        return self._dense_jit(self.params, tokens)[-1]

@@ -35,7 +35,7 @@ or manually):
    decode state;
 4. decode — one batched token step over every decoding slot is
    dispatched from that state, and nothing of it is waited for.  Where
-   the engine ``fuses`` (its model offers ``turn_core``) and a slot
+   the engine ``fuses`` (its model supplies ``turn_logits``) and a slot
    decodes, the first ``prefill_lanes`` chunks of phase 3 are not
    dispatched there but ride inside this step, one pass over the
    weights for both; a slot whose last chunk rode is switched on behind

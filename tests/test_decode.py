@@ -1,7 +1,8 @@
 """Autoregressive decode plane (mxnet_tpu/serving/decode/): paged KV
 cache, continuous batching, speculative decode.
 
-Tier-1 acceptance lives here, all in-process (CPU, no sockets):
+Tier-1 acceptance lives here, all in-process (CPU, no sockets), beside
+what every model of the plane must pass (``test_decode_contract.py``):
 
 - the page allocator recycles freed pages and fails atomically on
   exhaustion; the paged-attention kernel matches the gather-based
@@ -36,7 +37,7 @@ import jax.numpy as jnp
 
 import mxnet_tpu as mx  # noqa: F401  (registers ops + kernel specs)
 from mxnet_tpu import profiler, telemetry, tracing
-from mxnet_tpu.serving import (BadRequestError, DecodeEngine, DecodeModel,
+from mxnet_tpu.serving import (BadRequestError, DecodeModel,
                                DecodeScheduler, QueueFullError,
                                RequestTimeoutError, ServingClosedError,
                                ServingServer, slo)
@@ -44,18 +45,12 @@ from mxnet_tpu.serving.decode import OutOfPagesError
 from mxnet_tpu.serving.decode.paged_kv import (PageAllocator, PagedKVCache,
                                                uniform_layout)
 
+from decode_families import build, engine, paged_oracle, run
+from decode_families import clean  # noqa: F401  (a fixture)
+
 VOCAB = 48
 REPO = pathlib.Path(__file__).resolve().parent.parent
-
-
-@pytest.fixture(autouse=True)
-def _clean(monkeypatch):
-    telemetry.clear_sinks()
-    slo.undeclare()
-    yield
-    slo.undeclare()
-    telemetry.clear_sinks()
-    telemetry.enabled()     # re-sync env cache after monkeypatch undo
+pytestmark = pytest.mark.usefixtures("clean")
 
 
 @pytest.fixture(scope="module")
@@ -70,21 +65,15 @@ def draft():
     return DecodeModel(VOCAB, dim=16, n_heads=2, n_layers=1, seed=7)
 
 
-def _engine(model, **kw):
-    kw.setdefault("max_slots", 4)
-    kw.setdefault("num_pages", 32)
-    kw.setdefault("page_size", 8)
-    return DecodeEngine(model, **kw)
+# the engines of this file: four slots, 32 pages of 8, and the engine's
+# own defaults of chunk, floor and pages a slot
+PLAIN = dict(max_slots=4, num_pages=32, page_size=8, pages_per_slot=None,
+             prefill_chunk=None, prefill_floor=16)
 
 
 def _sched(eng, **kw):
     kw.setdefault("start", False)
     return DecodeScheduler(eng, **kw)
-
-
-def _run(sch):
-    while sch._has_work():
-        sch.step()
 
 
 def _prompts(n, lo=3, hi=12, seed=1):
@@ -96,7 +85,7 @@ def _prompts(n, lo=3, hi=12, seed=1):
 
 def _gen(sch, prompts, max_new=8, **kw):
     futs = [sch.submit(p, max_new_tokens=max_new, **kw) for p in prompts]
-    _run(sch)
+    run(sch)
     return [f.result(0) for f in futs]
 
 
@@ -200,12 +189,11 @@ def test_paged_attention_ragged_parity_vs_oracle(case):
     tables are a random permutation of the pool.  Every idle slot's
     output is exact zeros, though the kernel walks the live slots
     alone."""
-    from mxnet_tpu.ops.paged_attention import (paged_attention,
-                                               paged_attention_reference)
+    from mxnet_tpu.ops.paged_attention import paged_attention
     ps, p_, block_k, lengths, *heads = _PA_CASES[case]
     q, kp, vp, tables, lengths = _pa_args(ps, p_, lengths, *heads)
     out = paged_attention(q, kp, vp, tables, lengths, block_k=block_k)
-    ref = paged_attention_reference(q, kp, vp, tables, lengths)
+    ref = paged_oracle(q, kp, vp, tables, lengths)
     onp.testing.assert_allclose(onp.asarray(out), onp.asarray(ref),
                                 rtol=2e-4, atol=2e-4)
     idle = onp.asarray(lengths) == 0
@@ -296,16 +284,6 @@ def _whole_buffer_equations(jaxpr, nelem):
                    for v in eqn.outvars)]
 
 
-def _tiny_hybrid():
-    """``FalconH1`` at the benchmark configuration's ``rehearsal`` size,
-    as ``tests/test_decode_hybrid.py`` builds it."""
-    from mxnet_tpu.serving import FalconH1
-    with open(REPO / "chipbench" / "configs" / "falcon_h1_34b.json") as f:
-        cfg = json.load(f)
-    cfg.update(cfg.pop("rehearsal"))
-    return FalconH1(cfg, seed=5, dtype="float32")
-
-
 def _core_call(eng, core):
     """One of the model's traced cores and abstract-enough arguments
     for it, past ``params`` and ``pool``."""
@@ -317,11 +295,10 @@ def _core_call(eng, core):
     if core == "verify":
         return mdl.verify_core, (jnp.zeros((slots, 3), jnp.int32), ints,
                                  tables, act)
-    slot = (jnp.zeros((1,), jnp.int32),) if mdl.state_spec else ()
     return mdl.prefill_core, (jnp.zeros((1, 8), jnp.int32),
                               jnp.zeros((1,), jnp.int32),
                               jnp.full((1,), 5, jnp.int32),
-                              tables[:1]) + slot
+                              tables[:1], jnp.zeros((1,), jnp.int32))
 
 
 @pytest.mark.parametrize("family,core", [
@@ -337,7 +314,7 @@ def test_cores_touch_a_layer_buffer_only_by_its_scatter(model, family, core):
     copy in front of the Mosaic call on the chip.)"""
     if family == "hybrid":
         # 8 slots x (4 heads x 16 x 16) of state, as large as a K/V buffer
-        model, kw = _tiny_hybrid(), dict(max_slots=8, num_pages=32)
+        model, kw = build("hybrid"), dict(max_slots=8, num_pages=32)
         # the kernel's call, and the jit around it on its static
         # arguments (PR 40), in a decode step
         state = {"decode": ["jit", "pallas_call"],
@@ -346,7 +323,7 @@ def test_cores_touch_a_layer_buffer_only_by_its_scatter(model, family, core):
         kw, state = {}, []
     # a fraction of the pool per slot table, so no gather is as large
     # as a buffer
-    eng = _engine(model, pages_per_slot=4, **kw)
+    eng = engine(model, PLAIN, pages_per_slot=4, **kw)
     pool = eng.cache.pool
     assert all(buf.size >= pool[0][0].size for buf in pool[0][:3])
     fn, args = _core_call(eng, core)
@@ -380,8 +357,8 @@ def test_paged_kernel_block_follows_the_page_size(model, family, page_size,
     model asks, in the decode step and in every offset of a verify
     window."""
     if family == "hybrid":
-        model = _tiny_hybrid()
-    eng = _engine(model, page_size=page_size, num_pages=16,
+        model = build("hybrid")
+    eng = engine(model, PLAIN, page_size=page_size, num_pages=16,
                   pages_per_slot=4)
     for core in ["decode"] + ["verify"] * (family == "transformer"):
         fn, args = _core_call(eng, core)
@@ -398,7 +375,7 @@ def test_pool_is_one_k_and_one_v_buffer_per_layer(model, draft, spec):
     structure after a prefill chunk and a decode (or speculative) step,
     and the step's rows written where the page table points."""
     import jax
-    eng = _engine(model, **(dict(draft_model=draft, spec_k=2)
+    eng = engine(model, PLAIN, **(dict(draft_model=draft, spec_k=2)
                             if spec else {}))
     caches = [(eng.cache, model)]
     if spec:
@@ -440,15 +417,6 @@ def test_pool_is_one_k_and_one_v_buffer_per_layer(model, draft, spec):
 
 # -- continuous batching vs the dense oracle --------------------------------
 
-def test_scheduler_matches_greedy_reference(model):
-    prompts = _prompts(5, seed=2)
-    sch = _sched(_engine(model))
-    got = _gen(sch, prompts, max_new=10)
-    sch.close(drain=True)
-    for p, g in zip(prompts, got):
-        assert g == model.greedy_reference(p, 10)
-
-
 @pytest.mark.parametrize("prompts,max_new,want,chained,edits", [
     # slot 0: a prompt of 6, then positions 6..10; slot 1: a prompt of
     # 15 in two chunks of 8, so from the second turn, positions 15, 16.
@@ -467,7 +435,7 @@ def test_kv_live_share_counts_the_pages_under_live_lengths(
     ``engine.stats()`` against lengths counted by hand, ``chained`` and
     ``state_edits`` beside it; the keys the benchmark reads stay where
     they were."""
-    eng = _engine(model, prefill_chunk=8, prefill_floor=8)
+    eng = engine(model, PLAIN, prefill_chunk=8, prefill_floor=8)
     sch = _sched(eng)
     table = eng.max_slots * eng.cache.pages_per_slot
     assert eng.stats()["kv_live_share"] == 0.0
@@ -502,7 +470,7 @@ def test_eos_stops_generation(model):
     p = _prompts(1, seed=4)[0]
     ref = model.greedy_reference(p, 12)
     eos = ref[3]                        # cut mid-stream
-    sch = _sched(_engine(model))
+    sch = _sched(engine(model, PLAIN))
     got = _gen(sch, [p], max_new=12, eos=eos)[0]
     sch.close(drain=True)
     assert got == model.greedy_reference(p, 12, eos=eos)
@@ -517,7 +485,7 @@ def test_warm_admissions_never_recompile(model):
     of two lanes and one of one) nor a second with staggered admissions
     (requests joining mid-flight: one lane, then two) adds a compile, and every
     page returns to the free list."""
-    eng = _engine(model)
+    eng = engine(model, PLAIN)
     sch = _sched(eng)
     prompts = _prompts(6, lo=3, hi=8, seed=5)   # one pow2 bucket
     eng.warmup([8])
@@ -528,7 +496,7 @@ def test_warm_admissions_never_recompile(model):
     futs = [sch.submit(prompts[3], max_new_tokens=6)]
     sch.step()                          # admit + begin while others queue
     futs += [sch.submit(p, max_new_tokens=6) for p in prompts[4:]]
-    _run(sch)
+    run(sch)
     assert [f.result(0) for f in futs] == [
         model.greedy_reference(p, 6) for p in prompts[3:]]
     assert eng.compiles == warm         # steady state: 0 new compiles
@@ -545,7 +513,7 @@ def test_a_burst_fills_its_slots_in_one_prefill_dispatch(model, monkeypatch):
     record counts the dispatches beside the tokens, and
     ``stats()["prefill"]`` and its traced twin count runs, chunks and
     rows (the twin: what was dispatched under a capture)."""
-    eng = _engine(model, prefill_chunk=16, prefill_floor=8)
+    eng = engine(model, PLAIN, prefill_chunk=16, prefill_floor=8)
     assert eng.warmup([8, 16]) == ["decode", "state_edit", "prefill_b8",
                                    "prefill_b16", "prefill_b32",
                                    "prefill_b64"]
@@ -565,7 +533,7 @@ def test_a_burst_fills_its_slots_in_one_prefill_dispatch(model, monkeypatch):
     turns.append(sch.step())
     assert [(t["prefill_runs"], t["prefill_tokens"]) for t in turns] == [
         (1, 5 + 16 + 16 + 16), (1, 4 + 16), (1, 5)]
-    _run(sch)
+    run(sch)
     assert [f.result(0) for f in futs] == [
         model.greedy_reference(p, 3) for p in prompts]
     assert eng.compiles == compiled
@@ -577,7 +545,7 @@ def test_a_burst_fills_its_slots_in_one_prefill_dispatch(model, monkeypatch):
                                           "rows": 32, "fused": 0,
                                           "chunks_per_run": 2.0,
                                           "fused_share": 0.0}
-    assert _engine(model).stats()["prefill"]["chunks_per_run"] == 0.0
+    assert engine(model, PLAIN).stats()["prefill"]["chunks_per_run"] == 0.0
     sch.close(drain=True)
 
 
@@ -592,9 +560,9 @@ def warm(model):
 
     def get(family):
         if family not in made:
-            mdl = model if family == "transformer" else _tiny_hybrid()
-            eng = _engine(mdl, max_slots=3, num_pages=24, pages_per_slot=8,
-                          prefill_chunk=16, prefill_floor=8)
+            mdl = model if family == "transformer" else build("hybrid")
+            eng = engine(mdl, PLAIN, max_slots=3, num_pages=24,
+                         pages_per_slot=8, prefill_chunk=16, prefill_floor=8)
             eng.warmup([8, 16])
             made[family] = (mdl, eng)
         mdl, eng = made[family]
@@ -718,7 +686,7 @@ def test_deadline_eviction_with_a_turn_in_flight(warm, family):
     assert rec["evictions"] == 1 and rec["tokens"] == 0
     assert telemetry.counter("decode.evictions").value == e0 + 1
     assert sch._slots[0] is not None and sch._slots[0].future is late
-    _run(sch)
+    run(sch)
     assert late.result(0) == mdl.greedy_reference(successor, 4)
     assert eng.cache.pages_used() == 0
     sch.close(drain=True)
@@ -782,7 +750,7 @@ def test_spec_identical_with_matched_draft(model):
     identical, and the whole run takes fewer engine steps."""
     prompts = _prompts(4, seed=6)
     ref = [model.greedy_reference(p, 9) for p in prompts]
-    eng = _engine(model, num_pages=64, draft_model=model, spec_k=3)
+    eng = engine(model, PLAIN, num_pages=64, draft_model=model, spec_k=3)
     sch = _sched(eng)
     got = _gen(sch, prompts, max_new=9)
     st = sch.stats()
@@ -797,7 +765,7 @@ def test_spec_identical_with_mismatched_draft(model, draft):
     """A draft that almost never agrees must not change the output —
     the verify pass IS the target model's greedy decode."""
     prompts = _prompts(4, seed=8)
-    eng = _engine(model, num_pages=64, draft_model=draft, spec_k=3)
+    eng = engine(model, PLAIN, num_pages=64, draft_model=draft, spec_k=3)
     sch = _sched(eng)
     got = _gen(sch, prompts, max_new=9)
     st = sch.stats()
@@ -825,7 +793,7 @@ def _traced():
 def _mixed_turn(model, **kw):
     """A scheduler whose next ``step()`` both prefills (a request just
     submitted) and decodes (one admitted a turn earlier)."""
-    sch = _sched(_engine(model, **kw))
+    sch = _sched(engine(model, PLAIN, **kw))
     first, second = _prompts(2, lo=4, hi=8, seed=9)
     sch.submit(first, max_new_tokens=6)
     sch.step()
@@ -845,7 +813,7 @@ def test_one_turn_leaves_every_phase_span_nested(model, _traced):
     evs = [e for e in tracing._completed_events()
            if e["name"].startswith("decode.")]
     tracing.disable()
-    _run(sch)
+    run(sch)
     sch.close(drain=True)
     by_id = {e["args"]["span_id"]: e for e in evs}
 
@@ -894,7 +862,7 @@ def test_a_turn_under_a_capture_reaches_the_host_plane(
     assert not tracing.enabled()
     with xplane_capture() as found:
         sch.step()
-    _run(sch)
+    run(sch)
     sch.close(drain=True)
     assert {e["plane"] for e in found} == {"/host:CPU"}
     assert len({e["line"] for e in found}) == 1
@@ -915,7 +883,7 @@ def test_a_requests_waits_sum_to_its_first_answer(model):
     """One slot, three requests at once: the second and third wait in
     the queue no less than the service of those before them, and every
     request's ``queue_wait_ms + prefill_wait_ms`` is its ``ttft_ms``."""
-    eng = _engine(model, max_slots=1)
+    eng = engine(model, PLAIN, max_slots=1)
     sch = _sched(eng)
     futs = [sch.submit(p, max_new_tokens=5) for p in _prompts(3, seed=4)]
     t0 = time.perf_counter()
@@ -950,7 +918,7 @@ def test_observe_and_the_step_record_share_one_queue_wait(model):
     ``queue_wait_ms`` of the step record: one expression, two stamps."""
     slo.declare(latency_ms=1e9)
     slo.clear_ring()
-    sch = _sched(_engine(model, max_slots=1))
+    sch = _sched(engine(model, PLAIN, max_slots=1))
     futs = [sch.submit(p, max_new_tokens=2) for p in _prompts(2, seed=6)]
     records = []
     while sch._has_work():
@@ -967,7 +935,7 @@ def test_turn_clock_books_every_moment_of_its_thread(model):
     """``empty_s + host_s + sync_s`` is the scheduler thread's elapsed
     time, over a run with an idle stretch in it; the shares are of that
     sum."""
-    eng = _engine(model)
+    eng = engine(model, PLAIN)
     eng.warmup([8, 16])
     sch = _sched(eng)
     life = {}
@@ -1002,7 +970,7 @@ def test_traced_clock_and_waits_count_only_under_a_capture(model,
     """``stats()["traced"]`` holds the turns begun while a capture ran
     and the requests whose first token such a turn committed: the rule
     ``traced.decode_steps`` follows."""
-    eng = _engine(model, max_slots=2)
+    eng = engine(model, PLAIN, max_slots=2)
     sch = _sched(eng)
     futs = [sch.submit(p, max_new_tokens=4) for p in _prompts(3, seed=8)]
     on = {"now": False}
@@ -1067,7 +1035,7 @@ def test_an_empty_server_waits_under_a_span_on_the_host_plane(
     nothing is to run, is ``mxtpu.decode.empty`` on the scheduler's own
     line of ``/host:CPU``, beside its turns; and the clock's traced
     figures count it."""
-    eng = _engine(model)
+    eng = engine(model, PLAIN)
     eng.warmup([8, 16])
     sch = _sched(eng, start=True)
     with xplane_capture() as found:
@@ -1093,10 +1061,10 @@ def test_an_empty_server_waits_under_a_span_on_the_host_plane(
 
 @pytest.fixture(scope="module")
 def warm_engines(model, draft):
-    plain = _engine(model)
-    spec = _engine(model, num_pages=64, draft_model=draft, spec_k=3)
-    # a model that offers turn_core, at the Falcon cells' chunk of 128
-    fused = _engine(_tiny_hybrid(), prefill_chunk=128)
+    plain = engine(model, PLAIN)
+    spec = engine(model, PLAIN, num_pages=64, draft_model=draft, spec_k=3)
+    # a model that supplies turn_logits, at the Falcon cells' chunk of 128
+    fused = engine(build("hybrid"), PLAIN, prefill_chunk=128)
     return {"plain": (plain, plain.warmup([8])),
             "spec": (spec, spec.warmup([8])),
             "fused": (fused, fused.warmup([8]))}
@@ -1141,8 +1109,8 @@ def test_executable_carries_its_key_as_its_name(warm_engines, kind, key,
     materialises all a turn can dispatch (the chained turn's pair, or
     the speculative turn's, and beside the one-lane prefill buckets
     every multi-lane prefill, named by its rows, lanes x the full
-    chunk, which no one-lane bucket can reach; where the model offers
-    ``turn_core``, the decode step with one and two lanes inside it,
+    chunk, which no one-lane bucket can reach; where the model supplies
+    ``turn_logits``, the decode step with one and two lanes inside it,
     named by the lanes' rows), and the benchmark's patterns match the
     decode and prefill executables and nothing else:
     ``prefill_runs_per_step`` reads the same pattern, and a decode step
@@ -1167,7 +1135,7 @@ def test_executable_carries_its_key_as_its_name(warm_engines, kind, key,
 # -- lifecycle ---------------------------------------------------------------
 
 def test_close_drain_completes_inflight(model):
-    sch = DecodeScheduler(_engine(model), start=True)
+    sch = DecodeScheduler(engine(model, PLAIN), start=True)
     prompts = _prompts(5, seed=9)
     futs = [sch.submit(p, max_new_tokens=6) for p in prompts]
     sch.close(drain=True)
@@ -1178,7 +1146,7 @@ def test_close_drain_completes_inflight(model):
 
 
 def test_close_no_drain_fails_pending_and_frees_pages(model):
-    eng = _engine(model)
+    eng = engine(model, PLAIN)
     sch = _sched(eng)
     prompts = _prompts(6, seed=10)
     futs = [sch.submit(p, max_new_tokens=8) for p in prompts]
@@ -1194,7 +1162,7 @@ def test_close_no_drain_fails_pending_and_frees_pages(model):
 
 
 def test_queued_deadline_expires(model):
-    sch = _sched(_engine(model))
+    sch = _sched(engine(model, PLAIN))
     t0 = telemetry.counter("serving.timeouts").value
     fut = sch.submit(_prompts(1, seed=11)[0], max_new_tokens=4,
                      timeout_ms=1.0)
@@ -1207,7 +1175,7 @@ def test_queued_deadline_expires(model):
 
 
 def test_running_deadline_evicts_slot_and_frees_pages(model):
-    eng = _engine(model)
+    eng = engine(model, PLAIN)
     sch = _sched(eng)
     e0 = telemetry.counter("decode.evictions").value
     p = _prompts(1, seed=12)[0]
@@ -1231,7 +1199,7 @@ def test_running_deadline_evicts_slot_and_frees_pages(model):
 # -- pre-admission rejects + batch-engine zero-size fixes --------------------
 
 def test_submit_reject_matrix(model):
-    eng = _engine(model)
+    eng = engine(model, PLAIN)
     sch = _sched(eng, queue_depth=1)
     r0 = telemetry.counter("serving.rejected.shape").value
     with pytest.raises(BadRequestError):
@@ -1276,27 +1244,6 @@ def test_batch_engine_rejects_zero_size():
 
 # -- server integration ------------------------------------------------------
 
-def test_server_generate_inprocess(model):
-    from mxnet_tpu.gluon import nn
-    mx.random.seed(0)
-    net = nn.Sequential()
-    net.add(nn.Dense(4, in_units=8))
-    net.initialize()
-    srv = ServingServer(net, engine_args={"example_shape": (8,),
-                                          "dtype": "float32"})
-    with pytest.raises(ServingClosedError):    # no decoder attached
-        srv.generate([1, 2, 3])
-    sch = DecodeScheduler(_engine(model), start=True)
-    srv.attach_decoder(sch)
-    p = _prompts(1, seed=13)[0]
-    assert srv.generate(p, max_new_tokens=5) == \
-        model.greedy_reference(p, 5)
-    srv.stop(drain=True)                # stops batcher AND decoder
-    assert sch.closed
-    with pytest.raises(ServingClosedError):
-        srv.generate(p)
-
-
 @pytest.mark.slow
 def test_server_generate_http(model):
     import urllib.error
@@ -1308,7 +1255,7 @@ def test_server_generate_http(model):
     net.initialize()
     srv = ServingServer(net, engine_args={"example_shape": (8,),
                                           "dtype": "float32"},
-                        decoder=DecodeScheduler(_engine(model),
+                        decoder=DecodeScheduler(engine(model, PLAIN),
                                                 start=True))
     host, port = srv.start_http()
     base = f"http://{host}:{port}"
@@ -1340,7 +1287,7 @@ def test_reports_reconcile_decode_section(model, tmp_path, monkeypatch):
     path = str(tmp_path / "decode.jsonl")
     monkeypatch.setenv("MXNET_TELEMETRY_JSONL", path)
     prompts = _prompts(3, seed=15)
-    sch = _sched(_engine(model))
+    sch = _sched(engine(model, PLAIN))
     got = _gen(sch, prompts, max_new=5)
     sch.close(drain=True)
     monkeypatch.delenv("MXNET_TELEMETRY_JSONL")

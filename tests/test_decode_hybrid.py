@@ -1,7 +1,9 @@
 """The decode plane with a second kind of model: Falcon-H1's hybrid
 block (grouped-query attention beside Mamba-2 heads) behind the model
 protocol, per-slot recurrent state beside the paged K/V, the
-``ssm_update`` kernel and grouped-query ``paged_attention``.
+``ssm_update`` kernel and grouped-query ``paged_attention``: the
+family's own controls (what every model passes: ``test_decode_contract``,
+``test_model_contract``, ``test_decode_in_place``).
 
 All at the benchmark configuration's ``rehearsal`` size (same ratios as
 the published model: 5 query heads a KV head, 2 groups, convolution 4),
@@ -11,166 +13,48 @@ seeded random weights, on the CPU with the kernels interpreted:
   reference's full pass (``chipbench/reference/falcon_h1_ref.py``), on
   LOGITS, float32 and bfloat16 each with its own tolerance, prompts
   shorter than, equal to and longer than a chunk, two slots admitted at
-  different times sharing decode steps;
+  different times sharing decode steps, at the published multipliers and
+  with every multiplier at 1;
 - ``ssm_update`` against its oracle, the chunked scan against the
   time-step recurrence over ragged lengths and a non-zero initial state;
 - grouped-query ``paged_attention`` against its oracle, multi-head kept;
-- a slot released and re-acquired starts from zero state;
-- speculation with a recurrent model is refused;
-- through ``DecodeScheduler`` and ``ServingServer`` the model answers on
-  the entry points ``DecodeModel`` has, token for token its dense oracle.
+- an inactive slot keeps its state, admission zeroes it under its span,
+  a slot's next tenant is dispatched behind the stray step;
+- speculation with a recurrent model is refused.
 """
-import importlib.util
-import json
-import pathlib
-
 import numpy as onp
 import pytest
 
-import jax
 import jax.numpy as jnp
 
 import mxnet_tpu as mx  # noqa: F401  (registers ops + kernel specs)
-from mxnet_tpu import kernels, telemetry, tracing
+from mxnet_tpu import kernels, tracing
 from mxnet_tpu.ops import ssm
-from mxnet_tpu.ops.paged_attention import (paged_attention,
-                                           paged_attention_reference)
-from mxnet_tpu.serving import (DecodeEngine, DecodeModel, DecodeScheduler,
-                               FalconH1, ServingServer)
+from mxnet_tpu.ops.paged_attention import paged_attention
+from mxnet_tpu.serving import DecodeEngine, DecodeModel, DecodeScheduler
 
-REPO = pathlib.Path(__file__).resolve().parent.parent
-CHUNK = 16
-
-
-def _load(path, name):
-    spec = importlib.util.spec_from_file_location(name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+from decode_families import (CHUNK, HYBRID, Kit, Through, compare, config,
+                             engine, load, paged_oracle, published, run,
+                             tokens)
+from decode_families import clean  # noqa: F401  (a fixture)
 
 
 @pytest.fixture(scope="module")
-def ref():
-    return _load(REPO / "chipbench" / "reference" / "falcon_h1_ref.py",
-                 "falcon_h1_ref")
+def kit():
+    return Kit()
 
 
-def _config(multipliers="published"):
-    with open(REPO / "chipbench" / "configs" / "falcon_h1_34b.json") as f:
-        cfg = json.load(f)
-    cfg.update(cfg.pop("rehearsal"))
-    if multipliers == "ones":
-        # every mixer at full weight in the residual stream: an error in
-        # one of them cannot hide behind a small multiplier
-        for k in list(cfg):
-            if k.endswith("_multiplier"):
-                cfg[k] = 1.0
-        cfg["ssm_multipliers"] = [1.0] * 5
-        cfg["mlp_multipliers"] = [1.0, 1.0]
-    return cfg
+def _ones():
+    """Every multiplier at 1: every mixer at full weight in the residual
+    stream, so an error in one of them cannot hide behind a small
+    multiplier; the model computes the same function from other numbers,
+    which holds each multiplier to the reference's place for it."""
+    over = {k: 1.0 for k in config("falcon_h1_34b")
+            if k.endswith("_multiplier")}
+    return dict(over, ssm_multipliers=[1.0] * 5, mlp_multipliers=[1.0, 1.0])
 
 
-@pytest.fixture(scope="module")
-def models():
-    made = {}
-
-    def get(dtype="float32", multipliers="published"):
-        key = (dtype, multipliers)
-        if key not in made:
-            cfg = _config(multipliers)
-            made[key] = (FalconH1(cfg, seed=5, dtype=dtype), cfg)
-        return made[key]
-
-    return get
-
-
-def _engine(model, **kw):
-    kw.setdefault("max_slots", 3)
-    kw.setdefault("page_size", 8)
-    kw.setdefault("pages_per_slot", 8)
-    kw.setdefault("num_pages", 24)
-    kw.setdefault("prefill_chunk", CHUNK)
-    kw.setdefault("prefill_floor", 8)
-    return DecodeEngine(model, **kw)
-
-
-def _tokens(n, seed, vocab=128):
-    return [int(t) for t in
-            onp.random.RandomState(seed).randint(0, vocab, size=n)]
-
-
-_JITTED = {}        # model -> its two cores under jit, and the model
-
-
-class _Through:
-    """Drives an engine's cache by hand, keeping the logits the engine's
-    own executables reduce to a token."""
-
-    def __init__(self, model, eng):
-        self.model, self.eng = model, eng
-        # one jit a model for the whole file: a new one would trace the
-        # interpreted kernels and compile again for every engine
-        if id(model) not in _JITTED:
-            _JITTED[id(model)] = (jax.jit(model.decode_logits),
-                                  jax.jit(model.prefill_logits), model)
-        self.decode, self.prefill, _ = _JITTED[id(model)]
-
-    def feed_prompt(self, slot, prompt):
-        """Chunked prefill of ``prompt``; the logits after its last
-        token."""
-        eng, logits = self.eng, None
-        for start in range(0, len(prompt), CHUNK):
-            chunk = prompt[start:start + CHUNK]
-            padded = onp.zeros((eng.prefill_bucket(len(chunk)),), onp.int32)
-            padded[:len(chunk)] = chunk
-            # one lane: the lanes == 1 case of the one prefill path
-            eng.cache.pool, logits = self.prefill(
-                self.model.params, eng.cache.pool, jnp.asarray(padded)[None],
-                jnp.asarray([start], jnp.int32),
-                jnp.asarray([len(chunk)], jnp.int32),
-                jnp.asarray(eng.cache.tables[slot], jnp.int32)[None],
-                jnp.asarray([slot], jnp.int32))
-        return onp.asarray(logits[0], onp.float32)
-
-    def step(self, feed):
-        """One decode step; ``feed`` maps slot -> (token, position).
-        Logits per slot fed."""
-        n = self.eng.max_slots
-        tok, pos = onp.zeros((n,), onp.int32), onp.zeros((n,), onp.int32)
-        act = onp.zeros((n,), bool)
-        for s, (t, p) in feed.items():
-            tok[s], pos[s], act[s] = t, p, True
-        self.eng.cache.pool, logits = self.decode(
-            self.model.params, self.eng.cache.pool, jnp.asarray(tok),
-            jnp.asarray(pos), jnp.asarray(self.eng.cache.tables, jnp.int32),
-            jnp.asarray(act))
-        return {s: onp.asarray(logits[s], onp.float32) for s in feed}
-
-
-def _scaled(got, want):
-    return float(onp.abs(got - want).max() / onp.abs(want).max())
-
-
-# Largest |logit - reference| over largest |reference|.
-# float32: the cached path and the reference differ only in the order of
-# float32 sums (chunked scan or kernel against the recurrence, online
-# against plain softmax): measured 5.2e-7 to 8.7e-7 over these cases.
-# 1e-5 leaves an order of magnitude for another machine's sums and sits
-# a thousand times under what bfloat16 shows, so a path that quietly
-# ran in bfloat16 fails it.
-# bfloat16: the weights are the same bfloat16 numbers on both sides; the
-# program rounds every activation to 8 bits of mantissa (2^-9 = 2e-3 a
-# rounding) where the reference keeps float32, through two layers and a
-# head: measured 9.8e-3 to 1.5e-2 over these cases, so 3e-2.
-# What a wrong term reads (the reference against itself, float32):
-# without the convolution's oldest tap 0.47, with the keys zeroed 0.43.
-# The weights are drawn at the fan-in scale over their multiplier, as a
-# muP-trained checkpoint has them, so every branch weighs in the logits
-# at the published multipliers (test_every_branch_weighs_in_the_logits);
-# with every multiplier at 1 the model computes the same function from
-# other numbers, which holds each multiplier to the reference's place
-# for it.
-TOL = {"float32": 1e-5, "bfloat16": 3e-2}
+MULTIPLIERS = {"published": {}, "ones": _ones()}
 
 
 @pytest.mark.parametrize("multipliers", ["published", "ones"])
@@ -178,105 +62,29 @@ TOL = {"float32": 1e-5, "bfloat16": 3e-2}
                          ids=["short", "one_chunk", "three_chunks"])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_prefill_then_cached_decode_matches_the_reference_on_logits(
-        models, ref, dtype, prompt_len, multipliers):
+        kit, dtype, prompt_len, multipliers):
     """Slot 1 prefills and decodes; two steps in, slot 2 is admitted with
-    a prompt of its own, and both decode in the same steps."""
-    model, cfg = models(dtype, multipliers)
+    a prompt of its own, and both decode in the same steps (the
+    tolerances: ``decode_families.HYBRID``)."""
     new = 6
-    seq_a = _tokens(prompt_len + new, seed=prompt_len)
-    seq_b = _tokens(21 + new, seed=100 + prompt_len)
-    with jax.default_matmul_precision("highest"):
-        want_a = onp.asarray(ref.forward(model.params,
-                                         jnp.asarray(seq_a), cfg))
-        want_b = onp.asarray(ref.forward(model.params,
-                                         jnp.asarray(seq_b), cfg))
-    eng = _engine(model)
-    run = _Through(model, eng)
-    errs = []
-    eng.acquire_slot(1, len(seq_a))
-    errs.append(_scaled(run.feed_prompt(1, seq_a[:prompt_len]),
-                        want_a[prompt_len - 1]))
-    pa, pb = prompt_len, 21
-    for _ in range(2):
-        got = run.step({1: (seq_a[pa], pa)})
-        errs.append(_scaled(got[1], want_a[pa]))
-        pa += 1
-    eng.acquire_slot(2, len(seq_b))
-    errs.append(_scaled(run.feed_prompt(2, seq_b[:21]), want_b[20]))
-    while pa < len(seq_a):
-        got = run.step({1: (seq_a[pa], pa), 2: (seq_b[pb], pb)})
-        errs.append(_scaled(got[1], want_a[pa]))
-        errs.append(_scaled(got[2], want_b[pb]))
-        pa, pb = pa + 1, pb + 1
-    assert max(errs) <= TOL[dtype], errs
+    worst, _, _ = compare(kit, "hybrid", dtype, [
+        (1, tokens(prompt_len + new, seed=prompt_len), prompt_len, 0),
+        (2, tokens(21 + new, seed=100 + prompt_len), 21, 2)],
+        **MULTIPLIERS[multipliers])
+    assert worst <= HYBRID.tol[dtype], worst
 
 
-@pytest.mark.parametrize("matrix", ["wk", "wo", "w_out", "w_down"])
-def test_every_branch_weighs_in_the_logits(models, matrix):
-    """With one branch's matrix zeroed (the keys, the attention heads'
-    output, the state-space heads' output, the MLP's) the logits move by
-    a tenth of their size or more: measured 0.43, 0.57, 0.83, 0.54.  A
-    comparison on logits then sees a fault in any of them; drawn at the
-    plain fan-in scale the keys read 4e-4 here."""
-    model, _ = models("float32")
-    seq = jnp.asarray(_tokens(43, seed=37))
-    without = dict(model.params, layers=[
-        dict(lp, **{matrix: jnp.zeros_like(lp[matrix])})
-        for lp in model.params["layers"]])
-    with jax.default_matmul_precision("highest"):
-        want = onp.asarray(model.dense_logits(model.params, seq))
-        got = onp.asarray(model.dense_logits(without, seq))
-    assert _scaled(got, want) >= 0.1
-
-
-def test_dense_oracle_is_the_reference(models, ref):
-    model, cfg = models("float32")
-    seq = jnp.asarray(_tokens(40, seed=1))
-    with jax.default_matmul_precision("highest"):
-        want = onp.asarray(ref.forward(model.params, seq, cfg))
-        got = onp.asarray(model.dense_logits(model.params, seq))
-    assert _scaled(got, want) <= 1e-5
-
-
-def test_a_released_slot_starts_again_from_zero_state(models, ref):
-    model, cfg = models("float32", "ones")
-    eng = _engine(model)
-    run = _Through(model, eng)
-    first, second = _tokens(30, seed=3), _tokens(19, seed=4)
-    eng.acquire_slot(0, 40)
-    run.feed_prompt(0, first)
-    run.step({0: (7, 30)})
-    ssm_buf, conv_buf = eng.cache.pool[0][2:]
-    assert float(jnp.abs(ssm_buf[0]).max()) > 0
-    assert float(jnp.abs(conv_buf[0]).max()) > 0
-    assert float(jnp.abs(ssm_buf[1:]).max()) == 0      # the others: never
-    eng.release_slot(0)
-    eng.acquire_slot(0, 40)
-    for layer in eng.cache.pool:
-        for buf in layer[2:]:
-            assert float(jnp.abs(buf[0]).max()) == 0
-    with jax.default_matmul_precision("highest"):
-        want = onp.asarray(ref.forward(model.params, jnp.asarray(second),
-                                       cfg))
-    assert _scaled(run.feed_prompt(0, second), want[-1]) <= TOL["float32"]
-    st = eng.stats()
-    assert st["state_resets"] == 2 and st["state_slots_live"] == 1
-    assert st["state_bytes"] == sum(
-        b.size * b.dtype.itemsize for layer in eng.cache.pool
-        for b in layer[2:]) > 0
-
-
-def test_inactive_slots_keep_their_state(models):
-    model, _ = models("float32")
-    eng = _engine(model)
-    run = _Through(model, eng)
+def test_inactive_slots_keep_their_state(kit):
+    model, _ = kit.model("hybrid")
+    eng = engine(model)
+    through = Through(kit, "hybrid", model, eng)
     eng.acquire_slot(0, 30)
     eng.acquire_slot(2, 30)
-    run.feed_prompt(0, _tokens(9, seed=5))
-    run.feed_prompt(2, _tokens(17, seed=6))
+    through.feed_prompt(0, tokens(9, seed=5))
+    through.feed_prompt(2, tokens(17, seed=6))
     before = [[onp.asarray(b[2]) for b in layer[2:]]
               for layer in eng.cache.pool]
-    run.step({0: (3, 9)})
+    through.step({0: (3, 9)})
     for layer, was in zip(eng.cache.pool, before):
         for buf, old in zip(layer[2:], was):
             onp.testing.assert_array_equal(onp.asarray(buf[2]), old)
@@ -287,37 +95,19 @@ def test_inactive_slots_keep_their_state(models):
                                 {"draft_model": "draft"},
                                 {"draft_model": "draft", "spec_k": 3}],
                          ids=["spec_k", "draft", "both"])
-def test_speculation_with_recurrent_state_is_refused(models, kw):
-    model, _ = models("float32")
+def test_speculation_with_recurrent_state_is_refused(kit, kw):
+    model, _ = kit.model("hybrid")
     if "draft_model" in kw:
         kw = dict(kw, draft_model=DecodeModel(128, dim=16, n_heads=2,
                                               n_layers=1))
     with pytest.raises(ValueError, match="recurrent state.*snapshots"):
-        _engine(model, **kw)
-    assert _engine(model).spec_enabled is False       # and plain is fine
-
-
-def test_a_config_that_asks_for_what_is_not_there_is_refused():
-    with pytest.raises(ValueError, match="lacks"):
-        FalconH1({"vocab_size": 8})
-    with pytest.raises(ValueError, match="mamba_norm_before_gate"):
-        FalconH1(dict(_config(), mamba_norm_before_gate=True))
-
-
-def test_abstract_model_has_shapes_and_no_weights():
-    model = FalconH1(_config(), abstract=True)
-    leaves = jax.tree_util.tree_leaves(model.params)
-    assert all(isinstance(a, jax.ShapeDtypeStruct) for a in leaves)
-    family = _load(REPO / "chipbench" / "models" / "falcon_h1.py",
-                   "falcon_h1_family")
-    assert sum(a.size for a in leaves) == family.param_count(_config())
+        engine(model, **kw)
+    assert engine(model).spec_enabled is False       # and plain is fine
 
 
 def test_param_count_by_hand():
-    family = _load(REPO / "chipbench" / "models" / "falcon_h1.py",
-                   "falcon_h1_family")
-    with open(REPO / "chipbench" / "configs" / "falcon_h1_34b.json") as f:
-        cfg = json.load(f)
+    family = load("chipbench/models/falcon_h1.py", "falcon_h1_family")
+    cfg = published("falcon_h1_34b")
     # attention 5120x2560 + 2 x 5120x512 + 2560x5120 = 31,457,280; mixer
     # 5120x9248 + 5120x4 + 5120 + 4096x5120 + 4096 + 96 = 68,351,072; MLP
     # 3 x 5120x21504 = 330,301,440; two norms 10,240
@@ -333,68 +123,16 @@ def test_param_count_by_hand():
     assert family.ssm_update_flops(cfg, 96) == 5 * 96 * 32 * 128 * 256
 
 
-# -- through the scheduler and the server ------------------------------------
+# -- through the scheduler ----------------------------------------------------
 
-def _run(sch):
-    while sch._has_work():
-        sch.step()
-
-
-@pytest.fixture
-def _clean():
-    telemetry.clear_sinks()
-    yield
-    telemetry.clear_sinks()
-    telemetry.enabled()
-
-
-def test_scheduler_matches_the_dense_oracle_and_never_recompiles(
-        models, _clean):
-    model, _ = models("float32", "ones")
-    eng = _engine(model, max_slots=2)
-    # two slots: one multi-lane executable, two lanes of the full chunk,
-    # and the decode step with one and two such lanes inside it
-    assert eng.warmup([8, CHUNK]) == ["decode", "state_edit",
-                                      "decode_fill_b16", "decode_fill_b32",
-                                      "state_reset", "prefill_b8",
-                                      "prefill_b16", "prefill_b32"]
-    compiled = eng.compiles
-
-    class Sink:
-        records = []
-
-        def emit(self, record):
-            if "decode" in record:
-                self.records.append(record["decode"])
-
-    telemetry.add_sink(Sink())
-    sch = DecodeScheduler(eng, start=False)
-    prompts = [_tokens(n, seed=n) for n in (3, 16, 23, 40, 9)]
-    futs = [sch.submit(p, max_new_tokens=5) for p in prompts[:3]]
-    sch.step()
-    sch.step()
-    futs += [sch.submit(p, max_new_tokens=5) for p in prompts[3:]]
-    _run(sch)
-    for p, f in zip(prompts, futs):
-        assert f.result(0) == model.greedy_reference(p, 5)
-    assert eng.compiles == compiled
-    last = Sink.records[-1]
-    # the benchmark's five keys, and the state's counters beside them
-    assert {"ttft_ms", "tokens", "step_ms", "slots_active",
-            "queue_depth"} <= set().union(*(set(r) for r in Sink.records))
-    assert last["state_resets"] == 5 and last["state_slots_live"] == 0
-    assert last["state_bytes"] == eng.stats()["state_bytes"] > 0
-    assert sch.stats()["pages_used"] == 0
-
-
-def test_admission_zeroes_the_state_under_its_span(models, _clean):
-    model, _ = models("float32")
-    sch = DecodeScheduler(_engine(model), start=False)
-    sch.submit(_tokens(5, seed=1), max_new_tokens=2)
+def test_admission_zeroes_the_state_under_its_span(kit, clean):
+    model, _ = kit.model("hybrid")
+    sch = DecodeScheduler(engine(model), start=False)
+    sch.submit(tokens(5, seed=1), max_new_tokens=2)
     tracing.enable()
     tracing.clear()
     try:
-        _run(sch)
+        run(sch)
         evs = sorted((e for e in tracing._completed_events()
                       if e["name"].startswith("decode.")),
                      key=lambda e: e["ts"])
@@ -410,17 +148,17 @@ def test_admission_zeroes_the_state_under_its_span(models, _clean):
 
 
 def test_a_slots_next_tenant_is_dispatched_behind_the_stray_step(
-        models, _clean):
+        kit, clean):
     """What the chained turn's correctness rests on, for a model with
     state: when ``eos`` is read, one more step of the slot is already in
     flight and advances its state; the edit that switches the slot off,
     the next tenant's ``state_reset``, its chunk and the edit that
     switches it on all follow that step on the device's one stream, in
     this order, and the tenant decodes from zero state."""
-    model, _ = models("float32", "ones")
-    eng = _engine(model, max_slots=1)
+    model, _ = kit.model("hybrid", **MULTIPLIERS["ones"])
+    eng = engine(model, max_slots=1)
     eng.warmup([8])
-    first, second = _tokens(6, seed=21), _tokens(7, seed=22)
+    first, second = tokens(6, seed=21), tokens(7, seed=22)
     ref = model.greedy_reference(first, 8)
     eos = ref[2]
     calls = []
@@ -441,7 +179,7 @@ def test_a_slots_next_tenant_is_dispatched_behind_the_stray_step(
     mark = len(calls)
     assert calls[mark - 2:] == ["decode", "state_edit"]   # stray, then off
     late = sch.submit(second, max_new_tokens=3)
-    _run(sch)
+    run(sch)
     assert calls[mark:mark + 4] == ["state_reset", "prefill_b8",
                                     "state_edit", "decode"]
     assert late.result(0) == model.greedy_reference(second, 3)
@@ -457,23 +195,6 @@ def test_decode_model_keeps_no_state_and_its_counters_read_zero():
     assert (st["state_bytes"], st["state_slots_live"],
             st["state_resets"]) == (0, 0, 0)
     assert "state_reset" not in eng.warmup([8])
-
-
-def test_server_generate_answers_for_the_hybrid_model(models, _clean):
-    from mxnet_tpu.gluon import nn
-    model, _ = models("float32", "ones")
-    mx.random.seed(0)
-    net = nn.Sequential()
-    net.add(nn.Dense(4, in_units=8))
-    net.initialize()
-    srv = ServingServer(net, engine_args={"example_shape": (8,),
-                                          "dtype": "float32"})
-    sch = DecodeScheduler(_engine(model), start=True)
-    srv.attach_decoder(sch)
-    p = _tokens(21, seed=8)
-    assert srv.generate(p, max_new_tokens=4) == model.greedy_reference(p, 4)
-    srv.stop(drain=True)
-    assert sch.closed
 
 
 # -- the state-space update and scan -----------------------------------------
@@ -587,7 +308,7 @@ def test_paged_attention_grouped_query_matches_its_oracle(
     assert k_pool.shape[-1] == kv_h * d           # sized by the KV heads
     out = paged_attention(q, k_pool, v_pool, tables, lengths,
                           block_k=block_k)
-    want = paged_attention_reference(q, k_pool, v_pool, tables, lengths)
+    want = paged_oracle(q, k_pool, v_pool, tables, lengths)
     assert out.shape == q.shape
     onp.testing.assert_allclose(onp.asarray(out, "float32"),
                                 onp.asarray(want, "float32"),

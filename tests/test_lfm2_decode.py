@@ -1,18 +1,22 @@
 """The decode plane with a fourth kind of model: LFM2's stack (gated
 short convolutions and grouped-query attention in layers of their own,
 a routed layer held whole, selection by biased scores) behind the model
-protocol, over a cache laid out BY LAYER.
+protocol, over a cache laid out BY LAYER: the family's own controls
+(what every model passes: ``test_decode_contract``,
+``test_model_contract``, ``test_decode_in_place``).
 
 All at the benchmark configuration's ``rehearsal`` size (both kinds of
 operator under both kinds of feed-forward the cut has, 8 experts top-2,
-three taps), seeded random weights, on the CPU with the kernels
-interpreted:
+three taps), seeded random weights, every branch at the plain fan-in
+scale, on the CPU with the kernels interpreted:
 
 - prefill in chunks (shorter than, equal to and longer than the
   convolution's tail; a chunk boundary inside a prompt) then decode
   through pages and tails against the plain reference's full pass
   (``chipbench/reference/lfm2_ref.py``), on LOGITS, with the routing
-  ties handled as the comparison's docstring says;
+  ties handled as ``decode_families.compare`` says; a long table walked
+  by its live pages; the router at the published 32 experts keeps the
+  margin;
 - the controls: a lower precision, a weight taken from the biased
   score, a tail carried wrongly across a chunk boundary each fail a
   limit;
@@ -20,13 +24,8 @@ interpreted:
   must not change a weight; the stacked product against the loop;
 - the layout: a model of mixed layers is given pages for its attention
   layers alone, and the three models there were get the pools they got;
-- a slot released and taken again starts from a zero tail; chained and
-  synchronous turns give the same tokens; ``POST /generate`` answers.
+  the parameter count at the published widths by hand.
 """
-import importlib.util
-import json
-import pathlib
-
 import numpy as onp
 import pytest
 
@@ -34,270 +33,21 @@ import jax
 import jax.numpy as jnp
 
 import mxnet_tpu as mx  # noqa: F401  (registers ops + kernel specs)
-from mxnet_tpu import telemetry
 from mxnet_tpu.parallel.moe import held_experts, route_topk, stacked_experts
 from mxnet_tpu.serving import (AXK1, LFM2, DecodeEngine, DecodeModel,
-                               DecodeScheduler, FalconH1, PagedKVCache,
-                               ServingServer)
+                               FalconH1, PagedKVCache)
 from mxnet_tpu.serving.decode import lfm2 as lfm2_mod
 from mxnet_tpu.serving.decode import paged_kv
 
-REPO = pathlib.Path(__file__).resolve().parent.parent
-CHUNK = 16
+from decode_families import (CHUNK, MIXED, Kit, compare_one, config, engine,
+                             load, paged_oracle, published)
 
-
-def _load(path, name):
-    spec = importlib.util.spec_from_file_location(name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+TOL, MARGIN = MIXED.tol, MIXED.margin
 
 
 @pytest.fixture(scope="module")
-def ref():
-    return _load(REPO / "chipbench" / "reference" / "lfm2_ref.py", "lfm2_ref")
-
-
-def _config(name="lfm2_8b_a1b", **over):
-    with open(REPO / "chipbench" / "configs" / f"{name}.json") as f:
-        cfg = json.load(f)
-    cfg.update(cfg.pop("rehearsal"))
-    if "routed_down_divisor" in cfg:
-        # every branch at the plain fan-in scale here, whatever the
-        # benchmark's token check made the configuration choose
-        cfg["routed_down_divisor"] = 1
-    cfg.update(over)
-    return cfg
-
-
-@pytest.fixture(scope="module")
-def models():
-    made = {}
-
-    def get(dtype="float32", **over):
-        key = (dtype,) + tuple(sorted(over.items()))
-        if key not in made:
-            cfg = _config(**over)
-            made[key] = (LFM2(cfg, seed=5, dtype=dtype), cfg)
-        return made[key]
-
-    return get
-
-
-def _engine(model, **kw):
-    kw.setdefault("max_slots", 3)
-    kw.setdefault("page_size", 8)
-    kw.setdefault("pages_per_slot", 8)
-    kw.setdefault("num_pages", 24)
-    kw.setdefault("prefill_chunk", CHUNK)
-    kw.setdefault("prefill_floor", 8)
-    return DecodeEngine(model, **kw)
-
-
-def _tokens(n, seed, vocab=128):
-    return [int(t) for t in
-            onp.random.RandomState(seed).randint(0, vocab, size=n)]
-
-
-def _run(sch):
-    while sch._has_work():
-        sch.step()
-
-
-@pytest.fixture
-def _clean():
-    telemetry.clear_sinks()
-    yield
-    telemetry.clear_sinks()
-    telemetry.enabled()
-
-
-# -- prefill, then decode through pages and tails, against the reference ------
-
-def _with_picks(core):
-    """``core`` returning, beside its own outputs, the experts its
-    router selected in every routed layer ``(rows, top-k)`` each: the
-    router is watched while the core is TRACED, so the jitted whole
-    hands the selections back as outputs."""
-    def run(*args):
-        picks = []
-        real = lfm2_mod.route_topk
-
-        def spy(scores, top_k, **kw):
-            index, weight = real(scores, top_k, **kw)
-            picks.append(index)
-            return index, weight
-
-        lfm2_mod.route_topk = spy
-        try:
-            out = core(*args)
-        finally:
-            lfm2_mod.route_topk = real
-        return out, tuple(picks)
-
-    return jax.jit(run)
-
-
-_WATCHED = {}       # (model, what stands in its place) -> the watched cores
-
-
-class _Through:
-    """Drives an engine's cache by hand, keeping the logits the
-    executables reduce to a token and the experts the program's router
-    selected for every position and routed layer."""
-
-    def __init__(self, model, eng, variant=None):
-        self.model, self.eng = model, eng
-        self.picked = {}            # position -> [experts of each layer]
-        key = (id(model), variant)
-        if key not in _WATCHED:
-            _WATCHED[key] = (_with_picks(model.prefill_logits),
-                             _with_picks(model.decode_logits), model)
-        self._prefill, self._decode, _ = _WATCHED[key]
-
-    def _keep(self, picks, rows):
-        for row, pos in rows:
-            self.picked.setdefault(pos, []).extend(
-                set(onp.asarray(index[row]).tolist()) for index in picks)
-
-    def feed_prompt(self, slot, prompt, chunk=CHUNK):
-        eng, logits = self.eng, None
-        for start in range(0, len(prompt), chunk):
-            piece = prompt[start:start + chunk]
-            padded = onp.zeros((eng.prefill_bucket(len(piece)),), onp.int32)
-            padded[:len(piece)] = piece
-            # one lane: the lanes == 1 case of the one prefill path
-            (eng.cache.pool, logits), picks = self._prefill(
-                self.model.params, eng.cache.pool, jnp.asarray(padded)[None],
-                jnp.asarray([start], jnp.int32),
-                jnp.asarray([len(piece)], jnp.int32),
-                jnp.asarray(eng.cache.tables[slot], jnp.int32)[None],
-                jnp.asarray([slot], jnp.int32))
-            self._keep(picks, [(i, start + i) for i in range(len(piece))])
-        return onp.asarray(logits[0], onp.float32)
-
-    def step(self, slot, token, position):
-        n = self.eng.max_slots
-        tok, pos = onp.zeros((n,), onp.int32), onp.zeros((n,), onp.int32)
-        act = onp.zeros((n,), bool)
-        tok[slot], pos[slot], act[slot] = token, position, True
-        (self.eng.cache.pool, logits, _), picks = self._decode(
-            self.model.params, self.eng.cache.pool, jnp.asarray(tok),
-            jnp.asarray(pos), jnp.asarray(self.eng.cache.tables, jnp.int32),
-            jnp.asarray(act))
-        self._keep(picks, [(slot, position)])
-        return onp.asarray(logits[slot], onp.float32)
-
-
-def _reference_with(ref, params, tokens, cfg, picked):
-    """The reference's logits over ``tokens`` WITH THE PROGRAM'S
-    SELECTION: in every routed layer and at every position the experts
-    the program's router picked, weighted by the reference's own
-    UNBIASED scores.  Where that selection differs from the reference's
-    own, a tie that rounding upstream broke the other way, the position
-    met a flip, as wide as the differing expert's biased score lies
-    from the reference's last selected one.  Returns ``(logits,
-    positions that met a flip, the widest flip)``; the caller holds the
-    widest to its margin, beyond which it is another routing and no
-    tie."""
-    own = ref.route
-    k = cfg["num_experts_per_tok"]
-    layer_no = [0]
-    flipped, widest = set(), [0.0]
-
-    def route(scores, bias, cfg):
-        w = own(scores, bias, cfg)
-        biased = onp.asarray(scores if bias is None
-                             else scores + jnp.asarray(bias, jnp.float32))
-        sel = onp.asarray(w) > 0
-        kth = onp.sort(biased, axis=1)[:, -k]
-        for pos in range(biased.shape[0]):
-            mine = picked[pos][layer_no[0]]
-            theirs = set(onp.nonzero(sel[pos])[0].tolist())
-            for e in mine ^ theirs:
-                widest[0] = max(widest[0],
-                                float(abs(biased[pos, e] - kth[pos])))
-                flipped.add(pos)
-            sel[pos] = False
-            sel[pos, sorted(mine)] = True
-        layer_no[0] += 1
-        w = jnp.where(jnp.asarray(sel), scores, 0.0)
-        return (w / (w.sum(axis=-1, keepdims=True) + ref.TOPK_EPS)
-                * cfg["routed_scaling_factor"])
-
-    ref.route = route
-    try:
-        with jax.default_matmul_precision("highest"):
-            logits = ref.forward(params, jnp.asarray(tokens, jnp.int32), cfg)
-    finally:
-        ref.route = own
-    return onp.asarray(logits, onp.float32), flipped, widest[0]
-
-
-def _scaled(got, want):
-    return float(onp.abs(got - want).max() / onp.abs(want).max())
-
-
-# Largest |logit - reference| over largest |reference| (TOL), and how
-# wide a differing selection may be and still count as a tie (MARGIN),
-# every branch at the plain fan-in scale (``routed_down_divisor`` 1).
-# Each limit lies between two readings on this CPU at the rehearsal
-# size: the sound program's largest over the cases below and a
-# control's smallest (the tests of the controls say which).
-# float32: the cached path and the reference differ in the order of
-# float32 sums (the stacked product sums over experts and width at
-# once; the paged kernel's online softmax): sound 8.4e-7 to 1.12e-6, no
-# selection differs; the experts' matrices in bfloat16 read 2.3e-3, the
-# operators' 1.3e-2, the gate B * u and the tail in bfloat16 5.2e-3, a
-# weight from the biased score 3.7e-2, a chunk that starts from a zero
-# tail 0.28 or more: 5e-6.  A router's product in bfloat16 breaks three
-# ties in 120 positions, up to 6.1e-4 wide: 1e-5.
-# bfloat16: the weights are the same bfloat16 numbers on both sides, the
-# program rounds what it multiplies to 8 bits of mantissa (at 64 wide a
-# product is a sum of few terms and its rounding shows more than at
-# 2048), but for the convolution operator's two products, which take
-# their rows as two numbers (``lfm2._dot_wide``); its residual stream,
-# gate, tail and router are float32.  Logits: sound 8.5e-3 to 1.62e-2
-# over the cases (1.0e-2 to 2.5e-2 while the convolution's products
-# took their rows rounded once); the experts' matrices in float8 read
-# 2.7e-2 to 4.1e-2, the operators' 0.20 or more: 2.1e-2.  Ties: the
-# products upstream of the router break them, at up to three positions
-# of a case and up to 2.0e-3 wide (8.4e-3 before); the control is the
-# router's product in float8's 3 bits of mantissa (selections differ at
-# half of 121 positions, up to 2.7e-2 wide): 7e-3.
-TOL = {"float32": 5e-6, "bfloat16": 2.1e-2}
-MARGIN = {"float32": 1e-5, "bfloat16": 7e-3}
-
-
-def _compare(models, ref, dtype, prompt_len, n_decode=4, chunk=CHUNK,
-             coarse=None, variant=None, pages_per_slot=8, **over):
-    """Prefill ``prompt_len`` tokens by chunks of ``chunk``, decode
-    ``n_decode`` more through the cache, and compare the logits of the
-    last prompt position and of every decoded one with the reference's:
-    ``(the worst scaled difference, positions that met a flip, the
-    widest flip)``.  ``coarse(params)``: the PROGRAM runs with those
-    parameters (a control in lower precision), the reference with the
-    model's.  ``variant`` names what else stands in the program's place
-    (a patched method), so that its traced cores are its own."""
-    model, cfg = models(dtype, **over)
-    through = _Through(model, _engine(model, pages_per_slot=pages_per_slot,
-                                      num_pages=3 * pages_per_slot), variant)
-    toks = _tokens(prompt_len + n_decode, seed=prompt_len)
-    through.eng.acquire_slot(1, len(toks))
-    good = model.params
-    try:
-        if coarse is not None:
-            model.params = coarse(good)
-        got = {prompt_len - 1: through.feed_prompt(1, toks[:prompt_len],
-                                                   chunk)}
-        for p in range(prompt_len, len(toks)):
-            got[p] = through.step(1, toks[p], p)
-    finally:
-        model.params = good
-    want, flipped, widest = _reference_with(ref, good, toks, cfg,
-                                            through.picked)
-    worst = max(_scaled(got[p], want[p]) for p in got)
-    return worst, flipped, widest
+def kit():
+    return Kit()
 
 
 # prompt lengths by what the LAST chunk is to the tail of two rows: a
@@ -309,32 +59,32 @@ CASES = [1, 2, 5, CHUNK, 17, 18, 37]
 @pytest.mark.parametrize("prompt_len", CASES)
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_prefill_then_cached_decode_matches_the_reference_on_logits(
-        models, ref, dtype, prompt_len):
-    worst, _, widest = _compare(models, ref, dtype, prompt_len)
+        kit, dtype, prompt_len):
+    worst, _, widest = compare_one(kit, "mixed", dtype, prompt_len)
     assert worst <= TOL[dtype], worst
     assert widest <= MARGIN[dtype], widest
 
 
-def test_chunks_as_short_as_the_tail_carry_it(models, ref):
+def test_chunks_as_short_as_the_tail_carry_it(kit):
     """A prompt fed a row at a time: every chunk is shorter than the
     tail, so every new tail is one old row and one new."""
-    worst, _, widest = _compare(models, ref, "float32", 11, chunk=1)
+    worst, _, widest = compare_one(kit, "mixed", "float32", 11, chunk=1)
     assert worst <= TOL["float32"] and widest <= MARGIN["float32"]
 
 
 @pytest.mark.parametrize("prompt_len", [5, 18, 37])
-def test_a_long_table_is_walked_by_its_live_pages(models, ref, monkeypatch,
+def test_a_long_table_is_walked_by_its_live_pages(kit, monkeypatch,
                                                   prompt_len):
     """Beyond ``_GATHER_ROWS`` positions a chunk walks the slot's live
     pages under an online softmax instead of gathering the whole table
     (here the limit is lowered under the tests' 64 positions): the same
     logits, and a loop in the program where the gather has none."""
     monkeypatch.setattr(paged_kv, "_GATHER_ROWS", 32)
-    worst, _, widest = _compare(models, ref, "float32", prompt_len,
-                                variant="walk")
+    worst, _, widest = compare_one(kit, "mixed", "float32", prompt_len,
+                                   variant="walk")
     assert worst <= TOL["float32"] and widest <= MARGIN["float32"], worst
-    model, _ = models("float32")
-    eng = _engine(model)
+    model, _ = kit.model("mixed")
+    eng = engine(model)
     args = (model.params, eng.cache.pool, jnp.zeros((1, 8), jnp.int32),
             jnp.zeros((1,), jnp.int32), jnp.full((1,), 5, jnp.int32),
             jnp.asarray(eng.cache.tables[:1], jnp.int32),
@@ -342,33 +92,6 @@ def test_a_long_table_is_walked_by_its_live_pages(models, ref, monkeypatch,
     assert "while" in str(jax.make_jaxpr(model.prefill_logits)(*args))
     monkeypatch.setattr(paged_kv, "_GATHER_ROWS", 2048)
     assert "while" not in str(jax.make_jaxpr(model.prefill_logits)(*args))
-
-
-def test_dense_oracle_is_the_reference(models, ref):
-    model, cfg = models("float32")
-    toks = jnp.asarray(_tokens(29, seed=2), jnp.int32)
-    with jax.default_matmul_precision("highest"):
-        want = ref.forward(model.params, toks, cfg)
-    assert _scaled(onp.asarray(model.dense_logits(model.params, toks)),
-                   onp.asarray(want)) <= TOL["float32"]
-
-
-@pytest.mark.parametrize("matrix", ["wo", "w_out", "w_in", "conv_w",
-                                    "experts_w2", "w_router", "w2", "wk",
-                                    "expert_bias"])
-def test_every_branch_weighs_in_the_logits(models, matrix):
-    """Change one piece of every layer that has it (a matrix zeroed,
-    the selection bias reversed) and the logits move by far more than
-    the tolerance: no branch hides."""
-    model, _ = models("float32")
-    toks = jnp.asarray(_tokens(40, seed=4), jnp.int32)
-    want = onp.asarray(model.dense_logits(model.params, toks))
-    cut = dict(model.params, layers=[
-        {k: ((-20 * v if k == "expert_bias" else jnp.zeros_like(v))
-             if k == matrix else v) for k, v in lp.items()}
-        for lp in model.params["layers"]])
-    got = onp.asarray(model.dense_logits(cut, toks))
-    assert _scaled(got, want) > 100 * TOL["float32"]
 
 
 # -- the controls: each fails a limit the sound program keeps ------------------
@@ -379,21 +102,6 @@ def _rounded(mantissa_bits):
                                         exponent_bits=8,
                                         mantissa_bits=mantissa_bits)
     return rounded
-
-
-def _router_rounded(mantissa_bits):
-    """The router's product as a matmul in a lower precision gives it:
-    operands and result of ``mantissa_bits`` bits of mantissa (7:
-    bfloat16, 3: float8; ``reduce_precision``, which no compiler takes
-    for excess precision it may keep)."""
-    rounded = _rounded(mantissa_bits)
-
-    def scores(self, h, w_router):
-        return jax.nn.sigmoid(rounded(jnp.dot(
-            rounded(h), rounded(w_router),
-            precision=jax.lax.Precision.HIGHEST)))
-
-    return scores
 
 
 def _coarse(names, mantissa_bits):
@@ -417,26 +125,17 @@ def _operators(k):
     return k in ("w_in", "w_out", "wq", "wk", "wv", "wo")
 
 
-@pytest.mark.parametrize("dtype,mantissa_bits", [("float32", 7),
-                                                 ("bfloat16", 3)])
-def test_a_router_in_lower_precision_fails_the_margin(models, ref,
-                                                      monkeypatch, dtype,
-                                                      mantissa_bits):
-    """The router's product in the nearest precision below the model's
-    moves the scores by more than the margin allows a tie to be: over
-    the positions of one prompt some selection differs outside it (at
-    the published 32 experts top-4: among 8 scores ties are too rare
-    for 120 positions to meet one), where the sound program over the
-    same positions keeps inside it."""
-    wide = {"num_experts": 32, "num_experts_per_tok": 4}
-    worst, _, widest = _compare(models, ref, dtype, 120, 1,
-                                pages_per_slot=16, **wide)
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_router_at_the_published_width_keeps_the_margin(kit, dtype):
+    """At the published 32 experts top-4 (among 8 scores ties are too
+    rare for 120 positions to meet one) the sound program keeps inside
+    the tolerance and the margin over the positions on which a router in
+    a lower precision fails it (``test_model_contract``)."""
+    control = MIXED.router_control
+    worst, _, widest = compare_one(
+        kit, "mixed", dtype, control["prompt"], 1,
+        pages_per_slot=control["pages_per_slot"], **control["over"])
     assert worst <= TOL[dtype] and widest <= MARGIN[dtype], (worst, widest)
-    monkeypatch.setattr(LFM2, "_scores", _router_rounded(mantissa_bits))
-    _, flipped, widest = _compare(models, ref, dtype, 120, 1,
-                                  variant=f"router{mantissa_bits}",
-                                  pages_per_slot=16, **wide)
-    assert flipped and widest > 1.5 * MARGIN[dtype], widest
 
 
 @pytest.mark.parametrize("which,names", [("experts", _experts),
@@ -444,18 +143,18 @@ def test_a_router_in_lower_precision_fails_the_margin(models, ref,
 @pytest.mark.parametrize("dtype,mantissa_bits", [("float32", 7),
                                                  ("bfloat16", 3)])
 def test_matrices_in_lower_precision_fail_the_tolerance(
-        models, ref, dtype, mantissa_bits, which, names):
+        kit, dtype, mantissa_bits, which, names):
     """The stacked experts' matrices, or the operators', rounded to the
     nearest precision below the stated one (bfloat16 under float32,
     float8's mantissa under bfloat16), in the PROGRAM's place: over the
     limit the sound program keeps."""
-    worst, _, _ = _compare(models, ref, dtype, 37,
-                           coarse=_coarse(names, mantissa_bits))
+    worst, _, _ = compare_one(kit, "mixed", dtype, 37,
+                              coarse=_coarse(names, mantissa_bits))
     assert worst > 1.25 * TOL[dtype], worst
 
 
 def test_a_gate_and_tail_in_bfloat16_fail_the_float32_tolerance(
-        models, ref, monkeypatch):
+        kit, monkeypatch):
     """``g = B * u`` and with it the tail rounded to bfloat16 where
     float32 is stated."""
     real_slot, real_chunk = lfm2_mod.slot_conv, lfm2_mod.chunk_conv
@@ -465,12 +164,12 @@ def test_a_gate_and_tail_in_bfloat16_fail_the_float32_tolerance(
     monkeypatch.setattr(lfm2_mod, "chunk_conv",
                         lambda tail, g, w, slot, n:
                         real_chunk(tail, rounded(g), w, slot, n))
-    worst, _, _ = _compare(models, ref, "float32", 37, variant="gate_bf16")
+    worst, _, _ = compare_one(kit, "mixed", "float32", 37,
+                              variant="gate_bf16")
     assert worst > 100 * TOL["float32"], worst
 
 
-def test_a_weight_taken_from_the_biased_score_fails(models, ref,
-                                                    monkeypatch):
+def test_a_weight_taken_from_the_biased_score_fails(kit, monkeypatch):
     """A router that weighs by ``s + b`` where ``s`` is stated selects
     the same experts in its own layer and moves the logits far over
     the float32 limit (3.7e-2: over bfloat16's too)."""
@@ -478,14 +177,14 @@ def test_a_weight_taken_from_the_biased_score_fails(models, ref,
         return route_topk(scores + bias, top_k, **kw)
 
     monkeypatch.setattr(lfm2_mod, "route_topk", biased)
-    worst, _, _ = _compare(models, ref, "float32", 37,
-                           variant="biased_weight")
+    worst, _, _ = compare_one(kit, "mixed", "float32", 37,
+                              variant="biased_weight")
     assert worst > 1000 * TOL["float32"], worst
 
 
 @pytest.mark.parametrize("prompt_len", [17, 18, 37])
 def test_a_tail_carried_wrongly_across_a_chunk_boundary_fails(
-        models, ref, monkeypatch, prompt_len):
+        kit, monkeypatch, prompt_len):
     """A chunk that starts from a zero tail instead of the slot's: right
     inside the first chunk, wrong from the first boundary on."""
     real = lfm2_mod.chunk_conv
@@ -493,12 +192,13 @@ def test_a_tail_carried_wrongly_across_a_chunk_boundary_fails(
         lfm2_mod, "chunk_conv", lambda tail, g, w, slot, n:
         (real(jnp.zeros_like(tail), g, w, slot, n)[0],
          real(tail, g, w, slot, n)[1]))
-    worst, _, _ = _compare(models, ref, "float32", prompt_len,
-                           variant="zero_tail")
+    worst, _, _ = compare_one(kit, "mixed", "float32", prompt_len,
+                              variant="zero_tail")
     assert worst > 1000 * TOL["float32"], worst
     # the same fault inside one chunk is no fault: the slot's tail is
     # zero at admission
-    worst, _, _ = _compare(models, ref, "float32", 5, variant="zero_tail")
+    worst, _, _ = compare_one(kit, "mixed", "float32", 5,
+                              variant="zero_tail")
     assert worst <= TOL["float32"]
 
 
@@ -525,8 +225,7 @@ def test_the_convolution_operators_products_take_their_rows_as_two_numbers():
     assert jaxpr.count("dot_general") == 1 and "reduce_precision" in jaxpr
 
 
-def test_rows_rounded_once_fail_the_bfloat16_tolerance(models, ref,
-                                                       monkeypatch):
+def test_rows_rounded_once_fail_the_bfloat16_tolerance(kit, monkeypatch):
     """The convolution operator's products fed with rows rounded once,
     as every other product is: over the limit the sound program keeps
     (the cubic gate carries its input's rounding three times)."""
@@ -534,7 +233,7 @@ def test_rows_rounded_once_fail_the_bfloat16_tolerance(models, ref,
         lfm2_mod, "_dot_wide", lambda a, w: jnp.dot(
             _rounded(7)(a).astype(w.dtype), w,
             preferred_element_type=jnp.float32))
-    worst = max(_compare(models, ref, "bfloat16", n, variant="once")[0]
+    worst = max(compare_one(kit, "mixed", "bfloat16", n, variant="once")[0]
                 for n in (1, 5))
     assert worst > TOL["bfloat16"], worst
 
@@ -599,8 +298,7 @@ def test_packed_grouped_query_heads_match_the_oracle(page_size, block_k, h,
     from mxnet_tpu import kernels
     from mxnet_tpu.ops.paged_attention import (_pack_queries,
                                                _unpack_outputs,
-                                               paged_attention,
-                                               paged_attention_reference)
+                                               paged_attention)
     spec = kernels.get_kernel("paged_attention")
     arrays, _ = spec.make_args({"slots": 5,
                                 "pages_per_slot": 4 if page_size == 16 else 2,
@@ -609,7 +307,7 @@ def test_packed_grouped_query_heads_match_the_oracle(page_size, block_k, h,
     q, k_pool, v_pool, tables, lengths = arrays
     out = paged_attention(q, k_pool, v_pool, tables, lengths,
                           block_k=block_k)
-    want = paged_attention_reference(q, k_pool, v_pool, tables, lengths)
+    want = paged_oracle(q, k_pool, v_pool, tables, lengths)
     onp.testing.assert_allclose(onp.asarray(out, "float32"),
                                 onp.asarray(want, "float32"),
                                 rtol=tol, atol=tol)
@@ -647,7 +345,8 @@ def test_packed_heads_walk_four_pages_a_block(monkeypatch):
 
 # -- the router, the stacked product --------------------------------------------
 
-def test_a_bias_changes_the_selection_and_no_weight(ref):
+def test_a_bias_changes_the_selection_and_no_weight(kit):
+    ref = kit.ref("mixed")
     rng = onp.random.RandomState(11)
     scores = jnp.asarray(1 / (1 + onp.exp(-rng.randn(60, 32))), jnp.float32)
     bias = jnp.asarray(0.3 * rng.randn(32), jnp.float32)
@@ -715,11 +414,11 @@ def test_the_stacked_product_is_the_loop_and_counts_alike():
 
 # -- the layout ---------------------------------------------------------------------
 
-def test_a_mixed_model_is_given_pages_for_its_attention_layers_alone(models):
-    model, cfg = models("float32")
+def test_a_mixed_model_is_given_pages_for_its_attention_layers_alone(kit):
+    model, cfg = kit.model("mixed")
     kinds = cfg["layer_types"]
     assert kinds.count("full_attention") == 2 and len(kinds) == 6
-    eng = _engine(model)
+    eng = engine(model)
     lanes = cfg["num_key_value_heads"] * (cfg["hidden_size"]
                                           // cfg["num_attention_heads"])
     for layer, kind in zip(eng.cache.pool, kinds):
@@ -749,11 +448,11 @@ def test_a_mixed_model_is_given_pages_for_its_attention_layers_alone(models):
 
 
 def _falcon():
-    return FalconH1(_config("falcon_h1_34b"), seed=1, dtype="float32")
+    return FalconH1(config("falcon_h1_34b"), seed=1, dtype="float32")
 
 
 def _axk1():
-    return AXK1(_config("axk1_519b"), seed=1, dtype="float32")
+    return AXK1(config("axk1_519b"), seed=1, dtype="float32")
 
 
 @pytest.mark.parametrize("build,per_layer,page_bytes,state_bytes", [
@@ -770,7 +469,7 @@ def _axk1():
 def test_the_three_models_there_were_get_the_pools_they_got(
         build, per_layer, page_bytes, state_bytes):
     model = build()
-    eng = _engine(model)
+    eng = engine(model)
     assert len(eng.cache.pool) == model.n_layers
     for layer in eng.cache.pool:
         assert [buf.shape for buf in layer] == per_layer
@@ -783,145 +482,29 @@ def test_the_three_models_there_were_get_the_pools_they_got(
 
 
 def test_a_model_with_state_in_some_layers_cannot_be_a_speculations_target(
-        models):
-    model, _ = models("float32")
+        kit):
+    model, _ = kit.model("mixed")
     with pytest.raises(ValueError, match="conv"):
         DecodeEngine(model, spec_k=2)
     with pytest.raises(NotImplementedError):
         model.verify_core(model.params, (), None, None, None, None)
 
 
-# -- through the scheduler and the server ------------------------------------------
-
-def test_a_slot_taken_again_starts_from_a_zero_tail(models, _clean):
-    """Two requests through ONE slot, one after the other: the second
-    answers as if the slot had never been used (its tail was zeroed at
-    admission), and the state's counters say so."""
-    model, _ = models("float32")
-    eng = _engine(model, max_slots=1, num_pages=8)
-    sch = DecodeScheduler(eng, start=False)
-    first, second = _tokens(19, seed=1), _tokens(7, seed=2)
-    f1 = sch.submit(first, max_new_tokens=4)
-    _run(sch)
-    tails = [layer[0] for layer, kind in zip(eng.cache.pool, model.kinds)
-             if kind == "conv"]
-    assert all(onp.asarray(t).any() for t in tails)     # left behind
-    f2 = sch.submit(second, max_new_tokens=4)
-    _run(sch)
-    assert f1.result(0) == model.greedy_reference(first, 4)
-    assert f2.result(0) == model.greedy_reference(second, 4)
-    st = eng.stats()
-    assert st["state_resets"] == 2 and st["state_slots_live"] == 0
-    assert sch.stats()["pages_used"] == 0
-
-
-def test_scheduler_matches_the_dense_oracle_and_never_recompiles(models,
-                                                                 _clean):
-    """Chained turns (the scheduler's) and synchronous ones (a step
-    dispatched and read at once, by hand) give the same tokens."""
-    model, _ = models("float32")
-    eng = _engine(model, max_slots=2)
-    # two slots: one multi-lane executable, two lanes of the full chunk
-    assert eng.warmup([8, CHUNK]) == ["decode", "state_edit", "state_reset",
-                                      "prefill_b8", "prefill_b16",
-                                      "prefill_b32"]
-    compiled = eng.compiles
-    sch = DecodeScheduler(eng, start=False)
-    prompts = [_tokens(n, seed=n) for n in (1, 16, 17, 40, 9)]
-    futs = [sch.submit(p, max_new_tokens=5) for p in prompts[:3]]
-    sch.step()
-    sch.step()
-    futs += [sch.submit(p, max_new_tokens=5) for p in prompts[3:]]
-    _run(sch)
-    assert eng.compiles == compiled and eng.stats()["chained_share"] > 0.5
-    sync = _engine(model, max_slots=2)
-    for p, f in zip(prompts, futs):
-        assert f.result(0) == model.greedy_reference(p, 5)
-        sync.acquire_slot(0, len(p) + 5)
-        tok = None
-        for start in range(0, len(p), CHUNK):
-            tok, = sync.prefill_chunks([(0, p[start:start + CHUNK], start)])
-        sync.activate_slot(0, tok, len(p))
-        out = [int(tok)]
-        for _ in range(4):
-            nxt, _ = sync.read(*sync.decode_step())
-            out.append(int(nxt[0]))
-        sync.release_slot(0)
-        assert out == f.result(0)
-    assert sync.stats()["chained_share"] == 0.0
-    assert sch.stats()["pages_used"] == 0
-
-
-def test_counters_ride_with_the_tokens(models, _clean):
-    model, cfg = models("float32")
-    eng = _engine(model, max_slots=2)
-    sch = DecodeScheduler(eng, start=False)
-    prompts = [_tokens(n, seed=n) for n in (11, 20)]
-    futs = [sch.submit(p, max_new_tokens=6) for p in prompts]
-    _run(sch)
-    for p, f in zip(prompts, futs):
-        assert f.result(0) == model.greedy_reference(p, 6)
-    c = eng.stats()["counters"]
-    assert set(c) == {"moe_expert_rows_mean", "moe_expert_rows_max",
-                      "moe_experts_idle_share", "moe_load_imbalance"}
-    # every expert is held: a live slot gives top-k of the experts a row
-    live = c["moe_expert_rows_mean"] * cfg["num_experts"] \
-        / cfg["num_experts_per_tok"]
-    assert 1.0 <= live <= 2.0
-    assert 1.0 <= c["moe_expert_rows_max"] <= 2.0
-    assert c["moe_load_imbalance"] >= 1.0
-    assert 0.5 <= c["moe_experts_idle_share"] <= 0.75
-
-
-def test_server_generate_answers_for_the_mixed_model(models, _clean):
-    from mxnet_tpu.gluon import nn
-    model, _ = models("float32")
-    mx.random.seed(0)
-    net = nn.Sequential()
-    net.add(nn.Dense(4, in_units=8))
-    net.initialize()
-    srv = ServingServer(net, engine_args={"example_shape": (8,),
-                                          "dtype": "float32"})
-    sch = DecodeScheduler(_engine(model), start=True)
-    srv.attach_decoder(sch)
-    p = _tokens(21, seed=8)
-    assert srv.generate(p, max_new_tokens=4) == model.greedy_reference(p, 4)
-    srv.stop(drain=True)
-    assert sch.closed
-
-
 # -- the configuration ------------------------------------------------------------
 
-def test_a_config_that_asks_for_what_is_not_there_is_refused():
-    for over in ({"conv_bias": True}, {"tie_embedding": False},
-                 {"layer_types": ["conv"] * 5},
-                 {"layer_types": ["conv"] * 5 + ["sliding_attention"]},
-                 {"num_key_value_heads": 3}):
-        with pytest.raises(ValueError):
-            LFM2(_config(**over), abstract=True)
-    cfg = _config()
-    del cfg["conv_L_cache"]
-    with pytest.raises(ValueError, match="lacks"):
-        LFM2(cfg, abstract=True)
-
-
-def test_abstract_model_and_param_count_by_hand():
-    """The published widths as shapes only, against the benchmark's own
-    count and the sum written out in its configuration."""
-    with open(REPO / "chipbench" / "configs" / "lfm2_8b_a1b.json") as f:
-        cfg = json.load(f)
+def test_param_count_by_hand():
+    """The published widths as shapes only: the benchmark's count of the
+    configuration, by layer, of the whole published stack, and the
+    shapes of a layer of each kind (the total against the abstract
+    model: ``test_model_contract``)."""
+    cfg = published("lfm2_8b_a1b")
     assert cfg["reduced"] == ["num_hidden_layers", "layer_types"]
     assert cfg["layer_types"] == cfg["layer_types_published"][:16]
     assert (cfg["num_experts"], cfg["num_experts_per_tok"],
             cfg["vocab_size"]) == (32, 4, 65536)
     model = LFM2(cfg, abstract=True)
-    leaves = jax.tree_util.tree_leaves(model.params)
-    assert all(isinstance(l, jax.ShapeDtypeStruct) for l in leaves)
-    fam = _load(REPO / "chipbench" / "models" / "lfm2.py", "lfm2_family")
-    # the tied head is held twice and counted once
-    n = sum(int(onp.prod(l.shape)) for l in leaves) \
-        - int(onp.prod(model.params["head"].shape))
-    assert n == fam.param_count(cfg) == cfg["parameters"] == 5_399_129_024
+    fam = load("chipbench/models/lfm2.py", "lfm2_family")
+    assert fam.param_count(cfg) == cfg["parameters"] == 5_399_129_024
     assert fam.layer_param_count(cfg, 0) == 60_827_648
     assert fam.layer_param_count(cfg, 3) == 369_174_560
     assert fam.layer_param_count(cfg, 2) == 362_877_088

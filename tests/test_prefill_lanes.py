@@ -8,9 +8,6 @@ one lane at a time, which is the ``lanes == 1`` case of the same code and
 what the models' own files pin to their dense oracles; a padding lane
 writes nothing; and the engine's rule for how many lanes a dispatch has.
 """
-import json
-import pathlib
-
 import numpy as onp
 import pytest
 
@@ -18,30 +15,10 @@ import jax
 import jax.numpy as jnp
 
 import mxnet_tpu as mx  # noqa: F401  (registers ops + kernel specs)
-from mxnet_tpu.serving import (AXK1, LFM2, DecodeEngine, DecodeModel,
-                               FalconH1)
+from mxnet_tpu.serving import DecodeEngine, DecodeModel
 from mxnet_tpu.serving.decode.paged_kv import PageAllocator
 
-REPO = pathlib.Path(__file__).resolve().parent.parent
-CHUNK, VOCAB = 16, 128
-FAMILIES = ["transformer", "hybrid", "latent", "mixed"]
-
-
-def _rehearsal(name):
-    with open(REPO / "chipbench" / "configs" / f"{name}.json") as f:
-        cfg = json.load(f)
-    cfg.update(cfg.pop("rehearsal"))
-    return cfg
-
-
-def _build(family):
-    if family == "transformer":
-        return DecodeModel(VOCAB, dim=32, n_heads=4, n_layers=2, seed=0)
-    if family == "hybrid":
-        return FalconH1(_rehearsal("falcon_h1_34b"), seed=5, dtype="float32")
-    if family == "latent":
-        return AXK1(_rehearsal("axk1_519b"), seed=5, dtype="float32")
-    return LFM2(_rehearsal("lfm2_8b_a1b"), seed=5, dtype="float32")
+from decode_families import ALL, CHUNK, build, engine, tokens
 
 
 @pytest.fixture(scope="module")
@@ -52,9 +29,8 @@ def engines():
 
     def get(family):
         if family not in made:
-            made[family] = DecodeEngine(
-                _build(family), max_slots=4, page_size=8, pages_per_slot=8,
-                num_pages=32, prefill_chunk=CHUNK, prefill_floor=8)
+            made[family] = engine(build(family), max_slots=4,
+                                  num_pages=32)
         eng = made[family]
         for slot in range(eng.max_slots):
             eng.release_slot(slot)
@@ -65,11 +41,6 @@ def engines():
         return eng
 
     return get
-
-
-def _tokens(n, seed):
-    return [int(t) for t in
-            onp.random.RandomState(seed).randint(0, VOCAB, size=n)]
 
 
 def _feed(eng, prompts, turns, together):
@@ -110,14 +81,14 @@ CASES = {
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
-@pytest.mark.parametrize("family", FAMILIES)
+@pytest.mark.parametrize("family", ALL)
 def test_lanes_leave_what_one_lane_at_a_time_leaves(engines, family, case):
     """The same first tokens and, to rounding, the same pages, state
     and convolution tails, whether a turn's chunks ride as lanes of one
     dispatch or each in a dispatch of its own; and every prompt's first
     token is the dense oracle's."""
     lengths, turns = CASES[case]
-    prompts = [_tokens(n, seed=7 * n + i) for i, n in enumerate(lengths)]
+    prompts = [tokens(n, seed=7 * n + i) for i, n in enumerate(lengths)]
     want_toks, want_pool, want_runs = _feed(engines(family), prompts, turns,
                                             together=False)
     eng = engines(family)
@@ -140,7 +111,7 @@ def test_lanes_leave_what_one_lane_at_a_time_leaves(engines, family, case):
             at += 1
 
 
-@pytest.mark.parametrize("family", FAMILIES)
+@pytest.mark.parametrize("family", ALL)
 def test_a_padding_lane_writes_nothing(engines, family):
     """A dispatch of padding alone (what ``warmup`` stages) hands back
     the cache bit for bit: no page, no state, no convolution tail

@@ -1,13 +1,16 @@
 """The main path's Pallas kernels, compiled (not interpreted) for a
-described TPU v5e at the shapes ``chip_smoke.py`` runs, and the decode
-executables at the benchmark cell's pool — no chip needed.
+described TPU v5e at the shapes ``chip_smoke.py`` runs, and the chained
+decode executable at the two decode cells' whole geometry — no chip
+needed (every model's executables at its cell's pool:
+``test_decode_in_place.py``).
 
 Interpret mode accepts what the chip's compiler refuses (a batched
 matmul with no free lhs dim, a float iota, a block past the scoped-VMEM
 limit), so every other kernel test in the suite can pass on a kernel
 that cannot start on a TPU.  These cases ask libtpu's compiler itself.
 The kernels choose interpret mode from ``jax.default_backend()``; the
-test steers that call, the program has no option for it.
+test steers that call (``described_tpu.py``), the program has no option
+for it.
 """
 import os
 import re
@@ -16,38 +19,11 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import SingleDeviceSharding
+
+from described_tpu import compile_for_tpu, v5e  # noqa: F401  (fixtures)
 
 # the decode model of chip_smoke.py: 8 heads x 64, bf16, 8 slots
 H, D, SLOTS = 8, 64, 8
-
-
-@pytest.fixture(scope="module")
-def v5e():
-    """Sharding on one described (not attached) v5e device."""
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    from jax.experimental import topologies
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:      # no libtpu, or it cannot describe one
-        pytest.skip(f"cannot describe a v5e topology here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
-
-
-@pytest.fixture(autouse=True)
-def _compile_for_tpu(monkeypatch):
-    """Kernels see a TPU backend; the persistent compile cache is off
-    (an entry compiled for a described device cannot be read back
-    without a chip, and the next run would warn)."""
-    from jax.experimental.compilation_cache import compilation_cache as cc
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", was)
-    cc.reset_cache()
 
 
 def _compile(fn, sharding, *specs):
@@ -343,333 +319,6 @@ def test_layer_norm_residual_compiles(v5e):
              v5e, x, x, g, g)
 
 
-# -- the decode plane's executables at the benchmark cell's pool --------------
-# gpt2_decode_chat: 6144 pages x 16 x (16 heads x 64), bf16, 96 slots x 64
-# pages; 2 of its 24 layers, since every layer's buffers are treated alike.
-
-def _resident(spec, slots, pps):
-    """The engine's resident decode state: tokens, positions, active,
-    page tables."""
-    return (spec((slots,), "int32"), spec((slots,), "int32"),
-            spec((slots,), "bool"), spec((slots, pps), "int32"))
-
-
-def _lower_chained_decode(mdl, params, pool, state):
-    """``decode`` as ``DecodeEngine`` compiles it on a TPU: the model's
-    core inside the engine's wrapper, pool and resident state donated."""
-    from mxnet_tpu.serving.decode import engine as E
-    return jax.jit(lambda *a: E._chained_decode_core(mdl, *a),
-                   donate_argnums=(1, 2)).lower(params, pool, state)
-
-
-def _lower_prefill(mdl, params, pool, spec, pps, key, chunk):
-    """``prefill_b<rows>`` as ``DecodeEngine`` compiles it on a TPU: the
-    model's lane-form core inside the engine's wrapper, which unpacks
-    the one staged array, the pool donated.  More rows than the chunk
-    are lanes of the full chunk; fewer are one lane's bucket."""
-    from mxnet_tpu.serving.decode import engine as E
-    rows = int(key.rsplit("b", 1)[1])
-    lanes = max(1, rows // chunk)
-    stateful = any(state for _, state in mdl.cache_layout)
-    return jax.jit(lambda *a: E._prefill_core(mdl, stateful, pps, *a),
-                   donate_argnums=(1,)).lower(
-        params, pool, spec((lanes, rows // lanes + 3 + pps), "int32"))
-
-
-def _lower_turn(mdl, params, pool, state, spec, pps, key, chunk):
-    """``decode_fill_b<rows>`` as ``DecodeEngine`` compiles it on a TPU:
-    the model's turn core inside the engine's wrapper, the decode step's
-    resident state and lanes of the full chunk staged as a prefill
-    dispatch stages them, pool and state donated."""
-    from mxnet_tpu.serving.decode import engine as E
-    lanes = int(key.rsplit("b", 1)[1]) // chunk
-    return jax.jit(lambda *a: E._turn_core(mdl, pps, *a),
-                   donate_argnums=(1, 2)).lower(
-        params, pool, state, spec((lanes, chunk + 3 + pps), "int32"))
-
-
-@pytest.mark.parametrize("key", ["decode", "prefill_b128", "prefill_b512"])
-def test_decode_executables_update_the_pool_in_place(v5e, key):
-    """``memory_analysis`` of the compiled executable: the whole pool is
-    aliased to the outputs, and the temporaries hold no copy of even one
-    layer buffer (a slice in front of the Mosaic call, or a buffer set
-    back after the scatter, is 201 MB each).  ``prefill_b512`` is the
-    multi-lane executable of a paged-only model: four slots' chunks of
-    128 in one dispatch."""
-    from mxnet_tpu.serving import DecodeModel
-    layers, slots, pps = 2, 96, 64
-    mdl = DecodeModel(512, dim=1024, n_heads=16, n_layers=layers,
-                      mlp_ratio=4, dtype="bfloat16")
-
-    def spec(shape, dtype="bfloat16"):
-        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=v5e)
-
-    params = jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype),
-                                    mdl.params)
-    buf = spec((6144, 16, 1024))
-    pool = tuple((buf, buf) for _ in range(layers))
-    # donated as DecodeEngine._get_exec donates them on a TPU
-    if key == "decode":
-        lowered = _lower_chained_decode(mdl, params, pool,
-                                        _resident(spec, slots, pps))
-    else:
-        lowered = _lower_prefill(mdl, params, pool, spec, pps, key, 128)
-    mem = lowered.compile().memory_analysis()
-    one = buf.size * buf.dtype.itemsize          # 201 MB
-    state = mem.alias_size_in_bytes - 2 * layers * one
-    assert 0 <= state < 2 ** 16 and (state > 0) == (key == "decode")
-    assert mem.temp_size_in_bytes < one, mem.temp_size_in_bytes
-    # and no more of them than before the kernel took the pools as whole
-    # operands in HBM (PR 30): 3,064,320 and 3,354,624 bytes at 2 layers
-    assert mem.temp_size_in_bytes < 4 * 2 ** 20, mem.temp_size_in_bytes
-
-
-# falcon_h1_decode_chat: 768 pages x 128 x (4 KV heads x 128) bf16 and,
-# per layer, 96 slots of (32 x 128 x 256) + (3 x 5120) float32 state;
-# 2 of its 6 layers, the published widths and the whole vocabulary (the
-# parameters are shapes: nothing of their 4.4 GB is allocated).
-
-@pytest.mark.parametrize("key", ["decode", "prefill_b128", "prefill_b16",
-                                 "prefill_b256", "prefill_b512",
-                                 "decode_fill_b128", "decode_fill_b256",
-                                 "state_reset"])
-def test_hybrid_executables_update_kv_and_state_in_place(v5e, key):
-    """K/V and both state buffers are aliased to the outputs, and the
-    temporaries stay under one state-space buffer (403 MB): no
-    executable holds a copy of a buffer it was donated, the multi-lane
-    prefill executables (two and four slots' chunks of 128 in one
-    dispatch: their states read a slice a lane, written back in one
-    scatter) no more than the one-lane ones.  A gather of the lanes'
-    states had the TPU compiler copy the 403 MB buffer whole, a layer
-    (PERF.md section 6, PR 38).  The decode step with one or two lanes
-    inside it (``decode_fill``) reads the lanes' states out of what the
-    state update left and scatters them back into it: no copy either,
-    and ONE product a weight matrix for the slots' rows and the lanes'
-    together."""
-    import json
-    from mxnet_tpu.serving import FalconH1
-    from mxnet_tpu.serving.decode import engine as E
-    with open(os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "chipbench", "configs",
-            "falcon_h1_34b.json")) as f:
-        cfg = dict(json.load(f), num_hidden_layers=2)
-    mdl = FalconH1(cfg, abstract=True)
-
-    def spec(shape, dtype="bfloat16"):
-        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=v5e)
-
-    params = jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype),
-                                    mdl.params)
-    kv = spec((FH_SLOTS * FH_PAGES, FH_PAGE, 4 * 128))
-    ssm_buf = spec((FH_SLOTS, 32, 128, 256), "float32")
-    conv_buf = spec((FH_SLOTS, 3, 5120), "float32")
-    assert tuple(sh for _, sh, _ in mdl.state_spec) == (
-        ssm_buf.shape[1:], conv_buf.shape[1:])
-    pool = tuple((kv, kv, ssm_buf, conv_buf) for _ in range(2))
-    nbytes = lambda a: a.size * a.dtype.itemsize           # noqa: E731
-    if key == "state_reset":
-        donated = tuple(layer[2:] for layer in pool)
-        lowered = jax.jit(lambda *a: E._state_reset_core(*a),
-                          donate_argnums=(0,)).lower(
-                              donated, spec((), "int32"))
-    elif key == "decode":
-        donated = pool
-        lowered = _lower_chained_decode(
-            mdl, params, pool, _resident(spec, FH_SLOTS, FH_PAGES))
-    elif key.startswith("decode_fill"):
-        donated = pool
-        lowered = _lower_turn(mdl, params, pool,
-                              _resident(spec, FH_SLOTS, FH_PAGES), spec,
-                              FH_PAGES, key, 128)
-    else:
-        donated = pool
-        lowered = _lower_prefill(mdl, params, pool, spec, FH_PAGES, key, 128)
-    compiled = lowered.compile()
-    mem = compiled.memory_analysis()
-    state = mem.alias_size_in_bytes - sum(
-        nbytes(b) for layer in donated for b in layer)
-    assert 0 <= state < 2 ** 16
-    assert (state > 0) == key.startswith("decode")
-    assert mem.temp_size_in_bytes < nbytes(ssm_buf), mem.temp_size_in_bytes
-    if key.startswith("decode_fill"):
-        import re
-        # no copy of a state buffer, nor of anything near one: 39.6 MB
-        # (decode) and 17.7 (prefill_b256) at the cell's six layers
-        assert mem.temp_size_in_bytes < 100e6, mem.temp_size_in_bytes
-        lanes = int(key.rsplit("b", 1)[1]) // 128
-        text = compiled.as_text()
-        products = re.findall(r"= \S+ (?:convolution|dot)\(", text)
-        # nine weight matrices a layer for all rows, six a lane and layer
-        # for what is a slot's own, and one head over slots and lanes
-        assert len(products) == 2 * (9 + 6 * lanes) + 1, len(products)
-        calls = re.findall(r'^\s*(?:ROOT )?%(\S+) = .*custom_call_target='
-                           r'"tpu_custom_call"', text, re.M)
-        # per layer: the decode rows' state update and paged attention
-        assert sum("mxtpu_ssm_update" in c for c in calls) == 2
-        assert sum("mxtpu_paged_attention" in c for c in calls) == 2
-    if key.startswith("prefill"):
-        # nor a quarter of one: 17.8 MB (b128), 11.2 (b16), 33.9 (b256),
-        # 73.0 (b512) at these two layers and the whole vocabulary
-        assert mem.temp_size_in_bytes < 96e6, mem.temp_size_in_bytes
-        # ONE product a weight matrix whatever the lanes (nine a layer:
-        # q, k, v, o, the mixer's in and out, the MLP's three), six a
-        # lane and layer for what is a slot's own (two of its attention,
-        # four of its chunked scan), and the head: a product over the
-        # lanes' last rows, a multiply-reduce for one row
-        import re
-        lanes = max(1, int(key.rsplit("b", 1)[1]) // 128)
-        products = re.findall(r"= \S+ (?:convolution|dot)\(",
-                              compiled.as_text())
-        assert len(products) == 2 * (9 + 6 * lanes) + (lanes > 1)
-    if key == "decode":
-        import re
-        calls = re.findall(r'^\s*(?:ROOT )?%(\S+) = .*custom_call_target='
-                           r'"tpu_custom_call"', compiled.as_text(), re.M)
-        # per layer: rope on q and on k, paged attention, the state update
-        assert len(calls) == 4 * 2 and all("mxtpu_" in c for c in calls)
-        assert sum("mxtpu_ssm_update" in c for c in calls) == 2
-
-
-# axk1_decode_reasoning: one latent page buffer a layer, 4096 pages x 128
-# x 640 lanes bf16 (671 MB); 2 of its 7 layers, the dense one and an
-# expert layer with its 12 held experts, the published widths and the
-# vocabulary's slice (shapes only).  All seven layers, compiled here by
-# hand (PERF.md section 4, PR 33): arguments 14.380 GB of which the
-# pool's 4.698 GB is aliased; temporaries 99.6 MB (decode), 152.0 MB
-# (prefill b256), 65.0 MB (b32).  ``prefill_b512`` is two lanes of 256
-# (PR 38): 161.2 MB at these two layers against 103.8 at one lane.
-
-@pytest.mark.parametrize("key,temp_mb", [("decode", 64), ("prefill_b256", 140),
-                                         ("prefill_b32", 48),
-                                         ("prefill_b512", 280)])
-def test_latent_executables_update_the_cache_in_place(v5e, key, temp_mb):
-    """The latent pages are aliased to the outputs whole, the
-    temporaries hold no copy of a layer's buffer and stay near what
-    they were when the cell was added (47.7, 103.7 and 35.1 MB at these
-    two layers), and the decode step's attention is the named kernel,
-    once a layer."""
-    import json
-    import re
-    from mxnet_tpu.serving import AXK1
-    bench = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "chipbench")
-    with open(os.path.join(bench, "configs", "axk1_519b.json")) as f:
-        cfg = dict(json.load(f), num_hidden_layers=2)
-    with open(os.path.join(bench, "workloads",
-                           "axk1_decode_reasoning.json")) as f:
-        geo = json.load(f)["engine"]
-    mdl = AXK1(cfg, abstract=True)
-    slots, pps = geo["max_slots"], geo["pages_per_slot"]
-
-    def spec(shape, dtype="bfloat16"):
-        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=v5e)
-
-    params = jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype),
-                                    mdl.params)
-    buf = spec((geo["num_pages"], geo["page_size"], 640))
-    assert mdl.page_widths == (640,)
-    pool = ((buf,), (buf,))
-    one = buf.size * buf.dtype.itemsize                  # 671 MB
-    if key == "decode":
-        lowered = _lower_chained_decode(mdl, params, pool,
-                                        _resident(spec, slots, pps))
-    else:
-        lowered = _lower_prefill(mdl, params, pool, spec, pps, key,
-                                 geo["prefill_chunk"])
-    compiled = lowered.compile()
-    mem = compiled.memory_analysis()
-    state = mem.alias_size_in_bytes - 2 * one
-    assert 0 <= state < 2 ** 16 and (state > 0) == (key == "decode")
-    assert mem.temp_size_in_bytes < temp_mb * 1e6, mem.temp_size_in_bytes
-    calls = re.findall(r'^\s*(?:ROOT )?%(\S+) = .*custom_call_target='
-                       r'"tpu_custom_call"', compiled.as_text(), re.M)
-    if key == "decode":
-        assert len(calls) == 2
-        assert all("mxtpu_latent_attention" in c for c in calls)
-    else:       # a chunk walks the slot's live pages in XLA
-        assert not calls
-
-
-# lfm2_decode_reasoning: a cache laid out by layer.  The first 4 of its 16
-# layers, one whole period at the published widths (shapes only): two
-# dense convolution layers, a routed attention layer, a routed
-# convolution layer, all 32 experts stacked.  K and V pages in the one
-# attention layer, 3072 pages x 128 x 512 lanes bf16 (403 MB a buffer);
-# a tail of 192 slots x 2 x 2048 float32 (3.1 MB) in the other three.
-# All sixteen layers, compiled here by hand (PERF.md section 4, PR 35):
-# arguments 14.326 GB of which the cache's 3.259 GB is aliased;
-# temporaries 51.8 MB (decode), 18.4 MB (prefill b256), 17.1 MB (b32); a
-# chunk that gathered its slot's whole table of 4,096 positions for one
-# softmax kept 457.2 MB (b256).  ``prefill_b512`` is two lanes of 256
-# (PR 38): 64.1 MB at these four layers (5.8 at one lane), the stacked
-# experts' rows; while two lanes' walks read the page buffers themselves
-# the compiler copied K and V into another layout, 818 MB.
-
-@pytest.mark.parametrize("key,temp_mb", [("decode", 64), ("prefill_b256", 32),
-                                         ("prefill_b32", 32),
-                                         ("prefill_b512", 96)])
-def test_mixed_layer_executables_update_the_cache_in_place(v5e, key,
-                                                           temp_mb):
-    """Pages for the attention layer alone and the other layers' tails
-    are the arguments beside the weights; all of them are aliased to
-    the outputs whole; the temporaries hold no copy of a page buffer;
-    the decode step's attention is the named kernel, once an attention
-    layer."""
-    import json
-    import re
-    from mxnet_tpu.serving import LFM2
-    bench = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "chipbench")
-    with open(os.path.join(bench, "configs", "lfm2_8b_a1b.json")) as f:
-        cfg = json.load(f)
-    cfg = dict(cfg, num_hidden_layers=4, layer_types=cfg["layer_types"][:4])
-    with open(os.path.join(bench, "workloads",
-                           "lfm2_decode_reasoning.json")) as f:
-        geo = json.load(f)["engine"]
-    mdl = LFM2(cfg, abstract=True)
-    slots, pps = geo["max_slots"], geo["pages_per_slot"]
-
-    def spec(shape, dtype="bfloat16"):
-        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype),
-                                    sharding=v5e)
-
-    params = jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype),
-                                    mdl.params)
-    # the pool as the cache builds it from the model's layout
-    pool = tuple(
-        tuple(spec((geo["num_pages"], geo["page_size"], w)) for w in widths)
-        + tuple(spec((slots,) + tuple(sh), dt) for _, sh, dt in state)
-        for widths, state in mdl.cache_layout)
-    assert [len(layer) for layer in pool] == [1, 1, 2, 1]
-    nbytes = lambda a: a.size * a.dtype.itemsize           # noqa: E731
-    page_buf, tail = nbytes(pool[2][0]), nbytes(pool[0][0])
-    assert (page_buf, tail) == (3072 * 128 * 512 * 2, 192 * 2 * 2048 * 4)
-    cache = 2 * page_buf + 3 * tail
-    weights = sum(nbytes(a) for a in jax.tree_util.tree_leaves(params))
-    if key == "decode":
-        lowered = _lower_chained_decode(mdl, params, pool,
-                                        _resident(spec, slots, pps))
-    else:
-        lowered = _lower_prefill(mdl, params, pool, spec, pps, key,
-                                 geo["prefill_chunk"])
-    compiled = lowered.compile()
-    mem = compiled.memory_analysis()
-    # arguments: the weights, the cache, and a few small arrays
-    assert 0 <= mem.argument_size_in_bytes - weights - cache < 2 ** 16
-    state = mem.alias_size_in_bytes - cache
-    assert 0 <= state < 2 ** 16 and (state > 0) == (key == "decode")
-    assert mem.temp_size_in_bytes < temp_mb * 1e6, mem.temp_size_in_bytes
-    assert mem.temp_size_in_bytes < page_buf
-    calls = re.findall(r'^\s*(?:ROOT )?%(\S+) = .*custom_call_target='
-                       r'"tpu_custom_call"', compiled.as_text(), re.M)
-    assert all("mxtpu_" in c for c in calls), calls
-    # the one attention layer: rope on q and on k, then the paged
-    # kernel in the decode step; a chunk gathers its pages in XLA
-    assert sum("mxtpu_paged_attention" in c for c in calls) \
-        == (1 if key == "decode" else 0)
-    assert sum("mxtpu_rope" in c for c in calls) == 2
-
-
 # -- the chained decode executable at the two decode cells' whole geometry ----
 
 @pytest.mark.parametrize("cell,pool_bytes", [
@@ -684,6 +333,7 @@ def test_chained_decode_aliases_the_pool_and_the_resident_state(
     buffer, and ``state_edit`` rewrites the state where it is."""
     import json
     import re
+    from decode_families import lower, resident
     from mxnet_tpu.serving import DecodeModel, FalconH1
     from mxnet_tpu.serving.decode import engine as E
     bench = os.path.join(os.path.dirname(os.path.dirname(
@@ -717,8 +367,9 @@ def test_chained_decode_aliases_the_pool_and_the_resident_state(
                  for _ in range(layers))
     nbytes = lambda a: a.size * a.dtype.itemsize           # noqa: E731
     assert sum(nbytes(b) for layer in pool for b in layer) == pool_bytes
-    state = _resident(spec, slots, pps)
-    compiled = _lower_chained_decode(mdl, params, pool, state).compile()
+    state = resident(spec, slots, pps)
+    compiled = lower(mdl, "decode", params, pool, spec, slots, pps,
+                     geo["prefill_chunk"])[0].compile()
     mem = compiled.memory_analysis()
     # small arrays are padded to a tile: at least their bytes, under 64 KiB
     resident = mem.alias_size_in_bytes - pool_bytes

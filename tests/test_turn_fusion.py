@@ -1,6 +1,6 @@
 """A decode step carries a prefill dispatch's lanes: a turn that has
 slots decoding and slots filling reads the weights ONCE for both
-(``decode_fill_b<rows>``), for a model that offers ``turn_core``.
+(``decode_fill_b<rows>``), for a model that supplies ``turn_logits``.
 
 Held here, for ``FalconH1`` at CPU size: a fused dispatch leaves the
 pages, the recurrent state, the convolution tails, the decode tokens and
@@ -8,12 +8,10 @@ the lanes' first tokens that the decode step followed by the same chunks'
 prefill dispatch leave (one lane, two, a short final chunk padded to the
 full chunk's bucket, a padding lane); a padding lane writes nothing; a
 scheduler that fuses gives every request the tokens of one whose model
-has no ``turn_core``, which never dispatches a ``decode_fill``; and the
+has no ``turn_logits``, which never dispatches a ``decode_fill``; and the
 ``fused`` counter counts what was dispatched.
 """
 import functools
-import json
-import pathlib
 
 import numpy as onp
 import pytest
@@ -23,39 +21,29 @@ import jax.numpy as jnp
 
 import mxnet_tpu as mx  # noqa: F401  (registers ops + kernel specs)
 from mxnet_tpu import tracing
-from mxnet_tpu.serving import DecodeEngine, DecodeScheduler, FalconH1
+from mxnet_tpu.serving import DecodeScheduler, FalconH1
 from mxnet_tpu.serving.decode import engine as E
 from mxnet_tpu.serving.decode.engine import DecodePlaneModel
 from mxnet_tpu.serving.decode.paged_kv import PageAllocator
 
-REPO = pathlib.Path(__file__).resolve().parent.parent
-CHUNK = 16
+import decode_families as families
+from decode_families import CHUNK, build, config, tokens
 
-
-def _config():
-    with open(REPO / "chipbench" / "configs" / "falcon_h1_34b.json") as f:
-        cfg = json.load(f)
-    cfg.update(cfg.pop("rehearsal"))
-    return cfg
+# four slots: up to four lanes of the full chunk in a turn
+FOUR = dict(max_slots=4, num_pages=32)
 
 
 class _Unfused(FalconH1):
-    """The same model with no ``turn_core``: its chunks go through
+    """The same model with no ``turn_logits``: its chunks go through
     ``prefill_chunks`` whatever the turn holds."""
-    turn_core = DecodePlaneModel.turn_core
-
-
-def _engine(model, **kw):
-    return DecodeEngine(model, **{
-        **dict(max_slots=4, page_size=8, pages_per_slot=8, num_pages=32,
-               prefill_chunk=CHUNK, prefill_floor=8), **kw})
+    turn_logits = DecodePlaneModel.turn_logits
 
 
 @pytest.fixture(scope="module")
 def engine():
     """One engine for the whole file: its executables are compiled once,
     and every case starts it from an empty cache and resident state."""
-    eng = _engine(FalconH1(_config(), seed=5, dtype="float32"))
+    eng = families.engine(build("hybrid"), **FOUR)
 
     def fresh():
         for slot in range(eng.max_slots):
@@ -71,11 +59,6 @@ def engine():
         return eng
 
     return fresh
-
-
-def _tokens(n, seed):
-    return [int(t) for t in
-            onp.random.RandomState(seed).randint(0, 128, size=n)]
 
 
 # name -> (prompt lengths of the filling slots 1.., the tokens of each
@@ -99,14 +82,14 @@ def _turn(eng, case, fused):
     ``prefill_chunks(chunks)``.  The decode tokens, the lanes' tokens,
     the cache as host arrays, and the executables' keys in order."""
     lengths, fed = CASES[case]
-    prompt = _tokens(20, seed=1)
+    prompt = tokens(20, seed=1)
     eng.acquire_slot(0, 30)
     tok, = eng.prefill_chunks([(0, prompt[:CHUNK], 0)])
     tok, = eng.prefill_chunks([(0, prompt[CHUNK:], CHUNK)])
     eng.activate_slot(0, tok, len(prompt))
     chunks = []
     for slot, (n, done) in enumerate(zip(lengths, fed), start=1):
-        p = _tokens(n, seed=7 * n + slot)
+        p = tokens(n, seed=7 * n + slot)
         eng.acquire_slot(slot, n + 4)
         if done:
             eng.prefill_chunks([(slot, p[:done], 0)])
@@ -173,7 +156,7 @@ def test_a_padding_lane_writes_nothing(engine, lanes):
 
 def test_a_step_refuses_chunks_it_cannot_carry(engine):
     """A decoding slot's chunk, more chunks than lanes, or a model with
-    no ``turn_core``: the step refuses them before dispatching."""
+    no ``turn_logits``: the step refuses them before dispatching."""
     eng = engine()
     for slot in range(3):
         eng.acquire_slot(slot, 8)
@@ -182,7 +165,8 @@ def test_a_step_refuses_chunks_it_cannot_carry(engine):
         eng.decode_step([(0, [1, 2], 2)])
     with pytest.raises(ValueError):
         eng.decode_step([(1, [1], 0)] * (eng.prefill_lanes + 1))
-    unfused = _engine(_Unfused(_config(), seed=5, dtype="float32"))
+    unfused = families.engine(_Unfused(config("falcon_h1_34b"), seed=5,
+                                       dtype="float32"), **FOUR)
     assert eng.fuses and not unfused.fuses
     unfused.acquire_slot(1, 8)
     with pytest.raises(ValueError):
@@ -195,7 +179,7 @@ def _schedule(eng, monkeypatch):
     from 1 on, the profiler's capture on for turns 4 to 9.  Every
     request's tokens, and the executables dispatched, by key."""
     rs = onp.random.RandomState(40)
-    prompts = [_tokens(int(n), seed=i)
+    prompts = [tokens(int(n), seed=i)
                for i, n in enumerate(rs.randint(3, 45, size=9))]
     max_new = [int(n) for n in rs.randint(1, 10, size=9)]
     due = sorted(int(t) for t in rs.randint(0, 16, size=9))
@@ -219,10 +203,10 @@ def _schedule(eng, monkeypatch):
     return [f.result(0) for f in futs], keys, records
 
 
-def test_the_scheduler_fuses_and_every_request_gets_the_same_tokens(
+def test_the_scheduler_fuses_and_every_request_gets_the_sametokens(
         monkeypatch):
     """A scheduler over an engine that fuses gives every request the
-    tokens of one over the same model with no ``turn_core``, and no
+    tokens of one over the same model with no ``turn_logits``, and no
     executable is compiled after warm-up; the unfused engine dispatches
     no ``decode_fill``.  ``stats()["prefill"]`` (and its traced twin:
     what was dispatched under a capture) counts every chunk dispatch in
@@ -230,8 +214,8 @@ def test_the_scheduler_fuses_and_every_request_gets_the_same_tokens(
     ratio; the step record's ``prefill_fused`` sums to ``fused``."""
     got = {}
     for kind, cls in (("fused", FalconH1), ("unfused", _Unfused)):
-        eng = _engine(cls(_config(), seed=5, dtype="float32"), max_slots=3,
-                      num_pages=24)
+        eng = families.engine(cls(config("falcon_h1_34b"), seed=5,
+                                  dtype="float32"))
         warm = eng.warmup([8, 16])
         assert [k for k in warm if k.startswith("decode_fill")] == (
             ["decode_fill_b16", "decode_fill_b32"] if kind == "fused"
@@ -267,8 +251,8 @@ def test_a_kernel_is_traced_once_for_every_layer_and_executable():
     dispatch of the full chunk shares its lanes' scan (the same
     bucket): what keeps two more executables from costing a warm start
     every layer's tracing again (PERF.md, PR 40)."""
-    model = FalconH1(_config(), seed=5, dtype="float32")
-    eng = _engine(model)
+    model = build("hybrid")
+    eng = families.engine(model, **FOUR)
     args = (model.params, eng.cache.pool, eng._resident)
     staged = eng._stage(eng.cache, (), 2, CHUNK)
     decode = jax.make_jaxpr(functools.partial(E._chained_decode_core,
